@@ -26,10 +26,10 @@ __all__ = ["ClusterSpec", "ModelSpec", "Plan", "OptimizationTuner",
            "DEFAULT_CALIBRATION_PATH"]
 
 # On-target calibration artifact (written by scripts/tuner_calibrate_tpu.py
-# during an on-chip harvest window; committed so every later session's
-# estimates are grounded in measured hardware ratios rather than the
-# analytic roofline alone — reference: tuner/profiler.py profiles
-# candidate configs on the actual device).
+# on a chip; committed so every later session's estimates are grounded in
+# measured hardware ratios rather than the analytic roofline alone —
+# reference: tuner/profiler.py profiles candidate configs on the actual
+# device).
 DEFAULT_CALIBRATION_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "calibration", "tuner_tpu.json")

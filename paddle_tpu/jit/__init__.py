@@ -657,6 +657,30 @@ _verbosity = 0
 
 __all__ += ["enable_to_static", "set_code_level", "set_verbosity"]
 
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache somewhere that survives
+    the process, and return the directory in effect.  Entry points
+    (chip_smoke.py, bench.py) call this before their first trace;
+    `import paddle_tpu` does not.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: nothing is set here — JAX reads that
+    variable itself, and whoever set it owns the location.  Unset: the
+    cache goes to ``<checkout>/.jax_cache``, a FIXED path (the path is
+    part of the cache key, so a tempdir or a per-run name never hits)."""
+    import os
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+__all__ += ["enable_compile_cache"]
+
 # Staged lists: value-semantics fixed-capacity lists for code that appends
 # under converted (tensor-dependent) control flow — see
 # dy2static/staged_array.py (reference convert_operators.py:117

@@ -17,9 +17,9 @@ Two implementations behind one entry point, selected like
 
 - **Pallas kernel** (TPU, or CPU under ``PTPU_PALLAS_INTERPRET=1``), the
   decode (S_q = 1) shape: one program per row streams ONLY the row's
-  ``ceil(len / block_size)`` physical blocks from HBM (double-buffered
-  DMA, online softmax — the XLA fallback touches all ``max_blocks``
-  gathered rows), fuses the new token's quantize+scatter as a
+  ``ceil(len / block_size)`` physical blocks from HBM (two blocks in
+  flight per loop iteration, online softmax — the XLA fallback touches
+  all ``max_blocks`` gathered rows), fuses the new token's quantize+scatter as a
   read-modify-write of the row's last block BEFORE the stream (pools are
   aliased in place), and dequantizes int8 blocks at load time — the int8
   codes never exist as a dequantized [B, S_pad, H, D] float tensor
@@ -65,7 +65,7 @@ from .paged_attention import (paged_attention_arrays,
                               paged_cache_update_arrays,
                               quantized_cache_update_arrays)
 from .pallas_ops import (_NEG_INF, _count_path, _decode_seg_helpers,
-                         _interpret, _on_tpu)
+                         _interpret, _on_tpu, _two_block_dma_loop)
 
 __all__ = ["ragged_paged_attention_arrays"]
 
@@ -118,8 +118,8 @@ def _ragged_kernel_ok(q, k_blocks, c, quant) -> bool:
 
 # ---------------------------------------------------------------------------
 # the fused kernel (S_q = 1): cache update (read-modify-write of the
-# row's last block) then a double-buffered streamed attention over the
-# row's blocks, int8 dequant fused into the block loads
+# row's last block) then a streamed attention over the row's blocks, two
+# per loop iteration, int8 dequant fused into the block loads
 # ---------------------------------------------------------------------------
 
 def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
@@ -128,14 +128,17 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     """One program per batch row r:
 
     1. DMA the row's TARGET block (the one its write slot lands in) into
-       VMEM, splice/quantize the new token's K/V row in (int8: grow the
-       block scale monotonically and rescale the existing codes exactly
-       like `quantized_cache_update_arrays`), DMA it back — pools and
-       scale tables are aliased in place, and blocks a row writes are
+       VMEM, splice/quantize the new token's K/V row in (int8: rescale
+       the existing codes from the block's old scale to its grown one
+       exactly like `quantized_cache_update_arrays`; the [num_blocks, H]
+       scale tables themselves are grown by the caller in XLA, because a
+       one-row (1, H) DMA into them is below Mosaic's tile), DMA it
+       back — pools are aliased in place, and blocks a row writes are
        always privately owned (the engine privatizes shared last blocks
        at fork), so programs never race.
-    2. Stream the row's ``ceil(len/bs)`` blocks from HBM (double-buffered
-       DMA through the row's block table in SMEM), dequantizing int8
+    2. Stream the row's ``ceil(len/bs)`` blocks from HBM
+       (`_two_block_dma_loop`, through the row's block table in SMEM),
+       dequantizing int8
        codes at load via the per-block-per-head scales, with an online
        softmax; the target block's contribution comes from the updated
        VMEM copy, never re-read through the alias.
@@ -151,15 +154,9 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
 
     refs = list(refs)
     if quant:
-        gks_ref = refs.pop(0)
-        gvs_ref = refs.pop(0)
-        refs.pop(0)             # k_scales input: aliased, read pre-gathered
-        refs.pop(0)             # v_scales input
-        o_ref, ko_hbm, vo_hbm, kso_hbm, vso_hbm = refs[:5]
-        kbuf, vbuf, sem, ublk, usem, sstage = refs[5:]
-    else:
-        o_ref, ko_hbm, vo_hbm = refs[:3]
-        kbuf, vbuf, sem, ublk, usem = refs[3:]
+        gks_ref, gvs_ref, oks_ref, nks_ref, ovs_ref, nvs_ref = refs[:6]
+        refs = refs[6:]
+    o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sem, ublk, usem = refs
     hd = h * d
     r = pl.program_id(0)
     length = jnp.maximum(len_ref[r], 0)
@@ -187,22 +184,6 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     off_mask = (jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1) == off)
 
     if quant:
-        kn32 = kn_ref[...].astype(jnp.float32)          # [1, 1, hd]
-        vn32 = vn_ref[...].astype(jnp.float32)
-        lane_h = jax.lax.broadcasted_iota(jnp.int32, (1, h), 1)
-        head_of = jax.lax.broadcasted_iota(jnp.int32, (1, 1, hd), 2) // d
-
-        def _head_amax(x32):
-            # per-head abs-max of one [1, 1, hd] row as a lane-oriented
-            # [1, h] vector (static unroll: h is small, and a lane-space
-            # segmented max has no matmul form)
-            res = jnp.zeros((1, h), jnp.float32)
-            ax = jnp.abs(x32)
-            for j in range(h):
-                mj = jnp.max(jnp.where(head_of == j, ax, 0.0))
-                res = jnp.where(lane_h == j, mj, res)
-            return res
-
         def _sel_row(g_ref, kb):
             # row kb of the pre-gathered [1, maxb, h] scale view as
             # [1, h] — masked sublane sum instead of a dynamic VMEM slice
@@ -212,36 +193,30 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
             return jnp.sum(jnp.where(mask, rows, 0.0), axis=0,
                            keepdims=True)
 
-        def _quant_update(xn32, old_s, blk_codes):
+        def _quant_update(xn_ref, old_ref, new_ref, blk_codes):
             # mirrors quantized_cache_update_arrays for ONE incoming row:
-            # the scale only GROWS; existing codes rescale by old/new
-            # (exactly 1.0 when unchanged — bit-stable steady state); the
-            # row quantizes against the new scale
-            amax = _head_amax(xn32)                      # [1, h]
-            new_s = jnp.where(valid,
-                              jnp.maximum(old_s, amax / _QMAX), old_s)
+            # existing codes rescale by old/new scale (exactly 1.0 when
+            # unchanged — bit-stable steady state); the row quantizes
+            # against the new scale
+            old_s, new_s = old_ref[...], new_ref[...]    # [1, 1, h]
             factor = jnp.where(
                 new_s > 0, old_s / jnp.where(new_s > 0, new_s, 1.0), 1.0)
-            fac_hd = seg_dot(factor[:, None, :], expand, exact=True)
+            fac_hd = seg_dot(factor, expand, exact=True)
             resc = jnp.clip(
                 jnp.round(blk_codes.astype(jnp.float32) * fac_hd),
                 -_QMAX, _QMAX)
-            s_hd = seg_dot(new_s[:, None, :], expand, exact=True)
+            s_hd = seg_dot(new_s, expand, exact=True)
             safe = jnp.where(s_hd > 0, s_hd, 1.0)
-            qrow = jnp.clip(jnp.round(xn32 / safe), -_QMAX, _QMAX)
+            qrow = jnp.clip(
+                jnp.round(xn_ref[...].astype(jnp.float32) / safe),
+                -_QMAX, _QMAX)
             codes = jnp.where(off_mask & valid, qrow, resc)  # [1, bs, hd]
-            return codes, new_s, s_hd
+            return codes, s_hd
 
-        old_ks = _sel_row(gks_ref, tkb)
-        old_vs = _sel_row(gvs_ref, tkb)
-        k_codes, new_ks, ks_hd = _quant_update(kn32, old_ks,
-                                               ublk[0])
-        v_codes, new_vs, vs_hd = _quant_update(vn32, old_vs,
-                                               ublk[1])
+        k_codes, ks_hd = _quant_update(kn_ref, oks_ref, nks_ref, ublk[0])
+        v_codes, vs_hd = _quant_update(vn_ref, ovs_ref, nvs_ref, ublk[1])
         ublk[0] = k_codes.astype(jnp.int8)
         ublk[1] = v_codes.astype(jnp.int8)
-        sstage[0] = new_ks
-        sstage[1] = new_vs
         kup_f = k_codes * ks_hd          # dequantized local target block
         vup_f = v_codes * vs_hd
     else:
@@ -262,17 +237,6 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                                    usem.at[1])
         wk.start()
         wv.start()
-        if quant:
-            sk = pltpu.make_async_copy(sstage.at[0],
-                                       kso_hbm.at[pl.ds(blk, 1)],
-                                       usem.at[2])
-            sv = pltpu.make_async_copy(sstage.at[1],
-                                       vso_hbm.at[pl.ds(blk, 1)],
-                                       usem.at[3])
-            sk.start()
-            sv.start()
-            sk.wait()
-            sv.wait()
         # writes must complete before the stream below may read the same
         # HBM region (the target block's streamed copy is discarded, but
         # an in-flight overlapping read/write would be undefined)
@@ -281,37 +245,25 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
 
     # -- 2. streamed attention over the row's valid blocks ------------------
     qf = q_ref[...].astype(jnp.float32)                  # [1, 1, hd]
-    # clamp to >= 1 block: the pre-loop prefetch starts unconditionally
-    # and a zero-trip loop would leave its semaphore unbalanced (padding
-    # rows read one garbage block; their output is ignored)
-    num_kb = jnp.clip((length + bs - 1) // bs, 1, maxb)
+    # padding rows (length 0) stream nothing and put out zeros
+    num_kb = jnp.minimum((length + bs - 1) // bs, maxb)
 
-    def _copies(slot_i, kb):
+    def copies(slot_i, kb):
         b_kb = jnp.clip(tbl_ref[r, kb], 0, nb - 1)
         return (pltpu.make_async_copy(k_hbm.at[pl.ds(b_kb, 1)],
                                       kbuf.at[slot_i], sem.at[slot_i, 0]),
                 pltpu.make_async_copy(v_hbm.at[pl.ds(b_kb, 1)],
                                       vbuf.at[slot_i], sem.at[slot_i, 1]))
 
-    for c_ in _copies(0, 0):
-        c_.start()
-
-    def body(kb, carry):
+    def step(sl, kb, carry):
         m, l, acc = carry            # m, l: [1,1,h]; acc: [1,1,hd] fp32
-        sl = jax.lax.rem(kb, 2)
-
-        @pl.when(kb + 1 < num_kb)
-        def _prefetch():
-            for c_ in _copies(1 - sl, kb + 1):
-                c_.start()
-
-        kd, vd = _copies(sl, kb)
+        kd, vd = copies(sl, kb)
         kd.wait()
         is_t = valid & (kb == tkb)
         kf = kbuf[sl].astype(jnp.float32)                # [1, bs, hd]
         if quant:
-            ksel = jnp.where(is_t, new_ks, _sel_row(gks_ref, kb))
-            kf = kf * seg_dot(ksel[:, None, :], expand, exact=True)
+            kf = kf * seg_dot(_sel_row(gks_ref, kb)[:, None, :], expand,
+                              exact=True)
         kf = jnp.where(is_t, kup_f, kf)
         s = seg_dot(kf * qf, seg) * scale                # [1, bs, h]
         pos = kb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs, h), 1)
@@ -323,8 +275,8 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         vd.wait()
         vf = vbuf[sl].astype(jnp.float32)
         if quant:
-            vsel = jnp.where(is_t, new_vs, _sel_row(gvs_ref, kb))
-            vf = vf * seg_dot(vsel[:, None, :], expand, exact=True)
+            vf = vf * seg_dot(_sel_row(gvs_ref, kb)[:, None, :], expand,
+                              exact=True)
         vf = jnp.where(is_t, vup_f, vf)
         pexp = seg_dot(p, expand)                        # [1, bs, hd]
         pv = jnp.sum(pexp * vf, axis=1, keepdims=True)
@@ -334,7 +286,7 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     m0 = jnp.full((1, 1, h), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, 1, h), jnp.float32)
     acc0 = jnp.zeros((1, 1, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
+    m, l, acc = _two_block_dma_loop(num_kb, copies, step, (m0, l0, acc0))
     l_exp = seg_dot(l, expand, exact=True)
     o_ref[...] = (acc / jnp.maximum(l_exp, 1e-30)).astype(o_ref.dtype)
 
@@ -354,73 +306,68 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     del pos0   # the kernel masks by kv_lens; pos0 == kv_lens - 1 at C=1
     lens_i = jnp.asarray(kv_lens, jnp.int32).reshape(b)
     slots_i = jnp.asarray(slots, jnp.int32).reshape(b)
-    anyspace = getattr(pltpu, "HBM", pltpu.ANY)   # 0.4.x: ANY (HBM is the
-    #                                               newer-jax name)
-    in_specs = [
-        pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0)),     # q
-        pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0)),     # k_new
-        pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0)),     # v_new
-        pl.BlockSpec(memory_space=anyspace),                     # k pool
-        pl.BlockSpec(memory_space=anyspace),                     # v pool
-    ]
+    row = pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0))
+    pool = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [row, row, row, pool, pool]        # q, k_new, v_new, pools
     args = [q.reshape(b, c, hd), k_new.reshape(b, c, hd),
             v_new.reshape(b, c, hd), k_blocks.reshape(nb, bs, hd),
             v_blocks.reshape(nb, bs, hd)]
-    out_shape = [jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
-                 jax.ShapeDtypeStruct((nb, bs, hd), pool_dt),
-                 jax.ShapeDtypeStruct((nb, bs, hd), pool_dt)]
-    out_specs = [pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0)),
-                 pl.BlockSpec(memory_space=anyspace),
-                 pl.BlockSpec(memory_space=anyspace)]
+    if quant:
+        # grow the written blocks' scales here (the first half of
+        # quantized_cache_update_arrays, bitwise: amax/qmax is monotone,
+        # so a scatter-max of the quotients equals the quotient of the
+        # max) and hand each row its target block's old and new scale;
+        # the kernel streams against the NEW table
+        valid = (slots_i >= 0) & (slots_i < nb * bs)
+        blk = jnp.where(valid, slots_i // bs, nb)
+        safe_blk = jnp.clip(blk, 0, nb - 1)
+        safe_tbl = jnp.clip(tbl, 0, nb - 1)
+        scale_row = pl.BlockSpec((1, 1, h), lambda r, *pre: (r, 0, 0))
+        gathered = pl.BlockSpec((1, maxb, h), lambda r, *pre: (r, 0, 0))
+        in_specs += [gathered, gathered]
+        new_scales, row_scales = [], []
+        for rows, scales in ((k_new, k_scales), (v_new, v_scales)):
+            amax = jnp.max(jnp.abs(rows.reshape(b, h, d).astype(
+                jnp.float32)), axis=-1)
+            grown = scales.at[blk].max(amax / _QMAX, mode="drop")
+            new_scales.append(grown)
+            row_scales += [scales[safe_blk][:, None, :],
+                           grown[safe_blk][:, None, :]]
+            in_specs += [scale_row, scale_row]
+        args += [jnp.take(new_scales[0], safe_tbl, axis=0),
+                 jnp.take(new_scales[1], safe_tbl, axis=0)] + row_scales
     scratch = [
-        pltpu.VMEM((2, 1, bs, hd), pool_dt),      # k stream double-buffer
-        pltpu.VMEM((2, 1, bs, hd), pool_dt),      # v stream double-buffer
+        pltpu.VMEM((2, 1, bs, hd), pool_dt),      # k stream, two blocks
+        pltpu.VMEM((2, 1, bs, hd), pool_dt),      # v stream, two blocks
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.VMEM((2, 1, bs, hd), pool_dt),      # target block k/v
-        pltpu.SemaphoreType.DMA((4,)),
+        pltpu.SemaphoreType.DMA((2,)),
     ]
-    # aliasing indices INCLUDE the scalar-prefetch args (lens=0, slots=1,
-    # tables=2, q=3, k_new=4, v_new=5, pools=6/7; int8 adds gathered
-    # scale views 8/9 and the scale tables 10/11)
-    aliases = {6: 1, 7: 2}
-    if quant:
-        safe_tbl = jnp.clip(tbl, 0, nb - 1)
-        in_specs += [
-            pl.BlockSpec((1, maxb, h), lambda r, *pre: (r, 0, 0)),
-            pl.BlockSpec((1, maxb, h), lambda r, *pre: (r, 0, 0)),
-            pl.BlockSpec(memory_space=anyspace),
-            pl.BlockSpec(memory_space=anyspace),
-        ]
-        args += [jnp.take(k_scales, safe_tbl, axis=0),
-                 jnp.take(v_scales, safe_tbl, axis=0),
-                 k_scales, v_scales]
-        out_shape += [jax.ShapeDtypeStruct((nb, h), jnp.float32),
-                      jax.ShapeDtypeStruct((nb, h), jnp.float32)]
-        out_specs += [pl.BlockSpec(memory_space=anyspace),
-                      pl.BlockSpec(memory_space=anyspace)]
-        aliases.update({10: 3, 11: 4})
-        scratch.append(pltpu.VMEM((2, 1, h), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=in_specs,
-        out_specs=out_specs,
+        out_specs=[row, pool, pool],
         scratch_shapes=scratch,
     )
     kernel = functools.partial(_ragged_fused_kernel, bs=bs, h=h, d=d,
                                nb=nb, maxb=maxb, scale=scale, quant=quant)
+    # aliasing indices INCLUDE the scalar-prefetch args (lens=0, slots=1,
+    # tables=2, q=3, k_new=4, v_new=5, pools=6/7)
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
+        out_shape=[jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+                   jax.ShapeDtypeStruct((nb, bs, hd), pool_dt),
+                   jax.ShapeDtypeStruct((nb, bs, hd), pool_dt)],
+        input_output_aliases={6: 1, 7: 2},
         interpret=_interpret(),
     )(lens_i, slots_i, tbl, *args)
     o = outs[0].reshape(b, c, h, d)
     k2 = outs[1].reshape(k_blocks.shape)
     v2 = outs[2].reshape(v_blocks.shape)
     if quant:
-        return o, k2, v2, outs[3], outs[4]
+        return o, k2, v2, new_scales[0], new_scales[1]
     return o, k2, v2
 
 

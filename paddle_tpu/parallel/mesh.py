@@ -52,10 +52,15 @@ def set_mesh(mesh: Mesh):
 def get_mesh() -> Optional[Mesh]:
     m = _current()
     if m is None:
-        # default: trivial 1-axis mesh over all devices on 'dp'
-        devs = np.array(jax.devices()).reshape(-1, 1, 1, 1, 1, 1)
-        m = Mesh(devs, ("dp", "sharding", "pp", "ep", "sp", "mp"))
-        _state.mesh = m
+        # default: trivial 1-axis mesh over all devices on 'dp'.  Kept
+        # apart from the installed mesh: `_current()` stays None until
+        # init_mesh/set_mesh, which is how a kernel call site tells a
+        # program the user sharded from one that merely ran on a
+        # multi-device host
+        m = getattr(_state, "default", None)
+        if m is None:
+            devs = np.array(jax.devices()).reshape(-1, 1, 1, 1, 1, 1)
+            m = _state.default = Mesh(devs, AXIS_ORDER)
     return m
 
 
@@ -72,47 +77,6 @@ def axis_size(name: str) -> int:
 
 def has_axis(name: str) -> bool:
     return axis_size(name) > 1
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=False):
-    """jax.shard_map across jax versions. Newer jax exposes
-    `jax.shard_map(..., axis_names=<manual axes>, check_vma=...)`; 0.4.x
-    only has `jax.experimental.shard_map.shard_map(..., auto=<NON-manual
-    axes>, check_rep=...)`. Same partial-manual semantics, inverted axis
-    selector — this wrapper accepts the new-style kwargs and translates
-    when running on the old API."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=frozenset(axis_names) if axis_names else None,
-            check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
-    if axis_names:
-        # size-1 axes are semantically identical manual or auto (one shard
-        # holds the full extent); folding them into the manual set empties
-        # `auto` on single-parallelism meshes, dodging the partial-manual
-        # constructs old XLA can't partition on some backends
-        # ("PartitionId instruction is not supported for SPMD").
-        auto = frozenset(a for a in mesh.axis_names
-                         if a not in axis_names and mesh.shape[a] > 1)
-    if auto and jax.default_backend() == "cpu":
-        # True partial-manual on 0.4.x XLA-CPU is a minefield: lowering
-        # hits "PartitionId instruction is not supported for SPMD
-        # partitioning" or fatally aborts the process in the
-        # float-normalization pass. Refuse loudly rather than crash
-        # (accelerator backends are left to try the `auto=` path).
-        raise NotImplementedError(
-            f"shard_map over manual axes {sorted(axis_names)} with live "
-            f"auto axes {sorted(auto)} needs jax >= 0.6 (jax.shard_map "
-            f"with axis_names); this jax ({jax.__version__}) only "
-            "partitions single-parallelism meshes reliably. Collapse the "
-            "mesh to the manual axes or upgrade jax.")
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto)
 
 
 class MeshGuard:

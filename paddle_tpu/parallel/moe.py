@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .mesh import get_mesh, axis_size, shard_map_compat
+from .mesh import get_mesh, axis_size
 from .. import monitor
 from ..profiler import RecordEvent
 
@@ -192,7 +192,7 @@ def _moe_mlp_dispatch(x, gate_logits, w_in, w_out, top_k, capacity_factor,
     mesh = get_mesh()
     body = partial(_moe_sharded, axis_name=axis, top_k=top_k,
                    capacity_factor=capacity_factor)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P()),

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from .mesh import get_mesh, axis_size, shard_map_compat
+from .mesh import get_mesh, axis_size
 from .. import monitor
 from ..profiler import RecordEvent
 
@@ -217,7 +217,7 @@ def pipeline_apply(
                            aux=amb if has_aux else None)
 
     @functools.partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=P(),
@@ -344,7 +344,7 @@ def _pipeline_interleaved(block_fn, stacked_params, x, n_microbatches,
     aux_xs = _split_aux(aux, M) if has_aux else ()
 
     @functools.partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=P(),
@@ -530,7 +530,7 @@ def _pipeline_1f1b_impl(block_fn, loss_fn, n_microbatches, axis,
     aux_xs = _split_aux(aux, M) if has_aux else ()
 
     @functools.partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P()),
         out_specs=(P(), (P(axis), P(), P())),

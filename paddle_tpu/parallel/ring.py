@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.dispatch import apply
-from .mesh import get_mesh, axis_size, shard_map_compat
+from .mesh import get_mesh, axis_size
 
 __all__ = ["ring_attention", "ring_attention_arrays", "zigzag_sequence_perm"]
 
@@ -259,12 +259,12 @@ def ring_attention_arrays(q, k, v, is_causal=True, scale=None, axis="sp",
 
     def mapped(body):
         if seg is None:
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 body, mesh=mesh, in_specs=(spec, spec, spec),
                 out_specs=spec, axis_names=frozenset({axis}),
                 check_vma=False)
             return lambda a, b_, c: fn(a, b_, c)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec, seg_spec),
             out_specs=spec, axis_names=frozenset({axis}), check_vma=False)
         return fn

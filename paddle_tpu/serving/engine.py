@@ -995,7 +995,7 @@ class LLMEngine:
         mon = monitor.enabled()
         # launch accounting (ISSUE 12): every jitted dispatch this step
         # records its cache key; the gauge is the LIVE twin of the
-        # BENCH_NOTES round-2 hand count — len() only, never iterated
+        # round-2 hand count — len() only, never iterated
         self._launches_this_step = set() if mon else None
         ragged = self.attention_impl == "ragged"
         # ragged: ONE fixed shape (max_num_seqs) serves every batch

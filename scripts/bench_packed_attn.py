@@ -6,8 +6,7 @@ Compares, at pretraining-ish shapes:
   dense_mask    — XLA softmax with a materialized [B,1,S,S] segment mask
   kernel_causal — flash kernel, causal only (no packing; throughput ceiling)
 
-Prints one JSON line per config. Run on the real chip (harvest battery
-stage `packed_attn`).
+Prints one JSON line per config. Run on the real chip.
 """
 import json
 import time

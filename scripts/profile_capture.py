@@ -1,5 +1,5 @@
 """Pull an on-demand device profile through MonitorServer `/profile`
-while a real workload runs — the ISSUE-12 harvest leg.
+while a real workload runs (ISSUE 12).
 
 Boots the process-wide monitor endpoint, runs one bench.py ladder config
 in a background thread (so the device is actually busy during the
@@ -11,10 +11,8 @@ restart, no code change, one HTTP GET.
     python scripts/profile_capture.py --config gpt124m_decode --secs 5
     python scripts/profile_capture.py --config resnet50 --secs 5
 
-Runnable on CPU (smoke) and on chip (scripts/harvest4_battery.sh queues
-the decode + resnet50 captures for the next healthy window).  Exit 0
-with a saved artifact, exit 3 when this backend's profiler is
-unavailable (the endpoint's clean 501) — an outage, not a bug.
+Runnable on CPU (smoke) and on chip.  Exit 0 with a saved artifact,
+exit 3 when this backend's profiler is unavailable (the endpoint's clean 501) — an outage, not a bug.
 """
 import argparse
 import os

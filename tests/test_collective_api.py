@@ -22,7 +22,6 @@ import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 from paddle_tpu import parallel
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.parallel.mesh import shard_map_compat
 
 
 def _run_sharded(fn, arr, axis="dp"):
@@ -34,7 +33,7 @@ def _run_sharded(fn, arr, axis="dp"):
     mesh = get_mesh()
     group = dist.new_group(axis_name=axis)
 
-    @functools.partial(shard_map_compat, mesh=mesh, in_specs=P(axis),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(axis),
                        out_specs=P(axis), axis_names=frozenset({axis}),
                        check_vma=False)
     def body(a):
@@ -117,7 +116,7 @@ def test_broadcast_allgather_alltoall():
     rng = np.random.RandomState(3)
     x = rng.randn(4, 2, 8).astype(np.float32)
 
-    @functools.partial(shard_map_compat, mesh=mesh, in_specs=P("dp"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
                        out_specs=P("dp"), axis_names=frozenset({"dp"}),
                        check_vma=False)
     def bcast(a):
@@ -126,7 +125,7 @@ def test_broadcast_allgather_alltoall():
     out = np.asarray(jax.jit(bcast)(jnp.asarray(x)), np.float32)
     np.testing.assert_allclose(out, np.repeat(x[2:3], 4, 0), rtol=1e-6)
 
-    @functools.partial(shard_map_compat, mesh=mesh, in_specs=P("dp"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
                        out_specs=P("dp"), axis_names=frozenset({"dp"}),
                        check_vma=False)
     def gathered_sum(a):
@@ -140,7 +139,7 @@ def test_broadcast_allgather_alltoall():
     np.testing.assert_allclose(out, np.repeat(x.sum(0, keepdims=True), 4, 0),
                                rtol=1e-5)
 
-    @functools.partial(shard_map_compat, mesh=mesh, in_specs=P("dp"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
                        out_specs=P("dp"), axis_names=frozenset({"dp"}),
                        check_vma=False)
     def a2a(a):
@@ -172,7 +171,7 @@ def test_stream_variants():
 
     captured = {}
 
-    @functools.partial(shard_map_compat, mesh=mesh, in_specs=P("dp"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
                        out_specs=P("dp"), axis_names=frozenset({"dp"}),
                        check_vma=False)
     def body(a):
@@ -223,7 +222,7 @@ def test_global_scatter_gather_uniform_capacity():
         from paddle_tpu.parallel.mesh import get_mesh
         group = dist.new_group(axis_name="dp")
 
-        @functools.partial(shard_map_compat, mesh=get_mesh(), in_specs=P("dp"),
+        @functools.partial(jax.shard_map, mesh=get_mesh(), in_specs=P("dp"),
                            out_specs=P("dp"), axis_names=frozenset({"dp"}),
                            check_vma=False)
         def body(a):

@@ -401,13 +401,13 @@ class TestQuantizedKVCache:
 def _shard4(fn, *arrays):
     """Run fn(*per-shard arrays) under shard_map over dp=4; inputs/outputs
     carry a leading member axis of 4."""
-    from paddle_tpu.parallel.mesh import get_mesh, shard_map_compat
+    from paddle_tpu.parallel.mesh import get_mesh
 
     parallel.init_mesh(dp=4)
     mesh = get_mesh()
     n = len(arrays)
 
-    @functools.partial(shard_map_compat, mesh=mesh, in_specs=(P("dp"),) * n,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("dp"),) * n,
                        out_specs=P("dp"), axis_names=frozenset({"dp"}),
                        check_vma=False)
     def body(*shards):
@@ -485,14 +485,14 @@ class TestQuantizedCollectives:
     def test_error_feedback_recovers_lost_signal(self):
         """50 repeated reductions of the same vector: with EF the running
         sum tracks the true mean far better than one-shot noise."""
-        from paddle_tpu.parallel.mesh import get_mesh, shard_map_compat
+        from paddle_tpu.parallel.mesh import get_mesh
 
         parallel.init_mesh(dp=4)
         mesh = get_mesh()
         rng = np.random.RandomState(5)
         a = rng.randn(4, 37).astype(np.float32)
 
-        @functools.partial(shard_map_compat, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P("dp"), P("dp")),
                            out_specs=(P("dp"), P("dp")),
                            axis_names=frozenset({"dp"}), check_vma=False)
@@ -515,7 +515,7 @@ class TestQuantizedCollectives:
         """`all_reduce(..., error_feedback=buf)` must rewrite the buffer
         with the local rounding residual (nonzero for off-grid values)."""
         import paddle_tpu.distributed as dist
-        from paddle_tpu.parallel.mesh import get_mesh, shard_map_compat
+        from paddle_tpu.parallel.mesh import get_mesh
 
         parallel.init_mesh(dp=4)
         mesh = get_mesh()
@@ -523,7 +523,7 @@ class TestQuantizedCollectives:
         rng = np.random.RandomState(6)
         a = rng.randn(4, 33).astype(np.float32)
 
-        @functools.partial(shard_map_compat, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P("dp"), P("dp")),
                            out_specs=(P("dp"), P("dp")),
                            axis_names=frozenset({"dp"}), check_vma=False)
@@ -579,7 +579,7 @@ class TestQuantizedCollectives:
         """The acceptance bar: an MNIST-scale DP run with int8 gradient
         all-reduce + error feedback reaches the same train-accuracy
         threshold as exact fp32 sync."""
-        from paddle_tpu.parallel.mesh import get_mesh, shard_map_compat
+        from paddle_tpu.parallel.mesh import get_mesh
         from paddle_tpu.vision.datasets import MNIST
 
         ds = MNIST(mode="train", size=256)
@@ -604,7 +604,7 @@ class TestQuantizedCollectives:
 
         def make_step(quant):
             @functools.partial(
-                shard_map_compat, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(P(), P("dp"), P("dp"), P("dp")),
                 out_specs=(P("dp"), P("dp")),
                 axis_names=frozenset({"dp"}), check_vma=False)

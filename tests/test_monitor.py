@@ -361,14 +361,13 @@ def test_collective_bytes_series():
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.distributed import collective as coll
     from paddle_tpu.parallel import mesh as mesh_mod
-    from paddle_tpu.parallel.mesh import shard_map_compat
 
     prev_mesh = mesh_mod._current()
     try:
         mesh = parallel.init_mesh(dp=2)
         group = coll.new_group(axis_name="dp")
 
-        @functools.partial(shard_map_compat, mesh=mesh, in_specs=P("dp"),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("dp"),
                            out_specs=P("dp"), axis_names=frozenset({"dp"}),
                            check_vma=False)
         def body(a):

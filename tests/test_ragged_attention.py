@@ -226,10 +226,13 @@ class TestRaggedKernelInterpret:
         want = paged_attention_arrays(q, k2r, v2r, tables, pos0)
         np.testing.assert_array_equal(np.asarray(k2), np.asarray(k2r))
         np.testing.assert_array_equal(np.asarray(v2), np.asarray(v2r))
-        # online softmax reorders reductions: last-ulp, not bitwise
+        # online softmax reorders reductions: last-ulp, not bitwise.  A
+        # near-zero output is a sum of O(1) p·v terms that cancel, so its
+        # last ulp sits at the terms' scale (fp32 eps 1.2e-7 × a few),
+        # not at the result's — hence atol an order above eps
         np.testing.assert_allclose(np.asarray(out[:2]),
                                    np.asarray(want[:2]),
-                                   rtol=1e-6, atol=1e-7)
+                                   rtol=1e-6, atol=1e-6)
 
     def test_int8_kernel_matches_reference(self, _interpret_mode):
         (q, kn, vn, kb, vb, tables, pos0, lens, slots,

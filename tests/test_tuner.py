@@ -220,7 +220,7 @@ class TestCalibration:
 
     def test_committed_tpu_calibration_ranks_headline_config_first(self):
         """Gated on the on-chip artifact (written by
-        scripts/tuner_calibrate_tpu.py during a harvest window): with TPU
+        scripts/tuner_calibrate_tpu.py on a chip): with TPU
         calibration loaded, the 124M/8-chip search must rank the
         known-good pure-DP headline config first."""
         import os

@@ -20,7 +20,7 @@ recompiles/transfers:
 - calls of the jit family (``jax.jit``/``pjit``/``vmap``/``pmap``/
   ``grad``/``value_and_grad``/``checkpoint``/``remat``/``eval_shape``,
   ``jax.lax.scan/while_loop/cond/fori_loop/switch/map``,
-  ``shard_map``/``shard_map_compat``): every argument that resolves to
+  ``shard_map``): every argument that resolves to
   a known function becomes an entry;
 - functions decorated with any of the above, incl. through
   ``functools.partial(jax.jit, ...)``.
@@ -42,7 +42,7 @@ JIT_DOTTED_LAST = {
     "shard_map",
 }
 # bare names that are unambiguous even without a jax-rooted dotted path
-JIT_BARE = {"pjit", "shard_map", "shard_map_compat"}
+JIT_BARE = {"pjit", "shard_map"}
 
 
 def dotted_name(node):
