@@ -15,11 +15,13 @@ Three data sources, one registry:
   (compute- vs memory-bound), the roofline-optimal step time, and the
   achieved-vs-optimal ratio — the number a perf PR must move.
 - **step-segment breakdown** — named, properly-synced sub-step timers:
-  the serving decode step reports prep/model/sampler in situ, and
-  ``LLMEngine.decode_breakdown()`` attributes the inside of the fused
-  program (block gather, attention, cache update, sampler) against each
-  segment's own cost-analysis prediction; ``hapi.Model`` splits the
-  eager train step into forward/backward/optimizer.
+  ``hapi.Model`` splits the eager train step into
+  forward/backward/optimizer, and ``LLMEngine.decode_breakdown()``
+  attributes the inside of the fused decode program (block gather,
+  attention, cache update, sampler) against each segment's own
+  cost-analysis prediction.  The serving step itself is NOT synced for
+  timing: its host phases are the always-on ``serving/host_time{phase}``
+  histogram (``monitor.trace.phase``), which ``report()`` appends.
 - **HBM attribution** — per-program peak-bytes estimate and headroom vs
   the chip's HBM (``perf/hbm_headroom``), the memfit gate's live twin.
 
@@ -550,10 +552,10 @@ def observe(label: str, wall_s: float):
 
 
 def observe_segment(step: str, name: str, wall_s: float):
-    """A named sub-step segment's synced wall time (prep/model/sampler in
-    the serving decode step; forward/backward/optimizer in the eager
-    train step).  Also lands in the ``step:name`` record so segments and
-    whole programs share one attribution table."""
+    """A named sub-step segment's synced wall time
+    (forward/backward/optimizer in the eager train step).  Also lands in
+    the ``step:name`` record so segments and whole programs share one
+    attribution table."""
     _registry().histogram(
         "perf/segment_time",
         "synced sub-step segment seconds").labels(
@@ -695,16 +697,35 @@ def _fmt(v, spec="{:.3g}", na="-"):
     return na if v is None else spec.format(v)
 
 
+def _host_phase_lines() -> list:
+    """The serving loop's host phases, most time first: the always-on
+    ``serving/host_time{phase}`` histogram that ``monitor.trace.phase``
+    feeds (unsynced host clock — the device runs underneath
+    ``engine/sample_dispatch`` and is waited for in ``engine/readback``)."""
+    series = _registry().snapshot().get("serving/host_time") or {}
+    rows = sorted(((k.partition("=")[2], v) for k, v in series.items()
+                   if k and v.get("count")), key=lambda kv: -kv[1]["sum"])
+    if not rows:
+        return []
+    lines = ["serving host phases (serving/host_time, host clock, "
+             "not synced):",
+             f"  {'phase':28s} {'calls':>6s} {'mean_ms':>9s} {'total_s':>9s}"]
+    lines += [f"  {name[:28]:28s} {v['count']:6d} "
+              f"{1e3 * v['sum'] / v['count']:9.3f} {v['sum']:9.3f}"
+              for name, v in rows]
+    return lines
+
+
 def report(top: int = 30) -> str:
     """Ranked attribution table (merged into ``Profiler.summary()``):
     programs/segments by total synced wall time, each with its roofline
     classification, MFU, and achieved-vs-optimal ratio.  The row with
     the smallest ach/opt ratio is the next optimization target; rows
     whose backend returned no analysis read 'unavailable' instead of a
-    fabricated MFU."""
+    fabricated MFU.  The serving loop's host phases follow."""
     recs = [r for r in records() if r.calls or r.cost or r.memory]
     if not recs:
-        return ""
+        return "\n".join(_host_phase_lines())
     chip = chip_spec()
     recs.sort(key=lambda r: -r.total_s)
     lines = [
@@ -745,7 +766,7 @@ def report(top: int = 30) -> str:
     if worst is not None:
         lines.append(f"  worst achieved-vs-optimal: {worst[0]} "
                      f"({worst[1]:.3f} of roofline)")
-    return "\n".join(lines)
+    return "\n".join(lines + _host_phase_lines())
 
 
 def hlo_report(fn=None, top: int = 10) -> str:

@@ -20,7 +20,20 @@ parent so a trace renders as a tree.  Producers:
   it back into a :class:`SpanContext` in ANOTHER process, so an rpc-
   issued request opens a *child* span on the remote worker and
   ``export_chrome_trace()`` shows one trace_id spanning processes
-  (``distributed/rpc.py`` carries the header on every call).
+  (``distributed/rpc.py`` carries the header on every call);
+- ``with trace.phase("engine/schedule"):`` — one boundary of the serving
+  loop, timed three ways at once: ALWAYS (under the PTPU_MONITOR gate)
+  into the ``serving/host_time{phase}`` histogram; whenever a profiler
+  session is open, as a ``ptpu:<name>`` event in the session's xplane,
+  on the device operations' clock (:func:`annotation` is the ONE place
+  the package talks to jax's trace annotations — ``profiler.RecordEvent``
+  calls it too); and, with PTPU_TRACE=1 inside an open span, as a child
+  span;
+- ``with trace.shared_span("serving/step") as sp: ... sp.link(root)`` —
+  a span for work done on behalf of SEVERAL traces (an engine step
+  serves a batch of requests).  It and its subtree are stored once and
+  filed under every linked trace; ``get_trace`` shows them there as a
+  child of that trace's linked root.
 
 Design constraints (shared with the metrics layer):
 
@@ -32,7 +45,8 @@ Design constraints (shared with the metrics layer):
 - **stdlib-only, no jax**: importable headlessly; chrome-trace export
   merges spans from `paddle_tpu.profiler`'s host tracer only when that
   module is ALREADY loaded (``sys.modules`` probe — never triggers an
-  accelerator import from here).
+  accelerator import from here), and :func:`annotation` resolves jax's
+  annotation class only once ``jax`` itself is in ``sys.modules``.
 - **bounded memory**: finished spans land in (a) the flight-recorder
   ring (`monitor.flight`) and (b) a per-trace store capped at
   ``PTPU_TRACE_MAX_TRACES`` traces (oldest evicted), which backs
@@ -62,8 +76,11 @@ import threading
 import time
 from collections import OrderedDict
 
+from . import enabled as _monitor_enabled, histogram as _histogram
+
 __all__ = [
     "Span", "SpanContext", "span", "start_span", "current_span", "attach",
+    "shared_span", "phase", "annotation", "PHASE_METRIC", "PHASE_PREFIX",
     "inject", "extract", "get_trace",
     "trace_ids", "chrome_events", "export_chrome_trace", "enabled",
     "enable", "refresh", "reset", "heartbeat", "last_activity_age",
@@ -131,7 +148,7 @@ class Span:
     store + flight ring) exactly once, at end."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "_t0", "ts_us", "dur_us", "tid", "_done")
+                 "_t0", "ts_us", "dur_us", "tid", "_done", "links")
 
     def __init__(self, name, trace_id, parent_id, attrs):
         self.name = name
@@ -139,6 +156,9 @@ class Span:
         self.span_id = _next_id()
         self.parent_id = parent_id
         self.attrs = attrs
+        # None for an ordinary span; for a shared_span() and its subtree
+        # the ONE list of (trace_id, root span_id) it is filed under
+        self.links = None
         self._t0 = time.perf_counter_ns()
         self.ts_us = self._t0 / 1000.0   # RecordEvent's timebase
         self.dur_us = None
@@ -157,6 +177,15 @@ class Span:
         _record(self)
         heartbeat()
         return self
+
+    def link(self, root: "Span") -> None:
+        """File this shared span (and its subtree) under `root`'s trace
+        as well; a no-op on an ordinary span or a null `root`."""
+        if self.links is not None and root:
+            self.links.append((root.trace_id, root.span_id))
+            self.attrs.setdefault("trace_ids", []).append(root.trace_id)
+            with _store_lock:
+                _open_trace(root.trace_id)
 
     def to_dict(self) -> dict:
         return {
@@ -185,6 +214,9 @@ class _NullSpan:
 
     def end(self, **attrs):
         return self
+
+    def link(self, root):
+        return None
 
     def to_dict(self):
         return {}
@@ -249,7 +281,7 @@ def _interesting(spans, root) -> bool:
     if fin is not None and fin != "stop":
         return True
     for d in spans:
-        if d["attrs"].get("error"):
+        if _entry_dict(d)["attrs"].get("error"):
             return True
     return False
 
@@ -268,15 +300,56 @@ def _tail_keep(spans, root) -> bool:
     return False
 
 
+def _entry_dict(entry) -> dict:
+    """A trace's list holds its own spans as dicts and the shared spans
+    filed under it as (root span_id, dict) pairs."""
+    return entry if type(entry) is dict else entry[1]
+
+
+def _adopted(entry, trace_id: str) -> dict:
+    """A shared span as the linked trace shows it: under that trace's id,
+    the shared root re-parented onto the linked root span."""
+    root_id, d = entry
+    d = dict(d, trace_id=trace_id)
+    if d["parent_id"] is None:
+        d["parent_id"] = root_id
+    return d
+
+
+def _open_trace(trace_id: str) -> list:
+    """The trace's list, made (and the oldest trace evicted) if absent;
+    call under _store_lock."""
+    spans = _traces.get(trace_id)
+    if spans is None:
+        spans = _traces[trace_id] = []
+        while len(_traces) > _MAX_TRACES:
+            _traces.popitem(last=False)
+    return spans
+
+
+def _record_shared(s: Span) -> None:
+    """File a shared span ONCE under every linked trace that is still in
+    the store (link() opened it; one that tail sampling has dropped since
+    is not brought back), and once in the flight ring.  It is no trace
+    of its own: steps would evict the requests from the bounded store."""
+    d = s.to_dict()
+    with _store_lock:
+        for trace_id, root_id in s.links:
+            spans = _traces.get(trace_id)
+            if spans is not None:
+                spans.append((root_id, d))
+    from . import flight
+
+    flight.record_span(d)
+
+
 def _record(s: Span) -> None:
+    if s.links is not None:
+        return _record_shared(s)
     d = s.to_dict()
     dropped = False
     with _store_lock:
-        spans = _traces.get(s.trace_id)
-        if spans is None:
-            spans = _traces[s.trace_id] = []
-            while len(_traces) > _MAX_TRACES:
-                _traces.popitem(last=False)
+        spans = _open_trace(s.trace_id)
         spans.append(d)
         # root ended → the trace is complete; with sampling on, decide
         # NOW whether the whole tree stays in the store
@@ -302,7 +375,9 @@ def get_trace(trace_id: str) -> list:
     """Every finished span of one trace (start-ordered span dicts);
     [] for an unknown/evicted id."""
     with _store_lock:
-        spans = list(_traces.get(trace_id, ()))
+        entries = list(_traces.get(trace_id, ()))
+    spans = [e if type(e) is dict else _adopted(e, trace_id)
+             for e in entries]
     return sorted(spans, key=lambda d: d["ts_us"])
 
 
@@ -423,7 +498,10 @@ def start_span(name: str, parent=None, trace_id=None, **attrs):
         trace_id = trace_id or parent.trace_id
     if trace_id is None:
         trace_id = _next_id("t")
-    return Span(name, trace_id, parent_id, attrs)
+    s = Span(name, trace_id, parent_id, attrs)
+    if type(parent) is Span:
+        s.links = parent.links      # a shared span's subtree is shared
+    return s
 
 
 class _Active:
@@ -461,6 +539,104 @@ def span(name: str, **attrs):
     return _Active(start_span(name, parent=_ctx.span, **attrs))
 
 
+def shared_span(name: str, **attrs):
+    """Context-manager span for work done on behalf of several traces —
+    an engine step that serves a batch of requests::
+
+        with trace.shared_span("serving/step") as sp:
+            ...
+            for req in batch:
+                sp.link(req.trace)     # the request's root span
+
+    It is a root of its own; it and every span opened inside it are
+    stored once and filed under each linked trace (``attrs["trace_ids"]``
+    names them), where ``get_trace`` shows the step as a child of the
+    linked root.  With no link it reaches only the flight ring."""
+    if not _enabled:
+        return _NULL
+    s = start_span(name, **attrs)
+    s.links = []
+    return _Active(s)
+
+
+# -- phases: one boundary, three clocks -------------------------------------
+PHASE_METRIC = "serving/host_time"
+PHASE_PREFIX = "ptpu:"
+_annotation_cls = None
+_phase_series: dict = {}
+
+
+def _profiler_annotation():
+    """jax's TraceAnnotation class once ``jax`` is loaded, else None —
+    this module never imports jax by itself."""
+    global _annotation_cls
+    if _annotation_cls is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return None
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+def _session_open() -> bool:
+    """Is a profiler session collecting annotations in this process?"""
+    cls = _profiler_annotation()
+    return cls is not None and cls.is_enabled()
+
+
+def annotation(name: str):
+    """An un-entered profiler annotation called `name`, or None when no
+    profiler session is open in this process (or jax is not loaded).
+    Inside a session (the benchmark's traced slice, `profiler.Profiler`,
+    a TensorBoard capture) the event lands in the session's xplane on
+    the clock of the device's operations."""
+    return _annotation_cls(name) if _session_open() else None
+
+
+class _Phase:
+    __slots__ = ("_name", "_t0", "_ann", "_active")
+
+    def __init__(self, name):
+        self._name = name
+
+    def __enter__(self):
+        self._ann = ann = annotation(PHASE_PREFIX + self._name)
+        if ann is not None:
+            ann.__enter__()
+        self._active = None
+        if _enabled and _ctx.span is not None:
+            self._active = _Active(start_span(self._name, parent=_ctx.span))
+            self._active.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, etype, evalue, tb):
+        dt = time.perf_counter() - self._t0
+        if self._active is not None:
+            self._active.__exit__(etype, evalue, tb)
+        if self._ann is not None:
+            self._ann.__exit__(etype, evalue, tb)
+        series = _phase_series.get(self._name)
+        if series is None:       # PHASE_METRIC, spelt out for the lint
+            series = _phase_series[self._name] = _histogram(
+                "serving/host_time",
+                "host seconds in one phase of the serving loop").labels(
+                phase=self._name)
+        series.observe(dt)
+        return False
+
+
+def phase(name: str):
+    """Time one phase of the serving loop (see the module docstring for
+    the three places the time goes, and `serving/engine.py` for the
+    phase names).  With the monitor and tracing off and no profiler
+    session open it is three flag reads and the no-op singleton."""
+    if not (_monitor_enabled() or _enabled or _session_open()):
+        return _NULL
+    return _Phase(name)
+
+
 # -- chrome/Perfetto export -------------------------------------------------
 
 def chrome_events(trace_id=None) -> list:
@@ -469,11 +645,21 @@ def chrome_events(trace_id=None) -> list:
     trace_id; ts/dur are in µs on the perf_counter timebase — the SAME
     base as profiler.RecordEvent host events."""
     pid = os.getpid()
-    with _store_lock:
-        if trace_id is not None:
-            groups = [list(_traces.get(trace_id, ()))]
-        else:
+    if trace_id is not None:
+        groups = [get_trace(trace_id)]
+    else:
+        # every trace: a shared span appears once, under its own ids
+        with _store_lock:
             groups = [list(v) for v in _traces.values()]
+        seen = set()
+        for i, entries in enumerate(groups):
+            groups[i] = own = []
+            for e in entries:
+                if type(e) is dict:
+                    own.append(e)
+                elif e[1]["span_id"] not in seen:
+                    seen.add(e[1]["span_id"])
+                    own.append(e[1])
     out = []
     for spans in groups:
         for d in spans:

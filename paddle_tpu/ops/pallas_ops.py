@@ -513,6 +513,7 @@ def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
         args.extend([segments, segments])
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -581,6 +582,7 @@ def _flash_bwd(q, k, v, out, lse, do, is_causal, scale,
                           has_mask=has_mask, has_lens=has_lens,
                           has_segs=has_segs,
                           causal_offset=sk - sq),
+        name="flash_bwd_dq",
         grid=(bh // H, H, sq // block_q),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d),
@@ -616,6 +618,7 @@ def _flash_bwd(q, k, v, out, lse, do, is_causal, scale,
                           has_mask=has_mask, has_lens=has_lens,
                           has_segs=has_segs,
                           causal_offset=sk - sq),
+        name="flash_bwd_dkv",
         grid=(bh // H, H, sk // block_k),
         in_specs=in_specs,
         out_specs=[
@@ -1191,6 +1194,7 @@ def flash_decode_arrays(q, k_cache, v_cache, length, scale=None,
     lengths = jnp.asarray(length, jnp.int32).reshape(1)
     out = pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
         interpret=_interpret(),
@@ -1400,6 +1404,7 @@ def fused_decode_layer_arrays(x, ln_w, ln_b, wqkv, bqkv, wo, bo,
     # outputs (y=0, k=1, v=2)
     y, k2, v2 = pl.pallas_call(
         kernel,
+        name="fused_decode_layer",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hd), x.dtype),
@@ -1523,6 +1528,7 @@ def _ln_fwd(x2, w, b, eps):
     out_dt = jnp.promote_types(jnp.promote_types(x2.dtype, w.dtype), b.dtype)
     return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
+        name="layer_norm_fwd",
         grid=(n // bm,),
         in_specs=[
             pl.BlockSpec((bm, h), lambda i: (i, 0)),
@@ -1557,6 +1563,7 @@ def _ln_vjp_bwd(eps, res, dy):
     grid = n // bm
     dx, dwp, dbp = pl.pallas_call(
         _ln_bwd_kernel,
+        name="layer_norm_bwd",
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((bm, h), lambda i: (i, 0)),
@@ -1655,6 +1662,7 @@ def fused_ffn_2d(x2, w1, b1, w2, act):
     block_i = 512 if i % 512 == 0 else 128
     return pl.pallas_call(
         functools.partial(_ffn_fwd_kernel, block_i=block_i, act=act),
+        name="ffn_fwd",
         grid=(n // bm,),
         in_specs=[
             pl.BlockSpec((bm, h), lambda r: (r, 0)),

@@ -356,6 +356,7 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     # tables=2, q=3, k_new=4, v_new=5, pools=6/7)
     outs = pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
                    jax.ShapeDtypeStruct((nb, bs, hd), pool_dt),

@@ -13,6 +13,8 @@ import threading
 import time
 from contextlib import contextmanager
 
+from ..monitor import trace as _mtrace
+
 __all__ = [
     "Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
@@ -44,8 +46,8 @@ _tracer = _HostTracer()
 
 class RecordEvent:
     """Host span annotation (reference: platform::RecordEvent,
-    profiler/event_tracing.h:49). Also emits a jax TraceAnnotation so spans
-    appear in xplane captures."""
+    profiler/event_tracing.h:49). Inside a profiler session the span also
+    lands in the xplane capture (`monitor.trace.annotation`)."""
 
     def __init__(self, name, event_type=None):
         self.name = name
@@ -54,13 +56,9 @@ class RecordEvent:
 
     def begin(self):
         self._t0 = time.perf_counter_ns()
-        try:
-            import jax.profiler
-
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
+        self._jax_ctx = _mtrace.annotation(self.name)
+        if self._jax_ctx is not None:
             self._jax_ctx.__enter__()
-        except Exception:
-            self._jax_ctx = None
 
     def end(self):
         if self._jax_ctx is not None:

@@ -59,6 +59,7 @@ from typing import Optional
 
 from .. import monitor
 from ..monitor import reqlog as mreqlog
+from ..monitor import trace as mtrace
 from .scheduler import SamplingParams, should_shed, worst_fast_burn
 
 __all__ = ["ApiServer", "start_api_server", "api_error",
@@ -131,6 +132,7 @@ class _Stream:
         self.req = None            # engine-mode: the live Request object
         self.sent = 0              # generated tokens already pushed
         self.cancelled = False     # handler gone — pump must release
+        self.submitted_t = None    # perf_counter at submit()
 
 
 class ApiServer:
@@ -166,6 +168,10 @@ class ApiServer:
             "by tenant")
         self._m_http = monitor.counter(
             "serving/http_requests", "API requests by response class")
+        self._m_submit_wait = monitor.histogram(
+            "serving/submit_wait",
+            "handler's submit to the pump's add_request, seconds (the "
+            "wait serving/queue_wait starts after)")
         self._stop = threading.Event()
         self._pump_thread = threading.Thread(
             target=self._pump, name="ptpu-api-pump", daemon=True)
@@ -193,6 +199,7 @@ class ApiServer:
     # -- handler-side API ---------------------------------------------------
 
     def submit(self, stream: _Stream) -> None:
+        stream.submitted_t = time.perf_counter()
         self._submit_q.put(stream)
 
     def live_burn(self) -> float:
@@ -227,11 +234,13 @@ class ApiServer:
 
     def _pump_once(self) -> None:
         busy = bool(self._streams)
-        self._drain_submits(block_s=0.0 if busy else self.poll_s)
+        with mtrace.phase("api/drain_submits"):
+            self._drain_submits(block_s=0.0 if busy else self.poll_s)
         if self.engine is not None:
             if self.engine.has_unfinished():
                 self.engine.step()
-            self._push_engine_progress()
+            with mtrace.phase("api/push_progress"):
+                self._push_engine_progress()
         else:
             self.router.poll()
             self._push_router_results()
@@ -253,6 +262,7 @@ class ApiServer:
             self._handle_submit(st)
 
     def _handle_submit(self, st: _Stream) -> None:
+        self._m_submit_wait.observe(time.perf_counter() - st.submitted_t)
         try:
             if self.engine is not None:
                 st.rid = self.engine.add_request(st.prompt_ids, st.params)

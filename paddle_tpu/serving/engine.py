@@ -71,9 +71,38 @@ caching, counted by the cache) and
 `serving/spec_proposed`/`spec_accepted`/`spec_accept_rate`
 (speculative decoding).
 
+Host phases (monitor.trace.phase): every boundary of `step()` is one
+phase, and the API pump adds two of its own around it:
+
+    api/drain_submits       the pump's _drain_submits, blocking get included
+    api/push_progress       _push_engine_progress: a queue.put per stream
+    engine/schedule         deadline sweep, shedding, scheduler.schedule(),
+                            preemption counts
+    engine/prepare          the step's input arrays (a prefill's ids/slots,
+                            a spec step's n-gram drafts), their uploads, the
+                            model program's dispatch call, _store_kv
+    engine/sample_dispatch  the sampler's arrays and its dispatch — the
+                            device is busy with the model program
+    engine/readback         np.asarray of tokens/keys: blocked on the device
+    engine/emit             per row: key upload, record_token, TTFT/TPOT
+                            (spec: acceptance and the table roll-back)
+    engine/retire           retire_finished, _finish_request, the SLO tick,
+                            the step's counters and gauges
+
+Except in sample_dispatch and readback the device has nothing queued:
+the other six sum to the step's host gap.  Gates: PTPU_MONITOR (default
+on) puts each duration into `serving/host_time{phase}`, nothing synced
+for it; an open profiler session gets a host event `ptpu:<phase>` on the
+device operations' clock (the programs are named for that view:
+prefill_<len>, ragged_decode, ragged_prefill_<c>, chunk_decode,
+chunk_prefill_<c>, spec_verify, sample); PTPU_TRACE=1 adds a
+`serving/step` span per step (`phase`, `rows`, the riders' `trace_ids`),
+the phases its children, filed under every rider's trace.
+
 Observability v2 (monitor.trace): with PTPU_TRACE=1 every request gets a
 trace — root `serving/request` span with `serving/queue_wait`,
-`serving/prefill` (one per chunk), and `serving/decode_step` children —
+`serving/prefill` (one per chunk), `serving/decode_step` (their `step`
+names the `serving/step` they rode) and those `serving/step` children —
 readable via `request_trace(rid)`, `/traces/<id>` on the live endpoint
 (`EngineConfig(metrics_port=...)`), or `trace.export_chrome_trace()`.
 Per-request latency decomposes into `serving/ttft` (arrival → first
@@ -741,79 +770,99 @@ class LLMEngine:
         # secs=..."): the step blocks here, completing no span, so the
         # monitor.watchdog post-mortem path is provable in tests
         faults.maybe_stall(site="engine.step")
-        self._expire_deadlines()
-        self._shed_best_effort()
-        try:
-            out = self.scheduler.schedule()
-        except RuntimeError as e:
-            # ISSUE 20 pressure forensics: an admission failure ("KV
-            # cache too small") leaves a kv_pressure flight dump naming
-            # who actually holds the pool, then propagates untouched
-            if "KV cache too small" in str(e):
-                self._kv_pressure("admission_failure", error=str(e))
-            raise
-        if out.preempted:
-            self._m_preempt.inc(len(out.preempted))
-            for r in out.preempted:
-                r.num_preemptions += 1
-        if out.kind == "prefill":
-            self._step_prefill(out)
-            phase, toks = "prefill", out.chunk_len
-        elif out.kind == "decode":
-            # spec decoding can emit MORE tokens than rows in one step —
-            # the decode body reports the real emitted count
-            toks = self._step_decode(out)
-            phase = "decode"
-        else:
-            phase, toks = "idle", 0
-        if mreqlog.enabled():
-            # peak-KV high-water per request: only worth the O(running)
-            # walk when someone is collecting the wide events
-            for r in self.scheduler.running:
-                blocks = len(self.cache._tables.get(r.req_id, ()))
-                if blocks > r.peak_kv_blocks:
-                    r.peak_kv_blocks = blocks
-        done = self.scheduler.retire_finished()
-        for req in done:
-            self._m_done.inc()
-            self._finish_request(req, "stop")
-        mslo.maybe_tick()   # one module-global read with PTPU_SLO unset
-        dt = time.perf_counter() - t0
-        mtrace.heartbeat()   # step completed — feed the watchdog even
-        #                      with tracing off (no span ends to beat)
-        if monitor.enabled():
-            self._m_step.labels(phase=phase).observe(dt)
-            # goodput: generated tokens over TOTAL engine wall time —
-            # decode_tps reads a single step, this reads the serving
-            # story (prefill, scheduling, idle steps all dilute it)
-            self._wall_s_total += dt
-            if phase == "prefill":
-                self._m_pre_toks.inc(toks)
-                self._m_pre_tps.set(toks / max(dt, 1e-9))
-            elif phase == "decode":
-                self._m_dec_toks.inc(toks)
-                self._m_dec_tps.set(toks / max(dt, 1e-9))
-                self._goodput_toks += toks
-            self._m_goodput.set(
-                self._goodput_toks / max(self._wall_s_total, 1e-9))
-            sched = self.scheduler
-            # queue_depth: admission backlog (never-started requests);
-            # waiting: everything not running, preempted included
-            self._m_queue.set(sum(1 for r in sched.waiting
-                                  if r.state == Request.WAITING))
-            self._m_running.set(len(sched.running))
-            self._m_waiting.set(len(sched.waiting))
-            # ISSUE 20: every capacity gauge reads the cache's ONE
-            # counts() source — utilization and the admission view
-            # (free+parked) can no longer be computed in two places
-            c = self.cache.counts()
-            self._m_blocks.set(c["in_use"])
-            self._m_util.set(c["in_use"] / max(c["total"], 1))
-            self._m_kv_free.set(c["free"])
-            self._m_kv_parked.set(c["parked"])
+        with mtrace.shared_span("serving/step") as step_span:
+            out, done = self._step_phases(t0, step_span)
         if mmem.enabled():
             self._memobs_step(out)
         return list(done)
+
+    def _step_phases(self, t0, step_span):
+        """step()'s body, phase by phase (the table in the module
+        docstring).  `step_span` is the step's shared span with
+        PTPU_TRACE=1 and the null span otherwise."""
+        with mtrace.phase("engine/schedule"):
+            self._expire_deadlines()
+            self._shed_best_effort()
+            try:
+                out = self.scheduler.schedule()
+            except RuntimeError as e:
+                # ISSUE 20 pressure forensics: an admission failure ("KV
+                # cache too small") leaves a kv_pressure flight dump naming
+                # who actually holds the pool, then propagates untouched
+                if "KV cache too small" in str(e):
+                    self._kv_pressure("admission_failure", error=str(e))
+                raise
+            if out.preempted:
+                self._m_preempt.inc(len(out.preempted))
+                for r in out.preempted:
+                    r.num_preemptions += 1
+            if step_span:
+                # before any phase span ends: the subtree is filed under
+                # the traces linked by then
+                riders = ([out.prefill_request] if out.prefill_request
+                          else out.decode_requests)
+                for r in riders:
+                    step_span.link(r.trace)
+                step_span.attrs.update(phase=out.kind, rows=len(riders))
+        toks = 0
+        if out.kind == "prefill":
+            self._step_prefill(out, step_span)
+            toks = out.chunk_len
+        elif out.kind == "decode":
+            # spec decoding can emit MORE tokens than rows in one step —
+            # the decode body reports the real emitted count
+            toks = self._step_decode(out, step_span)
+        with mtrace.phase("engine/retire"):
+            if mreqlog.enabled():
+                # peak-KV high-water per request: only worth the O(running)
+                # walk when someone is collecting the wide events
+                for r in self.scheduler.running:
+                    blocks = len(self.cache._tables.get(r.req_id, ()))
+                    if blocks > r.peak_kv_blocks:
+                        r.peak_kv_blocks = blocks
+            done = self.scheduler.retire_finished()
+            for req in done:
+                self._m_done.inc()
+                self._finish_request(req, "stop")
+            mslo.maybe_tick()   # one module-global read with PTPU_SLO unset
+            dt = time.perf_counter() - t0
+            mtrace.heartbeat()   # step completed — feed the watchdog even
+            #                      with tracing off (no span ends to beat)
+            if monitor.enabled():
+                self._observe_step(out.kind, toks, dt)
+        return out, done
+
+    def _observe_step(self, phase, toks, dt) -> None:
+        """The per-step counters and gauges (monitor on)."""
+        self._m_step.labels(phase=phase).observe(dt)
+        # goodput: generated tokens over TOTAL engine wall time —
+        # decode_tps reads a single step, this reads the serving
+        # story (prefill, scheduling, idle steps all dilute it)
+        self._wall_s_total += dt
+        if phase == "prefill":
+            self._m_pre_toks.inc(toks)
+            self._m_pre_tps.set(toks / max(dt, 1e-9))
+        elif phase == "decode":
+            self._m_dec_toks.inc(toks)
+            self._m_dec_tps.set(toks / max(dt, 1e-9))
+            self._goodput_toks += toks
+        self._m_goodput.set(
+            self._goodput_toks / max(self._wall_s_total, 1e-9))
+        sched = self.scheduler
+        # queue_depth: admission backlog (never-started requests);
+        # waiting: everything not running, preempted included
+        self._m_queue.set(sum(1 for r in sched.waiting
+                              if r.state == Request.WAITING))
+        self._m_running.set(len(sched.running))
+        self._m_waiting.set(len(sched.waiting))
+        # ISSUE 20: every capacity gauge reads the cache's ONE
+        # counts() source — utilization and the admission view
+        # (free+parked) can no longer be computed in two places
+        c = self.cache.counts()
+        self._m_blocks.set(c["in_use"])
+        self._m_util.set(c["in_use"] / max(c["total"], 1))
+        self._m_kv_free.set(c["free"])
+        self._m_kv_parked.set(c["parked"])
 
     # -- memory microscope (ISSUE 20; PTPU_MEMOBS-gated) --------------------
 
@@ -886,7 +935,7 @@ class LLMEngine:
 
     # -- step bodies --------------------------------------------------------
 
-    def _step_prefill(self, out):
+    def _step_prefill(self, out, step_span):
         req = out.prefill_request
         start, chunk = out.chunk_start, out.chunk_len
         req.prefill_chunks += 1
@@ -904,7 +953,8 @@ class LLMEngine:
         sp = None
         if req.trace is not None:
             sp = mtrace.start_span("serving/prefill", parent=req.trace,
-                                   chunk_start=start, chunk_len=chunk)
+                                   chunk_start=start, chunk_len=chunk,
+                                   step=step_span.span_id)
         try:
             self._prefill_body(req, start, chunk)
         finally:
@@ -912,42 +962,45 @@ class LLMEngine:
                 sp.end()
 
     def _prefill_body(self, req, start, chunk):
-        ids = np.asarray([req.prompt_ids[start:start + chunk]], np.int32)
-        positions = np.arange(start, start + chunk, dtype=np.int64)
-        slots = np.asarray(
-            [[self.cache.slot(req.req_id, int(p)) for p in positions]],
-            np.int32)
-        kv = self._kv_flat()
-        if start == 0 and chunk == req.prompt_len:
-            # whole prompt in one chunk: flash within the chunk, the
-            # dense prefill's exact arithmetic
-            fn = self._get_prefill_exec(chunk)
-            logits, kv_out = fn(self._param_arrays(), kv, jnp.asarray(ids),
-                                jnp.asarray(slots))
-        else:
-            tables = jnp.asarray(
-                [self.cache.padded_table(req.req_id, self.blocks_per_seq)],
-                jnp.int32)
-            if self.attention_impl == "ragged":
-                fn = self._get_ragged_exec(1, chunk)
-                logits, kv_out = fn(
-                    self._param_arrays(), kv, jnp.asarray(ids),
-                    jnp.asarray([start], jnp.int32),
-                    jnp.asarray([start + chunk], jnp.int32), tables,
-                    jnp.asarray(slots))
+        with mtrace.phase("engine/prepare"):
+            ids = np.asarray([req.prompt_ids[start:start + chunk]],
+                             np.int32)
+            positions = np.arange(start, start + chunk, dtype=np.int64)
+            slots = np.asarray(
+                [[self.cache.slot(req.req_id, int(p)) for p in positions]],
+                np.int32)
+            kv = self._kv_flat()
+            if start == 0 and chunk == req.prompt_len:
+                # whole prompt in one chunk: flash within the chunk, the
+                # dense prefill's exact arithmetic
+                fn = self._get_prefill_exec(chunk)
+                logits, kv_out = fn(self._param_arrays(), kv,
+                                    jnp.asarray(ids), jnp.asarray(slots))
             else:
-                fn = self._get_chunk_exec(1, chunk)
-                logits, kv_out = fn(
-                    self._param_arrays(), kv, jnp.asarray(ids),
-                    jnp.asarray([start], jnp.int32), tables,
-                    jnp.asarray(slots))
-        self._store_kv(kv_out)
-        req.num_computed = start + chunk
-        if req.prefix_keys:
-            # index the blocks this chunk just filled (full prompt blocks
-            # only — their content is final while referenced)
-            self.cache.register_prefix(req.req_id, req.prefix_keys,
-                                       req.num_computed)
+                tables = jnp.asarray(
+                    [self.cache.padded_table(req.req_id,
+                                             self.blocks_per_seq)],
+                    jnp.int32)
+                if self.attention_impl == "ragged":
+                    fn = self._get_ragged_exec(1, chunk)
+                    logits, kv_out = fn(
+                        self._param_arrays(), kv, jnp.asarray(ids),
+                        jnp.asarray([start], jnp.int32),
+                        jnp.asarray([start + chunk], jnp.int32), tables,
+                        jnp.asarray(slots))
+                else:
+                    fn = self._get_chunk_exec(1, chunk)
+                    logits, kv_out = fn(
+                        self._param_arrays(), kv, jnp.asarray(ids),
+                        jnp.asarray([start], jnp.int32), tables,
+                        jnp.asarray(slots))
+            self._store_kv(kv_out)
+            req.num_computed = start + chunk
+            if req.prefix_keys:
+                # index the blocks this chunk just filled (full prompt
+                # blocks only — their content is final while referenced)
+                self.cache.register_prefix(req.req_id, req.prefix_keys,
+                                           req.num_computed)
         if req.prefill_done:
             if req.params.max_new_tokens <= 0:
                 # dense generate(max_new_tokens=0) emits nothing
@@ -955,10 +1008,11 @@ class LLMEngine:
             else:
                 self._sample_rows([req], logits)
 
-    def _step_decode(self, out) -> int:
+    def _step_decode(self, out, step_span) -> int:
         rows = list(out.decode_requests)
         spans = [mtrace.start_span("serving/decode_step", parent=r.trace,
-                                   pos=r.total_len - 1, batch=len(rows))
+                                   pos=r.total_len - 1, batch=len(rows),
+                                   step=step_span.span_id)
                  for r in rows if r.trace is not None]
         try:
             return self._decode_body(rows)
@@ -968,7 +1022,8 @@ class LLMEngine:
 
     def _decode_body(self, rows) -> int:
         if self.spec_tokens:
-            drafts = [self._propose(r) for r in rows]
+            with mtrace.phase("engine/prepare"):   # the host's n-gram scan
+                drafts = [self._propose(r) for r in rows]
             if any(drafts):
                 return self._decode_body_spec(rows, drafts)
             # zero drafts anywhere this step (cold history, sampling
@@ -978,19 +1033,14 @@ class LLMEngine:
             # padding.  Both shapes compile once; steady state stays
             # two launches either way.
             n = self._decode_body_plain(rows)
-            for req in rows:
-                # release the scheduler's (clamped) draft reservation
-                self.cache.truncate_to(req.req_id, req.total_len)
+            with mtrace.phase("engine/emit"):
+                for req in rows:
+                    # release the scheduler's (clamped) draft reservation
+                    self.cache.truncate_to(req.req_id, req.total_len)
             return n
         return self._decode_body_plain(rows)
 
     def _decode_body_plain(self, rows) -> int:
-        # perf mode (PTPU_PERF=1): the decode hot path reports named,
-        # properly-synced sub-step segments — host `prep`, the fused
-        # `model` program (gather+attention+cache update), and `sampler`
-        # (timed inside _sample_rows, whose np.asarray readback syncs it)
-        perf_on = mperf.enabled()
-        t0 = time.perf_counter() if perf_on else 0.0
         n = len(rows)
         mon = monitor.enabled()
         # launch accounting (ISSUE 12): every jitted dispatch this step
@@ -1000,69 +1050,71 @@ class LLMEngine:
         ragged = self.attention_impl == "ragged"
         # ragged: ONE fixed shape (max_num_seqs) serves every batch
         # composition — no per-bucket recompiles when the running-request
-        # count crosses a power of 2
+        # count crosses a power of 2; only the bucketed fallback derives
+        # bb from len(rows), and its pow-2 buckets bound the program count
+        # at log2(max_num_seqs) BY DESIGN (pinned by the bucket-crossing
+        # recompile tests)
         bb = (self.scheduler.max_num_seqs if ragged
               else self._bucket_batch(n))
-        num_slots = self.cache.num_slots
-        # recompile-hazard markers below: on the ragged DEFAULT bb is
-        # the FIXED max_num_seqs (zero hazard); only the bucketed
-        # fallback derives bb from len(rows), and there the pow-2
-        # bucketing bounds the program count at log2(max_num_seqs) BY
-        # DESIGN (pinned by the bucket-crossing recompile tests)
-        toks = np.zeros((bb, 1), np.int32)  # ptpu-check[recompile-hazard]: pow2-bounded, see above
-        pos0 = np.zeros((bb,), np.int32)  # ptpu-check[recompile-hazard]: pow2-bounded, see above
-        lens = np.zeros((bb,), np.int32)  # ptpu-check[recompile-hazard]: pow2-bounded, see above
-        tables = np.full((bb, self.blocks_per_seq), self.cache.num_blocks,
-                         np.int32)  # ptpu-check[recompile-hazard]: pow2-bounded, see above
-        slots = np.full((bb, 1), num_slots, np.int32)  # ptpu-check[recompile-hazard]: pow2-bounded, see above
-        for i, req in enumerate(rows):
-            toks[i, 0] = req.output_ids[-1] if req.output_ids \
-                else req.prompt_ids[-1]
-            p = req.total_len - 1
-            pos0[i] = p
-            lens[i] = req.total_len
-            tables[i] = self.cache.padded_table(req.req_id,
-                                                self.blocks_per_seq)
-            slots[i, 0] = self.cache.slot(req.req_id, p)
         self._m_attn_impl.labels(kind=self.attention_impl).inc()
-        if perf_on:
-            t1 = time.perf_counter()
-            mperf.observe_segment("decode", "prep", t1 - t0)
-        if ragged:
-            fn = self._get_ragged_exec(bb, 1)
-            if mon:
-                self._launches_this_step.add(("ragged", bb, 1))
-            logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
-                                jnp.asarray(toks), jnp.asarray(pos0),
-                                jnp.asarray(lens), jnp.asarray(tables),
-                                jnp.asarray(slots))
-        else:
-            fn = self._get_chunk_exec(bb, 1)
-            if mon:
-                self._launches_this_step.add(("chunk", bb, 1))
-            logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
-                                jnp.asarray(toks), jnp.asarray(pos0),
-                                jnp.asarray(tables), jnp.asarray(slots))
-        if perf_on:
-            jax.block_until_ready(logits)
-            mperf.observe_segment("decode", "model",
-                                  time.perf_counter() - t1)
-        self._store_kv(kv_out)
+        with mtrace.phase("engine/prepare"):
+            toks, pos0, lens, tables, slots = self._decode_inputs(
+                rows, [()] * n, bb, 1)
+            if ragged:
+                fn = self._get_ragged_exec(bb, 1)
+                if mon:
+                    self._launches_this_step.add(("ragged", bb, 1))
+                logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
+                                    jnp.asarray(toks), jnp.asarray(pos0),
+                                    jnp.asarray(lens), jnp.asarray(tables),
+                                    jnp.asarray(slots))
+            else:
+                fn = self._get_chunk_exec(bb, 1)
+                if mon:
+                    self._launches_this_step.add(("chunk", bb, 1))
+                logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
+                                    jnp.asarray(toks), jnp.asarray(pos0),
+                                    jnp.asarray(tables), jnp.asarray(slots))
+            self._store_kv(kv_out)
         self._sample_rows(rows, logits)
         if mon:
             # padding accounting: bb rows ran, n were real — the
             # serving-goodput blind spot the ragged fixed-shape program
             # introduced.  Decode runs C=1, so rows ARE tokens and the
-            # two series carry one value today; they diverge only if a
-            # multi-token decode (speculative verification, ROADMAP
-            # item 1) lands on this path — the schema reserves the
-            # distinction now so consumers never need a migration
+            # two series carry one value here; they diverge on the
+            # speculative path (_decode_body_spec)
             waste = (bb - n) / max(bb, 1)
             self._m_pad_rows.set(waste)
             self._m_pad_toks.set(waste)
             self._m_kernels.set(len(self._launches_this_step))
             self._launches_this_step = None
         return n
+
+    def _decode_inputs(self, rows, drafts, bb, cw):
+        """Host arrays of one decode program of fixed shape [bb, cw]: row
+        i feeds its last token and `drafts[i]`; padding rows and unused
+        draft positions keep the dropped-slot sentinel (no write, outputs
+        never read)."""
+        toks = np.zeros((bb, cw), np.int32)
+        pos0 = np.zeros((bb,), np.int32)
+        lens = np.zeros((bb,), np.int32)
+        tables = np.full((bb, self.blocks_per_seq), self.cache.num_blocks,
+                         np.int32)
+        slots = np.full((bb, cw), self.cache.num_slots, np.int32)
+        for i, req in enumerate(rows):
+            toks[i, 0] = req.output_ids[-1] if req.output_ids \
+                else req.prompt_ids[-1]
+            m = len(drafts[i])
+            if m:
+                toks[i, 1:1 + m] = drafts[i]
+            p = req.total_len - 1
+            pos0[i] = p
+            lens[i] = req.total_len + m
+            tables[i] = self.cache.padded_table(req.req_id,
+                                                self.blocks_per_seq)
+            for j in range(1 + m):
+                slots[i, j] = self.cache.slot(req.req_id, p + j)
+        return toks, pos0, lens, tables, slots
 
     # -- speculative decoding (ISSUE 15 b) ----------------------------------
 
@@ -1097,62 +1149,24 @@ class LLMEngine:
         plus the correction token is accepted, and the block table rolls
         back to the accepted length.  Multiple tokens per step at the
         same TWO program launches as plain decode."""
-        perf_on = mperf.enabled()
-        t0 = time.perf_counter() if perf_on else 0.0
         n = len(rows)
         mon = monitor.enabled()
         self._launches_this_step = set() if mon else None
-        k = self.spec_tokens
-        cw = k + 1                     # verify chunk width, fixed
+        cw = self.spec_tokens + 1      # verify chunk width, fixed
         bb = self.scheduler.max_num_seqs
-        num_slots = self.cache.num_slots
-        # fixed [bb, k+1] shapes: bb is the engine-constant max_num_seqs
-        # and k the engine-constant draft budget — zero recompile hazard
-        toks = np.zeros((bb, cw), np.int32)
-        pos0 = np.zeros((bb,), np.int32)
-        lens = np.zeros((bb,), np.int32)
-        tables = np.full((bb, self.blocks_per_seq), self.cache.num_blocks,
-                         np.int32)
-        slots = np.full((bb, cw), num_slots, np.int32)
-        for i, req in enumerate(rows):
-            toks[i, 0] = req.output_ids[-1] if req.output_ids \
-                else req.prompt_ids[-1]
-            m = len(drafts[i])
-            if m:
-                toks[i, 1:1 + m] = drafts[i]
-            p = req.total_len - 1
-            pos0[i] = p
-            lens[i] = req.total_len + m
-            tables[i] = self.cache.padded_table(req.req_id,
-                                                self.blocks_per_seq)
-            for j in range(1 + m):
-                # draft positions past m keep the dropped-slot sentinel:
-                # no write, garbage logits the emission loop never reads
-                slots[i, j] = self.cache.slot(req.req_id, p + j)
         self._m_attn_impl.labels(kind=self.attention_impl).inc()
-        if perf_on:
-            t1 = time.perf_counter()
-            mperf.observe_segment("decode", "prep", t1 - t0)
-        fn = self._get_verify_exec(bb, cw)
-        if mon:
-            self._launches_this_step.add(("verify", bb, cw))
-        logits0, greedy, kv_out = fn(
-            self._param_arrays(), self._kv_flat(), jnp.asarray(toks),
-            jnp.asarray(pos0), jnp.asarray(lens), jnp.asarray(tables),
-            jnp.asarray(slots))
-        if perf_on:
-            jax.block_until_ready(logits0)
-            mperf.observe_segment("decode", "model",
-                                  time.perf_counter() - t1)
-        self._store_kv(kv_out)
-        emitted = self._emit_spec(rows, drafts, logits0,
-                                  np.asarray(greedy))
-        # roll every table back to its accepted length (rejected-draft
-        # blocks return to the pool; finished rows are freed by
-        # retire_finished right after — truncating first keeps the
-        # shared-block refcounts exact either way)
-        for req in rows:
-            self.cache.truncate_to(req.req_id, req.total_len)
+        with mtrace.phase("engine/prepare"):
+            toks, pos0, lens, tables, slots = self._decode_inputs(
+                rows, drafts, bb, cw)
+            fn = self._get_verify_exec(bb, cw)
+            if mon:
+                self._launches_this_step.add(("verify", bb, cw))
+            logits0, greedy, kv_out = fn(
+                self._param_arrays(), self._kv_flat(), jnp.asarray(toks),
+                jnp.asarray(pos0), jnp.asarray(lens), jnp.asarray(tables),
+                jnp.asarray(slots))
+            self._store_kv(kv_out)
+        emitted = self._emit_spec(rows, drafts, logits0, greedy)
         if mon:
             real_q = n + sum(len(d) for d in drafts)
             self._m_pad_rows.set((bb - n) / max(bb, 1))
@@ -1161,7 +1175,7 @@ class LLMEngine:
             self._launches_this_step = None
         return emitted
 
-    def _emit_spec(self, rows, drafts, logits0, greedy_h) -> int:
+    def _emit_spec(self, rows, drafts, logits0, greedy) -> int:
         """Per-row acceptance + emission.  The position-0 logits run
         through the SAME (\"sample\", bb) program as plain decode — key
         threading and sampling rows' streams are bit-identical to
@@ -1169,57 +1183,42 @@ class LLMEngine:
         verified draft run: draft j is accepted iff it equals the greedy
         token at position j-1, which validates position j's logits,
         whose greedy token is emitted (the correction/bonus token ends
-        the run)."""
-        perf_on = mperf.enabled()
-        t0 = time.perf_counter() if perf_on else 0.0
-        bb = int(logits0.shape[0])
-        keys = np.zeros((bb, 2), np.uint32)
-        ds = np.zeros((bb,), bool)
-        temp = np.ones((bb,), np.float32)
-        topk = np.zeros((bb,), np.int32)
-        topp = np.ones((bb,), np.float32)
-        for i, req in enumerate(rows):
-            p = req.params
-            keys[i] = np.asarray(req.key, np.uint32)
-            ds[i] = p.do_sample
-            temp[i] = p.temperature
-            topk[i] = p.top_k
-            topp[i] = p.top_p
-        fn = self._get_sample_exec(bb)
-        if self._launches_this_step is not None:
-            self._launches_this_step.add(("sample", bb))
-        toks, new_keys = fn(logits0, jnp.asarray(keys), jnp.asarray(ds),
-                            jnp.asarray(temp), jnp.asarray(topk),
-                            jnp.asarray(topp))
-        toks = np.asarray(toks)
-        new_keys = np.asarray(new_keys)
+        the run).  Every table then rolls back to its accepted length
+        (rejected-draft blocks return to the pool; finished rows are
+        freed by retire_finished right after — truncating first keeps
+        the shared-block refcounts exact either way)."""
+        toks, new_keys = self._dispatch_sampler(rows, logits0)
+        with mtrace.phase("engine/readback"):
+            toks = np.asarray(toks)
+            new_keys = np.asarray(new_keys)
+            greedy_h = np.asarray(greedy)
         now = time.perf_counter()
-        if perf_on:
-            mperf.observe_segment("decode", "sampler", now - t0)
         emitted = proposed = accepted = 0
-        for i, req in enumerate(rows):
-            req.key = jnp.asarray(new_keys[i], jnp.uint32)
-            out = [int(toks[i])]
-            m = len(drafts[i])
-            proposed += m
-            if not req.params.do_sample:
-                g = greedy_h[i]
-                # out[0] == g[0]: both argmax the same fp32 logits row
-                for j in range(1, m + 1):
-                    if int(drafts[i][j - 1]) != int(g[j - 1]):
-                        break
-                    out.append(int(g[j]))
-            row_emitted = 0
-            for tok in out:
-                req.record_token(tok)
-                row_emitted += 1
-                self._record_latency(req, now)
-                if req.finished:
-                    break          # eos inside the accepted run
-            emitted += row_emitted
-            accepted += row_emitted - 1
-            req.spec_proposed += m
-            req.spec_accepted += row_emitted - 1
+        with mtrace.phase("engine/emit"):
+            for i, req in enumerate(rows):
+                req.key = jnp.asarray(new_keys[i], jnp.uint32)
+                out = [int(toks[i])]
+                m = len(drafts[i])
+                proposed += m
+                if not req.params.do_sample:
+                    g = greedy_h[i]
+                    # out[0] == g[0]: both argmax the same fp32 logits row
+                    for j in range(1, m + 1):
+                        if int(drafts[i][j - 1]) != int(g[j - 1]):
+                            break
+                        out.append(int(g[j]))
+                row_emitted = 0
+                for tok in out:
+                    req.record_token(tok)
+                    row_emitted += 1
+                    self._record_latency(req, now)
+                    if req.finished:
+                        break          # eos inside the accepted run
+                emitted += row_emitted
+                accepted += row_emitted - 1
+                req.spec_proposed += m
+                req.spec_accepted += row_emitted - 1
+                self.cache.truncate_to(req.req_id, req.total_len)
         self._spec_proposed_total += proposed
         self._spec_accepted_total += accepted
         if monitor.enabled():
@@ -1259,44 +1258,45 @@ class LLMEngine:
                 req.tpot_max = gap
         req.last_token_t = now
 
+    def _dispatch_sampler(self, rows, logits):
+        """Launch the (\"sample\", B) program over [B, V] fp32 logits (B
+        may exceed len(rows) by padding); returns its two device arrays.
+        The model program is still running when this returns."""
+        with mtrace.phase("engine/sample_dispatch"):
+            bb = int(logits.shape[0])
+            keys = np.zeros((bb, 2), np.uint32)
+            ds = np.zeros((bb,), bool)
+            temp = np.ones((bb,), np.float32)
+            topk = np.zeros((bb,), np.int32)
+            topp = np.ones((bb,), np.float32)
+            for i, req in enumerate(rows):
+                p = req.params
+                keys[i] = np.asarray(req.key, np.uint32)
+                ds[i] = p.do_sample
+                temp[i] = p.temperature
+                topk[i] = p.top_k
+                topp[i] = p.top_p
+            fn = self._get_sample_exec(bb)
+            if self._launches_this_step is not None:   # decode-step launch
+                # accounting only; the prefill path samples too but is not
+                # the steady-state loop the kernel count instruments
+                self._launches_this_step.add(("sample", bb))
+            return fn(logits, jnp.asarray(keys), jnp.asarray(ds),
+                      jnp.asarray(temp), jnp.asarray(topk),
+                      jnp.asarray(topp))
+
     def _sample_rows(self, rows, logits):
-        """Sample one token per live row from [B, V] fp32 logits (B may
-        exceed len(rows) by padding)."""
-        perf_on = mperf.enabled()   # read once: flipping perf on between
-        # here and the observe below must not pair a real clock with t0=0
-        t0 = time.perf_counter() if perf_on else 0.0
-        bb = int(logits.shape[0])
-        keys = np.zeros((bb, 2), np.uint32)
-        ds = np.zeros((bb,), bool)
-        temp = np.ones((bb,), np.float32)
-        topk = np.zeros((bb,), np.int32)
-        topp = np.ones((bb,), np.float32)
-        for i, req in enumerate(rows):
-            p = req.params
-            keys[i] = np.asarray(req.key, np.uint32)
-            ds[i] = p.do_sample
-            temp[i] = p.temperature
-            topk[i] = p.top_k
-            topp[i] = p.top_p
-        fn = self._get_sample_exec(bb)
-        if self._launches_this_step is not None:   # decode-step launch
-            # accounting only; the prefill path samples too but is not
-            # the steady-state loop the kernel count instruments
-            self._launches_this_step.add(("sample", bb))
-        toks, new_keys = fn(logits, jnp.asarray(keys), jnp.asarray(ds),
-                            jnp.asarray(temp), jnp.asarray(topk),
-                            jnp.asarray(topp))
-        toks = np.asarray(toks)
-        new_keys = np.asarray(new_keys)
+        """Sample one token per live row and emit it."""
+        toks, new_keys = self._dispatch_sampler(rows, logits)
+        with mtrace.phase("engine/readback"):   # blocked on the device
+            toks = np.asarray(toks)
+            new_keys = np.asarray(new_keys)
         now = time.perf_counter()
-        if perf_on:
-            # np.asarray above synced the sampler outputs: now - t0 is
-            # its true wall time (sampler is its own dispatch)
-            mperf.observe_segment("decode", "sampler", now - t0)
-        for i, req in enumerate(rows):
-            req.key = jnp.asarray(new_keys[i], jnp.uint32)
-            req.record_token(int(toks[i]))
-            self._record_latency(req, now)
+        with mtrace.phase("engine/emit"):
+            for i, req in enumerate(rows):
+                req.key = jnp.asarray(new_keys[i], jnp.uint32)
+                req.record_token(int(toks[i]))
+                self._record_latency(req, now)
 
     # -- perf attribution ---------------------------------------------------
 
@@ -1590,6 +1590,13 @@ class LLMEngine:
         monitor.flight.note("jit/recompile", fn=fname, axis=axis,
                             detail=detail)
 
+    @staticmethod
+    def _named(fn, name):
+        """`fn` under the name its jitted program carries on the
+        profiler's `XLA Modules` line (`jit_<name>`)."""
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
     def _model_logits(self, params, h):
         """Final LN + tied LM head over EVERY position — the dense
         path's ln_f arithmetic (`F.layer_norm`, NOT the block
@@ -1631,7 +1638,7 @@ class LLMEngine:
         if key not in self._jit_cache:
             self._count_compile("prefill", key)
 
-            def fn(params, kv_flat, ids, slots):
+            def prefill(params, kv_flat, ids, slots):
                 from ..ops.pallas_ops import flash_attention_arrays
 
                 pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
@@ -1659,7 +1666,9 @@ class LLMEngine:
                 h, kv_out = self._run_blocks(params, kv_flat, x, builder)
                 return self._model_tail(params, h), kv_out
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jit_cache[key] = jax.jit(
+                self._named(prefill, f"prefill_{p_len}"),
+                donate_argnums=(1,))
         return self._jit_cache[key]
 
     def _get_chunk_exec(self, b, c):
@@ -1667,7 +1676,7 @@ class LLMEngine:
         if key not in self._jit_cache:
             self._count_compile("chunk", key)
 
-            def fn(params, kv_flat, ids, pos0, tables, slots):
+            def chunk(params, kv_flat, ids, pos0, tables, slots):
                 pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
                 x = jnp.take(params["wte"], ids, axis=0) \
                     + jnp.take(params["wpe"], pos, axis=0)
@@ -1698,49 +1707,55 @@ class LLMEngine:
                 h, kv_out = self._run_blocks(params, kv_flat, x, builder)
                 return self._model_tail(params, h), kv_out
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jit_cache[key] = jax.jit(self._named(
+                chunk, "chunk_decode" if c == 1 else f"chunk_prefill_{c}"),
+                donate_argnums=(1,))
         return self._jit_cache[key]
 
+    def _ragged_blocks(self, c, params, kv_flat, ids, pos0, lens, tables,
+                       slots):
+        """Embeddings, then every block with ONE fused
+        `ragged_paged_attention_arrays` call per layer: cache write +
+        attention (+ int8 dequant at the block loads) — no separate
+        `block_gather/attention/cache_update` triple.  -> (h, kv_out)."""
+        pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
+        x = jnp.take(params["wte"], ids, axis=0) \
+            + jnp.take(params["wpe"], pos, axis=0)
+
+        def builder(kc, vc, ksc=None, vsc=None):
+            def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
+                if ksc is None:
+                    o, kc2, vc2 = ragged_paged_attention_arrays(
+                        q, k, v, kc, vc, tables, pos0, lens, slots)
+                    return o, (kc2, vc2)
+                o, kc2, vc2, ks2, vs2 = ragged_paged_attention_arrays(
+                    q, k, v, kc, vc, tables, pos0, lens, slots,
+                    k_scales=ksc, v_scales=vsc)
+                return o, (kc2, vc2, ks2, vs2)
+            return attn_fn
+
+        return self._run_blocks(params, kv_flat, x, builder)
+
     def _get_ragged_exec(self, b, c):
-        """The ISSUE-8 decode program: per layer, ONE fused
-        `ragged_paged_attention_arrays` call does cache write + attention
-        (+ int8 dequant at the block loads) — no separate
-        `block_gather/attention/cache_update` triple.  At (max_num_seqs,
-        1) this is the single compiled program every decode batch
-        composition runs."""
+        """The ISSUE-8 decode program (`_ragged_blocks` + the last
+        position's logits).  At (max_num_seqs, 1) this is the single
+        compiled program every decode batch composition runs."""
         key = ("ragged", b, c)
         if key not in self._jit_cache:
             self._count_compile("ragged", key)
 
-            def fn(params, kv_flat, ids, pos0, lens, tables, slots):
-                pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-                x = jnp.take(params["wte"], ids, axis=0) \
-                    + jnp.take(params["wpe"], pos, axis=0)
-
-                def builder(kc, vc, ksc=None, vsc=None):
-                    def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
-                        if ksc is None:
-                            o, kc2, vc2 = ragged_paged_attention_arrays(
-                                q, k, v, kc, vc, tables, pos0, lens,
-                                slots)
-                            return o, (kc2, vc2)
-                        o, kc2, vc2, ks2, vs2 = \
-                            ragged_paged_attention_arrays(
-                                q, k, v, kc, vc, tables, pos0, lens,
-                                slots, k_scales=ksc, v_scales=vsc)
-                        return o, (kc2, vc2, ks2, vs2)
-                    return attn_fn
-
-                h, kv_out = self._run_blocks(params, kv_flat, x, builder)
+            def ragged(params, kv_flat, *inputs):
+                h, kv_out = self._ragged_blocks(c, params, kv_flat, *inputs)
                 return self._model_tail(params, h), kv_out
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jit_cache[key] = jax.jit(self._named(
+                ragged, "ragged_decode" if c == 1 else f"ragged_prefill_{c}"),
+                donate_argnums=(1,))
         return self._jit_cache[key]
 
     def _get_verify_exec(self, b, c):
-        """The ISSUE-15 multi-token scoring program: the ragged fused
-        update+attend body at [b, c] (identical to `_get_ragged_exec` up
-        to the tail), returning EVERY position's greedy argmax plus the
+        """The ISSUE-15 multi-token scoring program: `_ragged_blocks` at
+        [b, c], returning EVERY position's greedy argmax plus the
         position-0 fp32 logits (the sampler's input).  ONE fixed shape
         (max_num_seqs, spec_tokens+1) serves every batch composition and
         every draft hit/miss mix — padded draft positions carry dropped
@@ -1749,31 +1764,14 @@ class LLMEngine:
         if key not in self._jit_cache:
             self._count_compile("verify", key)
 
-            def fn(params, kv_flat, ids, pos0, lens, tables, slots):
-                pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-                x = jnp.take(params["wte"], ids, axis=0) \
-                    + jnp.take(params["wpe"], pos, axis=0)
-
-                def builder(kc, vc, ksc=None, vsc=None):
-                    def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
-                        if ksc is None:
-                            o, kc2, vc2 = ragged_paged_attention_arrays(
-                                q, k, v, kc, vc, tables, pos0, lens,
-                                slots)
-                            return o, (kc2, vc2)
-                        o, kc2, vc2, ks2, vs2 = \
-                            ragged_paged_attention_arrays(
-                                q, k, v, kc, vc, tables, pos0, lens,
-                                slots, k_scales=ksc, v_scales=vsc)
-                        return o, (kc2, vc2, ks2, vs2)
-                    return attn_fn
-
-                h, kv_out = self._run_blocks(params, kv_flat, x, builder)
+            def verify(params, kv_flat, *inputs):
+                h, kv_out = self._ragged_blocks(c, params, kv_flat, *inputs)
                 logits = self._model_logits(params, h).astype(jnp.float32)
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return logits[:, 0], greedy, kv_out
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jit_cache[key] = jax.jit(
+                self._named(verify, "spec_verify"), donate_argnums=(1,))
         return self._jit_cache[key]
 
     def _get_sample_exec(self, b):
@@ -1808,5 +1806,6 @@ class LLMEngine:
                 out_key = jnp.where(ds, new_key, key_)
                 return tok, out_key
 
-            self._jit_cache[key] = jax.jit(jax.vmap(row))
+            self._jit_cache[key] = jax.jit(
+                self._named(jax.vmap(row), "sample"))
         return self._jit_cache[key]

@@ -331,8 +331,13 @@ def test_serving_request_trace_parity(model, eng):
     assert names.count("serving/prefill") == 1
     # first token samples at prefill end; the rest are decode steps
     assert names.count("serving/decode_step") == new - 1
+    # ... and the engine steps it rode cover the same time again
+    assert names.count("serving/step") == new
 
-    children_sum = sum(s["dur_us"] for s in spans if s["parent_id"])
+    children_sum = sum(s["dur_us"] for s in spans
+                       if s["name"] in ("serving/queue_wait",
+                                        "serving/prefill",
+                                        "serving/decode_step"))
     assert children_sum <= root["dur_us"] * 1.05
     assert children_sum >= root["dur_us"] * 0.5, (
         f"unattributed gap: children {children_sum:.0f}us of "
@@ -371,6 +376,190 @@ def test_aborted_request_trace_ends_with_abort(model, eng):
     spans = eng.request_trace(rid)
     root = [s for s in spans if s["name"] == "serving/request"][0]
     assert root["attrs"]["finish"] == "abort"
+
+
+# ---------------------------------------------------------------------------
+# serving phases (ISSUE 26): one boundary, three clocks
+# ---------------------------------------------------------------------------
+
+ENGINE_PHASES = ("engine/schedule", "engine/prepare",
+                 "engine/sample_dispatch", "engine/readback",
+                 "engine/emit", "engine/retire")
+API_PHASES = ("api/drain_submits", "api/push_progress")
+
+
+def _run_two(model, eng, new=(4, 3)):
+    """Two requests stepped to the end -> (request ids, steps taken)."""
+    from paddle_tpu.serving import SamplingParams
+
+    rids = [eng.add_request(_prompt(model, 20 + i),
+                            SamplingParams(max_new_tokens=n))
+            for i, n in enumerate(new)]
+    steps = 0
+    while eng.has_unfinished():
+        eng.step()
+        steps += 1
+    for rid in rids:
+        eng.release_request(rid)
+    return rids, steps
+
+
+@pytest.fixture()
+def phase_run(model, eng):
+    """Tracing OFF (the driver's setting): only the always-on part."""
+    trace.enable(False)
+    monitor.reset()
+    try:
+        _, steps = _run_two(model, eng)
+    finally:
+        trace.enable(True)
+    return steps, monitor.snapshot()
+
+
+@pytest.mark.parametrize("name", ENGINE_PHASES)
+def test_engine_phase_counted_once_a_step(phase_run, name):
+    steps, snap = phase_run
+    # 2 prefill steps + 3 decode steps: each ran a program and sampled
+    assert steps == 5
+    h = snap[trace.PHASE_METRIC][f"phase={name}"]
+    assert h["count"] == steps and h["sum"] > 0
+    by_kind = snap["serving/step_time"]
+    assert sum(v["count"] for v in by_kind.values()) == steps
+
+
+def test_phases_cover_the_step_and_nothing_twice(phase_run):
+    """The phases are disjoint pieces of step(): all but the last lie
+    inside the interval `serving/step_time` measures (engine/retire runs
+    on past it, through the gauges), and they leave little of it unnamed."""
+    _, snap = phase_run
+    by_phase = {n: snap[trace.PHASE_METRIC][f"phase={n}"]["sum"]
+                for n in ENGINE_PHASES}
+    stepped = sum(v["sum"] for v in snap["serving/step_time"].values())
+    named = sum(by_phase.values())
+    assert named - by_phase["engine/retire"] <= stepped
+    assert named >= 0.7 * stepped, (by_phase, stepped)
+
+
+def test_request_trace_shows_the_steps_it_rode(model, eng):
+    """With tracing on, every engine step is ONE `serving/step` span filed
+    under each rider's trace as a child of its root; the step's phases
+    are its children, inside it, in order, not overlapping."""
+    (rid_a, rid_b), steps = _run_two(model, eng)
+    a, b = eng.request_trace(rid_a), eng.request_trace(rid_b)
+    root = [s for s in a if s["name"] == "serving/request"][0]
+    step_spans = [s for s in a if s["name"] == "serving/step"]
+    # a rode its prefill step and 3 decode steps, not b's prefill
+    assert len(step_spans) == 4 < steps
+    assert [s["attrs"]["phase"] for s in step_spans] == \
+        ["prefill", "decode", "decode", "decode"]
+    assert all(s["parent_id"] == root["span_id"]
+               and s["trace_id"] == root["trace_id"] for s in step_spans)
+    own = {s["attrs"]["step"] for s in a
+           if s["name"] in ("serving/prefill", "serving/decode_step")}
+    assert own == {s["span_id"] for s in step_spans}
+    for st in step_spans:
+        kids = [s for s in a if s["parent_id"] == st["span_id"]]
+        assert [k["name"] for k in kids] == list(ENGINE_PHASES)
+        assert st["attrs"]["rows"] == len(st["attrs"]["trace_ids"])
+        assert root["trace_id"] in st["attrs"]["trace_ids"]
+        end = st["ts_us"]
+        for k in kids:                   # nested, ordered, disjoint
+            assert k["ts_us"] >= end - 1e-3
+            end = k["ts_us"] + k["dur_us"]
+        assert end <= st["ts_us"] + st["dur_us"] + 1e-3
+    # the two decode steps both rode are the SAME spans in both traces
+    shared = [s for s in step_spans if s["attrs"]["rows"] == 2]
+    assert len(shared) == 2
+    assert {s["span_id"] for s in shared} <= {s["span_id"] for s in b}
+    # and the all-traces export holds each of them once
+    ids = [e["args"]["span_id"] for e in trace.chrome_events()]
+    assert len(ids) == len(set(ids))
+
+
+def test_phase_is_an_event_of_an_open_profiler_session(model, eng, tmp_path):
+    """Inside a jax.profiler session the phases are host events of the
+    session's xplane (on the device trace's clock), named `ptpu:<phase>`;
+    `profiler.RecordEvent` goes through the same annotation."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from paddle_tpu import profiler
+
+    assert trace.annotation("outside") is None      # no session open
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run_two(model, eng, new=(2,))
+        with profiler.RecordEvent("host/record_event"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    for name in ENGINE_PHASES:
+        assert trace.PHASE_PREFIX + name in names, sorted(
+            n for n in names if n.startswith(trace.PHASE_PREFIX))
+    assert "host/record_event" in names
+
+
+def test_api_pump_phases_and_submit_wait(model, eng):
+    """One streamed completion through the HTTP front door: the pump's
+    two phases are counted, and `serving/submit_wait` holds the one wait
+    between the handler's put and the pump's add_request."""
+    from paddle_tpu.serving.api import ApiServer
+
+    monitor.reset()
+    srv = ApiServer(engine=eng, api_keys={}, poll_s=0.005)
+    try:
+        req = urllib.request.Request(
+            srv.url + "/v1/completions",
+            data=json.dumps({"prompt": [int(t) for t in _prompt(model, 30)],
+                             "max_tokens": 3, "stream": True}).encode(),
+            headers={"Content-Type": "application/json"})
+        body = urllib.request.urlopen(req, timeout=60).read().decode()
+    finally:
+        srv.stop()
+    assert body.rstrip().endswith("data: [DONE]")
+    snap = monitor.snapshot()
+    for name in API_PHASES:
+        assert snap[trace.PHASE_METRIC][f"phase={name}"]["count"] >= 3
+    wait = snap["serving/submit_wait"]
+    assert wait["count"] == 1 and 0 <= wait["sum"] < 5.0
+    assert snap["serving/queue_wait"]["count"] == 1
+
+
+def test_phase_disabled_overhead_guard():
+    """Monitor off, tracing off, no profiler session: a phase is the
+    no-op singleton, under the bound of the module's other guards."""
+    trace.enable(False)
+    monitor.enable(False)
+    try:
+        n, per_call = 50_000, float("inf")
+        for _ in range(4):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with trace.phase("t/overhead"):
+                    pass
+            per_call = min(per_call, (time.perf_counter() - t0) / n)
+    finally:
+        monitor.enable(True)
+        trace.enable(True)
+    assert per_call < 1e-6, f"disabled phase costs {per_call*1e9:.0f} ns"
+    assert "phase=t/overhead" not in monitor.snapshot().get(
+        trace.PHASE_METRIC, {})
+
+
+def test_phase_outside_a_span_opens_no_trace():
+    """Tracing on but no span open (the API pump between steps): the
+    phase is counted, and floods no trace into the bounded store."""
+    with trace.phase("t/loose"):
+        pass
+    assert trace.trace_ids() == []
+    assert monitor.snapshot()[trace.PHASE_METRIC]["phase=t/loose"][
+        "count"] == 1
 
 
 def test_watchdog_dumps_on_injected_stall(model, eng, tmp_path, monkeypatch):
