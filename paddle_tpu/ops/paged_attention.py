@@ -3,13 +3,21 @@
 caches + ragged batch decoding are the TPU-side key to high-throughput LLM
 serving).
 
-Layout: K/V live in fixed-size physical blocks
+Layout: K/V live in fixed-size physical blocks, heads flattened in the
+last axis
 
-    k_blocks, v_blocks : [num_blocks, block_size, num_heads, head_dim]
+    k_blocks, v_blocks : [num_blocks, block_size, num_heads * head_dim]
 
 and each sequence owns a *block table* row mapping its logical blocks to
 physical ones.  Token `p` of a sequence lives at physical slot
-``table[p // block_size] * block_size + p % block_size``.
+``table[p // block_size] * block_size + p % block_size``.  Every function
+here takes pools of that rank only.  It is the shape the ragged kernel
+DMAs (`ops.ragged_paged_attention`); a TPU tiles an array over its last
+two axes, so splitting ``H*D -> (H, D)`` on a POOL is a copy of the whole
+pool, and the readers below split the rows they gathered instead.  The
+writers move whole blocks for the same reason (`_block_window`): a block
+is whole tiles when `block_size` is a multiple of the pool dtype's sublane
+tile (8 f32 / 16 bf16 / 32 int8), one token's row never is.
 
 Numerics contract: `paged_attention_arrays` reproduces the masked-softmax
 decode path of `cached_attention_arrays` (models/gpt.py:326 is the
@@ -29,6 +37,7 @@ profiled on chip.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -65,42 +74,106 @@ def slot_mapping(block_table, positions, block_size, num_slots,
     return slots
 
 
-def paged_cache_update_arrays(blocks, rows, slots):
-    """Scatter new K (or V) rows into the paged pool.
+def _block_window(rows, slots, bs, nb):
+    """Cut each row's tokens into the blocks they land in, so a writer
+    moves whole ``[block_size, F]`` slabs: with heads flattened a token's
+    row is ONE sublane of F/128 tiles, and a scatter of S such part-tile
+    rows cost a prefill a sixth more on the chip (PERF.md, PR 27).
 
-    blocks: [num_blocks, block_size, H, D] (or [.., H*D])
-    rows:   [B, S, H, D] (or [B, S, H*D]) new keys/values
-    slots:  [B, S] int32 physical slots (from `slot_mapping`); out-of-range
-            entries (padding / inactive rows) are DROPPED, never clamped —
-            a clamp would silently corrupt the last block.
-    Returns the updated pool (same shape/dtype as `blocks`).
+    rows [B, S, F], slots [B, S] as `slot_mapping` makes them for S
+    consecutive positions: the in-range slots of a row are a PREFIX of it
+    and walk its blocks in order (`_check_consecutive`).
+    With ``W = (S + bs - 2) // bs + 1`` blocks that many tokens can touch:
+
+    ids  [B, W]         block each window block is (nb: nothing lands, the
+                        scatter drops it)
+    win  [B, W, bs, F]  the rows, each at its offset in its block
+    mask [B, W, bs]     where a token lands; elsewhere the block keeps
+                        what it held
     """
-    nb, bs = blocks.shape[0], blocks.shape[1]
-    feat = blocks.shape[2:]
-    flat = blocks.reshape((nb * bs,) + tuple(feat))
-    r = rows.reshape((-1,) + tuple(feat)).astype(blocks.dtype)
-    flat = flat.at[slots.reshape(-1)].set(r, mode="drop")
-    return flat.reshape(blocks.shape)
+    b, s = slots.shape
+    slots = jnp.asarray(slots, jnp.int32)
+    n = jnp.sum((slots >= 0) & (slots < nb * bs), axis=1)        # [B]
+    off = jnp.where(n > 0, slots[:, 0] % bs, 0)
+    w = (s + bs - 2) // bs + 1
+    tok = jnp.arange(w * bs, dtype=jnp.int32)[None] - off[:, None]
+    mask = ((tok >= 0) & (tok < n[:, None])).reshape(b, w, bs)
+    head = jnp.clip(tok[:, ::bs], 0, s - 1)      # first token of a block
+    ids = jnp.where(mask.any(axis=2),
+                    jnp.take_along_axis(slots, head, axis=1) // bs, nb)
+    padded = jnp.pad(rows, ((0, 0), (bs - 1, w * bs - s), (0, 0)))
+    win = jax.vmap(lambda p, o: jax.lax.dynamic_slice_in_dim(
+        p, bs - 1 - o, w * bs, axis=0))(padded, off)
+    return ids, win.reshape(b, w, bs, -1), mask
 
 
-def paged_gather_kv_arrays(blocks, block_table):
-    """Gather one sequence-major view of the pool: [B, max_blocks *
-    block_size, H, D].  Rows past a sequence's context hold garbage (stale
+def _check_consecutive(slots, bs, num_slots):
+    """What `_block_window` takes for granted, checked where it can be:
+    an eager caller's concrete slots (the engine's are traced)."""
+    import numpy as np
+
+    if isinstance(slots, jax.core.Tracer):
+        return
+    # ptpu-check[host-sync]: never reached under a trace (returned above)
+    sl = np.asarray(slots)
+    ok = (sl >= 0) & (sl < num_slots)
+    step = np.where(sl[:, :-1] % bs == bs - 1, sl[:, 1:] % bs == 0,
+                    sl[:, 1:] == sl[:, :-1] + 1)
+    if (ok[:, 1:] & ~ok[:, :-1]).any() or (ok[:, 1:] & ~step).any():
+        raise ValueError(
+            "a row's slots must be those of consecutive positions, the "
+            "out-of-range ones last (slot_mapping's): the pool is written "
+            "a block at a time")
+
+
+def paged_cache_update_arrays(blocks, rows, slots):
+    """Write new K (or V) rows into the paged pool.
+
+    blocks: [num_blocks, block_size, H*D]
+    rows:   [B, S, H, D] (or [B, S, H*D]) new keys/values
+    slots:  [B, S] int32 physical slots of S consecutive positions (from
+            `slot_mapping`); out-of-range entries (padding / inactive
+            rows, last in a row) are DROPPED, never clamped — a clamp
+            would silently corrupt the last block.
+    Returns the updated pool (same shape/dtype as `blocks`): only the
+    slots named change, but the device reads, merges and writes the
+    touched blocks whole (`_block_window`).
+    """
+    nb, bs, _ = blocks.shape
+    _check_consecutive(slots, bs, nb * bs)
+    return _update(blocks, rows, slots)
+
+
+@jax.jit    # a program writes 2 pools a layer: traced once, not 2L times
+def _update(blocks, rows, slots):
+    nb, bs, hd = blocks.shape
+    b, s = slots.shape
+    ids, win, mask = _block_window(
+        rows.reshape(b, s, hd).astype(blocks.dtype), slots, bs, nb)
+    merged = jnp.where(mask[..., None], win,
+                       blocks[jnp.clip(ids, 0, nb - 1)])
+    return blocks.at[ids.reshape(-1)].set(
+        merged.reshape(-1, bs, hd), mode="drop")
+
+
+def paged_gather_kv_arrays(blocks, block_table, num_heads):
+    """Gather one sequence-major view of the [num_blocks, block_size,
+    H*D] pool: [B, max_blocks * block_size, H, D] (heads split on the
+    gathered rows).  Rows past a sequence's context hold garbage (stale
     or zero blocks) — callers mask them; table entries are clipped into
     range (padding entries gather *some* block, masked the same way)."""
-    nb, bs = blocks.shape[0], blocks.shape[1]
-    feat = blocks.shape[2:]
+    nb, bs, hd = blocks.shape
     tbl = jnp.clip(jnp.asarray(block_table, jnp.int32), 0, nb - 1)
-    g = jnp.take(blocks, tbl, axis=0)          # [B, maxb, bs, *feat]
+    g = jnp.take(blocks, tbl, axis=0)          # [B, maxb, bs, H*D]
     b, maxb = tbl.shape
-    return g.reshape((b, maxb * bs) + tuple(feat))
+    return g.reshape(b, maxb * bs, num_heads, hd // num_heads)
 
 
 def quantized_cache_update_arrays(blocks, scales, rows, slots, qmax=127):
     """Scatter new K (or V) rows into an int8 paged pool with
     per-block-per-head abs-max scales (the `lowbit` KV wing).
 
-    blocks: int8 [num_blocks, block_size, H, D] codes
+    blocks: int8 [num_blocks, block_size, H*D] codes
     scales: f32  [num_blocks, H] — ``value = code * scale``
     rows:   [B, S, H, D] float K/V rows to write
     slots:  [B, S] int32 physical slots; out-of-range (padding) entries
@@ -117,43 +190,43 @@ def quantized_cache_update_arrays(blocks, scales, rows, slots, qmax=127):
 
     Returns (blocks', scales').
     """
-    nb, bs = blocks.shape[0], blocks.shape[1]
-    h = blocks.shape[2]
-    flat_slots = jnp.asarray(slots, jnp.int32).reshape(-1)
-    block_ids = flat_slots // bs                     # invalid slots → nb
-    rows_flat = rows.reshape(-1, h, blocks.shape[3])
-    # per-(block, head) abs-max of the incoming rows; the extra row nb
-    # swallows padding/invalid writes and is sliced off
-    row_amax = jnp.max(jnp.abs(rows_flat.astype(jnp.float32)), axis=-1)
-    cand = jnp.zeros((nb + 1, h), jnp.float32).at[
-        jnp.clip(block_ids, 0, nb)].max(row_amax)[:nb]
-    new_scales = jnp.maximum(scales, cand / qmax)
-    factor = jnp.where(new_scales > 0, scales / jnp.where(
-        new_scales > 0, new_scales, 1.0), 1.0)
-    # rescale ONLY the written blocks (the only ones whose scale can have
-    # changed): gather → rescale → scatter back at block granularity.
-    # Keeps the update O(written tokens), not O(pool) — the fp path's
-    # scatter shape — so XLA mutates the donated pool in place.
-    # Duplicate ids (a prefill chunk filling one block) scatter identical
-    # values; invalid ids (nb) gather clipped garbage that the
-    # mode="drop" scatter discards.
-    gid = jnp.clip(block_ids, 0, nb - 1)
-    gfactor = factor[gid]                            # [N, H]
-    rescaled = jnp.clip(
-        jnp.round(blocks[gid].astype(jnp.float32)
-                  * gfactor[:, None, :, None]),
-        -qmax, qmax).astype(jnp.int8)                # [N, bs, H, D]
-    q = blocks.at[block_ids].set(rescaled, mode="drop")
+    nb, bs, _ = blocks.shape
+    _check_consecutive(slots, bs, nb * bs)
+    return _quantized_update(blocks, scales, rows, slots, qmax)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _quantized_update(blocks, scales, rows, slots, qmax):
+    nb, bs, hd = blocks.shape
+    h = scales.shape[1]
+    d = hd // h
+    b, s = slots.shape
+    ids, win, mask = _block_window(
+        rows.reshape(b, s, hd).astype(jnp.float32), slots, bs, nb)
+    w = ids.shape[1]
+    win = win.reshape(b, w, bs, h, d)       # heads split on the written
+    lands = mask[..., None, None]           # blocks, never on the pool
+    # per-(block, head) abs-max of the incoming rows grows the scale
+    amax = jnp.max(jnp.where(lands, jnp.abs(win), 0.0), axis=(2, 4))
+    new_scales = scales.at[ids.reshape(-1)].max(
+        (amax / qmax).reshape(-1, h), mode="drop")
+    # the written blocks are the only ones whose scale can have changed:
+    # gather -> rescale -> merge the quantized rows in -> scatter back, at
+    # block granularity.  O(written blocks), not O(pool), so XLA mutates
+    # the donated pool in place.  Ids of nb gather clipped garbage that
+    # the mode="drop" scatter discards.
+    gid = jnp.clip(ids, 0, nb - 1)
+    new = new_scales[gid]                            # [B, W, H]
+    new = jnp.where(new > 0, new, 1.0)
+    factor = jnp.where(new_scales[gid] > 0, scales[gid] / new, 1.0)
+    rescaled = jnp.round(blocks[gid].reshape(b, w, bs, h, d).astype(
+        jnp.float32) * factor[:, :, None, :, None])
     # quantize the incoming rows against their block's (new) scale
-    wsc = jnp.concatenate([new_scales,
-                           jnp.ones((1, h), jnp.float32)], axis=0)[
-        jnp.clip(block_ids, 0, nb)]                  # [(B*S), H]
-    wsc = jnp.where(wsc > 0, wsc, 1.0)[:, :, None]
-    q_rows = jnp.clip(jnp.round(rows_flat.astype(jnp.float32) / wsc),
+    q_rows = jnp.round(win / new[:, :, None, :, None])
+    merged = jnp.clip(jnp.where(lands, q_rows, rescaled),
                       -qmax, qmax).astype(jnp.int8)
-    flat = q.reshape(nb * bs, h, blocks.shape[3])
-    flat = flat.at[flat_slots].set(q_rows, mode="drop")
-    return flat.reshape(blocks.shape), new_scales
+    return blocks.at[ids.reshape(-1)].set(
+        merged.reshape(-1, bs, hd), mode="drop"), new_scales
 
 
 def quantized_gather_kv_arrays(blocks, scales, block_table):
@@ -169,13 +242,16 @@ def quantized_gather_kv_arrays(blocks, scales, block_table):
     from .lowbit import _count
 
     _count("lowbit/dequant_calls", site="paged_gather")
-    nb, bs = blocks.shape[0], blocks.shape[1]
+    nb, bs, hd = blocks.shape
+    h = scales.shape[1]
+    d = hd // h
     tbl = jnp.clip(jnp.asarray(block_table, jnp.int32), 0, nb - 1)
-    g = jnp.take(blocks, tbl, axis=0)                # [B, maxb, bs, H, D]
-    s = jnp.take(scales, tbl, axis=0)                # [B, maxb, H]
-    deq = g.astype(jnp.float32) * s[:, :, None, :, None]
     b, maxb = tbl.shape
-    return deq.reshape((b, maxb * bs) + tuple(blocks.shape[2:]))
+    g = jnp.take(blocks, tbl, axis=0)                # [B, maxb, bs, H*D]
+    s = jnp.take(scales, tbl, axis=0)                # [B, maxb, H]
+    deq = g.reshape(b, maxb, bs, h, d).astype(jnp.float32) \
+        * s[:, :, None, :, None]
+    return deq.reshape(b, maxb * bs, h, d)
 
 
 def paged_attention_arrays(q, k_blocks, v_blocks, block_table, pos0,
@@ -183,7 +259,7 @@ def paged_attention_arrays(q, k_blocks, v_blocks, block_table, pos0,
     """Causal attention of a (ragged) batch against its paged KV cache.
 
     q:            [B, S, H, D] — S=1 at decode, >1 for a prefill chunk
-    k_blocks/v_blocks: [num_blocks, block_size, H, D] physical pools
+    k_blocks/v_blocks: [num_blocks, block_size, H*D] physical pools
                   (the current chunk's K/V must already be written —
                   write-then-attend, like the dense cache path)
     block_table:  [B, max_blocks] int32 per-row logical→physical map
@@ -208,8 +284,8 @@ def paged_attention_arrays(q, k_blocks, v_blocks, block_table, pos0,
         kg = quantized_gather_kv_arrays(k_blocks, k_scales, block_table)
         vg = quantized_gather_kv_arrays(v_blocks, v_scales, block_table)
     else:
-        kg = paged_gather_kv_arrays(k_blocks, block_table)  # [B, S_pad, H, D]
-        vg = paged_gather_kv_arrays(v_blocks, block_table)
+        kg = paged_gather_kv_arrays(k_blocks, block_table, h)
+        vg = paged_gather_kv_arrays(v_blocks, block_table, h)
     s_pad = kg.shape[1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, kg,
                         preferred_element_type=jnp.float32) * scale

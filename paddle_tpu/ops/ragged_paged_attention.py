@@ -297,8 +297,7 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     from jax.experimental.pallas import tpu as pltpu
 
     b, c, h, d = q.shape
-    nb, bs = int(k_blocks.shape[0]), int(k_blocks.shape[1])
-    hd = h * d
+    nb, bs, hd = k_blocks.shape
     quant = k_scales is not None
     pool_dt = k_blocks.dtype
     tbl = jnp.asarray(block_table, jnp.int32)
@@ -309,9 +308,10 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     row = pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0))
     pool = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [row, row, row, pool, pool]        # q, k_new, v_new, pools
+    # the pools go in and come out as they are kept: a reshape of one
+    # between [.., H, D] and [.., H*D] is a copy of all of it on a TPU
     args = [q.reshape(b, c, hd), k_new.reshape(b, c, hd),
-            v_new.reshape(b, c, hd), k_blocks.reshape(nb, bs, hd),
-            v_blocks.reshape(nb, bs, hd)]
+            v_new.reshape(b, c, hd), k_blocks, v_blocks]
     if quant:
         # grow the written blocks' scales here (the first half of
         # quantized_cache_update_arrays, bitwise: amax/qmax is monotone,
@@ -364,9 +364,7 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
         input_output_aliases={6: 1, 7: 2},
         interpret=_interpret(),
     )(lens_i, slots_i, tbl, *args)
-    o = outs[0].reshape(b, c, h, d)
-    k2 = outs[1].reshape(k_blocks.shape)
-    v2 = outs[2].reshape(v_blocks.shape)
+    o, k2, v2 = outs[0].reshape(b, c, h, d), outs[1], outs[2]
     if quant:
         return o, k2, v2, new_scales[0], new_scales[1]
     return o, k2, v2
@@ -385,7 +383,7 @@ def _folded_quant_attention(q, k_blocks, v_blocks, k_scales, v_scales,
     exact in real arithmetic because the scale is constant along the
     contracted head_dim axis."""
     b, s, h, d = q.shape
-    nb, bs = k_blocks.shape[0], k_blocks.shape[1]
+    nb, bs, _ = k_blocks.shape
     tbl = jnp.clip(jnp.asarray(block_table, jnp.int32), 0, nb - 1)
     maxb = tbl.shape[1]
     s_pad = maxb * bs
@@ -440,9 +438,11 @@ def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
                      mask as sequential decode — which is what lets the
                      engine score all k+1 positions in ONE launch and
                      stay token-identical to step-by-step greedy.
-    k_blocks/v_blocks: [num_blocks, block_size, H, D] physical pools
+    k_blocks/v_blocks: [num_blocks, block_size, H*D] physical pools
                      (fp, or int8 codes with `k_scales`/`v_scales`
-                     [num_blocks, H] per-block-per-head scale pools).
+                     [num_blocks, H] per-block-per-head scale pools);
+                     the kernel takes and returns them as they are,
+                     aliased in place.
     block_table:     [B, max_blocks] int32 per-row logical→physical map.
     pos0:            [B] int32 absolute position of each row's first
                      query (== context length before this chunk).

@@ -1362,8 +1362,8 @@ class LLMEngine:
                     kg = quantized_gather_kv_arrays(part[0], part[2], tbl)
                     vg = quantized_gather_kv_arrays(part[1], part[3], tbl)
                 else:
-                    kg = paged_gather_kv_arrays(part[0], tbl)
-                    vg = paged_gather_kv_arrays(part[1], tbl)
+                    kg = paged_gather_kv_arrays(part[0], tbl, nh)
+                    vg = paged_gather_kv_arrays(part[1], tbl, nh)
                 acc += jnp.sum(kg.astype(jnp.float32)) \
                     + jnp.sum(vg.astype(jnp.float32))
             return acc
@@ -1375,8 +1375,8 @@ class LLMEngine:
             kg0 = quantized_gather_kv_arrays(kv_flat[0], kv_flat[2], tables)
             vg0 = quantized_gather_kv_arrays(kv_flat[1], kv_flat[3], tables)
         else:
-            kg0 = paged_gather_kv_arrays(kv_flat[0], tables)
-            vg0 = paged_gather_kv_arrays(kv_flat[1], tables)
+            kg0 = paged_gather_kv_arrays(kv_flat[0], tables, nh)
+            vg0 = paged_gather_kv_arrays(kv_flat[1], tables, nh)
 
         def attention_fn(q_, kg, vg, pos0_):
             import math as _math

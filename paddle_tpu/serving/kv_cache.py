@@ -6,13 +6,18 @@ The dense decode path (`GPTForCausalLM.init_caches`) allocates a
 how short the request actually is.  `BlockKVCache` instead pools K/V in
 fixed-size physical blocks
 
-    k_blocks[l], v_blocks[l] : [num_blocks, block_size, H, D]   per layer
+    k_blocks[l], v_blocks[l] : [num_blocks, block_size, H*D]   per layer
 
 and gives each sequence a *block table* (list of physical block ids), so
 a request holds exactly ``ceil(len / block_size)`` blocks and frees them
 the moment it finishes.  The device arrays are plain jax buffers owned by
 this object; the engine's jitted step takes them donated and returns the
-updated pool.
+updated pool.  Heads stay flattened in the last axis, the shape the
+ragged kernel DMAs a block in: on a TPU an array is tiled over its last
+two axes, so a pool kept as ``[.., H, D]`` is relaid whole
+(``[.., bs, H*D]`` is another tiling of the same bytes) around every
+kernel call.  Readers that need heads split ``H*D -> (H, D)`` on the
+rows they gathered, never on the pool.
 
 Allocator design (host-side, O(1) per op):
 
@@ -138,6 +143,11 @@ class _Block:
 
 
 class BlockKVCache:
+    """Per-layer K/V pools ``[num_blocks, block_size, num_heads *
+    head_dim]`` (int8 codes plus ``[num_blocks, num_heads]`` float32
+    scales under ``kv_quant="int8"``) and the host-side allocator over
+    their blocks.  One pool shape, whatever reads it."""
+
     def __init__(self, num_layers, num_blocks, block_size, num_heads,
                  head_dim, dtype=jnp.float32, kv_quant=None):
         if kv_quant not in (None, "int8"):
@@ -150,8 +160,8 @@ class BlockKVCache:
         self.head_dim = int(head_dim)
         self.dtype = dtype
         self.kv_quant = kv_quant
-        shape = (self.num_blocks, self.block_size, self.num_heads,
-                 self.head_dim)
+        shape = (self.num_blocks, self.block_size,
+                 self.num_heads * self.head_dim)
         pool_dtype = jnp.int8 if kv_quant else dtype
         self.k_blocks = [jnp.zeros(shape, pool_dtype)
                          for _ in range(num_layers)]

@@ -2,6 +2,7 @@
 
     chiprun -- python scripts/onchip_checks.py
     python scripts/onchip_checks.py --aot     # no chip: compile only
+    chiprun -- python scripts/onchip_checks.py --writes [--tree DIR]
 
 Every Pallas kernel on the main path goes through actual Mosaic
 compilation and is compared with its XLA reference at the widths of both
@@ -14,6 +15,11 @@ failure exits non-zero.  The default-off kernels (fused decode, LN, FFN)
 are compiled once each and their outcome printed as "COMPILES"/"REFUSED"
 without failing the run: promoting or deleting them is another PR's.
 
+`--writes` runs the KV pool writers alone (`check_writes`) and prints what
+one write and one layer of the C > 1 fallback cost; `--tree DIR` takes
+`paddle_tpu` from another checkout (the parent, to compare with: same
+inputs, in the pool shape that tree keeps).
+
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
 stops there.  That catches Mosaic refusals from a CPU-only sandbox; it
@@ -24,12 +30,15 @@ import os
 import sys
 import threading
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.abspath(sys.argv[sys.argv.index("--tree") + 1])
+                if "--tree" in sys.argv else
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
 WIDTHS = {"hd768": (12, 64), "hd2048": (16, 128)}
 AOT = "--aot" in sys.argv[1:]
+WRITES_ONLY = "--writes" in sys.argv[1:]
 _AOT_SHARDING = None
 
 
@@ -59,8 +68,9 @@ def _compile_only(fn, args):
     """--aot: compile `fn` for the v5e topology, run nothing."""
     import jax
 
-    structs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=_AOT_SHARDING)
-               for a in args]
+    structs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=_AOT_SHARDING), list(args))
     jax.jit(fn).lower(*structs).compile()
 
 
@@ -168,12 +178,12 @@ def check_ragged(wname, h, d, quant):
             slots[r, 0] = int(tables[r, p // bs]) * bs + p % bs
     q, kn, vn = (_randn(rng, (b, 1, h, d), jnp.bfloat16) for _ in range(3))
     if quant:
-        kb, vb = (jnp.asarray(rng.randint(-127, 128, (nb, bs, h, d)),
+        kb, vb = (jnp.asarray(rng.randint(-127, 128, (nb, bs, h * d)),
                               jnp.int8) for _ in range(2))
         scales = [jnp.asarray(rng.rand(nb, h) * 0.05 + 0.01, jnp.float32)
                   for _ in range(2)]
     else:
-        kb, vb = (_randn(rng, (nb, bs, h, d), jnp.bfloat16)
+        kb, vb = (_randn(rng, (nb, bs, h * d), jnp.bfloat16)
                   for _ in range(2))
         scales = []
     tables, slots, lens_j = (jnp.asarray(x) for x in (tables, slots, lens))
@@ -208,6 +218,110 @@ def check_ragged(wname, h, d, quant):
         assert diff.max() <= step, \
             f"{name}: state {i} off by {diff.max()} (allowed {step})"
     print(f"OK {name}", flush=True)
+
+
+# (rows, tokens a row, first position): a whole prompt, a chunk from the
+# middle of a block, a speculative verify, one decode token a row
+WRITES = {"prompt384": (1, 384, 0), "chunk256+5": (1, 256, 1029),
+          "verify16x5": (16, 5, 200), "decode16x1": (16, 1, 333)}
+
+
+def check_writes(h=16, d=128, layers=12):
+    """The KV pool writers at the 1.3B pool (bf16 at block 16, int8 at
+    block 32): the pool after `paged_cache_update_arrays` /
+    `quantized_cache_update_arrays` against a numpy scatter of the same
+    rows, every slot not named bit for bit as it was; then what one
+    write costs, and what one layer of the C > 1 XLA fallback costs
+    (write + gather + attention, `ragged_paged_attention_arrays`), as
+    the engine's programs run them: `layers` of them unrolled in one
+    program, each on donated pools of its own.  A tree from
+    before PR 27 (`--tree`) keeps its pools ``[nb, bs, H, D]`` and its
+    layout paragraph says so: it gets them in that shape."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops import ragged_paged_attention as rp
+
+    flat = "num_heads * head_dim]" in pa.__doc__
+
+    def timed(step, make, rows):
+        if AOT:
+            return _compile_only(step, [make(), rows])
+        fn = jax.jit(lambda sts, r: [step(st, r + jnp.asarray(i, r.dtype))
+                                     for i, st in enumerate(sts)],
+                     donate_argnums=(0,))
+        sts = jax.block_until_ready(fn([make() for _ in range(layers)], rows))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            sts = fn(sts, rows)
+        jax.block_until_ready(sts)
+        return (time.perf_counter() - t0) / (5 * layers) * 1e6
+
+    for quant in (False, True):
+        bs = 32 if quant else 16
+        nb = 2048 * 16 // bs
+        rng = np.random.RandomState(7)
+        perm = rng.permutation(nb).astype(np.int32)
+        shape = (nb, bs, h * d) if flat else (nb, bs, h, d)
+        for case, (b, s, start) in WRITES.items():
+            maxb = 2048 // bs           # a 2048-token table a row
+            pos = start + np.arange(s)
+            tables = perm[:b * maxb].reshape(b, maxb)
+            slots = (tables[:, pos // bs] * bs + pos % bs).astype(np.int32)
+            rows = _randn(rng, (b, s, h, d), jnp.bfloat16)
+            name = f"write_{'int8' if quant else 'fp'}_{case}"
+            def fresh():          # made on the device, 134 MB each
+                keys = jax.random.split(jax.random.PRNGKey(11))
+                if not quant:
+                    return tuple(jax.random.normal(k, shape, jnp.bfloat16)
+                                 for k in keys)
+                return tuple(jax.random.randint(k, shape, -127, 128, jnp.int8)
+                             for k in keys) + tuple(
+                    jnp.full((nb, h), 0.05, jnp.float32) for _ in keys)
+
+            def write(st, r):
+                if quant:
+                    return tuple(pa.quantized_cache_update_arrays(
+                        st[0], st[1], r, slots))
+                return (pa.paged_cache_update_arrays(st[0], r, slots),)
+
+            def layer(st, r):     # st: the outputs' sum, then the pools
+                kw = dict(k_scales=st[3], v_scales=st[4]) if quant else {}
+                out = rp.ragged_paged_attention_arrays(
+                    r, r, r, st[1], st[2], jnp.asarray(tables),
+                    jnp.full((b,), start, jnp.int32),
+                    jnp.full((b,), start + s, jnp.int32), slots, **kw)
+                return (st[0] + out[0].astype(jnp.float32), *out[1:])
+
+            state = fresh()[::2]          # (K pool[, its scales])
+            if not AOT:
+                before = np.asarray(state[0]).reshape(nb * bs, h * d)
+                once = jax.jit(write)(state, rows)
+                after = np.asarray(once[0]).reshape(nb * bs, h * d)
+                named = slots.reshape(-1)
+                rest = np.ones(nb * bs, bool)
+                rest[named] = False
+                want, step = np.asarray(rows, np.float32), 0.0
+                if quant:
+                    # the rows' amax / 127 stays under the 0.05 every
+                    # block has: no scale grows, no old code is rescaled;
+                    # the device may round x / scale one step apart
+                    np.testing.assert_array_equal(np.asarray(once[1]),
+                                                  np.asarray(state[1]))
+                    want, step = np.round(want / 0.05), 1.0
+                np.testing.assert_array_equal(after[rest], before[rest], name)
+                diff = np.abs(after[named].astype(np.float32)
+                              - want.reshape(-1, h * d))
+                assert diff.max() <= step, f"{name}: {diff.max()}"
+            took = {"write": timed(write, lambda: fresh()[::2], rows)}
+            if s > 1:           # at C = 1 the kernel runs, not the fallback
+                took["fallback layer"] = timed(
+                    layer, lambda: (jnp.zeros(rows.shape, jnp.float32),
+                                    *fresh()), rows)
+            print(f"OK {name}" + "".join(
+                f"  {k} {v:.1f} us" for k, v in took.items() if v), flush=True)
 
 
 def check_generate():
@@ -302,10 +416,13 @@ def main():
                                 (check_ragged, (True,)))]
     if not AOT:
         checks.append(("check_generate", check_generate, ()))
+    checks.append(("check_writes", check_writes, ()))
     checks += [(f"try_default_off_{wname}", try_default_off, (wname, h, d))
                for wname, (h, d) in WIDTHS.items()]
+    if WRITES_ONLY:
+        checks = [c for c in checks if c[1] is check_writes]
     for name, fn, args in checks:
-        with _Watchdog(name):
+        with _Watchdog(name, 900.0 if fn is check_writes else 240.0):
             fn(*args)
     print("ALL AOT COMPILES OK" if AOT else "ALL ONCHIP CHECKS OK")
 

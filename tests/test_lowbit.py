@@ -334,7 +334,7 @@ class TestQuantizedKVCache:
         written rows; writes that do NOT raise a block's amax leave
         existing codes bit-identical."""
         nb, bs, h, d = 4, 4, 2, 3
-        blocks = jnp.zeros((nb, bs, h, d), jnp.int8)
+        blocks = jnp.zeros((nb, bs, h * d), jnp.int8)
         scales = jnp.zeros((nb, h), jnp.float32)
         rng = np.random.RandomState(0)
         rows = jnp.asarray(rng.randn(1, 4, h, d).astype(np.float32))
@@ -364,7 +364,7 @@ class TestQuantizedKVCache:
         idx = jnp.asarray(cache._tables["a"], jnp.int32)
         for l in range(2):
             cache.k_blocks[l] = cache.k_blocks[l].at[idx].set(
-                jnp.asarray(rng.randint(-127, 128, (2, 4, 2, 3)), jnp.int8))
+                jnp.asarray(rng.randint(-127, 128, (2, 4, 2 * 3)), jnp.int8))
             cache.k_scales[l] = cache.k_scales[l].at[idx].set(
                 jnp.asarray(rng.rand(2, 2), jnp.float32))
         kb = [np.asarray(k[idx]) for k in cache.k_blocks]

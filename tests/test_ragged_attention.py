@@ -120,8 +120,8 @@ class TestFallbackVsReference:
         q, kn, vn, tables, pos0, lens, slots, valid, qlens, geo = _mix(mix)
         nb, bs, H, D = geo
         rng = np.random.RandomState(1)
-        kb = jnp.asarray(rng.randn(nb, bs, H, D), jnp.float32)
-        vb = jnp.asarray(rng.randn(nb, bs, H, D), jnp.float32)
+        kb = jnp.asarray(rng.randn(nb, bs, H * D), jnp.float32)
+        vb = jnp.asarray(rng.randn(nb, bs, H * D), jnp.float32)
         k2r = paged_cache_update_arrays(kb, kn, slots)
         v2r = paged_cache_update_arrays(vb, vn, slots)
         want = paged_attention_arrays(q, k2r, v2r, tables, pos0)
@@ -139,8 +139,8 @@ class TestFallbackVsReference:
         q, kn, vn, tables, pos0, lens, slots, valid, qlens, geo = _mix(mix)
         nb, bs, H, D = geo
         rng = np.random.RandomState(2)
-        kb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H, D)), jnp.int8)
-        vb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H, D)), jnp.int8)
+        kb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H * D)), jnp.int8)
+        vb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H * D)), jnp.int8)
         ks = jnp.asarray(rng.rand(nb, H) * 0.2, jnp.float32)
         vs = jnp.asarray(rng.rand(nb, H) * 0.2, jnp.float32)
         k2r, ks2r = quantized_cache_update_arrays(kb, ks, kn, slots)
@@ -165,7 +165,7 @@ class TestFallbackVsReference:
     def test_scale_args_must_pair(self):
         q, kn, vn, tables, pos0, lens, slots, _, _, geo = _mix("single_row")
         nb, bs, H, D = geo
-        kb = jnp.zeros((nb, bs, H, D), jnp.int8)
+        kb = jnp.zeros((nb, bs, H * D), jnp.int8)
         with pytest.raises(ValueError, match="both k_scales and v_scales"):
             rp.ragged_paged_attention_arrays(
                 q, kn, vn, kb, kb, tables, pos0, lens, slots,
@@ -202,13 +202,13 @@ def _kernel_case(quant, seed=0):
         p = int(lens[b]) - 1
         slots[b, 0] = int(tables[b][p // bs]) * bs + p % bs
     if quant:
-        kb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H, D)), jnp.int8)
-        vb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H, D)), jnp.int8)
+        kb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H * D)), jnp.int8)
+        vb = jnp.asarray(rng.randint(-127, 128, (nb, bs, H * D)), jnp.int8)
         ks = jnp.asarray(rng.rand(nb, H) * 0.1, jnp.float32)
         vs = jnp.asarray(rng.rand(nb, H) * 0.1, jnp.float32)
     else:
-        kb = jnp.asarray(rng.randn(nb, bs, H, D), jnp.float32)
-        vb = jnp.asarray(rng.randn(nb, bs, H, D), jnp.float32)
+        kb = jnp.asarray(rng.randn(nb, bs, H * D), jnp.float32)
+        vb = jnp.asarray(rng.randn(nb, bs, H * D), jnp.float32)
         ks = vs = None
     return (q, kn, vn, kb, vb, jnp.asarray(tables), pos0,
             jnp.asarray(lens), jnp.asarray(slots), ks, vs)
@@ -254,6 +254,43 @@ class TestRaggedKernelInterpret:
         np.testing.assert_allclose(np.asarray(out[:2]),
                                    np.asarray(want[:2]),
                                    rtol=3e-5, atol=3e-6)
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+    def test_pools_pass_through_without_a_relayout(self, _interpret_mode,
+                                                   quant):
+        """The kernel call takes the pools as they are kept and hands
+        them back the same: no `reshape`/`transpose` equation anywhere
+        in the traced program touches an array of the pool's element
+        count.  On a TPU each such reshape between ``[.., H, D]`` and
+        ``[.., H*D]`` was a copy of the whole pool, twice per pool per
+        layer per decode step."""
+        import jax
+
+        (q, kn, vn, kb, vb, tables, pos0, lens, slots,
+         ks, vs) = _kernel_case(quant)
+        assert kb.ndim == 3 and kb.size != q.size
+        kw = dict(k_scales=ks, v_scales=vs) if quant else {}
+        closed = jax.make_jaxpr(
+            lambda *a: rp.ragged_paged_attention_arrays(*a, **kw))(
+            q, kn, vn, kb, vb, tables, pos0, lens, slots)
+
+        def equations(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from equations(sub)
+
+        eqns = list(equations(closed.jaxpr))
+        assert any(e.primitive.name == "pallas_call" for e in eqns)
+        relaid = [
+            str(e) for e in eqns
+            if e.primitive.name in ("reshape", "transpose")
+            and any(getattr(v.aval, "size", 0) == kb.size
+                    for v in list(e.invars) + list(e.outvars))]
+        assert not relaid, relaid
+        outs = closed.out_avals
+        assert outs[1].shape == outs[2].shape == kb.shape
+        assert outs[1].dtype == kb.dtype
 
     @pytest.mark.slow
     def test_scale_growth_steady_state_bit_stable(self, _interpret_mode):
@@ -530,7 +567,7 @@ class TestDequantPassEliminated:
         (q, kn, vn, tables, pos0, lens, slots, _v, _q,
          geo) = _mix("all_decode")
         nb, bs, H, D = geo
-        kb = jnp.zeros((nb, bs, H, D), jnp.int8)
+        kb = jnp.zeros((nb, bs, H * D), jnp.int8)
         ks = jnp.zeros((nb, H), jnp.float32)
         monitor.enable(True)
         try:

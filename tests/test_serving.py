@@ -381,6 +381,171 @@ class TestAllocator:
                 np.asarray(cache.k_blocks[l][np.asarray(t1)]), want_k[l])
 
 
+class TestPoolLayout:
+    """The pools at rest are ``[num_blocks, block_size, H*D]`` — the shape
+    the ragged kernel DMAs — and every block operation of the allocator
+    moves a block's bytes (and an int8 block's scales) unchanged through
+    it.  Content goes in and comes out through the ops the engine uses,
+    as ``[.., H, D]`` token rows."""
+    L, NB, BS, H, D = 2, 8, 4, 2, 4
+
+    def _cache(self, quant):
+        return BlockKVCache(self.L, self.NB, self.BS, self.H, self.D,
+                            kv_quant=quant)
+
+    def _write(self, cache, seq, start, n, seed):
+        """Token rows ``[1, n, H, D]`` per layer at positions start.. of
+        `seq`, through the engine's update ops; -> the K rows written."""
+        from paddle_tpu.ops.paged_attention import (
+            paged_cache_update_arrays, quantized_cache_update_arrays)
+
+        rng = np.random.RandomState(seed)
+        slots = jnp.asarray([[cache.slot(seq, start + i)
+                              for i in range(n)]], jnp.int32)
+        wrote = []
+        for l in range(self.L):
+            k = jnp.asarray(rng.randn(1, n, self.H, self.D), jnp.float32)
+            if cache.kv_quant:
+                cache.k_blocks[l], cache.k_scales[l] = \
+                    quantized_cache_update_arrays(
+                        cache.k_blocks[l], cache.k_scales[l], k, slots)
+                cache.v_blocks[l], cache.v_scales[l] = \
+                    quantized_cache_update_arrays(
+                        cache.v_blocks[l], cache.v_scales[l], -k, slots)
+            else:
+                cache.k_blocks[l] = paged_cache_update_arrays(
+                    cache.k_blocks[l], k, slots)
+                cache.v_blocks[l] = paged_cache_update_arrays(
+                    cache.v_blocks[l], -k, slots)
+            wrote.append(np.asarray(k)[0])
+        return wrote
+
+    def _state(self, cache, seq, n):
+        """Everything `seq`'s first `n` tokens are made of: the raw
+        blocks (and scales) its table names, and the ``[n, H, D]`` view
+        the attention ops gather."""
+        from paddle_tpu.ops.paged_attention import (
+            paged_gather_kv_arrays, quantized_gather_kv_arrays)
+
+        idx = np.asarray(cache.block_table(seq)[:cache.blocks_needed(n)])
+        tbl = jnp.asarray(idx[None], jnp.int32)
+        out = []
+        for l in range(self.L):
+            for blocks, scales in (
+                    (cache.k_blocks[l],
+                     cache.k_scales[l] if cache.kv_quant else None),
+                    (cache.v_blocks[l],
+                     cache.v_scales[l] if cache.kv_quant else None)):
+                out.append(np.asarray(blocks[idx]))
+                if scales is None:
+                    view = paged_gather_kv_arrays(blocks, tbl, self.H)
+                else:
+                    out.append(np.asarray(scales[idx]))
+                    view = quantized_gather_kv_arrays(blocks, scales, tbl)
+                assert view.shape == (1, len(idx) * self.BS, self.H, self.D)
+                out.append(np.asarray(view)[0, :n])
+        return out
+
+    @staticmethod
+    def _same(got, want):
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
+    @pytest.mark.parametrize("quant", [None, "int8"])
+    def test_pool_shape_at_rest(self, quant):
+        cache = self._cache(quant)
+        shape = (self.NB, self.BS, self.H * self.D)
+        for l in range(self.L):
+            assert cache.k_blocks[l].shape == shape
+            assert cache.v_blocks[l].shape == shape
+            assert cache.k_blocks[l].dtype == (jnp.int8 if quant
+                                               else jnp.float32)
+        assert (cache.num_heads, cache.head_dim) == (self.H, self.D)
+        if quant:
+            assert cache.k_scales[0].shape == (self.NB, self.H)
+        # the engine's programs hand the pools back in the shape they took
+        cache.allocate("a", 3)
+        self._write(cache, "a", 0, 3, seed=0)
+        assert cache.k_blocks[0].shape == cache.v_blocks[1].shape == shape
+
+    def test_rows_land_heads_flattened(self):
+        """Token p's ``[H, D]`` row is row ``slot(p)`` of the flattened
+        pool, head h in lanes ``[h*D, (h+1)*D)``."""
+        cache = self._cache(None)
+        cache.allocate("a", 6)
+        wrote = self._write(cache, "a", 0, 6, seed=1)
+        for l in range(self.L):
+            flat = np.asarray(cache.k_blocks[l]).reshape(
+                self.NB * self.BS, self.H * self.D)
+            for p in range(6):
+                np.testing.assert_array_equal(
+                    flat[cache.slot("a", p)], wrote[l][p].reshape(-1))
+        view = self._state(cache, "a", 6)[1]
+        np.testing.assert_array_equal(view, wrote[0])
+
+    @pytest.mark.parametrize("quant", [None, "int8"])
+    def test_swap_roundtrip(self, quant):
+        cache = self._cache(quant)
+        cache.allocate("a", 7)
+        self._write(cache, "a", 0, 7, seed=2)
+        want = self._state(cache, "a", 7)
+        saved = cache.swap_out("a")
+        assert cache.blocks_in_use == 0
+        assert saved["k"][0].shape == (2, self.BS, self.H * self.D)
+        cache.allocate("x", 9)                 # churn the pool
+        self._write(cache, "x", 0, 9, seed=3)
+        cache.free("x")
+        cache.swap_in("a", saved)
+        self._same(self._state(cache, "a", 7), want)
+
+    @pytest.mark.parametrize("quant", [None, "int8"])
+    def test_copy_on_write(self, quant):
+        cache = self._cache(quant)
+        cache.allocate("a", 6)                 # a partial last block
+        self._write(cache, "a", 0, 6, seed=4)
+        want = self._state(cache, "a", 6)
+        cache.fork("a", "b")
+        cache.grow_to("b", 7)                  # CoW of the shared block
+        assert cache.block_table("a")[1] != cache.block_table("b")[1]
+        self._same(self._state(cache, "b", 6), want)
+        self._write(cache, "b", 6, 1, seed=5)  # b's own 7th token
+        self._same(self._state(cache, "a", 6), want)
+
+    @pytest.mark.parametrize("quant", [None, "int8"])
+    def test_prefix_adoption(self, quant):
+        from paddle_tpu.serving.kv_cache import prefix_block_keys
+
+        cache = self._cache(quant)
+        keys = prefix_block_keys(list(range(8)), self.BS)
+        cache.allocate("a", 8)                 # two full blocks
+        self._write(cache, "a", 0, 8, seed=6)
+        want = self._state(cache, "a", 8)
+        cache.register_prefix("a", keys, 8)
+        cache.free("a")                        # parked, still adoptable
+        assert cache.match_prefix(keys) == 2
+        assert cache.adopt_prefix("b", keys, 2) == 8
+        self._same(self._state(cache, "b", 8), want)
+        cache.grow_to("b", 9)                  # next token: a fresh block
+        self._write(cache, "b", 8, 1, seed=7)
+        self._same(self._state(cache, "b", 8), want)
+
+    @pytest.mark.parametrize("quant", [None, "int8"])
+    def test_truncate_to(self, quant):
+        cache = self._cache(quant)
+        cache.allocate("a", 6)
+        self._write(cache, "a", 0, 6, seed=8)
+        cache.grow_to("a", 11)                 # five draft positions
+        self._write(cache, "a", 6, 5, seed=9)
+        want = self._state(cache, "a", 7)      # one draft accepted
+        cache.truncate_to("a", 7)
+        assert len(cache.block_table("a")) == 2 and cache.blocks_in_use == 2
+        self._same(self._state(cache, "a", 7), want)
+        cache.grow_to("a", 9)                  # decoding goes on
+        self._write(cache, "a", 7, 2, seed=10)
+        assert len(self._state(cache, "a", 9)[-1]) == 9
+
+
 class TestChunkedPrefill:
     def test_chunked_prefill_matches_unchunked_engine(self, model):
         """Chunked prefill (token-budget admission) is mathematically the
@@ -508,12 +673,12 @@ class TestPagedAttentionOp:
         v_new = rng.randn(B, 1, H, D).astype(np.float32)
         # paged pool holding the same tokens at scattered physical blocks
         tables = np.asarray([[7, 2, 5, 9], [1, 8, 3, 0]], np.int32)
-        kb = np.zeros((NB, BS, H, D), np.float32)
-        vb = np.zeros((NB, BS, H, D), np.float32)
+        kb = np.zeros((NB, BS, H * D), np.float32)
+        vb = np.zeros((NB, BS, H * D), np.float32)
         for b in range(B):
             for p in range(int(lens[b])):
-                kb[tables[b][p // BS], p % BS] = kd[b, p].reshape(H, D)
-                vb[tables[b][p // BS], p % BS] = vd[b, p].reshape(H, D)
+                kb[tables[b][p // BS], p % BS] = kd[b, p]
+                vb[tables[b][p // BS], p % BS] = vd[b, p]
         # oracle: per-row dense decode at its own scalar t
         want = []
         for b in range(B):
@@ -535,10 +700,66 @@ class TestPagedAttentionOp:
             np.testing.assert_array_equal(np.asarray(got[b:b + 1]),
                                           want[b])
 
+    @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("s_len,start", [(1, 0), (1, 3), (3, 2), (8, 0),
+                                             (10, 0), (7, 5), (5, 4)])
+    def test_writers_move_blocks_and_change_only_the_named_slots(
+            self, s_len, start, quant):
+        """The writers read, merge and write the touched blocks whole
+        (`_block_window`); what lands is a plain scatter of the rows,
+        from any offset in a block: every slot not named is bit for bit
+        what it was, a row with a short valid prefix writes only that,
+        an inactive row nothing."""
+        from paddle_tpu.ops.paged_attention import (
+            paged_cache_update_arrays, quantized_cache_update_arrays,
+            slot_mapping)
+
+        rng = np.random.RandomState(10 * s_len + start)
+        B, H, D, BS, NB = 3, 2, 4, 4, 16
+        rows = rng.randn(B, s_len, H, D).astype(np.float32)
+        tables = np.asarray([[7, 2, 5, 11], [1, 8, 3, 12], [0, 4, 6, 13]],
+                            np.int32)
+        pos = start + np.tile(np.arange(s_len, dtype=np.int32), (B, 1))
+        keep = [s_len, max(s_len - 2, 0), 0]        # valid prefix per row
+        valid = np.arange(s_len)[None] < np.asarray(keep)[:, None]
+        slots = slot_mapping(tables, pos, BS, NB * BS, valid=valid)
+        named = np.asarray(slots)[valid]
+        if quant:
+            pool = jnp.asarray(rng.randint(-127, 128, (NB, BS, H * D)),
+                               jnp.int8)
+            # a scale above the rows' amax / 127: nothing is rescaled
+            scales = jnp.full((NB, H), 1.0, jnp.float32)
+            got, sc = quantized_cache_update_arrays(pool, scales,
+                                                    jnp.asarray(rows), slots)
+            np.testing.assert_array_equal(np.asarray(sc), np.asarray(scales))
+            want = np.array(pool).reshape(NB * BS, H * D)
+            want[named] = np.round(rows[valid]).reshape(-1, H * D)
+        else:
+            pool = jnp.asarray(rng.randn(NB, BS, H * D), jnp.float32)
+            got = paged_cache_update_arrays(pool, jnp.asarray(rows), slots)
+            want = np.array(pool).reshape(NB * BS, H * D)
+            want[named] = rows[valid].reshape(-1, H * D)
+        np.testing.assert_array_equal(
+            np.asarray(got).reshape(NB * BS, H * D), want)
+
+    def test_writers_refuse_slots_that_are_not_consecutive(self):
+        from paddle_tpu.ops.paged_attention import paged_cache_update_arrays
+
+        pool = jnp.zeros((4, 4, 2), jnp.float32)
+        rows = jnp.ones((1, 3, 1, 2), jnp.float32)
+        for bad in ([[0, 2, 3]], [[16, 1, 2]], [[3, 5, 6]]):
+            with pytest.raises(ValueError, match="consecutive"):
+                paged_cache_update_arrays(pool, rows,
+                                          jnp.asarray(bad, jnp.int32))
+        # the end of one block, then the start of ANY other: fine
+        out = paged_cache_update_arrays(pool, rows,
+                                        jnp.asarray([[3, 8, 9]], jnp.int32))
+        assert float(out.sum()) == 6.0
+
     def test_oob_slots_are_dropped_not_clamped(self):
         from paddle_tpu.ops.paged_attention import paged_cache_update_arrays
 
-        kb = jnp.zeros((2, 2, 1, 1), jnp.float32)
+        kb = jnp.zeros((2, 2, 1), jnp.float32)
         rows = jnp.ones((1, 1, 1, 1), jnp.float32)
         out = paged_cache_update_arrays(kb, rows,
                                         jnp.asarray([[4]], jnp.int32))
