@@ -14,12 +14,16 @@ from .gpt import (
     gpt3_1p3b_config,
     gpt3_6p7b_config,
 )
+from .afmoe import AfmoeConfig, AfmoeForCausalLM, afmoe_test_config
+from .serving_form import LayerSpec, ServingForm
 from .bert import BertConfig, BertModel, BertForSequenceClassification, bert_base_config
 
 __all__ = [
     "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPretrainingCriterion",
     "gpt_test_config", "gpt2_124m_config", "gpt3_1p3b_config",
     "gpt3_6p7b_config",
+    "AfmoeConfig", "AfmoeForCausalLM", "afmoe_test_config",
+    "LayerSpec", "ServingForm",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "bert_base_config",
 ]

@@ -36,6 +36,7 @@ from ..nn.initializer import Normal, Constant
 from ..nn.norm import LayerNorm
 from ..nn.common import Linear, Dropout, Embedding
 from ..ops.pallas_ops import cached_attention_arrays, flash_attention
+from .serving_form import LayerSpec, ServingForm
 from ..parallel import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     ParallelCrossEntropy, constraint, shard_parameter,
@@ -811,6 +812,63 @@ class GPTModel(Layer):
         return self.ln_f(x)
 
 
+class GPTServingForm(ServingForm):
+    """The stacked-blocks GPT as `LLMEngine` serves it: the dense path's
+    own arithmetic (same embedding takes, `_stacked_block_body`,
+    `F.layer_norm` float32 statistics, the tied head's einsum), so a paged
+    decode returns the tokens of `generate()`.  One cache group, full
+    attention, as many K/V heads as query heads."""
+
+    def __init__(self, model):
+        cfg = model.cfg
+        if not cfg.stacked_blocks:
+            raise ValueError(
+                "LLMEngine serves the stacked-blocks GPT form "
+                "(GPTConfig(stacked_blocks=True)) — per-layer Layer "
+                "modules would re-trace one program per layer")
+        self.gpt, self.cfg = model.gpt, cfg
+        self.vocab_size = cfg.vocab_size
+        self.max_position_embeddings = cfg.max_position_embeddings
+        nh = cfg.num_attention_heads
+        self.layer_specs = [LayerSpec(nh, nh, cfg.hidden_size // nh, None,
+                                      "full")] * cfg.num_hidden_layers
+        self._stack_names = list(model.gpt.blocks._names)
+
+    @property
+    def dtype(self):
+        return self.gpt.embeddings.word_embeddings.weight.dtype
+
+    def params(self):
+        gpt = self.gpt
+        params = {n: getattr(gpt.blocks, n)._data for n in self._stack_names}
+        params["wte"] = gpt.embeddings.word_embeddings.weight._data
+        params["wpe"] = gpt.embeddings.position_embeddings.weight._data
+        params["lnf_w"] = gpt.ln_f.weight._data
+        params["lnf_b"] = gpt.ln_f.bias._data
+        return params
+
+    def embed(self, params, ids, pos):
+        return jnp.take(params["wte"], ids, axis=0) \
+            + jnp.take(params["wpe"], pos, axis=0)
+
+    def layer(self, l, params, h, pos, attn_fn, valid=None):
+        spec = self.layer_specs[l]
+        p = {n: params[n][l] for n in self._stack_names}
+        h, extra = _stacked_block_body(p, h, attn_fn, spec.num_heads,
+                                       spec.head_dim,
+                                       self.cfg.layer_norm_epsilon)
+        return h, extra, None
+
+    def logits(self, params, h):
+        # the dense path's ln_f arithmetic (`F.layer_norm`, NOT the block
+        # `_stacked_ln`) and lm_head einsum, so parity tracks the oracle
+        from ..nn.functional import layer_norm_arrays
+
+        hn = layer_norm_arrays(h, params["lnf_w"], params["lnf_b"],
+                               epsilon=self.cfg.layer_norm_epsilon)
+        return jnp.einsum("bsh,vh->bsv", hn, params["wte"])
+
+
 def _zigzag_active(cfg):
     """True when the model-level zigzag context-parallel layout applies
     (mesh/config only; the caller validates seq divisibility)."""
@@ -855,6 +913,10 @@ class GPTForCausalLM(Layer):
         self.cfg = cfg
         self.gpt = GPTModel(cfg)
         self._gen_step = None       # (shapes key, jitted fn) decode cache
+
+    def serving_form(self):
+        """What `serving.LLMEngine` runs of this model."""
+        return GPTServingForm(self)
 
     def __deepcopy__(self, memo):
         # the decode cache's jitted closure captures SELF — a deepcopy

@@ -255,7 +255,8 @@ def quantized_gather_kv_arrays(blocks, scales, block_table):
 
 
 def paged_attention_arrays(q, k_blocks, v_blocks, block_table, pos0,
-                           scale=None, k_scales=None, v_scales=None):
+                           scale=None, k_scales=None, v_scales=None,
+                           window=None):
     """Causal attention of a (ragged) batch against its paged KV cache.
 
     q:            [B, S, H, D] — S=1 at decode, >1 for a prefill chunk
@@ -275,25 +276,43 @@ def paged_attention_arrays(q, k_blocks, v_blocks, block_table, pos0,
     k_scales/v_scales: pass the [num_blocks, H] per-block-per-head scale
     pools to read int8-quantized K/V blocks (the lowbit KV wing) — the
     gather dequantizes, the attention arithmetic is unchanged.
+
+    Grouped heads: the pools may hold FEWER heads than q (rows of
+    `H_kv * D`, H a multiple of H_kv); query head h reads K/V head
+    h // (H / H_kv).  `window`: a key is visible iff it lies fewer than
+    `window` positions behind the query; table entries wholly behind
+    every query's window may point nowhere (they gather some block, and
+    this mask covers it).
     """
     b, s, h, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    h_kv = int(k_blocks.shape[2]) // d
     if k_scales is not None:
         # lowbit path: int8 pools + per-block-per-head scales dequantize
         # inside the gather; the attention arithmetic below is unchanged
         kg = quantized_gather_kv_arrays(k_blocks, k_scales, block_table)
         vg = quantized_gather_kv_arrays(v_blocks, v_scales, block_table)
     else:
-        kg = paged_gather_kv_arrays(k_blocks, block_table, h)
-        vg = paged_gather_kv_arrays(v_blocks, block_table, h)
+        kg = paged_gather_kv_arrays(k_blocks, block_table, h_kv)
+        vg = paged_gather_kv_arrays(v_blocks, block_table, h_kv)
     s_pad = kg.shape[1]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kg,
-                        preferred_element_type=jnp.float32) * scale
     q_pos = jnp.asarray(pos0, jnp.int32)[:, None] + jnp.arange(
         s, dtype=jnp.int32)[None, :]                       # [B, S]
     k_pos = jnp.arange(s_pad, dtype=jnp.int32)
-    causal = k_pos[None, None, :] <= q_pos[:, :, None]     # [B, S, S_pad]
-    logits = jnp.where(causal[:, None], logits, _NEG_INF)
+    seen = k_pos[None, None, :] <= q_pos[:, :, None]       # [B, S, S_pad]
+    if window is not None:
+        seen &= q_pos[:, :, None] - k_pos[None, None, :] < window
+    if h_kv != h:
+        qg = q.reshape(b, s, h_kv, h // h_kv, d)
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kg,
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(seen[:, None, None], logits, _NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(vg.dtype), vg)
+        return out.reshape(b, s, h, d).astype(q.dtype)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kg,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(seen[:, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(vg.dtype), vg)
     return out.astype(q.dtype)

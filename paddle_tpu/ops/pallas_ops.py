@@ -70,18 +70,30 @@ def _on_tpu() -> bool:
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, mask=None, is_causal=False, scale=None,
-                  kv_lens=None, segment_ids=None):
+                  kv_lens=None, segment_ids=None, window=None):
     """q,k,v: [B,S,H,D] → [B,S,H,D]. Computed in fp32 accumulation.
     kv_lens: optional [B] int32 valid key lengths (right-padded batch).
     segment_ids: optional [B, S] int32 packed-sequence ids (self-attention
-    only): position pairs attend iff their ids match."""
+    only): position pairs attend iff their ids match.
+    k, v may hold fewer heads than q (grouped heads: query head h reads
+    K/V head h // ratio).  window: with is_causal, a key is visible iff it
+    lies fewer than `window` positions behind the query."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    if k.shape[2] != q.shape[2]:
+        if q.shape[2] % k.shape[2]:
+            raise ValueError(
+                f"{q.shape[2]} query heads are not a multiple of the K/V "
+                f"heads ({k.shape[2]})")
+        ratio = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, ratio, axis=2), jnp.repeat(v, ratio, axis=2)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     if is_causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((sq, sk), bool), sk - sq)
+        if window is not None:
+            causal &= ~jnp.tril(jnp.ones((sq, sk), bool), sk - sq - window)
         logits = jnp.where(causal, logits, _NEG_INF)
     if kv_lens is not None:
         k_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
@@ -133,7 +145,7 @@ def _seg_kb_bounds(seg_vec, lo, hi, seq_len, block):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, block_k, seq_k,
                       scale, causal, block_q, has_mask, has_lens,
-                      has_segs=False, causal_offset=0):
+                      has_segs=False, causal_offset=0, window=None):
     from jax.experimental import pallas as pl
 
     refs = list(refs)
@@ -168,6 +180,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, block_k, seq_k,
             # query row i attends keys <= i + (sk - sq)
             q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             s = jnp.where(q_pos + causal_offset >= k_pos, s, _NEG_INF)
+            if window is not None:
+                s = jnp.where(q_pos + causal_offset - k_pos < window, s,
+                              _NEG_INF)
         if has_lens:
             s = jnp.where(k_pos < kv_len, s, _NEG_INF)
         if has_segs:
@@ -186,6 +201,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, block_k, seq_k,
         last_kb = jnp.minimum(
             ((qi + 1) * block_q + causal_offset + block_k - 1) // block_k,
             num_kb)
+        if window is not None:
+            # key blocks wholly behind the window of this block's FIRST
+            # query are behind every query's: skipped like the segment
+            # envelope below, the in-tile mask kills the rest
+            first_kb = jnp.maximum(
+                qi * block_q + causal_offset - window + 1, 0) // block_k
     else:
         last_kb = num_kb
     if has_lens:
@@ -449,8 +470,14 @@ def _interpret() -> bool:
 
 
 def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
-               n_heads=1, mask=None, kv_lens=None, segments=None):
+               n_heads=1, mask=None, kv_lens=None, segments=None,
+               kv_heads=None, window=None):
     """q,k,v: [BH, S, D] (heads folded into batch) → (out, lse).
+
+    kv_heads: k, v fold fewer heads than q ([B * kv_heads, S, D]); the
+    program of query head h reads K/V head h // (n_heads / kv_heads), and
+    the runtime skips the copy while consecutive programs read the same
+    one.  window (with is_causal): see `flash_attention_arrays`.
 
     mask: optional additive [B, Hm, Sq, Sk] with Hm in {1, n_heads} —
     loaded blockwise via its own BlockSpec, so a per-batch mask (Hm=1) is
@@ -473,6 +500,8 @@ def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
     assert block_q is not None and block_k is not None
 
     H = n_heads
+    Hk = kv_heads or H
+    ratio = H // Hk
     has_mask = mask is not None
     has_lens = kv_lens is not None
     has_segs = segments is not None
@@ -487,12 +516,13 @@ def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
         has_lens=has_lens,
         has_segs=has_segs,
         causal_offset=sk - sq,
+        window=window,
     )
     grid = (bh // H, H, sq // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, h, i: (b * H + h, i, 0)),
-        pl.BlockSpec((1, sk, d), lambda b, h, i: (b * H + h, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda b, h, i: (b * H + h, 0, 0)),
+        pl.BlockSpec((1, sk, d), lambda b, h, i: (b * Hk + h // ratio, 0, 0)),
+        pl.BlockSpec((1, sk, d), lambda b, h, i: (b * Hk + h // ratio, 0, 0)),
     ]
     args = [q, k, v]
     if has_lens:
@@ -646,12 +676,19 @@ def _mask_shape_ok(mask, B, H, sq, sk) -> bool:
     return (mq, mk) == (sq, sk) and bm in (1, B) and hm in (1, H)
 
 
-def _pallas_ok(q, k, is_causal, mask, kv_lens=None, segment_ids=None) -> bool:
+def _pallas_ok(q, k, is_causal, mask, kv_lens=None, segment_ids=None,
+               window=None) -> bool:
     if not (_on_tpu() or _interpret()):
         _count_path("attn_fallback:off_tpu")
         return False
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    if h % k.shape[2]:
+        _count_path("attn_fallback:kv_head_groups")
+        return False
+    if window is not None and (not is_causal or window < 1):
+        _count_path("attn_fallback:window_not_causal")
+        return False
     if d % 128 != 0 and d not in (64, 128, 256):
         _count_path("attn_fallback:head_dim")
         return False
@@ -789,6 +826,31 @@ def _flash_kernel_on_mesh(q, k, v, mask, lens, segs, is_causal, scale):
     )(q, k, v, mask, lens, segs)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_forward_only(q, k, v, is_causal, scale, window):
+    """The flash forward kernel with grouped heads and/or a window.  It
+    has no backward: differentiating it raises instead of tracing a JVP
+    through the kernel."""
+    b, _, h, _ = q.shape
+    of, _ = _flash_fwd(_fold_heads(q), _fold_heads(k), _fold_heads(v),
+                       is_causal, scale, n_heads=h, kv_heads=k.shape[2],
+                       window=window)
+    return _unfold_heads(of, b, h)
+
+
+def _flash_forward_only_fwd(q, k, v, is_causal, scale, window):
+    return _flash_forward_only(q, k, v, is_causal, scale, window), None
+
+
+def _flash_forward_only_bwd(is_causal, scale, window, res, g):
+    raise NotImplementedError(
+        "flash attention with grouped heads or a window is served forward "
+        "only; train through mha_reference")
+
+
+_flash_forward_only.defvjp(_flash_forward_only_fwd, _flash_forward_only_bwd)
+
+
 def _normalize_mask(attn_mask):
     """Bring a (shape-validated) user mask to additive [Bm, Hm, Sq, Sk]
     without broadcasting it out in HBM."""
@@ -806,7 +868,8 @@ _NEG_INF_MASK = -1e30
 
 
 def flash_attention_arrays(q, k, v, attn_mask=None, is_causal=False,
-                           scale=None, kv_lens=None, segment_ids=None):
+                           scale=None, kv_lens=None, segment_ids=None,
+                           window=None):
     """Array-level entry (used inside compiled training steps).
 
     attn_mask on the KERNEL path is treated as a CONSTANT (stop_gradient):
@@ -827,7 +890,17 @@ def flash_attention_arrays(q, k, v, attn_mask=None, is_causal=False,
     only; positions attend iff ids match, composed with is_causal. The
     kernel masks in-tile and SKIPS key blocks outside each q block's
     segment envelope, so packed batches keep flash cost with no [S, S]
-    mask in HBM. Rows with an id that appears nowhere else (e.g. padding)
+    mask in HBM.
+
+    window: optional int, with is_causal: a key is visible iff it lies
+    fewer than `window` positions behind the query (sliding-window
+    attention).  The kernel skips key blocks wholly behind a q block's
+    window, so a long prompt pays S x window and not S^2.
+
+    Grouped heads: k, v may hold fewer heads than q ([B, S, H_kv, D], H a
+    multiple of H_kv); query head h reads K/V head h // (H / H_kv) and K/V
+    are never repeated in HBM.  Grouped heads and `window` are served
+    forward only (inference): this entry's backward does not carry them. Rows with an id that appears nowhere else (e.g. padding)
     produce unspecified output at those positions — ignore them, as with
     any padded attention. (SURVEY declares this capability class native —
     the reference has no flash kernels at all; analog masking semantics:
@@ -850,6 +923,16 @@ def flash_attention_arrays(q, k, v, attn_mask=None, is_causal=False,
                 f"segment_ids must be [batch, seq] = [{b}, {sq}] for "
                 f"self-attention (got shape {tuple(segs.shape)}, "
                 f"key length {sk})")
+    grouped = k.shape[2] != q.shape[2]
+    if grouped or window is not None:
+        if attn_mask is not None or lens is not None or segs is not None:
+            raise ValueError("grouped heads and window compose with "
+                             "is_causal only")
+        if _pallas_ok(q, k, is_causal, None, window=window):
+            _count_path("attn_kernel" + (":grouped" if grouped else "")
+                        + (":window" if window is not None else ""))
+            return _flash_forward_only(q, k, v, is_causal, scale, window)
+        return mha_reference(q, k, v, None, is_causal, scale, window=window)
     if _pallas_ok(q, k, is_causal, attn_mask,
                   None if lens is None else lens[:, 0], segs):
         _count_path("attn_kernel" + (":kv_lens" if lens is not None else "")
@@ -1000,8 +1083,8 @@ def _decode_seg_helpers(h, d, fast):
     return seg, expand, seg_dot
 
 
-def _two_block_dma_loop(num_kb, copies, step, carry):
-    """carry = step(slot, kb, carry) for kb in [0, num_kb), two blocks per
+def _two_block_dma_loop(num_kb, copies, step, carry, first_kb=0):
+    """carry = step(slot, kb, carry) for kb in [first_kb, num_kb), two blocks per
     loop iteration: an iteration starts the DMAs of both its blocks
     (`copies(slot, kb)` builds block kb's descriptors into buffer `slot`),
     then `step` waits on each in turn and does its math — the second block
@@ -1021,7 +1104,7 @@ def _two_block_dma_loop(num_kb, copies, step, carry):
             c.start()
 
     def body(g, carry):
-        kb0 = 2 * g                 # in range by the loop bound
+        kb0 = first_kb + 2 * g      # in range by the loop bound
         kb1 = kb0 + 1               # may fall off the end of the row
         start(0, kb0)
 
@@ -1033,7 +1116,7 @@ def _two_block_dma_loop(num_kb, copies, step, carry):
         return jax.lax.cond(kb1 < num_kb,
                             lambda c: step(1, kb1, c), lambda c: c, carry)
 
-    return jax.lax.fori_loop(0, (num_kb + 1) // 2, body, carry)
+    return jax.lax.fori_loop(0, (num_kb - first_kb + 1) // 2, body, carry)
 
 
 def _prefix_attn_loop(qf, length, num_kb, row0, k_hbm, v_hbm, k_buf, v_buf,

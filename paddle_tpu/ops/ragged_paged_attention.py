@@ -65,7 +65,7 @@ from .paged_attention import (paged_attention_arrays,
                               paged_cache_update_arrays,
                               quantized_cache_update_arrays)
 from .pallas_ops import (_NEG_INF, _count_path, _decode_seg_helpers,
-                         _interpret, _on_tpu, _two_block_dma_loop)
+                         _dot_f32, _interpret, _on_tpu, _two_block_dma_loop)
 
 __all__ = ["ragged_paged_attention_arrays"]
 
@@ -78,7 +78,7 @@ _QMAX = 127
 # are observable)
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel_ok(q, k_blocks, c, quant) -> bool:
+def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
     """Geometry/flag gate for the fused ragged kernel.  The kernel serves
     the decode shape (C = 1) — chunked-prefill and speculative-verify
     rows (C > 1) take the fallback, which is the parity-exact program
@@ -97,8 +97,25 @@ def _ragged_kernel_ok(q, k_blocks, c, quant) -> bool:
         return False
     _, _, h, d = q.shape
     bs = int(k_blocks.shape[1])
-    if d not in (64, 128, 256) or (h * d) % 128 != 0:
+    hd_kv = int(k_blocks.shape[2])
+    if d not in (64, 128, 256) or hd_kv % 128 != 0:
         _count_path("ragged_fallback:head_geometry")
+        return False
+    if hd_kv % d or (h * d) % hd_kv or h * d // hd_kv > _GROUP_ROWS:
+        # query heads must be a whole number of groups over the K/V heads
+        _count_path("ragged_fallback:kv_head_groups")
+        return False
+    if h * d != hd_kv and d % 128:
+        # a grouped K/V head is read as whole lane tiles of a block
+        _count_path("ragged_fallback:grouped_head_dim")
+        return False
+    if quant and (h * d != hd_kv or window is not None):
+        # int8 pools are read with as many K/V heads as query heads and
+        # no window (the scale tables are gathered per query head)
+        _count_path("ragged_fallback:quant_grouped_or_window")
+        return False
+    if window is not None and window < 1:
+        _count_path("ragged_fallback:window_lt_1")
         return False
     # block DMAs slice [block_size, H*D] slabs: the sublane dim must be a
     # tile multiple for the pool dtype ((8,128) f32 / (16,128) bf16 /
@@ -124,7 +141,7 @@ def _ragged_kernel_ok(q, k_blocks, c, quant) -> bool:
 
 def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                          k_hbm, v_hbm, *refs, bs, h, d, nb, maxb, scale,
-                         quant):
+                         quant, window=None, groups=1):
     """One program per batch row r:
 
     1. DMA the row's TARGET block (the one its write slot lands in) into
@@ -142,6 +159,21 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
        codes at load via the per-block-per-head scales, with an online
        softmax; the target block's contribution comes from the updated
        VMEM copy, never re-read through the alias.
+
+    Grouped heads (`h` counts the K/V heads of a pool row, `groups` the
+    query heads that read each): the row's query block is `[GP, h * d]`,
+    member g at lane segment j being query head j * groups + g, padded
+    with zero rows to GP = 8 sublanes.  K/V head j of a streamed block is
+    the lane slice `[:, j*d:(j+1)*d]` (whole lane tiles: d is a multiple
+    of 128), and its members' logits and weighted values are two small
+    MXU products, `q_j [GP, d] x K_j^T` and `p [GP, bs] x V_j`, with the
+    online-softmax state kept per head - the dot products run on the MXU
+    with the members as its rows.  (First built as the K∘q-then-segment
+    product per member: 56 us a 64-token block on the chip, 117 ms a
+    layer at 32 rows; PERF.md, PR 28.)  `window`: the stream starts at
+    block `max(0, length - window) // bs` and positions under
+    `length - window` are masked (table entries behind that may point
+    nowhere; they are never read).
 
     Rows whose write slot is out of range (batch padding / evicted rows)
     skip the write and produce garbage output the engine ignores.  Heads
@@ -244,9 +276,10 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         wv.wait()
 
     # -- 2. streamed attention over the row's valid blocks ------------------
-    qf = q_ref[...].astype(jnp.float32)                  # [1, 1, hd]
     # padding rows (length 0) stream nothing and put out zeros
     num_kb = jnp.minimum((length + bs - 1) // bs, maxb)
+    low = 0 if window is None else jnp.maximum(length - window, 0)
+    first_kb = 0 if window is None else jnp.minimum(low // bs, num_kb)
 
     def copies(slot_i, kb):
         b_kb = jnp.clip(tbl_ref[r, kb], 0, nb - 1)
@@ -254,6 +287,21 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                                       kbuf.at[slot_i], sem.at[slot_i, 0]),
                 pltpu.make_async_copy(v_hbm.at[pl.ds(b_kb, 1)],
                                       vbuf.at[slot_i], sem.at[slot_i, 1]))
+
+    def seen_at(kb, shape, axis):
+        pos = kb * bs + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        seen = pos < length
+        if window is not None:
+            seen &= pos >= low
+        return seen
+
+    if groups > 1:
+        _grouped_stream(q_ref, o_ref, kbuf, vbuf, ublk, copies, seen_at,
+                        valid, tkb, first_kb, num_kb, bs=bs, h=h, d=d,
+                        scale=scale)
+        return
+
+    qf = q_ref[...].astype(jnp.float32)                  # [1, 1, hd]
 
     def step(sl, kb, carry):
         m, l, acc = carry            # m, l: [1,1,h]; acc: [1,1,hd] fp32
@@ -266,8 +314,7 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                               exact=True)
         kf = jnp.where(is_t, kup_f, kf)
         s = seg_dot(kf * qf, seg) * scale                # [1, bs, h]
-        pos = kb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs, h), 1)
-        s = jnp.where(pos < length, s, _NEG_INF)
+        s = jnp.where(seen_at(kb, (1, bs, h), 1), s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -286,18 +333,73 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     m0 = jnp.full((1, 1, h), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, 1, h), jnp.float32)
     acc0 = jnp.zeros((1, 1, hd), jnp.float32)
-    m, l, acc = _two_block_dma_loop(num_kb, copies, step, (m0, l0, acc0))
+    m, l, acc = _two_block_dma_loop(num_kb, copies, step, (m0, l0, acc0),
+                                    first_kb=first_kb)
     l_exp = seg_dot(l, expand, exact=True)
     o_ref[...] = (acc / jnp.maximum(l_exp, 1e-30)).astype(o_ref.dtype)
 
 
+_GROUP_ROWS = 8        # a row's group members, padded to one sublane tile
+
+
+def _grouped_stream(q_ref, o_ref, kbuf, vbuf, ublk, copies, seen_at, valid,
+                    tkb, first_kb, num_kb, *, bs, h, d, scale):
+    """Step 2 of `_ragged_fused_kernel` for grouped heads, full precision:
+    q_ref/o_ref are `[1, GP, h*d]`, K/V head j the lane slice j of a
+    streamed `[1, bs, h*d]` block.  Per block and head, two MXU products
+    with the group members as rows; state per head m, l `[GP, 1]` and acc
+    `[GP, d]` in float32."""
+    gp = _GROUP_ROWS
+    q = q_ref[0]                                          # [GP, hd]
+    heads = [slice(j * d, (j + 1) * d) for j in range(h)]
+
+    def step(sl, kb, carry):
+        kd, vd = copies(sl, kb)
+        kd.wait()
+        is_t = valid & (kb == tkb)
+        # the row's own new token lives in the updated VMEM copy of its
+        # target block, never in what was streamed through the alias
+        k_blk = jnp.where(is_t, ublk[0], kbuf[sl])[0]     # [bs, hd]
+        seen = seen_at(kb, (gp, bs), 1)
+        half = []
+        for (m, l, _), lanes in zip(carry, heads):
+            s = _dot_f32(q[:, lanes], k_blk[:, lanes],
+                         transpose_b=True) * scale        # [GP, bs]
+            s = jnp.where(seen, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            half.append((m_new, alpha * l + jnp.sum(p, axis=1,
+                                                    keepdims=True),
+                         p, alpha))
+        vd.wait()
+        v_blk = jnp.where(is_t, ublk[1], vbuf[sl])[0]
+        return tuple(
+            (m_new, l_new,
+             acc * alpha + _dot_f32(p.astype(v_blk.dtype), v_blk[:, lanes]))
+            for (m_new, l_new, p, alpha), (_, _, acc), lanes
+            in zip(half, carry, heads))
+
+    head0 = (jnp.full((gp, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((gp, 1), jnp.float32),
+             jnp.zeros((gp, d), jnp.float32))
+    done = _two_block_dma_loop(num_kb, copies, step, (head0,) * h,
+                               first_kb=first_kb)
+    for (_, l, acc), lanes in zip(done, heads):
+        o_ref[0, :, lanes] = (acc / jnp.maximum(l, 1e-30)).astype(
+            o_ref.dtype)
+
+
 def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
-                        pos0, kv_lens, slots, k_scales, v_scales, scale):
+                        pos0, kv_lens, slots, k_scales, v_scales, scale,
+                        window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, c, h, d = q.shape
+    b, c, h_q, d = q.shape
     nb, bs, hd = k_blocks.shape
+    h = hd // d                    # K/V heads: the heads of a pool row
+    g = h_q // h                   # query heads a K/V head
     quant = k_scales is not None
     pool_dt = k_blocks.dtype
     tbl = jnp.asarray(block_table, jnp.int32)
@@ -307,10 +409,19 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     slots_i = jnp.asarray(slots, jnp.int32).reshape(b)
     row = pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0))
     pool = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [row, row, row, pool, pool]        # q, k_new, v_new, pools
+    if g == 1:
+        q_row, q_members = row, q.reshape(b, c, hd)
+    else:
+        # query head j * G + m -> member m, lane segment j; the members
+        # padded with zero rows to one sublane tile
+        q_row = pl.BlockSpec((1, _GROUP_ROWS, hd), lambda r, *pre: (r, 0, 0))
+        q_members = jnp.pad(
+            jnp.swapaxes(q.reshape(b, h, g, d), 1, 2).reshape(b, g, hd),
+            ((0, 0), (0, _GROUP_ROWS - g), (0, 0)))
+    in_specs = [q_row, row, row, pool, pool]      # q, k_new, v_new, pools
     # the pools go in and come out as they are kept: a reshape of one
     # between [.., H, D] and [.., H*D] is a copy of all of it on a TPU
-    args = [q.reshape(b, c, hd), k_new.reshape(b, c, hd),
+    args = [q_members, k_new.reshape(b, c, hd),
             v_new.reshape(b, c, hd), k_blocks, v_blocks]
     if quant:
         # grow the written blocks' scales here (the first half of
@@ -347,24 +458,27 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=in_specs,
-        out_specs=[row, pool, pool],
+        out_specs=[q_row, pool, pool],
         scratch_shapes=scratch,
     )
     kernel = functools.partial(_ragged_fused_kernel, bs=bs, h=h, d=d,
-                               nb=nb, maxb=maxb, scale=scale, quant=quant)
+                               nb=nb, maxb=maxb, scale=scale, quant=quant,
+                               window=window, groups=g)
     # aliasing indices INCLUDE the scalar-prefetch args (lens=0, slots=1,
     # tables=2, q=3, k_new=4, v_new=5, pools=6/7)
     outs = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(q_members.shape, q.dtype),
                    jax.ShapeDtypeStruct((nb, bs, hd), pool_dt),
                    jax.ShapeDtypeStruct((nb, bs, hd), pool_dt)],
         input_output_aliases={6: 1, 7: 2},
         interpret=_interpret(),
     )(lens_i, slots_i, tbl, *args)
-    o, k2, v2 = outs[0].reshape(b, c, h, d), outs[1], outs[2]
+    o = outs[0].reshape(b, c, h_q, d) if g == 1 else jnp.swapaxes(
+        outs[0][:, :g].reshape(b, g, h, d), 1, 2).reshape(b, c, h_q, d)
+    k2, v2 = outs[1], outs[2]
     if quant:
         return o, k2, v2, new_scales[0], new_scales[1]
     return o, k2, v2
@@ -417,7 +531,8 @@ def _folded_quant_attention(q, k_blocks, v_blocks, k_scales, v_scales,
 
 def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
                                   block_table, pos0, kv_lens, slots,
-                                  k_scales=None, v_scales=None, scale=None):
+                                  k_scales=None, v_scales=None, scale=None,
+                                  window=None):
     """Fused cache-update + causal paged attention for a ragged batch in
     ONE fixed-shape program.
 
@@ -451,6 +566,15 @@ def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
                      bound; ignored by the masked fallback.
     slots:           [B, C] int32 physical write slots; out-of-range
                      entries (padding / evicted rows) are dropped.
+    window:          optional int: a key is visible iff it lies fewer
+                     than `window` positions behind the query (a sliding-
+                     window layer).  The kernel then starts its stream at
+                     the window's first block; table entries wholly
+                     behind the window may point nowhere.
+
+    Grouped heads: k_new/v_new and the pools may hold fewer heads than q
+    ([B, C, H_kv, D], rows of H_kv * D); query head h reads K/V head
+    h // (H / H_kv).  Full precision only.
 
     Returns ``(out, k_blocks', v_blocks')`` — plus ``(k_scales',
     v_scales')`` in quantized mode.  The new tokens' K/V are written to
@@ -462,16 +586,20 @@ def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
     quant = k_scales is not None
     if quant != (v_scales is not None):
         raise ValueError("pass both k_scales and v_scales, or neither")
-    if _ragged_kernel_ok(q, k_blocks, c, quant):
+    if quant and (window is not None
+                  or int(k_blocks.shape[2]) != h * d):
+        raise ValueError("int8 pools are read with as many K/V heads as "
+                         "query heads and no window")
+    if _ragged_kernel_ok(q, k_blocks, c, quant, window):
         return _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks,
                                    block_table, pos0, kv_lens, slots,
-                                   k_scales, v_scales, scale)
+                                   k_scales, v_scales, scale, window)
     if not quant:
         # bitwise the reference composition — the fp parity contract
         k2 = paged_cache_update_arrays(k_blocks, k_new, slots)
         v2 = paged_cache_update_arrays(v_blocks, v_new, slots)
         out = paged_attention_arrays(q, k2, v2, block_table, pos0,
-                                     scale=scale)
+                                     scale=scale, window=window)
         return out, k2, v2
     k2, ks2 = quantized_cache_update_arrays(k_blocks, k_scales, k_new,
                                             slots)

@@ -5,7 +5,14 @@ gate -> global_scatter all-to-all dispatch -> local experts -> global_gather)
 with the collective ops paddle/fluid/operators/collective/global_scatter_op.cu.cc
 and global_gather_op.cu.cc.
 
-TPU-native design (GShard-style, SPMD):
+Two dispatches live here.  Training (`moe_mlp_arrays`) is the capacity-
+factor one below.  Serving (`held_experts_arrays`) is dropless: one chip
+of an expert-parallel deployment is told which experts it holds, routes
+over ALL of them, and computes its own experts' part of the result with a
+grouped product - no token is dropped at any imbalance, and nothing stands
+in for the chips that are not here.
+
+TPU-native design of the training dispatch (GShard-style, SPMD):
 - top-k gating with a static capacity C = ceil(cf * k * tokens / E): static
   shapes keep XLA happy; overflow tokens are dropped (their combine weight
   is zero) exactly like the reference's capacity overflow.
@@ -29,7 +36,8 @@ from .mesh import get_mesh, axis_size
 from .. import monitor
 from ..profiler import RecordEvent
 
-__all__ = ["moe_mlp_arrays", "moe_capacity"]
+__all__ = ["moe_mlp_arrays", "moe_capacity", "held_experts_arrays",
+           "route_top_k"]
 
 
 def _maybe_record_routing(dispatch, n_tokens, top_k):
@@ -202,3 +210,92 @@ def _moe_mlp_dispatch(x, gate_logits, w_in, w_out, top_k, capacity_factor,
     # surrounding jit in this jax version; jax.jit inlines when already
     # inside a trace, so this is a no-op on the blessed compiled path
     return jax.jit(fn)(x, gate_logits, w_in, w_out)
+
+
+# ---------------------------------------------------------------------------
+# serving: the held experts' share of a dropless expert layer
+# ---------------------------------------------------------------------------
+
+# A prefill of T tokens makes T * top_k pairs, of which a chip that holds
+# 1/8 of the experts multiplies about 1/8.  From this many pairs on, the
+# grouped products run over a quarter of the rows when the held pairs fit
+# there (they lie first after the sort) and over all of them when not.
+_SPLIT_ROWS = 1024
+
+
+def route_top_k(m, router_w, bias, top_k, route_scale):
+    """Sigmoid routing over the router's full width -> (sel [T,k] int32,
+    w [T,k] float32).  Scores are float32 whatever `m` is: a bf16 score
+    moves the top-k across near-ties.  `bias` enters the selection only;
+    the weights are the selected scores normalised over all `top_k`
+    (held here or not) times `route_scale`."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        m, router_w, preferred_element_type=jnp.float32))
+    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, sel, axis=-1)
+    w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * route_scale
+
+
+def held_experts_arrays(m, router_w, bias, experts, first, n, top_k,
+                        route_scale, valid=None, scope="moe"):
+    """What experts `first .. first + n - 1` add for the tokens `m`.
+
+    m:        [T, H] tokens (after the pre-MLP norm)
+    router_w: [H, E] router over the published width E (E >= first + n)
+    bias:     [E] selection bias (never enters the weights)
+    experts:  (gate_w [n,H,I], up_w [n,H,I], down_w [n,I,H]) SwiGLU experts
+    valid:    optional [T] bool; a False row (batch padding) routes nowhere
+    -> (y [T, H] float32, stats int32 [4] = pairs held, pairs absent,
+        distinct held experts with at least one token, tokens routed;
+        held + absent == top_k * tokens when no pair was dropped)
+
+    The (token, expert) pairs are sorted by expert, held ones first, one
+    `jax.lax.ragged_dot` per matrix runs over the held groups, and the
+    weighted rows are scatter-added to their tokens.  Shapes are static
+    (`T * top_k` rows); pairs on absent experts fall in a trailing group
+    that is never multiplied, and what those experts would add is left
+    out: the caller goes on with the partial result."""
+    t, h = m.shape
+    gate_w, up_w, down_w = experts
+    with jax.named_scope(f"{scope}/router"):
+        sel, w = route_top_k(m, router_w, bias, top_k, route_scale)
+        local = sel - first
+        here = (local >= 0) & (local < n)
+        real = (jnp.ones((t, 1), bool) if valid is None
+                else valid[:, None])
+        held = here & real
+        key = jnp.where(held, local, n).reshape(-1)          # [T*k]
+        order = jnp.argsort(key, stable=True)
+        tok = (order // top_k).astype(jnp.int32)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+        n_held = jnp.sum(sizes)
+        w_sorted = w.reshape(-1)[order]
+        # counted each on its own: rows the grouped products cover, pairs
+        # on experts that live elsewhere, experts with a row, tokens
+        stats = jnp.stack([
+            n_held, jnp.sum((~here & real).astype(jnp.int32)),
+            jnp.sum((sizes > 0).astype(jnp.int32)),
+            jnp.sum(real.astype(jnp.int32))])
+
+    def run(rows):
+        x = jnp.take(m, tok[:rows], axis=0)                   # [rows, H]
+        g = jax.lax.ragged_dot(x, gate_w, sizes)
+        u = jax.lax.ragged_dot(x, up_w, sizes)
+        a = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(m.dtype)
+        y = jax.lax.ragged_dot(a, down_w, sizes,
+                               preferred_element_type=jnp.float32)
+        # rows past the held groups belong to no group: the CPU's
+        # ragged_dot leaves them 0, the TPU's leaves them unwritten
+        covered = jnp.arange(rows, dtype=jnp.int32)[:, None] < n_held
+        return jnp.zeros((t, h), jnp.float32).at[tok[:rows]].add(
+            jnp.where(covered, y * w_sorted[:rows, None], 0.0))
+
+    with jax.named_scope(f"{scope}/experts"):
+        total = t * top_k
+        if total < _SPLIT_ROWS:
+            return run(total), stats
+        return jax.lax.cond(n_held <= total // 4,
+                            lambda: run(total // 4),
+                            lambda: run(total)), stats

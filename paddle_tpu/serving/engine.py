@@ -8,6 +8,19 @@ per bucket), with the scheduler — waiting queue, token-budget admission,
 preemption — living OUTSIDE the compiled step (the MPK structure from
 PAPERS.md: runtime scheduling around static tensor programs).
 
+The engine names no model (ROADMAP D2).  A model hands it a serving form
+(`models.serving_form.ServingForm`, from `model.serving_form()`): the
+parameter arrays, `embed`, one `layer` step per layer, `logits`, and per
+layer a `LayerSpec` (query heads, K/V heads, head size, window, cache
+group).  The engine builds the attention each layer step calls and keeps
+one `BlockKVCache` per cache group - its own id space, pool shape and block
+table; a request holds a table in each, and every program takes one table
+and one slot array per group.  A stacked-blocks GPT is one group of full
+layers and allocates exactly as before; afmoe (models/afmoe.py) is a
+`full` and a `window` group, the second bounded by the window whatever a
+sequence's length (kv_cache.py).  Options a family does not carry
+(`ServingForm.unsupported`) raise at construction by name.
+
 Step programs (all array-level, weights threaded as inputs):
 
 - ``prefill(P)``   — one request, exact prompt length, causal flash
@@ -69,7 +82,16 @@ wall time, prefill/idle included).  ISSUE 15:
 `serving/prefix_hits`/`prefix_hit_tokens`/`prefix_evictions` (prefix
 caching, counted by the cache) and
 `serving/spec_proposed`/`spec_accepted`/`spec_accept_rate`
-(speculative decoding).
+(speculative decoding).  ISSUE 28: `serving/kv_blocks_in_use{group}`,
+`serving/kv_window_released` (blocks given back from behind a window),
+`serving/kv_tokens_live{group}` (keys the decode kernels have to read,
+summed over decode steps: a row's length, or `min(length, window)` in a
+window group), and what a form's layers count a step
+(`ServingForm.stat_counters`; afmoe: `serving/moe_pairs{phase,where=held|
+absent}`, `serving/moe_experts_touched{phase}`, `serving/moe_tokens{phase}`,
+phase the kind of step), returned by the step's program and read back with
+its tokens - no sync of their own; and `serving/kv_block_steps{group}`
+(blocks held, summed over decode steps).
 
 Host phases (monitor.trace.phase): every boundary of `step()` is one
 phase, and the API pump adds two of its own around it:
@@ -145,7 +167,7 @@ from ..ops.paged_attention import (paged_attention_arrays,
                                    paged_cache_update_arrays,
                                    quantized_cache_update_arrays)
 from ..ops.ragged_paged_attention import ragged_paged_attention_arrays
-from .kv_cache import BlockKVCache, prefix_block_keys
+from .kv_cache import BlockKVCache, CacheGroups, prefix_block_keys
 from .scheduler import (Request, SamplingParams, Scheduler, priority_rank,
                         should_shed, worst_fast_burn)
 from .spec import propose_ngram
@@ -207,29 +229,46 @@ class EngineConfig:
 
 
 class LLMEngine:
-    """add_request() / step() / generate() over a stacked-blocks GPT."""
+    """add_request() / step() / generate() over a model's serving form."""
 
     def __init__(self, model, config: Optional[EngineConfig] = None):
-        cfg = model.cfg
-        if not cfg.stacked_blocks:
+        if not hasattr(model, "serving_form"):
             raise ValueError(
-                "LLMEngine serves the stacked-blocks GPT form "
-                "(GPTConfig(stacked_blocks=True)) — per-layer Layer "
-                "modules would re-trace one program per layer")
+                f"LLMEngine serves models that hand it a serving form "
+                f"(`serving_form()`, models/serving_form.py); "
+                f"{type(model).__name__} has none")
+        form = model.serving_form()
         self.model = model
         model.eval()
-        self.cfg = cfg
+        self.form = form
+        self.cfg = model.cfg
         self.config = config or EngineConfig()
         c = self.config
         self.max_model_len = int(c.max_model_len
-                                 or cfg.max_position_embeddings)
+                                 or form.max_position_embeddings)
         # gathered view width mirrors the dense ring rounding
         # (init_caches: length rounds up to 128) so the decode softmax
         # reduces over the SAME padded extent as the dense oracle
         ring = -(-self.max_model_len // 128) * 128
         self.blocks_per_seq = -(-ring // c.block_size)
-        nh = cfg.num_attention_heads
-        hd = cfg.hidden_size // nh
+        # cache groups, in the order the layers first name them; a layer's
+        # pools are entry `_layer_slot[l]` of its group's cache
+        specs = list(form.layer_specs)
+        self._groups: dict = {}
+        self._layer_slot = []
+        for spec in specs:
+            layers = self._groups.setdefault(spec.group, [])
+            self._layer_slot.append(len(layers))
+            layers.append(spec)
+        # full groups first: `self.cache` is a full group where one exists
+        self._groups = dict(sorted(
+            self._groups.items(), key=lambda kv: kv[1][0].window is not None))
+        for name, layers in self._groups.items():
+            if len({(s.num_kv_heads, s.head_dim, s.window)
+                    for s in layers}) != 1:
+                raise ValueError(
+                    f"cache group {name!r}: its layers must share K/V "
+                    "heads, head size and window")
         if c.kv_cache_dtype not in (None, "int8"):
             raise ValueError(
                 f'kv_cache_dtype must be None or "int8", got '
@@ -245,21 +284,7 @@ class LLMEngine:
                 f'attention_impl must be "ragged" or "bucketed", got '
                 f'{impl!r}')
         self.attention_impl = impl
-        wdtype = model.gpt.embeddings.word_embeddings.weight.dtype
-        fp_blocks = c.max_num_seqs * self.blocks_per_seq
-        if c.num_blocks is not None:
-            num_blocks = c.num_blocks
-        elif self._kv_quant:
-            # same BYTE budget as the full-precision default pool — the
-            # whole point: halved/quartered bytes/block ⇒ ~2–4× blocks,
-            # fewer preemptions under the same memory ceiling
-            budget = fp_blocks * BlockKVCache.block_bytes(
-                c.block_size, nh, hd, wdtype) * cfg.num_hidden_layers
-            num_blocks = budget // (BlockKVCache.block_bytes(
-                c.block_size, nh, hd, wdtype, self._kv_quant)
-                * cfg.num_hidden_layers)
-        else:
-            num_blocks = fp_blocks
+        wdtype = form.dtype
         pc = c.enable_prefix_caching
         if pc is None:
             pc = os.environ.get("PTPU_PREFIX_CACHE", "0").lower() in (
@@ -274,9 +299,28 @@ class LLMEngine:
                 "speculative decoding needs the ragged attention path "
                 "(the fixed-shape multi-token verify program); "
                 'attention_impl="bucketed" cannot serve it')
-        self.cache = BlockKVCache(
-            cfg.num_hidden_layers, num_blocks, c.block_size, nh, hd,
-            dtype=wdtype, kv_quant=self._kv_quant)
+        on = {"kv_cache_dtype": self._kv_quant,
+              "speculative_tokens": self.spec_tokens,
+              "enable_prefix_caching": self.prefix_caching}
+        for option in form.unsupported:
+            if on.get(option):
+                raise ValueError(
+                    f"EngineConfig.{option}={on[option]!r} is not carried "
+                    f"to {type(model).__name__} yet (its serving form "
+                    "lists it as unsupported); leave it off")
+        sizes = self._pool_sizes(wdtype)
+        self.caches = {
+            name: BlockKVCache(
+                len(layers), sizes[name], c.block_size,
+                layers[0].num_kv_heads, layers[0].head_dim, dtype=wdtype,
+                kv_quant=self._kv_quant, window=layers[0].window, name=name)
+            for name, layers in self._groups.items()}
+        # the first group's cache: the only one of a one-group model
+        self.cache = next(iter(self.caches.values()))
+        # what the scheduler allocates from: all groups or none
+        self.kv = (self.cache if len(self.caches) == 1
+                   else CacheGroups(self.caches))
+        num_blocks = self.cache.num_blocks
         if monitor.enabled():
             monitor.gauge("lowbit/kv_blocks",
                           "paged KV pool size in blocks").labels(
@@ -284,13 +328,14 @@ class LLMEngine:
             if self._kv_quant:
                 # what THIS pool's block count would have cost at the
                 # model dtype, minus what the quantized pool costs
-                fp_cost = num_blocks * cfg.num_hidden_layers \
-                    * BlockKVCache.block_bytes(c.block_size, nh, hd, wdtype)
+                spec = specs[0]
+                fp_cost = num_blocks * len(specs) * BlockKVCache.block_bytes(
+                    c.block_size, spec.num_kv_heads, spec.head_dim, wdtype)
                 monitor.counter("lowbit/bytes_saved").labels(
                     wing="kv_cache").add(max(0, fp_cost
                                              - self.cache.pool_bytes))
         self.scheduler = Scheduler(
-            self.cache, max_num_seqs=c.max_num_seqs,
+            self.kv, max_num_seqs=c.max_num_seqs,
             max_num_batched_tokens=(c.max_num_batched_tokens
                                     or self.max_model_len),
             spec_tokens=self.spec_tokens,
@@ -298,7 +343,6 @@ class LLMEngine:
         self._requests: dict = {}
         self._next_id = 0
         self._jit_cache: dict = {}
-        self._stack_names = list(model.gpt.blocks._names)
         # monitor handles (cheap no-ops when PTPU_MONITOR=0)
         m = monitor
         self._m_queue = m.gauge("serving/queue_depth",
@@ -392,6 +436,33 @@ class LLMEngine:
         self._m_tenant_kv_peak = m.gauge(
             "serving/kv_blocks_peak_share",
             "peak fraction of the KV pool held, by tenant")
+        # ISSUE 28: per cache group (blocks held now; keys the decode
+        # kernels had to read and blocks held, both summed over decode
+        # steps), and what a form's layers count a step, by kind of step
+        per_group = (
+            m.gauge("serving/kv_blocks_in_use",
+                    "KV blocks held, by cache group"),
+            m.counter("serving/kv_tokens_live",
+                      "keys the decode kernels had to read, by cache group"),
+            m.counter("serving/kv_block_steps",
+                      "KV blocks held, summed over decode steps, by cache "
+                      "group"))
+        self._m_group = [tuple(x.labels(group=g) for x in per_group)
+                         for g in self.caches]
+        self._m_kv_released = m.counter(
+            "serving/kv_window_released",
+            "blocks given back from behind a sliding window")
+        self._released_seen = 0
+        self._m_stats = {
+            # ptpu-check[metric-hygiene]: names and labels are the form's `stat_counters`: literals in the model's file
+            phase: [m.counter(name).labels(phase=phase, **labels)
+                    for name, labels in form.stat_counters]
+            for phase in ("prefill", "decode")}
+        # every layer's (cache, index in it), in layer order
+        self._layer_pools = [(self.caches[spec.group], i)
+                             for spec, i in zip(specs, self._layer_slot)]
+        self._pool_names = ("k_blocks", "v_blocks") + (
+            ("k_scales", "v_scales") if self._kv_quant else ())
         self._tenant_kv_peak: dict = {}
         self._storm = mmem.StormDetector()
         self._memobs_prev = {"evict": 0, "swap_in": 0}
@@ -412,6 +483,55 @@ class LLMEngine:
             from ..monitor import serve as mserve
 
             self.metrics_server = mserve.start_server(c.metrics_port)
+
+    def _pool_sizes(self, wdtype) -> dict:
+        """Blocks per cache group.  `num_blocks` sizes the full groups (a
+        window group too, when it is the smaller); the default is the
+        dense-equivalent pool, `max_num_seqs` whole sequences, and for a
+        window group `max_num_seqs * (ceil(window / block_size) + 1)`, all
+        a sequence ever holds there.  A default that the device cannot
+        hold raises before anything is allocated."""
+        c = self.config
+        bs = c.block_size
+        sizes, asked = {}, 0
+        for name, layers in self._groups.items():
+            spec = layers[0]
+            fp_blocks = c.max_num_seqs * self.blocks_per_seq
+            if spec.window is not None:
+                fp_blocks = min(fp_blocks, c.max_num_seqs
+                                * (-(-spec.window // bs) + 1))
+            per_block = len(layers) * BlockKVCache.block_bytes(
+                bs, spec.num_kv_heads, spec.head_dim, wdtype)
+            if c.num_blocks is not None:
+                n = (c.num_blocks if spec.window is None
+                     else min(c.num_blocks, fp_blocks))
+            elif self._kv_quant:
+                # same BYTE budget as the full-precision default pool —
+                # the whole point: halved/quartered bytes/block ⇒ ~2–4×
+                # blocks, fewer preemptions under the same memory ceiling
+                n = fp_blocks * per_block // (
+                    len(layers) * BlockKVCache.block_bytes(
+                        bs, spec.num_kv_heads, spec.head_dim, wdtype,
+                        self._kv_quant))
+            else:
+                n = fp_blocks
+                asked += n * per_block
+            sizes[name] = n
+        limit = self._device_bytes()
+        if asked and limit and asked > limit:
+            raise ValueError(
+                f"the default KV pools ask for {asked:,} bytes "
+                f"({c.max_num_seqs} sequences of max_model_len "
+                f"{self.max_model_len}) and the device holds {limit:,}: "
+                "set EngineConfig.max_model_len to the longest request "
+                "served, or EngineConfig.num_blocks to the pool size")
+        return sizes
+
+    @staticmethod
+    def _device_bytes() -> int:
+        """The device's memory, 0 where the backend does not say (CPU)."""
+        stats = jax.devices()[0].memory_stats() or {}
+        return int(stats.get("bytes_limit", 0))
 
     # -- request API --------------------------------------------------------
 
@@ -467,11 +587,11 @@ class LLMEngine:
         # is fed next step); the child re-feeds it as its final "prompt"
         # token through its own prefill continuation
         req.num_computed = parent.total_len - 1
-        self.cache.fork(parent_id, req.req_id)
+        self.kv.fork(parent_id, req.req_id)
         # that re-feed WRITE lands at position total_len-1, which lives in
         # the (shared) last block — privatize it now so the child's
         # recomputation can never perturb the parent's cache
-        self.cache.privatize_last_block(req.req_id)
+        self.kv.privatize_last_block(req.req_id)
         self._begin_trace(req, forked_from=parent_id)
         self._requests[req.req_id] = req
         self.scheduler.add(req)
@@ -503,7 +623,7 @@ class LLMEngine:
             "output_ids": list(req.output_ids),
             "params": req.params,
             "key": np.asarray(req.key, np.uint32),
-            "kv": self.cache.swap_out(req_id),
+            "kv": self.kv.swap_out(req_id),
         }
         self.scheduler.running.remove(req)
         self._finish_request(req, "migrated")
@@ -682,11 +802,11 @@ class LLMEngine:
         sched = self.scheduler
         if req in sched.running:
             sched.running.remove(req)
-            self.cache.free(req_id)
+            self.kv.free(req_id)
         elif req in sched.waiting:
             sched.waiting.remove(req)
-            if req.req_id in self.cache._tables:   # forked child prefix
-                self.cache.free(req_id)
+            if req.req_id in self.kv._tables:   # forked child prefix
+                self.kv.free(req_id)
         req.swap = None
         req.state = Request.FINISHED
 
@@ -858,7 +978,15 @@ class LLMEngine:
         # ISSUE 20: every capacity gauge reads the cache's ONE
         # counts() source — utilization and the admission view
         # (free+parked) can no longer be computed in two places
-        c = self.cache.counts()
+        per = [k.counts() for k in self.caches.values()]
+        c = per[0]
+        if len(per) > 1:
+            c = {key: sum(p[key] for p in per) for key in c}
+            released = sum(k.released for k in self.caches.values())
+            self._m_kv_released.inc(released - self._released_seen)
+            self._released_seen = released
+        for p, (in_use, _, _) in zip(per, self._m_group):
+            in_use.set(p["in_use"])
         self._m_blocks.set(c["in_use"])
         self._m_util.set(c["in_use"] / max(c["total"], 1))
         self._m_kv_free.set(c["free"])
@@ -965,35 +1093,38 @@ class LLMEngine:
         with mtrace.phase("engine/prepare"):
             ids = np.asarray([req.prompt_ids[start:start + chunk]],
                              np.int32)
-            positions = np.arange(start, start + chunk, dtype=np.int64)
-            slots = np.asarray(
-                [[self.cache.slot(req.req_id, int(p)) for p in positions]],
-                np.int32)
+            whole = start == 0 and chunk == req.prompt_len
+            # one slot array a cache group: a whole prompt writes a window
+            # group from `tail_start` on, the rest of it is never read
+            slots = tuple(jnp.asarray(np.asarray(
+                [[k.slot(req.req_id, p) for p in range(
+                    k.tail_start(chunk) if whole else start,
+                    start + chunk)]], np.int32))
+                for k in self.caches.values())
             kv = self._kv_flat()
-            if start == 0 and chunk == req.prompt_len:
+            stats = None
+            if whole:
                 # whole prompt in one chunk: flash within the chunk, the
                 # dense prefill's exact arithmetic
                 fn = self._get_prefill_exec(chunk)
-                logits, kv_out = fn(self._param_arrays(), kv,
-                                    jnp.asarray(ids), jnp.asarray(slots))
+                logits, kv_out, stats = fn(self._param_arrays(), kv,
+                                           jnp.asarray(ids), slots)
             else:
-                tables = jnp.asarray(
-                    [self.cache.padded_table(req.req_id,
-                                             self.blocks_per_seq)],
-                    jnp.int32)
+                tables = tuple(jnp.asarray(np.asarray(
+                    [k.padded_table(req.req_id, self.blocks_per_seq)],
+                    np.int32)) for k in self.caches.values())
                 if self.attention_impl == "ragged":
                     fn = self._get_ragged_exec(1, chunk)
-                    logits, kv_out = fn(
+                    logits, kv_out, stats = fn(
                         self._param_arrays(), kv, jnp.asarray(ids),
                         jnp.asarray([start], jnp.int32),
                         jnp.asarray([start + chunk], jnp.int32), tables,
-                        jnp.asarray(slots))
+                        slots)
                 else:
                     fn = self._get_chunk_exec(1, chunk)
                     logits, kv_out = fn(
                         self._param_arrays(), kv, jnp.asarray(ids),
-                        jnp.asarray([start], jnp.int32), tables,
-                        jnp.asarray(slots))
+                        jnp.asarray([start], jnp.int32), tables, slots)
             self._store_kv(kv_out)
             req.num_computed = start + chunk
             if req.prefix_keys:
@@ -1006,7 +1137,7 @@ class LLMEngine:
                 # dense generate(max_new_tokens=0) emits nothing
                 req.state = Request.FINISHED
             else:
-                self._sample_rows([req], logits)
+                self._sample_rows([req], logits, stats, "prefill")
 
     def _step_decode(self, out, step_span) -> int:
         rows = list(out.decode_requests)
@@ -1036,7 +1167,7 @@ class LLMEngine:
             with mtrace.phase("engine/emit"):
                 for req in rows:
                     # release the scheduler's (clamped) draft reservation
-                    self.cache.truncate_to(req.req_id, req.total_len)
+                    self.kv.truncate_to(req.req_id, req.total_len)
             return n
         return self._decode_body_plain(rows)
 
@@ -1064,19 +1195,26 @@ class LLMEngine:
                 fn = self._get_ragged_exec(bb, 1)
                 if mon:
                     self._launches_this_step.add(("ragged", bb, 1))
-                logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
-                                    jnp.asarray(toks), jnp.asarray(pos0),
-                                    jnp.asarray(lens), jnp.asarray(tables),
-                                    jnp.asarray(slots))
+                logits, kv_out, stats = fn(
+                    self._param_arrays(), self._kv_flat(),
+                    jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(lens),
+                    tables, slots)
             else:
                 fn = self._get_chunk_exec(bb, 1)
                 if mon:
                     self._launches_this_step.add(("chunk", bb, 1))
+                stats = None
                 logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
                                     jnp.asarray(toks), jnp.asarray(pos0),
-                                    jnp.asarray(tables), jnp.asarray(slots))
+                                    tables, slots)
             self._store_kv(kv_out)
-        self._sample_rows(rows, logits)
+            if mon:
+                for k, (_, live, held) in zip(self.caches.values(),
+                                              self._m_group):
+                    live.inc(int(lens.sum()) if k.window is None else
+                             int(np.minimum(lens, k.window).sum()))
+                    held.inc(k.blocks_in_use)
+        self._sample_rows(rows, logits, stats, "decode")
         if mon:
             # padding accounting: bb rows ran, n were real — the
             # serving-goodput blind spot the ragged fixed-shape program
@@ -1091,16 +1229,18 @@ class LLMEngine:
         return n
 
     def _decode_inputs(self, rows, drafts, bb, cw):
-        """Host arrays of one decode program of fixed shape [bb, cw]: row
-        i feeds its last token and `drafts[i]`; padding rows and unused
+        """Inputs of one decode program of fixed shape [bb, cw]: row i
+        feeds its last token and `drafts[i]`; padding rows and unused
         draft positions keep the dropped-slot sentinel (no write, outputs
-        never read)."""
+        never read).  Tables and slots are one device array a cache
+        group."""
         toks = np.zeros((bb, cw), np.int32)
         pos0 = np.zeros((bb,), np.int32)
         lens = np.zeros((bb,), np.int32)
-        tables = np.full((bb, self.blocks_per_seq), self.cache.num_blocks,
-                         np.int32)
-        slots = np.full((bb, cw), self.cache.num_slots, np.int32)
+        caches = list(self.caches.values())
+        tables = [np.full((bb, self.blocks_per_seq), k.num_blocks, np.int32)
+                  for k in caches]
+        slots = [np.full((bb, cw), k.num_slots, np.int32) for k in caches]
         for i, req in enumerate(rows):
             toks[i, 0] = req.output_ids[-1] if req.output_ids \
                 else req.prompt_ids[-1]
@@ -1110,11 +1250,12 @@ class LLMEngine:
             p = req.total_len - 1
             pos0[i] = p
             lens[i] = req.total_len + m
-            tables[i] = self.cache.padded_table(req.req_id,
-                                                self.blocks_per_seq)
-            for j in range(1 + m):
-                slots[i, j] = self.cache.slot(req.req_id, p + j)
-        return toks, pos0, lens, tables, slots
+            for k, tbl, slt in zip(caches, tables, slots):
+                tbl[i] = k.padded_table(req.req_id, self.blocks_per_seq)
+                for j in range(1 + m):
+                    slt[i, j] = k.slot(req.req_id, p + j)
+        return (toks, pos0, lens, tuple(jnp.asarray(t) for t in tables),
+                tuple(jnp.asarray(sl) for sl in slots))
 
     # -- speculative decoding (ISSUE 15 b) ----------------------------------
 
@@ -1163,8 +1304,7 @@ class LLMEngine:
                 self._launches_this_step.add(("verify", bb, cw))
             logits0, greedy, kv_out = fn(
                 self._param_arrays(), self._kv_flat(), jnp.asarray(toks),
-                jnp.asarray(pos0), jnp.asarray(lens), jnp.asarray(tables),
-                jnp.asarray(slots))
+                jnp.asarray(pos0), jnp.asarray(lens), tables, slots)
             self._store_kv(kv_out)
         emitted = self._emit_spec(rows, drafts, logits0, greedy)
         if mon:
@@ -1218,7 +1358,7 @@ class LLMEngine:
                 accepted += row_emitted - 1
                 req.spec_proposed += m
                 req.spec_accepted += row_emitted - 1
-                self.cache.truncate_to(req.req_id, req.total_len)
+                self.kv.truncate_to(req.req_id, req.total_len)
         self._spec_proposed_total += proposed
         self._spec_accepted_total += accepted
         if monitor.enabled():
@@ -1285,12 +1425,18 @@ class LLMEngine:
                       jnp.asarray(temp), jnp.asarray(topk),
                       jnp.asarray(topp))
 
-    def _sample_rows(self, rows, logits):
-        """Sample one token per live row and emit it."""
+    def _sample_rows(self, rows, logits, stats=None, phase="decode"):
+        """Sample one token per live row and emit it.  `stats`: what the
+        form's layers counted in the program that made `logits`, read
+        back behind the tokens (that program has ended by then)."""
         toks, new_keys = self._dispatch_sampler(rows, logits)
         with mtrace.phase("engine/readback"):   # blocked on the device
             toks = np.asarray(toks)
             new_keys = np.asarray(new_keys)
+            if stats is not None and monitor.enabled():
+                for counter, n in zip(self._m_stats[phase],
+                                      np.asarray(stats)):
+                    counter.inc(int(n))
         now = time.perf_counter()
         with mtrace.phase("engine/emit"):
             for i, req in enumerate(rows):
@@ -1327,10 +1473,14 @@ class LLMEngine:
         sit in ONE report and the fusion win is readable as
         ``ragged_fused.wall_time_s`` vs the trio's sum.
         """
-        cfg = self.cfg
-        L = cfg.num_hidden_layers
-        nh = cfg.num_attention_heads
-        hd = cfg.hidden_size // nh
+        if len(self.caches) > 1 or self.form.layer_specs[0].num_heads \
+                != self.form.layer_specs[0].num_kv_heads:
+            raise ValueError(
+                "decode_breakdown covers one cache group of layers with as "
+                "many K/V heads as query heads")
+        L = len(self.form.layer_specs)
+        nh = self.form.layer_specs[0].num_heads
+        hd = self.form.layer_specs[0].head_dim
         ragged = self.attention_impl == "ragged"
         # the LIVE decode batch width: the ragged program runs at
         # max_num_seqs, the bucketed fallback at its full-batch bucket
@@ -1338,7 +1488,7 @@ class LLMEngine:
               else self._bucket_batch(self.scheduler.max_num_seqs))
         s_pad = self.blocks_per_seq * self.cache.block_size
         num_slots = self.cache.num_slots
-        wdtype = self.model.gpt.embeddings.word_embeddings.weight.dtype
+        wdtype = self.form.dtype
         kv_flat = self._kv_flat()
         tables = (jnp.arange(bb * self.blocks_per_seq, dtype=jnp.int32)
                   % max(self.cache.num_blocks, 1)).reshape(
@@ -1466,16 +1616,16 @@ class LLMEngine:
         if ragged:
             out["step"] = mperf.measure(
                 self._get_ragged_exec(bb, 1),
-                self._param_arrays(), kv_copy2, toks, pos0, lens, tables,
-                slots, label="decode:step", reps=reps,
+                self._param_arrays(), kv_copy2, toks, pos0, lens, (tables,),
+                (slots,), label="decode:step", reps=reps,
                 rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
         else:
             out["step"] = mperf.measure(
                 self._get_chunk_exec(bb, 1),
-                self._param_arrays(), kv_copy2, toks, pos0, tables, slots,
-                label="decode:step", reps=reps,
+                self._param_arrays(), kv_copy2, toks, pos0, (tables,),
+                (slots,), label="decode:step", reps=reps,
                 rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
-        logits = jnp.zeros((bb, cfg.vocab_size), jnp.float32)
+        logits = jnp.zeros((bb, self.form.vocab_size), jnp.float32)
         out["sampler"] = mperf.measure(
             self._get_sample_exec(bb),
             logits, jnp.zeros((bb, 2), jnp.uint32),
@@ -1495,34 +1645,19 @@ class LLMEngine:
     # -- array plumbing -----------------------------------------------------
 
     def _param_arrays(self):
-        gpt = self.model.gpt
-        params = {n: getattr(gpt.blocks, n)._data for n in self._stack_names}
-        params["wte"] = gpt.embeddings.word_embeddings.weight._data
-        params["wpe"] = gpt.embeddings.position_embeddings.weight._data
-        params["lnf_w"] = gpt.ln_f.weight._data
-        params["lnf_b"] = gpt.ln_f.bias._data
-        return params
+        return self.form.params()
 
     def _kv_flat(self):
-        c = self.cache
-        if self._kv_quant:
-            return tuple(a for quad in zip(c.k_blocks, c.v_blocks,
-                                           c.k_scales, c.v_scales)
-                         for a in quad)
-        return tuple(a for pair in zip(c.k_blocks, c.v_blocks)
-                     for a in pair)
+        """Every layer's pools in layer order: (k, v) a layer, or (k, v,
+        k_scales, v_scales) under int8."""
+        return tuple(getattr(c, n)[i] for c, i in self._layer_pools
+                     for n in self._pool_names)
 
     def _store_kv(self, kv_out):
-        L = self.cfg.num_hidden_layers
-        c = self.cache
-        if self._kv_quant:
-            c.k_blocks = [kv_out[4 * l] for l in range(L)]
-            c.v_blocks = [kv_out[4 * l + 1] for l in range(L)]
-            c.k_scales = [kv_out[4 * l + 2] for l in range(L)]
-            c.v_scales = [kv_out[4 * l + 3] for l in range(L)]
-        else:
-            c.k_blocks = [kv_out[2 * l] for l in range(L)]
-            c.v_blocks = [kv_out[2 * l + 1] for l in range(L)]
+        it = iter(kv_out)
+        for c, i in self._layer_pools:
+            for n in self._pool_names:
+                getattr(c, n)[i] = next(it)
 
     # -- jitted step programs ----------------------------------------------
 
@@ -1598,73 +1733,86 @@ class LLMEngine:
         return fn
 
     def _model_logits(self, params, h):
-        """Final LN + tied LM head over EVERY position — the dense
-        path's ln_f arithmetic (`F.layer_norm`, NOT the block
-        `_stacked_ln`) and lm_head einsum, shared at array level so
-        parity tracks the oracle by construction.  ALL logits-producing
-        step programs (prefill/chunk/ragged tails AND the spec verify
-        program) go through here: a change to the oracle tail reaches
-        them all."""
-        from ..nn.functional import layer_norm_arrays
-
-        hn = layer_norm_arrays(h, params["lnf_w"], params["lnf_b"],
-                               epsilon=self.cfg.layer_norm_epsilon)
-        return jnp.einsum("bsh,vh->bsv", hn, params["wte"])
+        """The form's head over EVERY position (GPT: the dense path's
+        ln_f arithmetic and lm_head einsum, so parity tracks the oracle by
+        construction).  ALL logits-producing step programs go through the
+        form: a change to the oracle tail reaches them all."""
+        return self.form.logits(params, h)
 
     def _model_tail(self, params, h):
         """Last position's fp32 logits — the decode/prefill tail."""
-        return self._model_logits(params, h)[:, -1].astype(jnp.float32)
+        return self.form.last_logits(params, h).astype(jnp.float32)
 
-    def _run_blocks(self, params, kv_flat, x, attn_builder):
-        from ..models.gpt import _stacked_block_body
-
-        cfg = self.cfg
-        nh = cfg.num_attention_heads
-        hd = cfg.hidden_size // nh
-        eps = cfg.layer_norm_epsilon
+    def _run_blocks(self, params, kv_flat, x, pos, attn_builder,
+                    valid=None):
+        """Every layer of the form over `x`; `attn_builder(spec, group
+        index, *the layer's pools)` makes the attention that layer's step
+        calls.  -> (h, the updated pools in layer order, the layers'
+        summed counts or None)."""
         stride = 4 if self._kv_quant else 2
+        groups = list(self._groups)
         h = x
         outs = []
-        for l in range(cfg.num_hidden_layers):
+        stats = None
+        for l, spec in enumerate(self.form.layer_specs):
             layer_kv = kv_flat[stride * l:stride * (l + 1)]
-            p = {n: params[n][l] for n in self._stack_names}
-            attn_fn = attn_builder(*layer_kv)
-            h, extra = _stacked_block_body(p, h, attn_fn, nh, hd, eps)
+            attn_fn = attn_builder(spec, groups.index(spec.group),
+                                   *layer_kv)
+            h, extra, st = self.form.layer(l, params, h, pos, attn_fn,
+                                           valid=valid)
             outs += list(extra)
-        return h, tuple(outs)
+            if st is not None:
+                stats = st if stats is None else stats + st
+        return h, tuple(outs), stats
+
+    @staticmethod
+    def _attn_scope(spec):
+        return jax.named_scope("attn/window" if spec.window else "attn/full")
 
     def _get_prefill_exec(self, p_len):
         key = ("prefill", p_len)
         if key not in self._jit_cache:
             self._count_compile("prefill", key)
 
+            # a window group is written from its tail on (kv_cache.py)
+            tails = [k.tail_start(p_len) for k in self.caches.values()]
+
             def prefill(params, kv_flat, ids, slots):
                 from ..ops.pallas_ops import flash_attention_arrays
 
                 pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
-                x = jnp.take(params["wte"], ids, axis=0) \
-                    + jnp.take(params["wpe"], pos, axis=0)
+                x = self.form.embed(params, ids, pos)
 
-                def builder(kc, vc, ksc=None, vsc=None):
+                def builder(spec, g, kc, vc, ksc=None, vsc=None):
                     def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
                         # flash within the chunk reads the fp K/V it just
                         # computed — only the STORED cache is quantized
+                        t0, sl = tails[g], slots[g]
+                        kw, vw = (k, v) if not t0 else (k[:, t0:], v[:, t0:])
                         if ksc is None:
-                            kc2 = paged_cache_update_arrays(kc, k, slots)
-                            vc2 = paged_cache_update_arrays(vc, v, slots)
+                            kc2 = paged_cache_update_arrays(kc, kw, sl)
+                            vc2 = paged_cache_update_arrays(vc, vw, sl)
                             extra = (kc2, vc2)
                         else:
                             kc2, ks2 = quantized_cache_update_arrays(
-                                kc, ksc, k, slots)
+                                kc, ksc, kw, sl)
                             vc2, vs2 = quantized_cache_update_arrays(
-                                vc, vsc, v, slots)
+                                vc, vsc, vw, sl)
                             extra = (kc2, vc2, ks2, vs2)
-                        o = flash_attention_arrays(q, k, v, is_causal=True)
+                        with self._attn_scope(spec):
+                            if spec.window is None:
+                                o = flash_attention_arrays(
+                                    q, k, v, is_causal=True)
+                            else:
+                                o = flash_attention_arrays(
+                                    q, k, v, is_causal=True,
+                                    window=spec.window)
                         return o, extra
                     return attn_fn
 
-                h, kv_out = self._run_blocks(params, kv_flat, x, builder)
-                return self._model_tail(params, h), kv_out
+                h, kv_out, stats = self._run_blocks(params, kv_flat, x, pos,
+                                                    builder)
+                return self._model_tail(params, h), kv_out, stats
 
             self._jit_cache[key] = jax.jit(
                 self._named(prefill, f"prefill_{p_len}"),
@@ -1678,17 +1826,18 @@ class LLMEngine:
 
             def chunk(params, kv_flat, ids, pos0, tables, slots):
                 pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-                x = jnp.take(params["wte"], ids, axis=0) \
-                    + jnp.take(params["wpe"], pos, axis=0)
+                x = self.form.embed(params, ids, pos)
 
-                def builder(kc, vc, ksc=None, vsc=None):
-                    def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
+                def builder(spec, g, kc, vc, ksc=None, vsc=None):
+                    def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc,
+                                tables=tables[g], slots=slots[g]):
                         # write-then-attend, the dense cache ordering
                         if ksc is None:
                             kc2 = paged_cache_update_arrays(kc, k, slots)
                             vc2 = paged_cache_update_arrays(vc, v, slots)
-                            o = paged_attention_arrays(q, kc2, vc2, tables,
-                                                       pos0)
+                            o = paged_attention_arrays(
+                                q, kc2, vc2, tables, pos0,
+                                **self._window_kw(spec))
                             return o, (kc2, vc2)
                         # lowbit KV: quantizing write, dequantizing
                         # gather — the current chunk's own K/V round-trip
@@ -1704,7 +1853,8 @@ class LLMEngine:
                         return o, (kc2, vc2, ks2, vs2)
                     return attn_fn
 
-                h, kv_out = self._run_blocks(params, kv_flat, x, builder)
+                h, kv_out, _ = self._run_blocks(params, kv_flat, x, pos,
+                                                builder)
                 return self._model_tail(params, h), kv_out
 
             self._jit_cache[key] = jax.jit(self._named(
@@ -1717,24 +1867,33 @@ class LLMEngine:
         """Embeddings, then every block with ONE fused
         `ragged_paged_attention_arrays` call per layer: cache write +
         attention (+ int8 dequant at the block loads) — no separate
-        `block_gather/attention/cache_update` triple.  -> (h, kv_out)."""
+        `block_gather/attention/cache_update` triple.  `tables` and
+        `slots` hold one array a cache group.  -> (h, kv_out, stats)."""
         pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-        x = jnp.take(params["wte"], ids, axis=0) \
-            + jnp.take(params["wpe"], pos, axis=0)
+        x = self.form.embed(params, ids, pos)
 
-        def builder(kc, vc, ksc=None, vsc=None):
-            def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
-                if ksc is None:
-                    o, kc2, vc2 = ragged_paged_attention_arrays(
-                        q, k, v, kc, vc, tables, pos0, lens, slots)
-                    return o, (kc2, vc2)
-                o, kc2, vc2, ks2, vs2 = ragged_paged_attention_arrays(
-                    q, k, v, kc, vc, tables, pos0, lens, slots,
-                    k_scales=ksc, v_scales=vsc)
-                return o, (kc2, vc2, ks2, vs2)
+        def builder(spec, g, kc, vc, ksc=None, vsc=None):
+            def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc,
+                        tables=tables[g], slots=slots[g]):
+                with self._attn_scope(spec):
+                    if ksc is None:
+                        o, kc2, vc2 = ragged_paged_attention_arrays(
+                            q, k, v, kc, vc, tables, pos0, lens, slots,
+                            **self._window_kw(spec))
+                        return o, (kc2, vc2)
+                    o, kc2, vc2, ks2, vs2 = ragged_paged_attention_arrays(
+                        q, k, v, kc, vc, tables, pos0, lens, slots,
+                        k_scales=ksc, v_scales=vsc)
+                    return o, (kc2, vc2, ks2, vs2)
             return attn_fn
 
-        return self._run_blocks(params, kv_flat, x, builder)
+        # a padding row of the fixed-shape batch has no keys
+        return self._run_blocks(params, kv_flat, x, pos, builder,
+                                valid=lens > 0)
+
+    @staticmethod
+    def _window_kw(spec) -> dict:
+        return {} if spec.window is None else {"window": spec.window}
 
     def _get_ragged_exec(self, b, c):
         """The ISSUE-8 decode program (`_ragged_blocks` + the last
@@ -1745,8 +1904,9 @@ class LLMEngine:
             self._count_compile("ragged", key)
 
             def ragged(params, kv_flat, *inputs):
-                h, kv_out = self._ragged_blocks(c, params, kv_flat, *inputs)
-                return self._model_tail(params, h), kv_out
+                h, kv_out, stats = self._ragged_blocks(c, params, kv_flat,
+                                                       *inputs)
+                return self._model_tail(params, h), kv_out, stats
 
             self._jit_cache[key] = jax.jit(self._named(
                 ragged, "ragged_decode" if c == 1 else f"ragged_prefill_{c}"),
@@ -1765,7 +1925,8 @@ class LLMEngine:
             self._count_compile("verify", key)
 
             def verify(params, kv_flat, *inputs):
-                h, kv_out = self._ragged_blocks(c, params, kv_flat, *inputs)
+                h, kv_out, _ = self._ragged_blocks(c, params, kv_flat,
+                                                   *inputs)
                 logits = self._model_logits(params, h).astype(jnp.float32)
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return logits[:, 0], greedy, kv_out

@@ -83,6 +83,24 @@ table back by releasing the surplus blocks — slots inside kept blocks
 that held rejected K/V are re-written by later real tokens before any
 mask lets a query read them.
 
+**Window groups** (``window=W``, ISSUE 28): the pool of a model's
+sliding-window layers.  A key further than W behind the newest query is
+never read again, so before every step the table gives back each block
+that lies wholly behind ``length + 1 - W`` (`grow_to`): a sequence holds
+at most ``ceil(W / block_size) + 1`` blocks however long it grows.  The
+table keeps every LOGICAL index - a released entry holds ``num_blocks``,
+the id that points nowhere (slots made from it are dropped, gathers of it
+are masked by the window) - so position p still lives at
+``table[p // block_size]``.  A whole-prompt prefill longer than the
+window is given blocks for its tail only (`allocate(tail_only=True)`,
+`tail_start`).  Swap-out saves the live blocks and their logical indices.
+Copy-on-fork, prefix caching, speculative roll-back and int8 are not
+carried over a window group and raise.
+
+`CacheGroups` puts the caches of a model's layer groups (one id space,
+pool shape and table each) behind the allocator calls the scheduler
+makes: each call reaches every group or none.
+
 **Quantized mode** (``kv_quant="int8"``, the `paddle_tpu.lowbit` KV
 wing): pools store int8 codes plus per-block-per-head float32 scales
 (``k_scales[l], v_scales[l] : [num_blocks, num_heads]``, value =
@@ -107,7 +125,8 @@ import jax.numpy as jnp
 from .. import monitor
 from ..monitor import memory as mmemory
 
-__all__ = ["BlockKVCache", "BlockAllocatorError", "prefix_block_keys"]
+__all__ = ["BlockKVCache", "BlockAllocatorError", "CacheGroups",
+           "prefix_block_keys"]
 
 
 class BlockAllocatorError(RuntimeError):
@@ -149,10 +168,17 @@ class BlockKVCache:
     their blocks.  One pool shape, whatever reads it."""
 
     def __init__(self, num_layers, num_blocks, block_size, num_heads,
-                 head_dim, dtype=jnp.float32, kv_quant=None):
+                 head_dim, dtype=jnp.float32, kv_quant=None, window=None,
+                 name="full"):
         if kv_quant not in (None, "int8"):
             raise ValueError(
                 f'kv_quant must be None or "int8", got {kv_quant!r}')
+        if window is not None and kv_quant:
+            raise ValueError('kv_quant="int8" is not carried over a '
+                             "window group")
+        self.window = None if window is None else int(window)
+        self.name = name
+        self.released = 0      # blocks given back from behind the window
         self.num_layers = int(num_layers)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
@@ -311,6 +337,60 @@ class BlockKVCache:
     def blocks_needed(self, num_tokens) -> int:
         return -(-int(num_tokens) // self.block_size)
 
+    # -- window groups ------------------------------------------------------
+
+    def _first_live(self, length) -> int:
+        """First logical block a step still reads when the sequence holds
+        `length` tokens before it: the step's first query, at position
+        `length`, sees keys from `length + 1 - window` on."""
+        if self.window is None:
+            return 0
+        return max(0, int(length) + 1 - self.window) // self.block_size
+
+    def tail_start(self, num_tokens) -> int:
+        """First position a whole-prompt prefill of `num_tokens` writes
+        into this group: 0, or the start of the first block the decode
+        step after it still reads."""
+        return self._first_live(num_tokens) * self.block_size
+
+    def _live(self, table) -> list:
+        return [i for i in table if i < self.num_blocks]
+
+    def _new_blocks(self, seq_id, num_tokens, tail_only=False) -> int:
+        """Blocks `allocate` / `grow_to` would take, less those `grow_to`
+        gives back first."""
+        t = self._tables.get(seq_id)
+        if t is None:
+            first = self._first_live(num_tokens) if tail_only else 0
+            return self.blocks_needed(num_tokens) - first
+        need = self.blocks_needed(num_tokens) - len(t)
+        if self.window is not None:
+            need -= len(self._live(
+                t[:self._first_live(self._lengths[seq_id])]))
+        return need
+
+    def can_allocate(self, num_tokens, tail_only=False) -> bool:
+        """Room for a fresh sequence of `num_tokens`?  `tail_only`: the
+        step is a whole-prompt prefill, which reads its keys from the
+        chunk, so a window group holds the tail alone."""
+        return self._new_blocks(None, num_tokens, tail_only) \
+            <= self.num_free_blocks
+
+    def fits_empty(self, seq_id, num_tokens) -> bool:
+        """Could the EMPTY pool cover `num_tokens` for this sequence?"""
+        need = self.blocks_needed(num_tokens)
+        if self.window is not None:
+            need = min(need, self.blocks_needed(self.window) + 1)
+        if self._needs_cow(seq_id, num_tokens):
+            need += 1
+        return need <= self.num_blocks
+
+    def live_tokens(self, length) -> int:
+        """Tokens a decode step over `length` keys reads from this
+        group."""
+        return int(length) if self.window is None \
+            else min(int(length), self.window)
+
     # -- allocate / grow / free --------------------------------------------
 
     def _take(self) -> int:
@@ -361,6 +441,8 @@ class BlockKVCache:
         """Will growing to `num_tokens` write into a SHARED partially-
         filled last block?  (A full shared block is never written again —
         new tokens land in fresh blocks — so it can stay shared.)"""
+        if self.window is not None:
+            return False           # never forked, so never shared
         t = self._tables.get(seq_id)
         old = self._lengths.get(seq_id, 0)
         return bool(t) and num_tokens > old \
@@ -370,21 +452,22 @@ class BlockKVCache:
     def can_grow_to(self, seq_id, num_tokens) -> bool:
         """Enough free blocks (plus a possible copy-on-write block) to
         cover `num_tokens` for this sequence?"""
-        have = len(self._tables.get(seq_id, ()))
-        need = self.blocks_needed(num_tokens) - have
+        need = self._new_blocks(seq_id, num_tokens)
         if self._needs_cow(seq_id, num_tokens):
             need += 1              # CoW of the shared last block
         return need <= self.num_free_blocks
 
-    def allocate(self, seq_id, num_tokens):
-        """Register `seq_id` and give it blocks covering `num_tokens`."""
+    def allocate(self, seq_id, num_tokens, tail_only=False):
+        """Register `seq_id` and give it blocks covering `num_tokens`
+        (`tail_only`: see `can_allocate`)."""
         if seq_id in self._tables:
             raise BlockAllocatorError(f"sequence {seq_id} already allocated")
-        need = self.blocks_needed(num_tokens)
+        need = self._new_blocks(None, num_tokens, tail_only)
         if need > self.num_free_blocks:
             raise BlockAllocatorError("out of KV blocks")
         ids = [self._take() for _ in range(need)]
-        self._tables[seq_id] = ids
+        nowhere = self.blocks_needed(num_tokens) - need
+        self._tables[seq_id] = [self.num_blocks] * nowhere + ids
         self._lengths[seq_id] = int(num_tokens)
         self._reset_scales(ids)
 
@@ -396,6 +479,15 @@ class BlockKVCache:
         t = self._tables[seq_id]
         if self._needs_cow(seq_id, num_tokens):
             self._cow_last_block(seq_id)
+        if self.window is not None:
+            # give back what lies wholly behind the window of this step's
+            # first query; the entry keeps its place and points nowhere
+            for j in range(min(self._first_live(self._lengths[seq_id]),
+                               len(t))):
+                if t[j] < self.num_blocks:
+                    self._release(t[j])
+                    t[j] = self.num_blocks
+                    self.released += 1
         new_ids = []
         while len(t) < self.blocks_needed(num_tokens):
             new_ids.append(self._take())
@@ -404,7 +496,7 @@ class BlockKVCache:
         self._reset_scales(new_ids)
 
     def free(self, seq_id):
-        for idx in self._tables.pop(seq_id):
+        for idx in self._live(self._tables.pop(seq_id)):
             self._release(idx)
         self._lengths.pop(seq_id, None)
 
@@ -415,6 +507,7 @@ class BlockKVCache:
         for its other holders).  Slots inside KEPT blocks that held
         rejected K/V are overwritten by later real tokens before any
         causal mask lets a query read them."""
+        self._no_window("truncate_to (speculative roll-back)")
         t = self._tables[seq_id]
         keep = self.blocks_needed(num_tokens)
         while len(t) > keep:
@@ -427,6 +520,7 @@ class BlockKVCache:
     def fork(self, parent_id, child_id):
         """Share the parent's blocks with a new sequence (refcount bump —
         no copy until one of them appends into the shared last block)."""
+        self._no_window("fork")
         if child_id in self._tables:
             raise BlockAllocatorError(f"sequence {child_id} already exists")
         t = self._tables[parent_id]
@@ -435,6 +529,12 @@ class BlockKVCache:
         self._tables[child_id] = list(t)
         self._lengths[child_id] = self._lengths[parent_id]
         self.acct.on("fork", len(t))
+
+    def _no_window(self, what):
+        if self.window is not None:
+            raise BlockAllocatorError(
+                f"{what} is not carried over a window group "
+                f"(group {self.name!r}, window {self.window})")
 
     def _reset_scales(self, ids):
         """Zero the quant scales of freshly (re)allocated blocks — a
@@ -477,6 +577,7 @@ class BlockKVCache:
         — a full block is never written again while referenced, so its
         content is final.  First writer wins: an existing key keeps
         pointing at the original block (dedup, not re-pointing)."""
+        self._no_window("prefix caching")
         t = self._tables[seq_id]
         full = min(len(keys), int(num_tokens) // self.block_size, len(t))
         # chain id: the chain's FIRST key names the whole registration
@@ -566,21 +667,35 @@ class BlockKVCache:
         """Evict: host-snapshot the sequence's block contents and free its
         blocks.  Returns the opaque saved state for `swap_in`."""
         t = self._tables[seq_id]
-        idx = np.asarray(t, np.int32)
+        live = self._live(t)
+        idx = np.asarray(live, np.int32)
         saved = {
             "len": self._lengths[seq_id],
             "k": [np.asarray(k[idx]) for k in self.k_blocks],
             "v": [np.asarray(v[idx]) for v in self.v_blocks],
         }
+        if self.window is not None:
+            # where each saved block sits in the table, and its width
+            saved["logical"] = [j for j, i in enumerate(t)
+                                if i < self.num_blocks]
+            saved["width"] = len(t)
         if self.kv_quant:
             # codes alone are meaningless — the scales ARE the values'
             # exponents; saving both is what keeps the quantized domain
             # bit-stable across evict/restore
             saved["ks"] = [np.asarray(s[idx]) for s in self.k_scales]
             saved["vs"] = [np.asarray(s[idx]) for s in self.v_scales]
-        self.acct.on("swap_out", len(t))
+        self.acct.on("swap_out", len(live))
         self.free(seq_id)
         return saved
+
+    @staticmethod
+    def swap_blocks(saved) -> int:
+        """Blocks a snapshot needs to come back."""
+        return len(saved["k"][0])
+
+    def can_swap_in(self, saved) -> bool:
+        return self.swap_blocks(saved) <= self.num_free_blocks
 
     def swap_in(self, seq_id, saved):
         """Restore an evicted sequence bit-exactly into fresh blocks."""
@@ -588,9 +703,16 @@ class BlockKVCache:
         if n > self.num_free_blocks:
             raise BlockAllocatorError("out of KV blocks")
         self.acct.on("swap_in", n)
-        self._tables[seq_id] = [self._take() for _ in range(n)]
+        ids = [self._take() for _ in range(n)]
+        if "logical" in saved:
+            table = [self.num_blocks] * saved["width"]
+            for j, i in zip(saved["logical"], ids):
+                table[j] = i
+        else:
+            table = ids
+        self._tables[seq_id] = table
         self._lengths[seq_id] = saved["len"]
-        idx = jnp.asarray(self._tables[seq_id], jnp.int32)
+        idx = jnp.asarray(ids, jnp.int32)
         for l in range(self.num_layers):
             self.k_blocks[l] = self.k_blocks[l].at[idx].set(
                 jnp.asarray(saved["k"][l]))
@@ -601,3 +723,97 @@ class BlockKVCache:
                     jnp.asarray(saved["ks"][l]))
                 self.v_scales[l] = self.v_scales[l].at[idx].set(
                     jnp.asarray(saved["vs"][l]))
+
+
+
+class CacheGroups:
+    """The caches of a model's layer groups behind the allocator calls the
+    scheduler and the engine make.  Every group has its own id space, pool
+    shape and table; a sequence holds a table in each, and each call
+    reaches all groups or none (the checks run over every group before
+    any group is touched)."""
+
+    def __init__(self, groups: dict):
+        self.groups = dict(groups)          # name -> BlockKVCache
+        self._all = list(self.groups.values())
+        self.first = self._all[0]
+        self.block_size = self.first.block_size
+        if any(c.block_size != self.block_size for c in self._all):
+            raise ValueError("cache groups share one block_size")
+
+    # the scheduler's membership test and its messages read the first
+    # group (a sequence is in every group's tables or in none)
+    @property
+    def _tables(self):
+        return self.first._tables
+
+    @property
+    def num_blocks(self):
+        return self.first.num_blocks
+
+    @property
+    def num_free_blocks(self):
+        return min(c.num_free_blocks for c in self._all)
+
+    def blocks_needed(self, num_tokens):
+        return self.first.blocks_needed(num_tokens)
+
+    def _needs_cow(self, seq_id, num_tokens):
+        return any(c._needs_cow(seq_id, num_tokens) for c in self._all)
+
+    def can_allocate(self, num_tokens, tail_only=False):
+        return all(c.can_allocate(num_tokens, tail_only) for c in self._all)
+
+    def fits_empty(self, seq_id, num_tokens):
+        return all(c.fits_empty(seq_id, num_tokens) for c in self._all)
+
+    def can_grow_to(self, seq_id, num_tokens):
+        return all(c.can_grow_to(seq_id, num_tokens) for c in self._all)
+
+    def allocate(self, seq_id, num_tokens, tail_only=False):
+        if not self.can_allocate(num_tokens, tail_only):
+            raise BlockAllocatorError("out of KV blocks")
+        for c in self._all:
+            c.allocate(seq_id, num_tokens, tail_only)
+
+    def grow_to(self, seq_id, num_tokens):
+        if not self.can_grow_to(seq_id, num_tokens):
+            raise BlockAllocatorError("out of KV blocks")
+        for c in self._all:
+            c.grow_to(seq_id, num_tokens)
+
+    def free(self, seq_id):
+        for c in self._all:
+            c.free(seq_id)
+
+    def swap_out(self, seq_id):
+        return {"groups": {n: c.swap_out(seq_id)
+                           for n, c in self.groups.items()}}
+
+    def swap_blocks(self, saved):
+        return max(c.swap_blocks(saved["groups"][n])
+                   for n, c in self.groups.items())
+
+    def can_swap_in(self, saved):
+        return all(c.can_swap_in(saved["groups"][n])
+                   for n, c in self.groups.items())
+
+    def swap_in(self, seq_id, saved):
+        if not self.can_swap_in(saved):
+            raise BlockAllocatorError("out of KV blocks")
+        for n, c in self.groups.items():
+            c.swap_in(seq_id, saved["groups"][n])
+
+    def fork(self, parent_id, child_id):
+        for c in self._all:
+            c._no_window("fork")
+        for c in self._all:
+            c.fork(parent_id, child_id)
+
+    def privatize_last_block(self, seq_id):
+        for c in self._all:
+            c.privatize_last_block(seq_id)
+
+    def truncate_to(self, seq_id, num_tokens):
+        for c in self._all:
+            c.truncate_to(seq_id, num_tokens)

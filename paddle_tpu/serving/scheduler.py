@@ -360,7 +360,7 @@ class Scheduler:
                             "KV cache too small: an evicted request can "
                             "never be restored "
                             f"(free={self.cache.num_free_blocks} blocks, "
-                            f"needs {len(head.swap['k'][0])})")
+                            f"needs {self.cache.swap_blocks(head.swap)})")
                     raise RuntimeError(
                         "KV cache too small: cannot hold a single request "
                         f"(free={self.cache.num_free_blocks} blocks, "
@@ -443,8 +443,10 @@ class Scheduler:
         elif forked:
             fits = self.cache.can_grow_to(req.req_id, target)
         else:
-            fits = (self.cache.blocks_needed(target)
-                    <= self.cache.num_free_blocks)
+            # a whole prompt in one chunk reads its keys from the chunk:
+            # a window group then holds the prompt's tail alone
+            whole = start == 0 and target == req.prompt_len
+            fits = self.cache.can_allocate(target, tail_only=whole)
         if not fits:
             return None
         self.waiting.remove(req)
@@ -457,7 +459,7 @@ class Scheduler:
         elif forked:
             self.cache.grow_to(req.req_id, target)
         else:
-            self.cache.allocate(req.req_id, target)
+            self.cache.allocate(req.req_id, target, tail_only=whole)
         req.state = Request.RUNNING
         self.running.append(req)
         self._charge(req, chunk)
@@ -477,7 +479,7 @@ class Scheduler:
     # -- eviction -----------------------------------------------------------
 
     def _can_swap_in(self, req) -> bool:
-        return len(req.swap["k"][0]) <= self.cache.num_free_blocks
+        return self.cache.can_swap_in(req.swap)
 
     def _ensure_blocks(self, req, target_len, preempted, protect=None) -> bool:
         """Make the pool able to cover `target_len` for `req`, evicting
@@ -490,10 +492,7 @@ class Scheduler:
                 # blocks (e.g. forked children in the waiting queue); a
                 # request that cannot fit in the EMPTY pool would evict
                 # itself, swap back in, and livelock forever — raise
-                need = self.cache.blocks_needed(target_len) + (
-                    1 if self.cache._needs_cow(req.req_id, target_len)
-                    else 0)
-                if need > self.cache.num_blocks:
+                if not self.cache.fits_empty(req.req_id, target_len):
                     raise RuntimeError(
                         "KV cache too small: request needs "
                         f"{self.cache.blocks_needed(target_len)} blocks "

@@ -20,6 +20,14 @@ one write and one layer of the C > 1 fallback cost; `--tree DIR` takes
 `paddle_tpu` from another checkout (the parent, to compare with: same
 inputs, in the pool shape that tree keeps).
 
+`--afmoe` runs the grouped-head and windowed calls of both attention
+kernels at the published afmoe shapes (48 query heads over 8 K/V heads of
+128, window 4096, block 64) against their XLA fallbacks, and then a
+5-layer afmoe engine (the benchmark's `trinity-large-ep8-l5`: prefill of
+4,224 tokens and 8 decode steps) against `benchmark/lib/reference_afmoe`,
+with what the reference's deliberate faults read against the same tokens.
+The default run includes the kernel calls, not the engine leg.
+
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
 stops there.  That catches Mosaic refusals from a CPU-only sandbox; it
@@ -39,6 +47,8 @@ import numpy as np
 WIDTHS = {"hd768": (12, 64), "hd2048": (16, 128)}
 AOT = "--aot" in sys.argv[1:]
 WRITES_ONLY = "--writes" in sys.argv[1:]
+AFMOE_ONLY = "--afmoe" in sys.argv[1:]
+AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
 
 
@@ -79,6 +89,7 @@ def _check(name, fn, ref, args, tol=5e-2):
     (same pytree of outputs); under --aot, compile `fn` and stop."""
     import jax
 
+    rel = []
     if AOT:
         _compile_only(fn, args)
     else:
@@ -88,7 +99,10 @@ def _check(name, fn, ref, args, tol=5e-2):
             g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
             assert np.isfinite(g).all(), name
             np.testing.assert_allclose(g, w, rtol=tol, atol=tol, err_msg=name)
-    print(f"OK {name}", flush=True)
+            rel.append(float(np.sqrt(((g - w) ** 2).mean()
+                                     / max((w ** 2).mean(), 1e-30))))
+    print(f"OK {name}" + (f" (relative rms error {max(rel):.4f})"
+                          if rel else ""), flush=True)
 
 
 def _randn(rng, shape, dtype):
@@ -218,6 +232,194 @@ def check_ragged(wname, h, d, quant):
         assert diff.max() <= step, \
             f"{name}: state {i} off by {diff.max()} (allowed {step})"
     print(f"OK {name}", flush=True)
+
+
+def check_ragged_grouped(window):
+    """The decode kernel with 48 query heads over pools of 8 K/V heads,
+    with and without the window, against its XLA fallback: lengths 1, and
+    4095, 4096, 4097 either side of the window, 8448, and a padding row;
+    table entries wholly behind a window point nowhere, as the window
+    group leaves them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ragged_paged_attention as rp
+
+    hq, hkv, d, bs = (AFMOE[k] for k in ("hq", "hkv", "d", "bs"))
+    rng = np.random.RandomState(11)
+    lens = np.asarray([1, 4095, 4096, 4097, 8448, 0], np.int32)
+    b, maxb = len(lens), 8448 // bs
+    nb = b * maxb
+    perm = rng.permutation(nb).astype(np.int32).reshape(b, maxb)
+    blk = np.arange(maxb)[None] * bs
+    live = blk < lens[:, None]
+    if window:
+        live &= blk + bs > lens[:, None] - window
+    tables = np.where(live, perm, nb)
+    slots = np.full((b, 1), nb * bs, np.int32)
+    for r in range(b):
+        if lens[r]:
+            p = int(lens[r]) - 1
+            slots[r, 0] = int(tables[r, p // bs]) * bs + p % bs
+    q = _randn(rng, (b, 1, hq, d), jnp.bfloat16)
+    kn, vn = (_randn(rng, (b, 1, hkv, d), jnp.bfloat16) for _ in range(2))
+    kb, vb = (_randn(rng, (nb, bs, hkv * d), jnp.bfloat16)
+              for _ in range(2))
+    tables, slots, lens_j = (jnp.asarray(x) for x in (tables, slots, lens))
+    pos0 = jnp.maximum(lens_j - 1, 0)
+
+    def call(q, kn, vn, kb, vb):
+        return rp.ragged_paged_attention_arrays(
+            q, kn, vn, kb, vb, tables, pos0, lens_j, slots,
+            **({"window": window} if window else {}))
+
+    name = f"ragged_grouped_48over8_window{window}"
+    if AOT:
+        _compile_only(call, [q, kn, vn, kb, vb])
+        print(f"OK {name}", flush=True)
+        return
+    got = jax.jit(call)(q, kn, vn, kb, vb)
+    os.environ["PTPU_RAGGED_KERNEL"] = "0"
+    try:
+        want = jax.jit(call)(q, kn, vn, kb, vb)
+    finally:
+        del os.environ["PTPU_RAGGED_KERNEL"]
+    rows = np.asarray(lens) > 0
+    out, out_ref = (np.asarray(x[0], np.float32)[rows] for x in (got, want))
+    assert np.isfinite(out).all(), name
+    # over thousands of keys the output is a small mean of values: judge
+    # it by its own size, row by row (bf16 operands: under 2%)
+    rel = np.sqrt(((out - out_ref) ** 2).mean((1, 2, 3))
+                  / (out_ref ** 2).mean((1, 2, 3)))
+    assert rel.max() < 2e-2, f"{name}: relative error a row {rel}"
+    for g, w in zip(got[1:], want[1:]):
+        assert (np.asarray(g, np.float32) == np.asarray(w, np.float32)
+                ).all(), f"{name}: pools differ"
+    print(f"OK {name} (relative rms error a row, worst {rel.max():.4f})",
+          flush=True)
+
+
+def check_flash_grouped(window, s=8192):
+    """Flash forward over 48 query heads and 8 K/V heads at S = 8192, with
+    and without the window, against plain XLA attention on the first and
+    the last 256 query rows (all of it would be a 13 GB score matrix)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_ops as po
+
+    hq, hkv, d = (AFMOE[k] for k in ("hq", "hkv", "d"))
+    rng = np.random.RandomState(13)
+    q = _randn(rng, (1, s, hq, d), jnp.bfloat16)
+    k, v = (_randn(rng, (1, s, hkv, d), jnp.bfloat16) for _ in range(2))
+
+    def fwd(q, k, v):
+        o = po.flash_attention_arrays(q, k, v, is_causal=True,
+                                      window=window)
+        return o[:, :256], o[:, -256:]
+
+    def ref(q, k, v):
+        outs = []
+        for lo in (0, s - 256):
+            i = lo + jnp.arange(256)[:, None]
+            j = jnp.arange(s)[None]
+            seen = j <= i
+            if window:
+                seen &= i - j < window
+            qq = q[:, lo:lo + 256].reshape(1, 256, hkv, hq // hkv, d)
+            sc = jnp.einsum("bqhgd,bkhd->bhgqk", qq, k,
+                            preferred_element_type=jnp.float32) / d ** 0.5
+            p = jax.nn.softmax(jnp.where(seen, sc, -1e30), axis=-1)
+            outs.append(jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype),
+                                   v).reshape(1, 256, hq, d))
+        return tuple(outs)
+
+    _check(f"flash_grouped_48over8_window{window}_s{s}", fwd, ref, [q, k, v])
+
+
+def check_afmoe_engine():
+    """`trinity-large-ep8-l5` through LLMEngine at its published widths and
+    the cell's engine settings: prompts of 1,024 and 4,224 tokens (one
+    past the window) decoded together for 48 steps and one of 8,192 for 8,
+    the served tokens against the plain reference's full forward, then
+    against the reference's deliberate faults; and, without a cache, every
+    logit of one 1,024-token sequence."""
+    import json
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from benchmark.lib import family, reference_afmoe as ref
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+    from paddle_tpu.serving.scheduler import SamplingParams
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-ep8-l5.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "longmix-c32.json")) as f:
+        engine_kw = json.load(f)["engine"]
+    model, cfg = family.build_model(config, 2 ** 31 + 5)
+    model.eval()
+    params = ref.params_from_model(model)
+    rng = np.random.RandomState(5)
+
+    # every logit, no cache: the error of a position is rounding, or a
+    # token the router sent elsewhere than the float32 reference did
+    ids = rng.randint(0, cfg.vocab_size, 1024)
+    got = np.asarray(jax.jit(model.forward_arrays)(
+        model.param_arrays(), jnp.asarray(ids[None])), np.float32)[0]
+    want = np.asarray(ref.logits(params, jnp.asarray(ids), config))
+    err = np.sqrt(((got - want) ** 2).mean(-1))
+    print("afmoe forward: rms logit error a position, quantiles "
+          "50/90/99/100%: "
+          f"{np.round(np.percentile(err, [50, 90, 99, 100]), 4).tolist()} "
+          f"(logit std {want.std(-1).mean():.3f})", flush=True)
+    del got, want
+
+    engine = LLMEngine(model, EngineConfig(**engine_kw))
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (1024, 4224, 8192)]
+    t0 = time.perf_counter()
+    outs = engine.generate(prompts[:2], SamplingParams(max_new_tokens=48))
+    outs += engine.generate(prompts[2:], SamplingParams(max_new_tokens=8))
+    print(f"afmoe engine: 3 prefills, 48 + 8 decode steps in "
+          f"{time.perf_counter() - t0:.1f} s (compiles included); window "
+          f"group released {engine.caches['window'].released} blocks; "
+          f"peak {jax.devices()[0].memory_stats()['peak_bytes_in_use']:,} "
+          "bytes", flush=True)
+    for cache in engine.caches.values():
+        cache.k_blocks = cache.v_blocks = None
+    del engine
+    readings = {}
+    for fault in ref.FAULTS:
+        if fault == "fp8":
+            readings["8k"] = _served_margins(ref, params, config, prompts[2:],
+                                             outs[2:], None)
+        readings[fault or "none"] = _served_margins(
+            ref, params, config, prompts[:2], outs[:2], fault)
+    for name, r in readings.items():
+        print(f"afmoe engine: served tokens under the reference with fault "
+              f"{name}: {json.dumps(r)}", flush=True)
+    assert readings["none"]["median"] <= 0.05, readings["none"]
+    print("OK afmoe_engine", flush=True)
+
+
+def _served_margins(ref, params, config, prompts, outs, fault):
+    """How far each served token's logit lies under its position's
+    largest in the reference (computed with `fault`): max, p90, median,
+    and the share of tokens within 0.25."""
+    all_m = []
+    for prompt, out in zip(prompts, outs):
+        width = -(-len(out) // 128) * 128
+        ids = np.zeros((1, width), np.int32)
+        ids[0, :len(out)] = out
+        margins, _ = ref.greedy_margins(params, ids, config, fault=fault)
+        all_m.append(margins[0, len(prompt) - 1:len(out) - 1])
+    m = np.concatenate(all_m)
+    return {"tokens": int(m.size), "max": round(float(m.max()), 4),
+            "p90": round(float(np.percentile(m, 90)), 4),
+            "median": round(float(np.median(m)), 4),
+            "within_0.25": round(float((m <= 0.25).mean()), 4)}
 
 
 # (rows, tokens a row, first position): a whole prompt, a chunk from the
@@ -414,15 +616,24 @@ def main():
               for fn, extra in ((check_flash, ()), (check_flash_decode, ()),
                                 (check_ragged, (False,)),
                                 (check_ragged, (True,)))]
-    if not AOT:
+    checks += [(f"{fn.__name__}_{w}", fn, (w,))
+               for fn in (check_ragged_grouped, check_flash_grouped)
+               for w in (None, AFMOE["window"])]
+    if AFMOE_ONLY:
+        checks = [c for c in checks if "grouped" in c[0]]
+        if not AOT:
+            checks.append(("check_afmoe_engine", check_afmoe_engine, ()))
+    if not AOT and not AFMOE_ONLY:
         checks.append(("check_generate", check_generate, ()))
-    checks.append(("check_writes", check_writes, ()))
-    checks += [(f"try_default_off_{wname}", try_default_off, (wname, h, d))
-               for wname, (h, d) in WIDTHS.items()]
+    if not AFMOE_ONLY:
+        checks.append(("check_writes", check_writes, ()))
+        checks += [(f"try_default_off_{wname}", try_default_off,
+                    (wname, h, d)) for wname, (h, d) in WIDTHS.items()]
     if WRITES_ONLY:
         checks = [c for c in checks if c[1] is check_writes]
     for name, fn, args in checks:
-        with _Watchdog(name, 900.0 if fn is check_writes else 240.0):
+        with _Watchdog(name, 900.0 if fn in (check_writes,
+                                             check_afmoe_engine) else 240.0):
             fn(*args)
     print("ALL AOT COMPILES OK" if AOT else "ALL ONCHIP CHECKS OK")
 

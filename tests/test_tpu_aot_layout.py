@@ -118,3 +118,107 @@ def test_cache_write_is_in_place(S, quant, name):
     assert found.get("fusion:scatter") == 1, found    # + its own body
     assert set(found) <= {"parameter", "bitcast", "scatter", "tuple",
                           "fusion:scatter"}, found
+
+
+# -- PR 28: grouped heads, windows, and the engine's seam ---------------------
+
+AFMOE = dict(hq=48, hkv=8, d=128, bs=64, rows=32, maxb=132)
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_ragged_kernel_compiles_at_the_afmoe_shapes(S, monkeypatch, window):
+    """48 query heads over pools of 8 K/V heads (rows 1024 wide), block
+    64, 32 rows of up to 8,448 tokens: Mosaic takes it, and the pools
+    still reach the kernel and leave it aliased."""
+    a = AFMOE
+    nb = a["rows"] * (a["maxb"] if window is None else 4096 // a["bs"] + 1)
+    monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+    pool = S((nb, a["bs"], a["hkv"] * a["d"]), jnp.bfloat16)
+    new = S((a["rows"], 1, a["hkv"], a["d"]), jnp.bfloat16)
+
+    def call(*args):
+        return rp.ragged_paged_attention_arrays(
+            *args, **({} if window is None else {"window": window}))
+
+    compiled = jax.jit(call, donate_argnums=(3, 4)).lower(
+        S((a["rows"], 1, a["hq"], a["d"]), jnp.bfloat16), new, new, pool,
+        pool, S((a["rows"], a["maxb"]), jnp.int32),
+        S((a["rows"],), jnp.int32), S((a["rows"],), jnp.int32),
+        S((a["rows"], 1), jnp.int32)).compile()
+    found = _pool_sized(compiled.as_text(),
+                        nb * a["bs"] * a["hkv"] * a["d"])
+    assert found.pop("custom-call") == 1
+    assert set(found) <= {"parameter", "get-tuple-element", "bitcast",
+                          "tuple"}, found
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+@pytest.mark.parametrize("seq", [1024, 8192])
+def test_flash_forward_compiles_at_the_afmoe_shapes(S, monkeypatch, seq,
+                                                    window):
+    """The prefill of the longest prompt: K and V of one K/V head whole in
+    fast memory, shared by its six query heads; K/V are not repeated."""
+    from paddle_tpu.ops import pallas_ops as po
+
+    a = AFMOE
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)
+    compiled = jax.jit(lambda q, k, v: po.flash_attention_arrays(
+        q, k, v, is_causal=True, window=window)).lower(
+        S((1, seq, a["hq"], a["d"]), jnp.bfloat16),
+        S((1, seq, a["hkv"], a["d"]), jnp.bfloat16),
+        S((1, seq, a["hkv"], a["d"]), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"bf16[1,{seq},{a['hq']},{a['d']}]" in text
+
+
+def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
+                                                         monkeypatch):
+    """The seam (ISSUE 28) costs the GPT cells nothing: the engine's decode
+    and prefill programs of a 2-layer GPT at the 1.3B widths compile, for
+    the described v5e, to the operation list recorded from the parent
+    commit (tests/fixtures/gpt_engine_ops_pr27.json)."""
+    import collections
+    import json
+
+    from paddle_tpu.framework.compat import LazyGuard
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.ops import pallas_ops as po
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+
+    monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)
+    with LazyGuard():
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=50304, hidden_size=2048, num_hidden_layers=2,
+            num_attention_heads=16, intermediate_size=8192,
+            max_position_embeddings=2048, stacked_blocks=True))
+    model.to(dtype="bfloat16")
+    eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=16))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def ops(compiled):
+        found = collections.Counter()
+        for line in compiled.as_text().splitlines():
+            m = re.match(
+                r"^\s*(?:ROOT )?%[\w.\-]+ = .*?[\]\})] ([\w\-]+)\(", line)
+            if m:
+                found[m.group(1)] += 1
+        return dict(found)
+
+    params = jax.tree_util.tree_map(on_chip, eng._param_arrays())
+    kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
+    got = {
+        "jit_ragged_decode": ops(eng._get_ragged_exec(16, 1).lower(
+            params, kv, i32(16, 1), i32(16), i32(16),
+            (i32(16, eng.blocks_per_seq),), (i32(16, 1),)).compile()),
+        "jit_prefill_512": ops(eng._get_prefill_exec(512).lower(
+            params, kv, i32(1, 512), (i32(1, 512),)).compile())}
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           "gpt_engine_ops_pr27.json")) as f:
+        assert got == json.load(f)
