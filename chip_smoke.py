@@ -426,7 +426,13 @@ def serve_leg(args):
         return {"a128": plain, **pair}
 
     served, counts = serve(EngineConfig(), fp_plan)
-    _check_paths(counts, need=["attn_kernel", "ragged_kernel"],
+    # heads of 128 lanes over full-precision pools take the per-head MXU
+    # products; the rehearsal's heads of 64 keep the segment body
+    d = cfg.hidden_size // cfg.num_attention_heads
+    body = "head_products" if d % 128 == 0 else "segment_products"
+    _check_paths(counts,
+                 need=["attn_kernel", "ragged_kernel",
+                       f"ragged_kernel:{body}"],
                  allowed_fallbacks=("ragged_fallback:chunk_gt_1",))
     out["attention_paths"] = counts
     for toks in served.values():
@@ -449,7 +455,9 @@ def serve_leg(args):
 
     toks8, counts8 = serve(EngineConfig(kv_cache_dtype="int8", block_size=32),
                            int8_plan)
-    _check_paths(counts8, need=["attn_kernel", "ragged_kernel"],
+    _check_paths(counts8,
+                 need=["attn_kernel", "ragged_kernel",
+                       "ragged_kernel:segment_products"],
                  allowed_fallbacks=("ragged_fallback:chunk_gt_1",))
     ranks8 = reference_ranks(prompts["a128"], toks8)
     agree = sum(a == b for a, b in zip(toks8, served["a128"]))

@@ -1056,7 +1056,12 @@ def _decode_seg_helpers(h, d, fast):
     segment indicator (s = (K ∘ q) @ seg, [rows, H*D] @ [H*D, H]) and
     per-head weights expand back to lanes with its swapped twin. Both are
     built straight from 2D iotas (Mosaic cannot legalize transposes of
-    these skinny shapes)."""
+    these skinny shapes).  Where d IS a lane tile a head can be sliced
+    out, and per-head MXU products are 13x faster on a v5e at d = 128
+    (`ragged_paged_attention._head_stream`; PERF.md, PR 29: this body
+    pays its indicator products per block whatever the block holds).
+    The flash-decode kernels below (`generate()`, no serving cell) still
+    take this body at every d."""
     hd = h * d
     seg = (jax.lax.broadcasted_iota(jnp.int32, (hd, h), 0) // d
            == jax.lax.broadcasted_iota(jnp.int32, (hd, h), 1)
@@ -1089,6 +1094,9 @@ def _two_block_dma_loop(num_kb, copies, step, carry, first_kb=0):
     (`copies(slot, kb)` builds block kb's descriptors into buffer `slot`),
     then `step` waits on each in turn and does its math — the second block
     streams in while the first computes, and `slot` is a PYTHON 0 or 1.
+    A "block" is whatever `copies` fetches for one index: the ragged
+    kernel's `_head_stream` makes it a tile of several pool blocks, each
+    with a DMA of its own, all started and waited on in the one iteration.
 
     No DMA is ever in flight across a loop iteration.  The classic form —
     prefetch block kb+1 at the top of iteration kb, wait for it in the

@@ -17,13 +17,21 @@ Two implementations behind one entry point, selected like
 
 - **Pallas kernel** (TPU, or CPU under ``PTPU_PALLAS_INTERPRET=1``), the
   decode (S_q = 1) shape: one program per row streams ONLY the row's
-  ``ceil(len / block_size)`` physical blocks from HBM (two blocks in
-  flight per loop iteration, online softmax — the XLA fallback touches
-  all ``max_blocks`` gathered rows), fuses the new token's quantize+scatter as a
-  read-modify-write of the row's last block BEFORE the stream (pools are
-  aliased in place), and dequantizes int8 blocks at load time — the int8
-  codes never exist as a dequantized [B, S_pad, H, D] float tensor
-  anywhere.
+  ``ceil(len / block_size)`` physical blocks from HBM with an online
+  softmax (the XLA fallback touches all ``max_blocks`` gathered rows),
+  fuses the new token's quantize+scatter as a read-modify-write of the
+  row's last block BEFORE the stream (pools are aliased in place).  The
+  stream has two bodies, chosen from head dim and pool dtype
+  (`_head_products_ok`).  Full-precision pools whose heads are whole lane
+  tiles (d = 128: both GPT benchmark configurations and afmoe): a loop
+  step takes a TILE of 64 tokens - ``64 // block_size`` table entries
+  gathered by as many DMAs into one VMEM buffer - and runs two MXU
+  products per K/V head over it, the query heads that share the K/V head
+  as the products' rows (`_head_stream`; two tiles in flight per loop
+  iteration).  Heads of 64 lanes and the int8 pools: one block a step,
+  heads flattened in the lanes and reduced through segment-indicator
+  matmuls, int8 blocks dequantized at load time — the int8 codes never
+  exist as a dequantized [B, S_pad, H, D] float tensor anywhere.
 
 - **XLA array-level fallback** (any backend, any chunk width C): the
   cache update and attention of `ops.paged_attention` composed in one
@@ -130,18 +138,38 @@ def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
         _count_path("ragged_fallback:dtype_mix")
         return False
     _count_path("ragged_kernel")
+    _count_path("ragged_kernel:head_products" if _head_products_ok(d, quant)
+                else "ragged_kernel:segment_products")
     return True
+
+
+# tokens of a row's K/V one step of the stream consumes, as
+# `_TILE_TOKENS // block_size` table entries gathered into one VMEM buffer:
+# the block at which PR 28 measured the per-head products at 37% of the
+# HBM roofline (0.85 us for 256 KB; at 16 tokens a step the same loop read
+# 3.6%, each step paying its DMA round trip and its MXU weight loads for a
+# quarter of the rows)
+_TILE_TOKENS = 64
+
+
+def _head_products_ok(d, quant) -> bool:
+    """Which body the stream takes, from what the call can see.  A head
+    that is whole lane tiles of a full-precision pool row is sliced out
+    and multiplied on the MXU (`_head_stream`), whatever the number of
+    query heads that read it, one included.  A head of 64 lanes cannot be
+    sliced out, and the int8 pools' scales are gathered per head beside
+    the codes: those keep the segment-indicator body."""
+    return d % 128 == 0 and not quant
 
 
 # ---------------------------------------------------------------------------
 # the fused kernel (S_q = 1): cache update (read-modify-write of the
-# row's last block) then a streamed attention over the row's blocks, two
-# per loop iteration, int8 dequant fused into the block loads
+# row's last block) then a streamed attention over the row's blocks
 # ---------------------------------------------------------------------------
 
 def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                          k_hbm, v_hbm, *refs, bs, h, d, nb, maxb, scale,
-                         quant, window=None, groups=1):
+                         quant, window=None, heads=False):
     """One program per batch row r:
 
     1. DMA the row's TARGET block (the one its write slot lands in) into
@@ -153,34 +181,29 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
        back — pools are aliased in place, and blocks a row writes are
        always privately owned (the engine privatizes shared last blocks
        at fork), so programs never race.
-    2. Stream the row's ``ceil(len/bs)`` blocks from HBM
-       (`_two_block_dma_loop`, through the row's block table in SMEM),
-       dequantizing int8
-       codes at load via the per-block-per-head scales, with an online
-       softmax; the target block's contribution comes from the updated
-       VMEM copy, never re-read through the alias.
+    2. Stream the row's K/V from HBM through its block table in SMEM with
+       an online softmax.  The row's own new token never comes back
+       through the alias: the stream takes it from `kn_ref`/`vn_ref`
+       (`_head_stream`) or from the updated VMEM copy of the target
+       block (the segment body below).
 
-    Grouped heads (`h` counts the K/V heads of a pool row, `groups` the
-    query heads that read each): the row's query block is `[GP, h * d]`,
-    member g at lane segment j being query head j * groups + g, padded
-    with zero rows to GP = 8 sublanes.  K/V head j of a streamed block is
-    the lane slice `[:, j*d:(j+1)*d]` (whole lane tiles: d is a multiple
-    of 128), and its members' logits and weighted values are two small
-    MXU products, `q_j [GP, d] x K_j^T` and `p [GP, bs] x V_j`, with the
-    online-softmax state kept per head - the dot products run on the MXU
-    with the members as its rows.  (First built as the K∘q-then-segment
-    product per member: 56 us a 64-token block on the chip, 117 ms a
-    layer at 32 rows; PERF.md, PR 28.)  `window`: the stream starts at
-    block `max(0, length - window) // bs` and positions under
-    `length - window` are masked (table entries behind that may point
-    nowhere; they are never read).
+    `h` counts the K/V heads of a pool row.  `heads` (`_head_products_ok`)
+    picks the body of step 2: `_head_stream`, or here the segment body for
+    heads of 64 lanes and int8 pools - as many query heads as K/V heads,
+    q `[1, 1, h*d]`, one block a step, two a loop iteration
+    (`_two_block_dma_loop`).  There the heads stay flattened in the lane
+    dim and per-head logits/weights go through the segment-indicator
+    matmuls of `_decode_seg_helpers` (a head that is not whole lane tiles
+    cannot be sliced out of a row under Mosaic's (8,128) tiling), int8
+    codes dequantized at load via the per-block-per-head scales.
+
+    `window`: the stream starts at block `max(0, length - window) // bs`
+    and positions under `length - window` are masked (table entries
+    behind that may point nowhere; they are never read).
 
     Rows whose write slot is out of range (batch padding / evicted rows)
-    skip the write and produce garbage output the engine ignores.  Heads
-    live flattened in the lane dim; per-head logits/weights go through
-    the segment-indicator matmuls of `_decode_seg_helpers` (Mosaic's
-    (8,128) tiling forbids slicing H or D when they are not tile
-    multiples)."""
+    skip the write; a row of length 0 streams nothing and puts out
+    zeros."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -196,13 +219,6 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     valid = (slot >= 0) & (slot < nb * bs)
     blk = jnp.clip(slot // bs, 0, nb - 1)
     off = jnp.where(valid, slot % bs, 0)
-    # the write slot is the row's LAST position (length - 1), so the
-    # target block is the last logical block the attention stream visits
-    tkb = jnp.where(valid, jnp.clip((length - 1) // bs, 0, maxb - 1), -1)
-
-    fast = (jnp.bfloat16 if (not quant and kbuf.dtype == jnp.bfloat16)
-            else jnp.float32)
-    seg, expand, seg_dot = _decode_seg_helpers(h, d, fast)
 
     # -- 1. fused cache update ---------------------------------------------
     rk = pltpu.make_async_copy(k_hbm.at[pl.ds(blk, 1)], ublk.at[0],
@@ -216,6 +232,8 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     off_mask = (jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1) == off)
 
     if quant:
+        seg, expand, seg_dot = _decode_seg_helpers(h, d, jnp.float32)
+
         def _sel_row(g_ref, kb):
             # row kb of the pre-gathered [1, maxb, h] scale view as
             # [1, h] — masked sublane sum instead of a dynamic VMEM slice
@@ -252,14 +270,10 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         kup_f = k_codes * ks_hd          # dequantized local target block
         vup_f = v_codes * vs_hd
     else:
-        kup = jnp.where(off_mask & valid,
-                        kn_ref[...].astype(ublk.dtype), ublk[0])
-        vup = jnp.where(off_mask & valid,
-                        vn_ref[...].astype(ublk.dtype), ublk[1])
-        ublk[0] = kup
-        ublk[1] = vup
-        kup_f = kup.astype(jnp.float32)
-        vup_f = vup.astype(jnp.float32)
+        ublk[0] = jnp.where(off_mask & valid,
+                            kn_ref[...].astype(ublk.dtype), ublk[0])
+        ublk[1] = jnp.where(off_mask & valid,
+                            vn_ref[...].astype(ublk.dtype), ublk[1])
 
     @pl.when(valid)
     def _writeback():
@@ -270,16 +284,36 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         wk.start()
         wv.start()
         # writes must complete before the stream below may read the same
-        # HBM region (the target block's streamed copy is discarded, but
-        # an in-flight overlapping read/write would be undefined)
+        # HBM region (what it reads at the new token's position is
+        # discarded, but an in-flight overlapping read/write would be
+        # undefined)
         wk.wait()
         wv.wait()
 
     # -- 2. streamed attention over the row's valid blocks ------------------
-    # padding rows (length 0) stream nothing and put out zeros
+    low = None if window is None else jnp.maximum(length - window, 0)
+
+    def seen_at(pos, end):
+        seen = pos < end
+        return seen if low is None else seen & (pos >= low)
+
+    if heads:
+        _head_stream(r, tbl_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
+                     kbuf, vbuf, sem, length, valid, low, seen_at, bs=bs,
+                     h=h, d=d, nb=nb, maxb=maxb, scale=scale)
+        return
+
+    # the write slot is the row's LAST position (length - 1), so the
+    # target block is the last logical block the attention stream visits
+    tkb = jnp.where(valid, jnp.clip((length - 1) // bs, 0, maxb - 1), -1)
     num_kb = jnp.minimum((length + bs - 1) // bs, maxb)
-    low = 0 if window is None else jnp.maximum(length - window, 0)
-    first_kb = 0 if window is None else jnp.minimum(low // bs, num_kb)
+    first_kb = 0 if low is None else jnp.minimum(low // bs, num_kb)
+    if not quant:
+        seg, expand, seg_dot = _decode_seg_helpers(
+            h, d, jnp.bfloat16 if kbuf.dtype == jnp.bfloat16
+            else jnp.float32)
+        kup_f = ublk[0].astype(jnp.float32)
+        vup_f = ublk[1].astype(jnp.float32)
 
     def copies(slot_i, kb):
         b_kb = jnp.clip(tbl_ref[r, kb], 0, nb - 1)
@@ -287,19 +321,6 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                                       kbuf.at[slot_i], sem.at[slot_i, 0]),
                 pltpu.make_async_copy(v_hbm.at[pl.ds(b_kb, 1)],
                                       vbuf.at[slot_i], sem.at[slot_i, 1]))
-
-    def seen_at(kb, shape, axis):
-        pos = kb * bs + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
-        seen = pos < length
-        if window is not None:
-            seen &= pos >= low
-        return seen
-
-    if groups > 1:
-        _grouped_stream(q_ref, o_ref, kbuf, vbuf, ublk, copies, seen_at,
-                        valid, tkb, first_kb, num_kb, bs=bs, h=h, d=d,
-                        scale=scale)
-        return
 
     qf = q_ref[...].astype(jnp.float32)                  # [1, 1, hd]
 
@@ -314,7 +335,8 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                               exact=True)
         kf = jnp.where(is_t, kup_f, kf)
         s = seg_dot(kf * qf, seg) * scale                # [1, bs, h]
-        s = jnp.where(seen_at(kb, (1, bs, h), 1), s, _NEG_INF)
+        pos = kb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs, h), 1)
+        s = jnp.where(seen_at(pos, length), s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -342,29 +364,74 @@ def _ragged_fused_kernel(len_ref, slot_ref, tbl_ref, q_ref, kn_ref, vn_ref,
 _GROUP_ROWS = 8        # a row's group members, padded to one sublane tile
 
 
-def _grouped_stream(q_ref, o_ref, kbuf, vbuf, ublk, copies, seen_at, valid,
-                    tkb, first_kb, num_kb, *, bs, h, d, scale):
-    """Step 2 of `_ragged_fused_kernel` for grouped heads, full precision:
-    q_ref/o_ref are `[1, GP, h*d]`, K/V head j the lane slice j of a
-    streamed `[1, bs, h*d]` block.  Per block and head, two MXU products
-    with the group members as rows; state per head m, l `[GP, 1]` and acc
-    `[GP, d]` in float32."""
-    gp = _GROUP_ROWS
-    q = q_ref[0]                                          # [GP, hd]
-    heads = [slice(j * d, (j + 1) * d) for j in range(h)]
+def _head_stream(r, tbl_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
+                 kbuf, vbuf, sem, length, valid, low, seen_at, *, bs, h, d,
+                 nb, maxb, scale):
+    """Step 2 of `_ragged_fused_kernel` where a K/V head is whole lane
+    tiles of a full-precision pool row.  q_ref/o_ref are `[1, GP, h*d]`:
+    the query heads that read K/V head j are the rows of lane segment j
+    (member g is query head j * G + g), padded with zero rows to GP = 8
+    sublanes - or `[1, 1, h*d]` for a row with as many query heads as K/V
+    heads, a group of one, whose row is repeated GP times here and put
+    out once.  A step takes a TILE of `kbuf.shape[2] // bs` table entries,
+    their blocks gathered by as many DMAs into one `[tile * bs, h*d]`
+    buffer, and runs per K/V head two MXU products over it with the
+    members as their rows - `q_j [GP, d] x K_j^T` and `p [GP, T] x V_j` -
+    with the online-softmax state kept per head: m, l `[GP, 1]` and acc
+    `[GP, d]` in float32.  Two tiles a loop iteration, every DMA started
+    and waited on inside it (`_two_block_dma_loop`, its blocks here being
+    tiles counted from the stream's first table entry).
 
-    def step(sl, kb, carry):
-        kd, vd = copies(sl, kb)
-        kd.wait()
-        is_t = valid & (kb == tkb)
-        # the row's own new token lives in the updated VMEM copy of its
-        # target block, never in what was streamed through the alias
-        k_blk = jnp.where(is_t, ublk[0], kbuf[sl])[0]     # [bs, hd]
-        seen = seen_at(kb, (gp, bs), 1)
+    The row's new token is the state the stream starts from (its logit
+    from `kn_ref`, its value from `vn_ref`), and position `length - 1` is
+    masked in what is streamed.  Entries of a row's last tile past its
+    last block fetch that last block again: whatever lands in a buffer is
+    a block the row owns, and every position past the row's end is
+    masked, so no product ever meets a buffer that was not filled."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    gp = _GROUP_ROWS
+    t_rows = kbuf.shape[2]
+    tile = t_rows // bs
+    members = q_ref.shape[1]                              # GP, or 1
+    lanes_of = [slice(j * d, (j + 1) * d) for j in range(h)]
+    # sliced from the ref head by head (a lane slice of a loaded one-row
+    # value has no Mosaic layout)
+    q_of = [jnp.broadcast_to(q_ref[0, :, lanes], (gp, d))
+            for lanes in lanes_of]
+    has_new = valid & (length > 0)
+    # positions the stream answers for: the new token's own is the state
+    n_old = jnp.where(has_new, length - 1, length)
+    num_kb = jnp.minimum((n_old + bs - 1) // bs, maxb)
+    first_kb = 0 if low is None else jnp.minimum(low // bs, num_kb)
+
+    def copies(slot_i, t):          # the tile's K descriptors, then its V
+        k_dmas, v_dmas = [], []
+        for i in range(tile):
+            kb = jnp.minimum(first_kb + t * tile + i, num_kb - 1)
+            b_kb = jnp.clip(tbl_ref[r, kb], 0, nb - 1)
+            rows = pl.ds(i * bs, bs)
+            k_dmas.append(pltpu.make_async_copy(
+                k_hbm.at[pl.ds(b_kb, 1)], kbuf.at[slot_i, :, rows],
+                sem.at[slot_i, 0]))
+            v_dmas.append(pltpu.make_async_copy(
+                v_hbm.at[pl.ds(b_kb, 1)], vbuf.at[slot_i, :, rows],
+                sem.at[slot_i, 1]))
+        return k_dmas + v_dmas
+
+    def step(sl, t, carry):
+        dmas = copies(sl, t)
+        for c in dmas[:tile]:
+            c.wait()
+        k_t = kbuf[sl, 0]                                 # [T, hd]
+        pos = ((first_kb + t * tile) * bs
+               + jax.lax.broadcasted_iota(jnp.int32, (gp, t_rows), 1))
+        seen = seen_at(pos, n_old)
         half = []
-        for (m, l, _), lanes in zip(carry, heads):
-            s = _dot_f32(q[:, lanes], k_blk[:, lanes],
-                         transpose_b=True) * scale        # [GP, bs]
+        for (m, l, _), q_j, lanes in zip(carry, q_of, lanes_of):
+            s = _dot_f32(q_j, k_t[:, lanes],
+                         transpose_b=True) * scale        # [GP, T]
             s = jnp.where(seen, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -372,27 +439,41 @@ def _grouped_stream(q_ref, o_ref, kbuf, vbuf, ublk, copies, seen_at, valid,
             half.append((m_new, alpha * l + jnp.sum(p, axis=1,
                                                     keepdims=True),
                          p, alpha))
-        vd.wait()
-        v_blk = jnp.where(is_t, ublk[1], vbuf[sl])[0]
+        for c in dmas[tile:]:
+            c.wait()
+        v_t = vbuf[sl, 0]
         return tuple(
             (m_new, l_new,
-             acc * alpha + _dot_f32(p.astype(v_blk.dtype), v_blk[:, lanes]))
+             acc * alpha + _dot_f32(p.astype(v_t.dtype), v_t[:, lanes]))
             for (m_new, l_new, p, alpha), (_, _, acc), lanes
-            in zip(half, carry, heads))
+            in zip(half, carry, lanes_of))
 
-    head0 = (jnp.full((gp, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((gp, 1), jnp.float32),
-             jnp.zeros((gp, d), jnp.float32))
-    done = _two_block_dma_loop(num_kb, copies, step, (head0,) * h,
-                               first_kb=first_kb)
-    for (_, l, acc), lanes in zip(done, heads):
-        o_ref[0, :, lanes] = (acc / jnp.maximum(l, 1e-30)).astype(
+    def new_row(ref, lanes):          # [1, d] float32, as the pool keeps it
+        return ref[0, :, lanes].astype(kbuf.dtype).astype(jnp.float32)
+
+    state0 = tuple(
+        (jnp.where(has_new, scale * jnp.sum(
+            q_j.astype(jnp.float32) * new_row(kn_ref, lanes),
+            axis=1, keepdims=True), _NEG_INF),
+         jnp.where(has_new, jnp.ones((gp, 1), jnp.float32), 0.0),
+         jnp.where(has_new, jnp.broadcast_to(new_row(vn_ref, lanes), (gp, d)),
+                   0.0))
+        for q_j, lanes in zip(q_of, lanes_of))
+    n_tiles = (num_kb - first_kb + tile - 1) // tile
+    done = _two_block_dma_loop(n_tiles, copies, step, state0)
+    for (_, l, acc), lanes in zip(done, lanes_of):
+        o_ref[0, :, lanes] = (acc / jnp.maximum(l, 1e-30))[:members].astype(
             o_ref.dtype)
 
 
+# jitted on its own: a model's layers call it with the same shapes, and the
+# kernel (the per-head body unrolls 16 or 32 heads, twice) is then traced
+# and lowered once a program, not once a layer - 0.3 s a layer on the host
+# of a v5e, which at 24 layers showed in a server's set-up (PERF.md, PR 29)
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
 def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
                         pos0, kv_lens, slots, k_scales, v_scales, scale,
-                        window=None):
+                        window=None, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -409,6 +490,8 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     slots_i = jnp.asarray(slots, jnp.int32).reshape(b)
     row = pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0))
     pool = pl.BlockSpec(memory_space=pltpu.HBM)
+    heads = _head_products_ok(d, quant)
+    t_rows = max(1, _TILE_TOKENS // bs) * bs if heads else bs
     if g == 1:
         q_row, q_members = row, q.reshape(b, c, hd)
     else:
@@ -448,8 +531,8 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
         args += [jnp.take(new_scales[0], safe_tbl, axis=0),
                  jnp.take(new_scales[1], safe_tbl, axis=0)] + row_scales
     scratch = [
-        pltpu.VMEM((2, 1, bs, hd), pool_dt),      # k stream, two blocks
-        pltpu.VMEM((2, 1, bs, hd), pool_dt),      # v stream, two blocks
+        pltpu.VMEM((2, 1, t_rows, hd), pool_dt),  # k stream, two tiles
+        pltpu.VMEM((2, 1, t_rows, hd), pool_dt),  # v stream, two tiles
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.VMEM((2, 1, bs, hd), pool_dt),      # target block k/v
         pltpu.SemaphoreType.DMA((2,)),
@@ -463,7 +546,7 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     )
     kernel = functools.partial(_ragged_fused_kernel, bs=bs, h=h, d=d,
                                nb=nb, maxb=maxb, scale=scale, quant=quant,
-                               window=window, groups=g)
+                               window=window, heads=heads)
     # aliasing indices INCLUDE the scalar-prefetch args (lens=0, slots=1,
     # tables=2, q=3, k_new=4, v_new=5, pools=6/7)
     outs = pl.pallas_call(
@@ -474,7 +557,7 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
                    jax.ShapeDtypeStruct((nb, bs, hd), pool_dt),
                    jax.ShapeDtypeStruct((nb, bs, hd), pool_dt)],
         input_output_aliases={6: 1, 7: 2},
-        interpret=_interpret(),
+        interpret=interpret,
     )(lens_i, slots_i, tbl, *args)
     o = outs[0].reshape(b, c, h_q, d) if g == 1 else jnp.swapaxes(
         outs[0][:, :g].reshape(b, g, h, d), 1, 2).reshape(b, c, h_q, d)
@@ -593,7 +676,8 @@ def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
     if _ragged_kernel_ok(q, k_blocks, c, quant, window):
         return _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks,
                                    block_table, pos0, kv_lens, slots,
-                                   k_scales, v_scales, scale, window)
+                                   k_scales, v_scales, scale, window,
+                                   interpret=_interpret())
     if not quant:
         # bitwise the reference composition — the fp parity contract
         k2 = paged_cache_update_arrays(k_blocks, k_new, slots)

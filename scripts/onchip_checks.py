@@ -28,6 +28,17 @@ kernels at the published afmoe shapes (48 query heads over 8 K/V heads of
 with what the reference's deliberate faults read against the same tokens.
 The default run includes the kernel calls, not the engine leg.
 
+`--cells` times the decode kernel alone at the calls of the three serving
+cells (chat: 16 rows x 16 heads over `[2048,16,2048]`; docqa: 8 rows x 32
+heads over `[1024,16,4096]`; afmoe: 32 rows x 48-over-8 heads over
+`[2080,64,1024]` with the window and `[4224,64,1024]` without), checks
+each against the XLA fallback, and prints ms a call, us a block and GB/s
+of live K/V.  `--cells --split` times it again with its matrix products
+cut out (DMA only), with its DMAs cut out (math only) and with rows of
+one token (what a row costs before its stream), and, where the call takes
+the per-head body, once more with the segment-indicator body forced.
+`--tree DIR` as above: the parent's kernel, same inputs.
+
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
 stops there.  That catches Mosaic refusals from a CPU-only sandbox; it
@@ -48,6 +59,8 @@ WIDTHS = {"hd768": (12, 64), "hd2048": (16, 128)}
 AOT = "--aot" in sys.argv[1:]
 WRITES_ONLY = "--writes" in sys.argv[1:]
 AFMOE_ONLY = "--afmoe" in sys.argv[1:]
+CELLS_ONLY = "--cells" in sys.argv[1:]
+SPLIT = "--split" in sys.argv[1:]
 AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
 
@@ -173,7 +186,6 @@ def check_ragged(wname, h, d, quant):
     """The serving decode kernel against its own XLA fallback
     (PTPU_RAGGED_KERNEL=0): mixed lengths incl. a block-aligned row, a
     one-token row and a padding row; outputs AND the updated pools."""
-    import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import ragged_paged_attention as rp
 
@@ -213,12 +225,7 @@ def check_ragged(wname, h, d, quant):
         _compile_only(call, [q, kn, vn, kb, vb, *scales])
         print(f"OK {name}", flush=True)
         return
-    got = jax.jit(call)(q, kn, vn, kb, vb, *scales)
-    os.environ["PTPU_RAGGED_KERNEL"] = "0"        # the gate reads it per trace
-    try:
-        want = jax.jit(call)(q, kn, vn, kb, vb, *scales)
-    finally:
-        del os.environ["PTPU_RAGGED_KERNEL"]
+    got, want = _kernel_and_fallback(call, (q, kn, vn, kb, vb, *scales))
     live = np.asarray(lens) > 0
     out, out_ref = (np.asarray(x[0], np.float32)[live] for x in (got, want))
     assert np.isfinite(out).all(), name
@@ -234,13 +241,47 @@ def check_ragged(wname, h, d, quant):
     print(f"OK {name}", flush=True)
 
 
+def _kernel_and_fallback(call, args):
+    """`call(*args)` through the decode kernel, then through its XLA
+    fallback (PTPU_RAGGED_KERNEL=0).  The gate reads the variable while
+    it is traced, and `jit` keeps a trace per FUNCTION: each side gets a
+    function of its own, or the second would be the first's program."""
+    import jax
+
+    got = jax.jit(lambda *a: call(*a))(*args)
+    os.environ["PTPU_RAGGED_KERNEL"] = "0"
+    try:
+        want = jax.jit(lambda *a: call(*a))(*args)
+    finally:
+        del os.environ["PTPU_RAGGED_KERNEL"]
+    return got, want
+
+
+def _ragged_vs_fallback(name, call, args, live):
+    """`call(*args)` through the decode kernel against the same call
+    through its XLA fallback: the `live` rows' outputs row by row, the
+    pools bit for bit."""
+    got, want = _kernel_and_fallback(call, args)
+    out, out_ref = (np.asarray(x[0], np.float32)[live] for x in (got, want))
+    assert np.isfinite(out).all(), name
+    # over thousands of keys the output is a small mean of values: judge
+    # it by its own size, row by row (bf16 operands: under 2%)
+    rel = np.sqrt(((out - out_ref) ** 2).mean((1, 2, 3))
+                  / (out_ref ** 2).mean((1, 2, 3)))
+    assert rel.max() < 2e-2, f"{name}: relative error a row {rel}"
+    for g, w in zip(got[1:], want[1:]):
+        assert (np.asarray(g, np.float32) == np.asarray(w, np.float32)
+                ).all(), f"{name}: pools differ"
+    print(f"OK {name} (relative rms error a row, worst {rel.max():.4f})",
+          flush=True)
+
+
 def check_ragged_grouped(window):
     """The decode kernel with 48 query heads over pools of 8 K/V heads,
     with and without the window, against its XLA fallback: lengths 1, and
     4095, 4096, 4097 either side of the window, 8448, and a padding row;
     table entries wholly behind a window point nowhere, as the window
     group leaves them."""
-    import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import ragged_paged_attention as rp
 
@@ -277,25 +318,161 @@ def check_ragged_grouped(window):
         _compile_only(call, [q, kn, vn, kb, vb])
         print(f"OK {name}", flush=True)
         return
-    got = jax.jit(call)(q, kn, vn, kb, vb)
-    os.environ["PTPU_RAGGED_KERNEL"] = "0"
-    try:
-        want = jax.jit(call)(q, kn, vn, kb, vb)
-    finally:
-        del os.environ["PTPU_RAGGED_KERNEL"]
-    rows = np.asarray(lens) > 0
-    out, out_ref = (np.asarray(x[0], np.float32)[rows] for x in (got, want))
-    assert np.isfinite(out).all(), name
-    # over thousands of keys the output is a small mean of values: judge
-    # it by its own size, row by row (bf16 operands: under 2%)
-    rel = np.sqrt(((out - out_ref) ** 2).mean((1, 2, 3))
-                  / (out_ref ** 2).mean((1, 2, 3)))
-    assert rel.max() < 2e-2, f"{name}: relative error a row {rel}"
-    for g, w in zip(got[1:], want[1:]):
-        assert (np.asarray(g, np.float32) == np.asarray(w, np.float32)
-                ).all(), f"{name}: pools differ"
-    print(f"OK {name} (relative rms error a row, worst {rel.max():.4f})",
-          flush=True)
+    _ragged_vs_fallback(name, call, (q, kn, vn, kb, vb), lens > 0)
+
+
+# the decode kernel's calls in the serving cells (BENCHMARK.json): rows,
+# query heads over K/V heads of 128, block size, pool blocks, table width,
+# window, live tokens a row (chat: mean 448; docqa: mean 1120; afmoe:
+# prompts of 1k / 4k / 8k at 13:13:6 rows, part-way through an answer)
+_SPREAD16 = [448 + round((r - 7.5) * 37) for r in range(16)]
+_SPREAD8 = [1120 + round((r - 3.5) * 100) for r in range(8)]
+_LONGMIX = [1100] * 13 + [4200] * 13 + [8300] * 6
+CELLS = {
+    "chat": dict(hq=16, hkv=16, bs=16, nb=2048, maxb=128, window=None,
+                 lens=_SPREAD16),
+    "docqa": dict(hq=32, hkv=32, bs=16, nb=1024, maxb=128, window=None,
+                  lens=_SPREAD8),
+    "afmoe_window": dict(hq=48, hkv=8, bs=64, nb=2080, maxb=132, window=4096,
+                         lens=_LONGMIX),
+    "afmoe_full": dict(hq=48, hkv=8, bs=64, nb=4224, maxb=132, window=None,
+                       lens=_LONGMIX),
+}
+
+
+class _NoCopy:
+    """A DMA descriptor that moves nothing (`--split`, math only)."""
+
+    def start(self, *a, **k):
+        pass
+
+    wait = start
+
+
+def _cut(rp, what):
+    """Patches, as (object, attribute, value), that take one half of the
+    decode kernel out of its trace: `dma_only` makes every matrix product
+    of the stream a constant (the blocks still arrive, nothing reads
+    them but the one elementwise pass the segment body makes over V),
+    `math_only` makes every DMA a no-op (the products run over whatever
+    the buffers hold), `segment_body` sends a call that would take the
+    per-head products through the segment-indicator body."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    if what == "dma_only":
+        def dot0(a, b, transpose_b=False):
+            return jnp.zeros((a.shape[0], b.shape[0 if transpose_b else 1]),
+                             jnp.float32)
+
+        helpers = rp._decode_seg_helpers
+
+        def helpers0(h, d, fast):
+            seg, expand, _ = helpers(h, d, fast)
+            return seg, expand, lambda a3, mat, exact=False: jnp.zeros(
+                a3.shape[:2] + (mat.shape[1],), jnp.float32)
+
+        return [(rp, "_dot_f32", dot0), (rp, "_decode_seg_helpers", helpers0)]
+    if what == "math_only":
+        return [(pltpu, "make_async_copy", lambda *a, **k: _NoCopy())]
+    if what == "segment_body":
+        return [(rp, "_head_products_ok", lambda *a, **k: False)]
+    return []
+
+
+def check_ragged_cell(cell):
+    """One serving cell's decode-kernel call, alone: against the XLA
+    fallback once, then timed as the engine runs it - 24 of them in
+    one program, the pools handed from one to the next in place."""
+    import contextlib
+    import time
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ragged_paged_attention as rp
+
+    c = CELLS[cell]
+    hq, hkv, bs, nb, maxb, window = (c[k] for k in (
+        "hq", "hkv", "bs", "nb", "maxb", "window"))
+    d, calls = 128, 24           # a 24-layer program's worth, back to back
+    rng = np.random.RandomState(17)
+
+    def inputs(lens):
+        lens = np.asarray(lens, np.int32)
+        b = len(lens)
+        kb = np.arange(maxb)[None] * bs
+        live = kb < lens[:, None]
+        if window:
+            live &= kb + bs > lens[:, None] - window
+        tables = np.full((b, maxb), -1, np.int32)
+        tables[live] = rng.permutation(nb)[:live.sum()]
+        p = lens - 1
+        slots = (tables[np.arange(b), p // bs] * bs + p % bs)[:, None]
+        seen = np.minimum(lens, window) if window else lens
+        return (tuple(jnp.asarray(x) for x in (tables, p, lens, slots)),
+                int(live.sum()), int(seen.sum()))
+
+    q = _randn(rng, (len(c["lens"]), 1, hq, d), jnp.bfloat16)
+    kn, vn = (_randn(rng, (len(c["lens"]), 1, hkv, d), jnp.bfloat16)
+              for _ in range(2))
+    kw = {"window": window} if window else {}
+
+    def pools():        # made on the device
+        return tuple(jax.random.normal(k, (nb, bs, hkv * d), jnp.bfloat16)
+                     for k in jax.random.split(jax.random.PRNGKey(5)))
+
+    def program(idx):
+        def run(kb, vb):
+            acc = jnp.zeros(q.shape, jnp.float32)
+            for i in range(calls):
+                o, kb, vb = rp.ragged_paged_attention_arrays(
+                    q + jnp.asarray(i, q.dtype), kn, vn, kb, vb, *idx, **kw)
+                acc += o.astype(jnp.float32)
+            return acc, kb, vb
+        return jax.jit(run, donate_argnums=(0, 1))
+
+    idx, blocks, tokens = inputs(c["lens"])
+    name = f"cell_{cell}"
+    if AOT:
+        _compile_only(lambda kb, vb: program(idx)(kb, vb), list(pools()))
+        print(f"OK {name}", flush=True)
+        return
+    _ragged_vs_fallback(
+        name, lambda kb, vb: rp.ragged_paged_attention_arrays(
+            q, kn, vn, kb, vb, *idx, **kw), pools(),
+        np.asarray(c["lens"]) > 0)
+
+    def timed(what, lens=None):
+        i, n_blocks, n_tokens = inputs(lens) if lens else (idx, blocks,
+                                                           tokens)
+        with contextlib.ExitStack() as cut:
+            for obj, attr, value in _cut(rp, what):
+                cut.enter_context(mock.patch.object(obj, attr, value,
+                                                    create=True))
+            jax.clear_caches()      # the kernel call is traced once a shape
+            fn = program(i)
+            state = jax.block_until_ready(fn(*pools()))[1:]
+        reps = 5
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            state = fn(*state)[1:]
+        jax.block_until_ready(state)
+        call = (time.perf_counter() - t0) / (reps * calls)
+        kv_bytes = n_tokens * hkv * d * 2 * 2
+        print(f"CELL {cell} {what + (' one-token rows' if lens else '')}: "
+              f"{call * 1e3:.4f} ms a call, {call * 1e6 / n_blocks:.3f} us a "
+              f"block ({n_blocks} blocks of {bs}, {len(i[2])} rows), "
+              f"{kv_bytes / call / 1e9:.1f} GB/s of live K/V", flush=True)
+        jax.clear_caches()          # no later trace may meet a cut kernel
+
+    timed("as_is")
+    if SPLIT:
+        timed("dma_only")
+        timed("math_only")
+        timed("as_is", lens=[1] * len(c["lens"]))
+        if hasattr(rp, "_head_products_ok") and bs < 64:
+            timed("segment_body")
 
 
 def check_flash_grouped(window, s=8192):
@@ -631,9 +808,12 @@ def main():
                     (wname, h, d)) for wname, (h, d) in WIDTHS.items()]
     if WRITES_ONLY:
         checks = [c for c in checks if c[1] is check_writes]
+    if CELLS_ONLY:
+        checks = [(f"check_ragged_cell_{c}", check_ragged_cell, (c,))
+                  for c in CELLS]
     for name, fn, args in checks:
-        with _Watchdog(name, 900.0 if fn in (check_writes,
-                                             check_afmoe_engine) else 240.0):
+        with _Watchdog(name, 900.0 if fn in (check_writes, check_afmoe_engine,
+                                             check_ragged_cell) else 240.0):
             fn(*args)
     print("ALL AOT COMPILES OK" if AOT else "ALL ONCHIP CHECKS OK")
 

@@ -214,6 +214,17 @@ def _kernel_case(quant, seed=0):
             jnp.asarray(lens), jnp.asarray(slots), ks, vs)
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included (the
+    kernel call is a `jit` of its own inside the caller's program)."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
 class TestRaggedKernelInterpret:
     def test_fp_kernel_matches_reference(self, _interpret_mode):
         (q, kn, vn, kb, vb, tables, pos0, lens, slots,
@@ -274,13 +285,7 @@ class TestRaggedKernelInterpret:
             lambda *a: rp.ragged_paged_attention_arrays(*a, **kw))(
             q, kn, vn, kb, vb, tables, pos0, lens, slots)
 
-        def equations(jaxpr):
-            for eqn in jaxpr.eqns:
-                yield eqn
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    yield from equations(sub)
-
-        eqns = list(equations(closed.jaxpr))
+        eqns = list(_equations(closed.jaxpr))
         assert any(e.primitive.name == "pallas_call" for e in eqns)
         relaid = [
             str(e) for e in eqns
@@ -343,6 +348,171 @@ class TestRaggedKernelInterpret:
         assert c.get("ragged_fallback:head_geometry") == 1
         assert c.get("ragged_fallback:block_size") == 1
         assert c.get("ragged_fallback:disabled") == 1
+
+
+# -- the per-head body over a 64-token tile (PR 29) ---------------------------
+
+def _tile_case(lens, *, bs, hq=2, hkv=2, d=128, nb_spare=3, seed=0,
+               window=None, dtype=np.float32):
+    """Decode rows of the given lengths (0: a padding row, slot -1) over
+    pools in which EVERY position no row can see is NaN: blocks no row
+    owns, and - with `window` - the blocks wholly behind it, which the
+    table names -1 as the window group leaves them.  Table entries past a
+    row's end are -1.  A kernel that multiplies a buffer it did not fill,
+    or a block it should not have fetched, by a zero weight puts NaN out."""
+    rng = np.random.RandomState(seed)
+    b = len(lens)
+    maxb = max(-(-n // bs) for n in lens) + 2
+    need = [-(-n // bs) for n in lens]
+    nb = sum(need) + nb_spare
+    perm = list(rng.permutation(nb))
+    tables = np.full((b, maxb), -1, np.int32)
+    slots = np.full((b, 1), -1, np.int32)
+    kb = np.full((nb, bs, hkv * d), np.nan, dtype)
+    vb = np.full((nb, bs, hkv * d), np.nan, dtype)
+    for r, n in enumerate(lens):
+        first = 0 if window is None else max(n - window, 0) // bs
+        for j in range(first, need[r]):
+            blk = tables[r, j] = perm.pop()
+            kb[blk] = rng.randn(bs, hkv * d)
+            vb[blk] = rng.randn(bs, hkv * d)
+        if n:
+            slots[r, 0] = tables[r, (n - 1) // bs] * bs + (n - 1) % bs
+    f = lambda *s: jnp.asarray(rng.randn(*s).astype(dtype))      # noqa: E731
+    lens = jnp.asarray(lens, jnp.int32)
+    return (f(b, 1, hq, d), f(b, 1, hkv, d), f(b, 1, hkv, d),
+            jnp.asarray(kb), jnp.asarray(vb), jnp.asarray(tables),
+            jnp.maximum(lens - 1, 0), lens, jnp.asarray(slots))
+
+
+def _against_fallback(args, window=None, tol=2e-6):
+    """The kernel (interpret mode) against the XLA reference composition,
+    row by row; padding rows put out zeros; the pools are written bit for
+    bit as `paged_cache_update_arrays` writes them."""
+    q, kn, vn, kb, vb, tables, pos0, lens, slots = args
+    kw = {} if window is None else {"window": window}
+    out, k2, v2 = rp.ragged_paged_attention_arrays(*args, **kw)
+    k2r = paged_cache_update_arrays(kb, kn, slots)
+    v2r = paged_cache_update_arrays(vb, vn, slots)
+    for got, ref in ((k2, k2r), (v2, v2r)):     # (NaN == NaN as float32)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32))
+    # the reference gathers every table entry: give it pools without the
+    # NaN (it masks those positions; the kernel must never have met them)
+    want = paged_attention_arrays(q, jnp.nan_to_num(k2r), jnp.nan_to_num(v2r),
+                                  tables, pos0, **kw)
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    for r, n in enumerate(np.asarray(lens)):
+        if n:
+            np.testing.assert_allclose(out[r], want[r], rtol=tol, atol=tol,
+                                       err_msg=f"row {r}, length {n}")
+        else:
+            np.testing.assert_array_equal(out[r], 0.0)
+
+
+# a row of every length around a block's and a tile's edge, a long one,
+# and a padding row between them
+TILE_LENS = [1, 15, 16, 17, 63, 0, 64, 65, 100, 448]
+
+
+class TestHeadProductsOverTiles:
+    @pytest.fixture(autouse=True)
+    def _counted(self, _interpret_mode, monkeypatch):
+        from paddle_tpu.ops import pallas_ops as po
+
+        monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
+        po.reset_attention_path_counts()
+        self.counts = po.attention_path_counts
+
+    @pytest.mark.parametrize("bs", [16, 32])
+    def test_one_member_every_length(self, bs):
+        """`g == 1`, d 128: lengths {1, 15, 16, 17, 63, 64, 65, 100, 448}
+        and a padding row in one batch; entries past a row's end -1, every
+        block no row owns NaN."""
+        _against_fallback(_tile_case(TILE_LENS, bs=bs))
+        assert self.counts().get("ragged_kernel:head_products") == 1
+        assert "ragged_kernel:segment_products" not in self.counts()
+
+    @pytest.mark.parametrize("length", [130, 146, 162, 178, 129, 192])
+    def test_write_slot_in_each_block_of_a_tile(self, length):
+        """The new token lands in the first, second, third and fourth
+        block of the row's last tile (`bs` 16: tiles of 64 from 0), at a
+        block's first position (129 = 128 + 1) and its last (192)."""
+        _against_fallback(_tile_case([length, 64], bs=16, seed=length))
+
+    @pytest.mark.parametrize("bs,window", [(16, 100), (16, 64), (32, 70),
+                                           (16, 17)])
+    def test_window_starts_inside_a_tile(self, bs, window):
+        """`first_kb` of the window is not a multiple of the tile (length
+        448, window 100, `bs` 16: block 21 of tiles of 4), the blocks
+        behind it are -1 in the table and NaN in the pool."""
+        lens = [448, 200, 65, 17, 0, 101]
+        assert any(max(n - window, 0) // bs % (64 // bs) for n in lens)
+        _against_fallback(_tile_case(lens, bs=bs, window=window),
+                          window=window)
+
+    @pytest.mark.parametrize("bs", [16, 32, 64])
+    def test_grouped_heads_over_tiles(self, bs):
+        """Six query heads over one K/V head (afmoe's group) at the block
+        sizes a user may set for it."""
+        _against_fallback(_tile_case([1, 63, 64, 65, 0, 300], bs=bs, hq=6,
+                                     hkv=1, window=None))
+        _against_fallback(_tile_case([1, 63, 64, 65, 0, 300], bs=bs, hq=6,
+                                     hkv=1, window=128), window=128)
+        assert self.counts().get("ragged_kernel:head_products") == 2
+
+    def test_bfloat16_pools(self):
+        """The served dtype: bf16 operands, float32 state - against the
+        same reference within bf16's rounding of the probabilities."""
+        _against_fallback(_tile_case(TILE_LENS, bs=16, hq=4, hkv=4,
+                                     dtype=jnp.bfloat16), tol=2e-2)
+
+    def test_evicted_row_streams_its_pool(self):
+        """A live row whose write slot is out of range (evicted between
+        schedule and run): nothing is written, and it attends over what
+        the pool holds for its `length` positions."""
+        args = list(_tile_case([70, 33], bs=16))
+        slots = np.asarray(args[8]).copy()
+        slots[0, 0] = args[3].shape[0] * 16          # out of range
+        args[8] = jnp.asarray(slots)
+        _against_fallback(tuple(args))
+
+    @pytest.mark.parametrize("quant,d", [(False, 64), (True, 64),
+                                         (True, 128)],
+                             ids=["fp-d64", "int8-d64", "int8-d128"])
+    def test_segment_body_kept(self, quant, d):
+        """Heads of 64 lanes and the int8 pools keep the segment body;
+        the choice reads head dim and pool dtype, nothing else."""
+        q = jnp.zeros((2, 1, 2, d), jnp.float32)
+        pool = jnp.zeros((4, 32, 2 * d), jnp.int8 if quant else jnp.float32)
+        assert rp._ragged_kernel_ok(q, pool, 1, quant)
+        assert self.counts().get("ragged_kernel:segment_products") == 1
+        assert "ragged_kernel:head_products" not in self.counts()
+
+    def test_tile_follows_block_size(self):
+        """`_TILE_TOKENS // block_size` table entries a step: the stream
+        buffers of the traced kernel are `[2, 1, 64, h*d]` at `bs` 16 and
+        32, one block at 64 and beyond, and one block for the segment
+        body."""
+        import jax
+
+        def stream_rows(bs, d=128, dtype=jnp.float32):
+            args = _tile_case([5], bs=bs, d=d, dtype=dtype)
+            jaxpr = jax.make_jaxpr(rp.ragged_paged_attention_arrays)(*args)
+            [call] = [e for e in _equations(jaxpr.jaxpr)
+                      if e.primitive.name == "pallas_call"]
+            # scratch follows the inputs and outputs: K stream, V stream,
+            # semaphores, the target block
+            k_stream, v_stream = [v.aval.shape for v in
+                                  call.params["jaxpr"].invars
+                                  if len(v.aval.shape) == 4][:2]
+            assert k_stream == v_stream and k_stream[:2] == (2, 1)
+            return k_stream[2]
+
+        assert rp._TILE_TOKENS == 64
+        assert [stream_rows(bs) for bs in (16, 32, 64, 128)] == [
+            64, 64, 64, 128]
+        assert stream_rows(16, d=64) == 16
 
 
 # ---------------------------------------------------------------------------

@@ -77,10 +77,17 @@ def _pool_sized(hlo_text, elems):
 def test_ragged_decode_call_moves_no_pool(S, monkeypatch, name):
     """One layer's fused update + attention at C = 1, pools donated: they
     reach the kernel and leave it aliased, and nothing else of their size
-    is computed."""
+    is computed.  At these widths the call takes the per-head products
+    over a tile of four blocks (PR 29): Mosaic accepts the four DMAs into
+    row slices `[i*16, (i+1)*16)` of one `[64, H*D]` buffer and the 16 /
+    32 unrolled heads' lane slices of it."""
+    from paddle_tpu.ops import pallas_ops as po
+
     b, h, nb = SHAPES[name]
     maxb = 2048 // BS
     monkeypatch.setattr(rp, "_on_tpu", lambda: True)   # the gate asks JAX
+    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
+    po.reset_attention_path_counts()
     row = S((b, 1, h, D), jnp.bfloat16)
     pool = S((nb, BS, h * D), jnp.bfloat16)
     compiled = jax.jit(rp.ragged_paged_attention_arrays,
@@ -88,6 +95,8 @@ def test_ragged_decode_call_moves_no_pool(S, monkeypatch, name):
         row, row, row, pool, pool, S((b, maxb), jnp.int32),
         S((b,), jnp.int32), S((b,), jnp.int32),
         S((b, 1), jnp.int32)).compile()
+    assert po.attention_path_counts() == {
+        "ragged_kernel": 1, "ragged_kernel:head_products": 1}
     found = _pool_sized(compiled.as_text(), nb * BS * h * D)
     assert found.pop("custom-call") == 1
     assert set(found) <= {"parameter", "get-tuple-element", "bitcast",
@@ -213,10 +222,17 @@ def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
 
     params = jax.tree_util.tree_map(on_chip, eng._param_arrays())
     kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
+    decode = eng._get_ragged_exec(16, 1).lower(
+        params, kv, i32(16, 1), i32(16), i32(16),
+        (i32(16, eng.blocks_per_seq),), (i32(16, 1),)).compile()
+    # the program around the kernel moves no pool (PR 27), with the tile
+    # as without it: each layer's K and V pool enters one kernel call
+    pools = _pool_sized(decode.as_text(), kv[0].size)
+    assert pools.pop("custom-call") == 2, pools
+    assert set(pools) <= {"parameter", "get-tuple-element", "bitcast",
+                          "tuple"}, pools
     got = {
-        "jit_ragged_decode": ops(eng._get_ragged_exec(16, 1).lower(
-            params, kv, i32(16, 1), i32(16), i32(16),
-            (i32(16, eng.blocks_per_seq),), (i32(16, 1),)).compile()),
+        "jit_ragged_decode": ops(decode),
         "jit_prefill_512": ops(eng._get_prefill_exec(512).lower(
             params, kv, i32(1, 512), (i32(1, 512),)).compile())}
     with open(os.path.join(os.path.dirname(__file__), "fixtures",
