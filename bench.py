@@ -382,92 +382,6 @@ def bench_lowbit_kv_decode():
                  q_tps, "tokens/sec", fp_tps)
 
 
-def bench_ragged_decode():
-    """ISSUE 8: ragged vs bucketed paged-serving decode, fp AND int8 KV.
-
-    Four engines on one model: {ragged, bucketed} x {fp, int8-KV}, each
-    warmed (compiles every program its path needs), then the STEADY-STATE
-    full-batch decode step is timed: min over every decode step() of
-    several interleaved passes.  Whole-generate walls proved ungateable
-    on this host (>50% run-to-run drift swamps the A/B),
-    while a min-of-steps measurement of two compiled programs is tight
-    enough for the 50% smoke-lane history gate.  Emits the ragged
-    steps' tokens/s with the SAME config's bucketed run as baseline, so
-    vs_baseline >= 1.0 means the single fixed-shape fused program is at
-    least as fast as the power-of-2-bucketed gather+attend dispatch —
-    on top of its structural win (no bucket recompiles; the recompile
-    cliff itself is pinned by tests/test_ragged_attention.py, not timed
-    here).  The int8 lanes use block_size=32 (the int8 sublane tile) so
-    the fused dequant-at-load Pallas kernel is the path actually timed
-    on a TPU host — at the default block_size=16 the int8 kernel gate
-    declines and the A/B would silently time the XLA fallback."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTForCausalLM, gpt_test_config, \
-        gpt2_124m_config
-    from paddle_tpu.serving import EngineConfig, LLMEngine, SamplingParams
-
-    on_tpu = _on_tpu()
-    cfg = (gpt2_124m_config(stacked_blocks=True) if on_tpu
-           else gpt_test_config(stacked_blocks=True,
-                                sequence_parallel=False))
-    batch, prompt, new = (8, 128, 128) if on_tpu else (4, 8, 16)
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(0, cfg.vocab_size, (prompt,)).astype("int32")
-               for _ in range(batch)]
-    sp = SamplingParams(max_new_tokens=new)
-
-    reps = 3 if on_tpu else 4
-    combos = [("ragged", None), ("bucketed", None),
-              ("ragged", "int8"), ("bucketed", "int8")]
-    engines = {}
-    for impl, kvd in combos:
-        eng = LLMEngine(model, EngineConfig(
-            block_size=32 if kvd else 16, max_num_seqs=batch,
-            kv_cache_dtype=kvd, attention_impl=impl))
-        eng.generate(prompts, sp)          # warmup: compiles every program
-        engines[(impl, kvd)] = eng
-
-    def min_decode_step(eng):
-        """One pass: admit the batch, prefill it, then min() over every
-        full-batch decode step's wall time."""
-        rids = [eng.add_request(p, sp) for p in prompts]
-        try:
-            while any(not eng._requests[r].prefill_done for r in rids):
-                eng.step()
-            best = float("inf")
-            while eng.has_unfinished():
-                t0 = time.perf_counter()
-                eng.step()
-                best = min(best, time.perf_counter() - t0)
-            return best
-        finally:
-            for r in rids:
-                eng.release_request(r)
-
-    # interleaved rounds with alternating order: the four engines take
-    # turns, so shared-host load drift hits every lane alike instead of
-    # whichever engine happened to run last
-    best = {k: float("inf") for k in combos}
-    for i in range(reps):
-        order = combos if i % 2 == 0 else list(reversed(combos))
-        for key in order:
-            best[key] = min(best[key], min_decode_step(engines[key]))
-    fp_ragged = batch / best[("ragged", None)]
-    fp_bucketed = batch / best[("bucketed", None)]
-    q_ragged = batch / best[("ragged", "int8")]
-    q_bucketed = batch / best[("bucketed", "int8")]
-    suffix = "" if on_tpu else "_cpu_smoke"
-    _emit(f"serving_ragged_decode_step_tokens_per_sec{suffix}",
-          fp_ragged, "tokens/sec", fp_bucketed)
-    return _emit(f"serving_ragged_int8_decode_step_tokens_per_sec{suffix}",
-                 q_ragged, "tokens/sec", q_bucketed)
-
-
 def bench_prefix_prefill():
     """ISSUE 15a: cold-vs-hot TTFT for a shared-prefix workload.
 
@@ -1262,7 +1176,6 @@ LADDER = {
     "gpt3_1p3b": bench_gpt3_1p3b,
     "gpt124m_decode": bench_decode,
     "lowbit_kv_decode": bench_lowbit_kv_decode,
-    "ragged_decode": bench_ragged_decode,
     "prefix_prefill": bench_prefix_prefill,
     "spec_decode": bench_spec_decode,
     "kernel_count": bench_kernel_count,

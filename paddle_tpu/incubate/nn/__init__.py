@@ -4,7 +4,6 @@ fused_feedforward_op.cu). TPU-native: flash attention (Pallas) + XLA-fused
 FFN."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from ...nn.layer import Layer
@@ -77,28 +76,10 @@ class FusedFeedForward(Layer):
         self.dropout2 = Dropout(dropout_rate)
         self.activation = activation
 
-    def _ffn(self, x):
-        """act(x @ W1 + b1) @ W2 + b2 — via the row-blocked Pallas kernel
-        (PTPU_PALLAS_FFN=1; the [tokens, I] intermediate never round-trips
-        HBM in the forward) when geometry allows, else XLA."""
-
-        if (self.activation in ("gelu", "relu")
-                # dropout inactive: p == 0 or eval mode (identity)
-                and (self.dropout1.p == 0.0 or not self.training)
-                and self.linear2.bias is not None):
-            from ...ops.pallas_ops import maybe_fused_ffn
-
-            y = maybe_fused_ffn(x, self.linear1.weight, self.linear1.bias,
-                                self.linear2.weight, self.activation)
-            if y is not None:
-                return y + self.linear2.bias
-        return self.linear2(
-            self.dropout1(getattr(F, self.activation)(self.linear1(x))))
-
     def forward(self, src, cache=None):
         residual = src
         x = self.ln(src) if self.normalize_before else src
-        x = self._ffn(x)
+        x = self.linear2(self.dropout1(getattr(F, self.activation)(self.linear1(x))))
         x = residual + self.dropout2(x)
         if not self.normalize_before:
             x = self.ln(x)
@@ -138,65 +119,6 @@ class FusedMultiTransformer(Layer):
         self.dropout = Dropout(dropout_rate)
 
     @staticmethod
-    def _fused_layer_decode(x2, lnw, lnb, wqkv, bqkv, wo, bo, cache, t,
-                            nh, eps):
-        """One layer's decode step through the fused Pallas kernel
-        (reference: fused_multi_transformer_op.cu decode branch — this IS
-        that op's shape): LN1 -> qkv -> ring cache write -> prefix
-        attention -> out-proj -> residual in one launch. cache:
-        [2, B, H, S_max, D] (reference layout), re-viewed flat for the
-        kernel and repacked after."""
-        from ...ops.pallas_ops import fused_decode_layer_arrays
-
-        if cache.ndim == 4:
-            # flat rings [2, B, S_max, H*D] (gen_cache(layout="flat")):
-            # no relayout at all — the kernel's in-place aliasing donates
-            # the REAL cache buffers
-            y, kc2, vc2 = fused_decode_layer_arrays(
-                x2, lnw, lnb, wqkv, bqkv, wo, bo, cache[0], cache[1], t,
-                nh, eps)
-            return y, jnp.stack([kc2, vc2])
-        # reference layout [2, B, H, S_max, D]: per-step relayout copies —
-        # the same cost the unfused _cached_attn path already pays, but it
-        # defeats the kernel's buffer donation; prefer layout="flat"
-        _, b, h, smax, d = cache.shape
-        kc = jnp.moveaxis(cache[0], 1, 2).reshape(b, smax, h * d)
-        vc = jnp.moveaxis(cache[1], 1, 2).reshape(b, smax, h * d)
-        y, kc2, vc2 = fused_decode_layer_arrays(
-            x2, lnw, lnb, wqkv, bqkv, wo, bo, kc, vc, t, nh, eps)
-        new_cache = jnp.stack([
-            jnp.moveaxis(kc2.reshape(b, smax, h, d), 2, 1),
-            jnp.moveaxis(vc2.reshape(b, smax, h, d), 2, 1)])
-        return y, new_cache
-
-    def _fused_decode_ok(self, x, cache):
-        """Gate: S==1 decode, no dropout, uniform bf16/f32 dtypes, kernel
-        geometry (delegates to pallas_ops._fused_decode_layer_ok on the
-        flat cache view). Int8 layers fail the dtype check naturally."""
-        from ...ops.pallas_ops import _fused_decode_layer_ok
-
-        if x.shape[1] != 1 or self.dropout_rate:
-            return False
-        blk = self.layers[0]
-        w = getattr(blk["qkv"], "weight", None)
-        E = x.shape[-1]
-        if (w is None or getattr(w, "ndim", 0) != 2
-                or tuple(w.shape) != (E, 3 * E)):
-            return False   # freed/absent float weights (int8 subclass)
-        if cache._data.ndim == 4:          # flat [2, B, Smax, H*D]
-            _, b, smax, hd = cache.shape
-        elif cache._data.ndim == 5:        # reference [2, B, H, Smax, D]
-            _, b, h, smax, d = cache.shape
-            hd = h * d
-        else:
-            return False
-        # abstract view: the gate only reads shape/dtype
-        kc_view = jax.ShapeDtypeStruct((b, smax, hd), cache._data.dtype)
-        return _fused_decode_layer_ok(
-            jax.ShapeDtypeStruct((b, hd), x.dtype), w._data, kc_view,
-            kc_view, self.num_heads)
-
-    @staticmethod
     def _cached_attn(q, k, v, cache, t, mask=None):
         """Array-level CacheKV attention. cache: [2, B, H, S_max, D]
         (reference layout, fused_multi_transformer_op.cu:90); q/k/v:
@@ -205,10 +127,6 @@ class FusedMultiTransformer(Layer):
         Returns (out, new_cache)."""
         from ...ops.pallas_ops import cached_attention_arrays
 
-        if cache.ndim == 4:          # flat rings [2, B, S_max, H*D]
-            o, kc, vc = cached_attention_arrays(q, k, v, cache[0], cache[1],
-                                                t, mask=mask)
-            return o, jnp.stack([kc, vc])
         kc = jnp.moveaxis(cache[0], 1, 2)        # -> [B, S_max, H, D]
         vc = jnp.moveaxis(cache[1], 1, 2)
         o, kc, vc = cached_attention_arrays(q, k, v, kc, vc, t, mask=mask)
@@ -216,23 +134,13 @@ class FusedMultiTransformer(Layer):
             [jnp.moveaxis(kc, 2, 1), jnp.moveaxis(vc, 2, 1)])
         return o, new_cache
 
-    def gen_cache(self, batch_size, max_length, dtype="float32",
-                  layout="reference"):
-        """Allocate per-layer CacheKV tensors. layout="reference":
+    def gen_cache(self, batch_size, max_length, dtype="float32"):
+        """Allocate per-layer CacheKV tensors:
         [2, bsz, num_head, max_seq_len, head_dim] (the fused op's CUDA
-        layout — kept as the compat default). layout="flat":
-        [2, bsz, max_seq_len, num_head*head_dim] rings — the TPU-native
-        form: decode writes stay contiguous one-row updates, and the
-        fused decode kernel donates the cache buffers in place instead of
-        round-tripping a relayout copy every layer every token."""
+        layout)."""
         from ...core.tensor import Tensor
 
-        if layout == "flat":
-            shape = (2, batch_size, max_length,
-                     self.num_heads * self.head_dim)
-        else:
-            shape = (2, batch_size, self.num_heads, max_length,
-                     self.head_dim)
+        shape = (2, batch_size, self.num_heads, max_length, self.head_dim)
         return [Tensor(jnp.zeros(shape, dtype)) for _ in range(self.num_layers)]
 
     def _proj(self, li, name, x):
@@ -253,31 +161,7 @@ class FusedMultiTransformer(Layer):
         B = None
         new_caches = []
         act = F.gelu if self.activation == "gelu" else F.relu
-        # time_step None is prefill at position 0 — the fused kernel's
-        # prefix contract needs t >= 1, so fused only on true decode steps
-        use_fused = (caches is not None and attn_mask is None
-                     and time_step is not None
-                     and self._fused_decode_ok(x, caches[0]))
         for li, blk in enumerate(self.layers):
-            if use_fused:
-                # whole attention half in ONE Pallas launch per layer
-                # (use_fused guarantees time_step is not None: the fused
-                # kernel's prefix contract excludes the t=0 prefill)
-                t = time_step
-                Bq, _, E = x.shape
-                y, new_cache = apply(
-                    self._fused_layer_decode, x.reshape([Bq, E]),
-                    blk["ln1"].weight, blk["ln1"].bias,
-                    blk["qkv"].weight, blk["qkv"].bias,
-                    blk["out"].weight, blk["out"].bias,
-                    caches[li], t, nh=self.num_heads, eps=self.epsilon,
-                    name="fused_decode_layer")
-                caches[li]._data = new_cache._data
-                new_caches.append(new_cache)
-                x = y.reshape([Bq, 1, E])
-                h = blk["ln2"](x)
-                x = x + self._proj(li, "ffn2", act(self._proj(li, "ffn1", h)))
-                continue
             h = blk["ln1"](x)
             qkv = self._proj(li, "qkv", h)
             if B is None:
@@ -329,12 +213,6 @@ class FusedMultiTransformerInt8(FusedMultiTransformer):
     an existing FusedMultiTransformer, or construct directly and call
     load-state on the float twin before `quantize_()`.
     """
-
-    def _fused_decode_ok(self, x, cache):
-        # the float fused-decode kernel would bypass the int8 GEMM
-        # reroute (and with free_float=False silently use the stale float
-        # weights) — quantized decode keeps its own path
-        return False
 
     def __init__(self, embed_dim, num_heads, dim_feedforward,
                  dropout_rate=0.0, activation="gelu", normalize_before=True,

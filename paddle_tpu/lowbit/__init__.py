@@ -10,8 +10,10 @@ Three wings, one storage convention (`ops/lowbit.py`: symmetric abs-max,
    QAT/PTQ `convert(weight_only=...)` targets it with calibrated scales.
 2. **quantized KV cache** (`serving.BlockKVCache(kv_quant="int8")`,
    `LLMEngine(EngineConfig(kv_cache_dtype="int8"))`) — int8 block pools
-   with per-block-per-head scales, dequantizing gather in
-   `ops/paged_attention.py`; ~halved bytes/block ⇒ ~2× blocks per pool.
+   with per-block-per-head scales, written by
+   `ops/paged_attention.py`'s quantizing pool writer and dequantized at
+   the block loads of `ops/ragged_paged_attention.py` (kernel) or folded
+   into its XLA fallback; ~halved bytes/block ⇒ ~2× blocks per pool.
 3. **quantized collectives** (`comm.py`) — EQuARX-style int8 all-reduce /
    all-gather (shared per-chunk scale, int32 reduction, optional error
    feedback), exposed as `distributed.all_reduce(..., compress="int8")`

@@ -263,21 +263,6 @@ class GPTMLP(Layer):
         )
 
     def forward(self, x):
-        from ..ops.pallas_ops import maybe_fused_ffn
-        from ..parallel.mesh import axis_size as _axis_size
-
-        # single-shard fast path: the row-blocked fused kernel keeps the
-        # [tokens, I] intermediate out of HBM; TP-sharded weights (mp>1)
-        # and quantized projections (lowbit WeightOnlyLinear carries
-        # packed codes, no fp `.weight`) stay on the layer-forward path
-        b2 = self.fc_out.bias
-        if _axis_size("mp") == 1 and b2 is not None \
-                and getattr(self.fc_in, "weight", None) is not None \
-                and getattr(self.fc_out, "weight", None) is not None:
-            y = maybe_fused_ffn(x, self.fc_in.weight, self.fc_in.bias,
-                                self.fc_out.weight, "gelu_tanh")
-            if y is not None:
-                return y + b2
         return self.fc_out(F.gelu(self.fc_in(x), approximate=True))
 
 
@@ -372,43 +357,10 @@ def _stacked_ln(h, w, b, eps):
 
 def _stacked_mlp(p, h, eps):
     """The MLP half of a stacked block (ln2 -> gelu(fc_in) -> fc_out ->
-    residual) — shared by _stacked_block_body and the fused-decode path,
-    which replaces only the attention half with one Pallas call."""
+    residual)."""
     hn = _stacked_ln(h, p["ln2_w"], p["ln2_b"], eps)
     m = jax.nn.gelu(hn @ p["fc_in_w"] + p["fc_in_b"], approximate=True)
     return h + m @ p["fc_out_w"] + p["fc_out_b"]
-
-
-def _stacked_mlp_fused_decode(p, h, eps):
-    """Decode-step MLP through the fused LN + FFN kernels (2 launches
-    instead of the ~8-op XLA chain) — the remaining half of the
-    fused_multi_transformer decode analog. Same arithmetic as
-    _stacked_mlp (gelu_tanh matches its approximate=True); returns None
-    when kernel geometry doesn't hold and the caller falls back."""
-    from ..ops.pallas_ops import (_ln_block_rows, ffn_geometry_ok,
-                                  fused_ffn_arrays, fused_layernorm_arrays,
-                                  ln_geometry_ok)
-
-    # the FFN kernel keeps its own opt-in: composing flags must not make
-    # PTPU_FUSED_DECODE silently enable the unpromoted MLP kernels
-    if os.environ.get("PTPU_PALLAS_FFN") != "1":
-        return None
-    mb, s, H = h.shape
-    I = int(p["fc_in_w"].shape[-1])
-    rows = mb * s
-    # cheap prechecks first so the gate counters only fire when BOTH
-    # kernels will actually run (a lone ln_kernel count with a vetoing
-    # ffn geometry would corrupt the path diagnostics)
-    if not (h.dtype == p["fc_in_w"].dtype == p["fc_out_w"].dtype
-            and H % 128 == 0 and I % 128 == 0
-            and _ln_block_rows(rows) is not None):
-        return None
-    if not (ln_geometry_ok(rows, H) and ffn_geometry_ok(rows, H, I, H)):
-        return None
-    hn = fused_layernorm_arrays(h, p["ln2_w"], p["ln2_b"], eps)
-    m = fused_ffn_arrays(hn, p["fc_in_w"], p["fc_in_b"], p["fc_out_w"],
-                         act="gelu_tanh")
-    return h + m + p["fc_out_b"]
 
 
 def _stacked_block_body(p, h, attn_fn, nh, hd, eps):
@@ -639,9 +591,6 @@ class GPTStackedBlocks(Layer):
         has_cm = cache_mask is not None
 
         def fn(a, t, *flat):
-            from ..ops.pallas_ops import (_fused_decode_layer_ok,
-                                          fused_decode_layer_arrays)
-
             if has_cm:
                 cm, flat = flat[0], flat[1:]
             else:
@@ -649,32 +598,10 @@ class GPTStackedBlocks(Layer):
             cache_flat, params_flat = flat[:2 * L], flat[2 * L:]
             params = dict(zip(names, params_flat))
             h = a
-            # fused per-layer decode (reference fused_multi_transformer
-            # decode branch): LN1 -> qkv -> cache write -> attention ->
-            # out-proj in ONE Pallas call per layer, attacking the
-            # kernel-launch count the decode bisect isolated. Gate is
-            # static per trace (shapes/dtypes identical across layers);
-            # padded batches pass their cache mask into the kernel.
-            fused = (not prefill and h.shape[1] == 1
-                     and _fused_decode_layer_ok(
-                         h[:, 0, :], params["qkv_w"][0], cache_flat[0],
-                         cache_flat[1], nh))
             outs = []
             for l in range(L):
                 kc, vc = cache_flat[2 * l], cache_flat[2 * l + 1]
                 p = {n: params[n][l] for n in names}
-                if fused:
-                    mb, _, H = h.shape
-                    y, kc2, vc2 = fused_decode_layer_arrays(
-                        h.reshape(mb, H), p["ln1_w"], p["ln1_b"],
-                        p["qkv_w"], p["qkv_b"], p["out_w"], p["out_b"],
-                        kc, vc, t, nh, eps, cache_mask=cm)
-                    y3 = y.reshape(mb, 1, H)
-                    h = _stacked_mlp_fused_decode(p, y3, eps)
-                    if h is None:
-                        h = _stacked_mlp(p, y3, eps)
-                    outs += [kc2, vc2]
-                    continue
 
                 def attn_fn(q, k, v, kc=kc, vc=vc):
                     o, kc2, vc2 = _cached_attn_arrays(q, k, v, kc, vc, t,

@@ -8,7 +8,6 @@ compiler + the Pallas kernels in paddle_tpu/ops/pallas_ops.py).
 from __future__ import annotations
 
 import math
-import os
 from functools import partial
 
 import numpy as np
@@ -680,23 +679,6 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     if isinstance(normalized_shape, int):
         normalized_shape = (normalized_shape,)
     naxes = tuple(range(-len(normalized_shape), 0))
-
-    if (len(normalized_shape) == 1 and weight is not None
-            and bias is not None
-            and os.environ.get("PTPU_PALLAS_LN") == "1"):
-        # opt-in fused Pallas path (single-pass row stats; SURVEY §7
-        # phase 7). Flag-gated until the on-chip A/B lands — the XLA
-        # fusion below is already good on this op.
-        from ...ops.pallas_ops import fused_layernorm_arrays, ln_geometry_ok
-
-        n_rows = int(math.prod(x.shape[:-1])) if len(x.shape) > 1 else 1
-        if ln_geometry_ok(n_rows, int(x.shape[-1])):
-            # dispatch under the SAME op name so AMP's black list treats
-            # both paths identically (flipping the A/B flag must not
-            # change autocast behavior)
-            return apply(
-                lambda a, w, b: fused_layernorm_arrays(a, w, b, eps=epsilon),
-                x, weight, bias, name="layer_norm")
 
     def fn(a, *wb):
         i = 0

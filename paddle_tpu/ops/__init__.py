@@ -21,9 +21,10 @@ from .array import *  # noqa: F401,F403
 from .extras import *  # noqa: F401,F403
 
 from . import array, creation, math, manipulation, logic, extras
-# serving-side paged-KV attention: importable as ops.paged_attention —
-# array-level only, deliberately NOT star-exported into the top-level
-# paddle namespace (it is an engine primitive, not a user tensor op)
+# serving-side paged-KV primitives (the ragged op's XLA fallback and the
+# pool writers): importable as ops.paged_attention — array-level only,
+# deliberately NOT star-exported into the top-level paddle namespace
+# (engine primitives, not user tensor ops)
 from . import paged_attention  # noqa: F401
 # low-bit quantized storage/compute primitives (paddle_tpu.lowbit's op
 # layer) — array-level only, same non-export rationale as paged_attention

@@ -1,7 +1,9 @@
-"""Paged-KV-cache attention (array level) — the serving-side primitive of
+"""Paged-KV-cache attention (array level) — the serving-side primitives of
 `paddle_tpu.serving` (Ragged Paged Attention, PAPERS.md: block-paged KV
 caches + ragged batch decoding are the TPU-side key to high-throughput LLM
-serving).
+serving): the pool writers the engine's prefill program calls, and the
+gather + masked attention that `ops.ragged_paged_attention` composes into
+its XLA fallback and that its tests hold it to.
 
 Layout: K/V live in fixed-size physical blocks, heads flattened in the
 last axis
@@ -234,8 +236,8 @@ def quantized_gather_kv_arrays(blocks, scales, block_table):
     returning float32 [B, max_blocks * block_size, H, D] =
     ``codes * per-block-per-head scale``.
 
-    This IS the separate dequant pass quantized serving pays on the
-    bucketed path (a 4-byte fp32 materialization of the 1-byte pool);
+    This IS the separate dequant pass (a 4-byte fp32 materialization of
+    the 1-byte pool) that `paged_attention_arrays` pays under int8;
     `ops.ragged_paged_attention` exists to not call it — the counter
     below is how the bench/tests pin that (ISSUE 8 acceptance: no
     ``site="paged_gather"`` increments on the ragged path)."""

@@ -1128,13 +1128,11 @@ def _two_block_dma_loop(num_kb, copies, step, carry, first_kb=0):
 
 
 def _prefix_attn_loop(qf, length, num_kb, row0, k_hbm, v_hbm, k_buf, v_buf,
-                      sem, seg, expand, seg_dot, *, bb, block_k, h, scale,
-                      mask_all=None):
+                      sem, seg, expand, seg_dot, *, bb, block_k, h, scale):
     """Online-softmax attention of qf [bb, 1, H*D] (fp32) against cache
-    rows [row0:row0+bb, 0:length) streamed from HBM two blocks at a time —
-    the shared core of _decode_kernel and _fused_decode_layer_kernel.
+    rows [row0:row0+bb, 0:length) streamed from HBM two blocks at a time.
     Returns the running (m, l, acc) softmax state ([bb,1,H] / [bb,1,H*D]
-    fp32) so callers can fold in further terms before normalizing."""
+    fp32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1154,11 +1152,6 @@ def _prefix_attn_loop(qf, length, num_kb, row0, k_hbm, v_hbm, k_buf, v_buf,
         kd.wait()
         kf = k_buf[slot].astype(jnp.float32)                     # [bb,bk,hd]
         s = seg_dot(kf * qf, seg) * scale                        # [bb,bk,H]
-        if mask_all is not None:
-            # additive row mask over cache positions (padded batches);
-            # rows address the caller's batch slab, like the cache DMAs
-            s = s + jax.lax.dynamic_slice(
-                mask_all, (row0, start), (bb, block_k))[:, :, None]
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (bb, block_k, h), 1)
         s = jnp.where(pos < length, s, _NEG_INF)
@@ -1335,489 +1328,3 @@ def _decode_ok(q, k_cache, v_cache) -> bool:
             return False
     _count_path("decode_kernel")
     return True
-
-
-# ---------------------------------------------------------------------------
-# Fused per-layer decode step (reference:
-# fused_multi_transformer_op.cu:90 — one CUDA op runs a whole layer's
-# decode: LN, qkv, cache write, attention, out-proj. The round-2 bisect
-# attributed the decode gap to kernel-LAUNCH count (~100-200 kernels/token
-# step at 124M ≈ 1-3 ms of fixed cost), so the TPU answer is the same
-# shape: ONE Pallas program per layer per token step.)
-# ---------------------------------------------------------------------------
-
-def _fused_decode_layer_kernel(len_ref, x_ref, lnw_ref, lnb_ref,
-                               wqkv_ref, bqkv_ref, wo_ref, bo_ref,
-                               k_in, v_in, *refs,
-                               block_k, h, d, eps, scale, has_mask):
-    """Single program: x [B, H*D] residual stream in, y = x + attn_out
-    out; the new token's k/v are written in place into the HBM cache rings
-    (k_out/v_out alias k_in/v_in). Prefix length t arrives via scalar
-    prefetch; the current token's k/v never round-trip through HBM — the
-    self-attention term folds into the online softmax from registers.
-    Requires t >= 1 (decode always follows a prefill). has_mask adds an
-    additive [B, S_max] row mask over prefix positions (padded-prompt
-    batches: -inf at pad slots; the current token is always valid)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    refs = list(refs)
-    mask_ref = refs.pop(0) if has_mask else None
-    y_ref, k_out, v_out, kv_stage, k_buf, v_buf, sem, wsem = refs
-    t = len_ref[0]                          # prefix length == write row
-    bb = x_ref.shape[0]
-    hd = h * d
-
-    # LN1 (fp32 row stats)
-    x32 = x_ref[...].astype(jnp.float32)                     # [B, hd]
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    xc = x32 - mu
-    rs = jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
-    xn = (xc * rs * lnw_ref[...].astype(jnp.float32)[None, :]
-          + lnb_ref[...].astype(jnp.float32)[None, :])
-
-    fast = jnp.bfloat16 if k_buf.dtype == jnp.bfloat16 else jnp.float32
-    qkv = _dot_f32(xn.astype(fast), wqkv_ref[...]) \
-        + bqkv_ref[...].astype(jnp.float32)[None, :]         # [B, 3hd] f32
-    q = qkv[:, :hd]
-    k_new = qkv[:, hd:2 * hd]
-    v_new = qkv[:, 2 * hd:]
-    qf = q[:, None, :]                                       # [B, 1, hd]
-
-    seg, expand, seg_dot = _decode_seg_helpers(h, d, fast)
-    num_kb = (t + block_k - 1) // block_k
-    mask_all = mask_ref[...].astype(jnp.float32) if has_mask else None
-    m, l, acc = _prefix_attn_loop(
-        qf, t, num_kb, 0, k_in, v_in, k_buf, v_buf, sem,
-        seg, expand, seg_dot, bb=bb, block_k=block_k, h=h, scale=scale,
-        mask_all=mask_all)
-
-    # current token's self-attention term, straight from registers
-    s_self = seg_dot(k_new[:, None, :] * qf, seg) * scale    # [B, 1, h]
-    m2 = jnp.maximum(m, s_self)
-    p_self = jnp.exp(s_self - m2)
-    alpha = jnp.exp(m - m2)
-    l = alpha * l + p_self
-    acc = (acc * seg_dot(alpha, expand, exact=True)
-           + seg_dot(p_self, expand) * v_new[:, None, :])
-
-    # cache write AFTER the prefix loop (no read/write overlap on the
-    # aliased ring) — the tiny one-row DMAs overlap the out-proj matmul
-    kv_stage[0] = k_new[:, None, :].astype(kv_stage.dtype)
-    kv_stage[1] = v_new[:, None, :].astype(kv_stage.dtype)
-    wk = pltpu.make_async_copy(
-        kv_stage.at[0], k_out.at[pl.ds(0, bb), pl.ds(t, 1)], wsem.at[0])
-    wv = pltpu.make_async_copy(
-        kv_stage.at[1], v_out.at[pl.ds(0, bb), pl.ds(t, 1)], wsem.at[1])
-    wk.start()
-    wv.start()
-
-    l_exp = seg_dot(l, expand, exact=True)                   # [B, 1, hd]
-    attn = (acc / jnp.maximum(l_exp, 1e-30))[:, 0, :]        # [B, hd] f32
-    proj = _dot_f32(attn.astype(fast), wo_ref[...]) \
-        + bo_ref[...].astype(jnp.float32)[None, :]
-    y_ref[...] = (x32 + proj).astype(y_ref.dtype)
-    wk.wait()
-    wv.wait()
-
-
-def fused_decode_layer_arrays(x, ln_w, ln_b, wqkv, bqkv, wo, bo,
-                              k_cache, v_cache, t, n_heads, eps=1e-5,
-                              scale=None, block_k=256, cache_mask=None):
-    """One transformer layer's decode step (S_q = 1) in ONE Pallas call:
-    LN -> qkv -> ring cache write (in place, aliased) -> online-softmax
-    attention over the valid prefix + the current token -> out-proj ->
-    residual add. x: [B, H*D]; caches: flat [B, S_max, H*D] rings;
-    t: int32 scalar prefix length (>= 1). cache_mask: optional additive
-    [B, S_max] (or [B, 1, 1, S_max]) row mask over cache positions —
-    padded-prompt batches keep the fused path. Returns
-    (y, k_cache, v_cache) with the caches updated in place (buffers
-    donated)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, hd = x.shape
-    h = n_heads
-    d = hd // h
-    s_max = k_cache.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_k = min(block_k, s_max)
-    while s_max % block_k:
-        block_k //= 2
-    itemsize = jnp.dtype(k_cache.dtype).itemsize
-    # shrink the streamed cache blocks until the two-block slabs
-    # plus resident weights fit the VMEM budget
-    weights_bytes = (hd * 3 * hd + hd * hd) * jnp.dtype(wqkv.dtype).itemsize
-    if cache_mask is not None:
-        weights_bytes += b * s_max * 4      # resident fp32 row mask block
-    while (block_k > 8
-           and 4 * b * block_k * hd * itemsize > 10 * 2**20 - weights_bytes):
-        block_k //= 2
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((b, hd), lambda i, len_ref: (0, 0)),          # x
-            pl.BlockSpec((hd,), lambda i, len_ref: (0,)),              # ln_w
-            pl.BlockSpec((hd,), lambda i, len_ref: (0,)),              # ln_b
-            pl.BlockSpec((hd, 3 * hd), lambda i, len_ref: (0, 0)),     # wqkv
-            pl.BlockSpec((3 * hd,), lambda i, len_ref: (0,)),          # bqkv
-            pl.BlockSpec((hd, hd), lambda i, len_ref: (0, 0)),         # wo
-            pl.BlockSpec((hd,), lambda i, len_ref: (0,)),              # bo
-            pl.BlockSpec(memory_space=pl.ANY),                         # k_in
-            pl.BlockSpec(memory_space=pl.ANY),                         # v_in
-        ] + ([pl.BlockSpec((b, s_max), lambda i, len_ref: (0, 0))]
-             if cache_mask is not None else []),                       # mask
-        out_specs=[
-            pl.BlockSpec((b, hd), lambda i, len_ref: (0, 0)),          # y
-            pl.BlockSpec(memory_space=pl.ANY),                         # k_out
-            pl.BlockSpec(memory_space=pl.ANY),                         # v_out
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, b, 1, hd), k_cache.dtype),                  # stage
-            pltpu.VMEM((2, b, block_k, hd), k_cache.dtype),
-            pltpu.VMEM((2, b, block_k, hd), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    kernel = functools.partial(_fused_decode_layer_kernel, block_k=block_k,
-                               h=h, d=d, eps=float(eps), scale=scale,
-                               has_mask=cache_mask is not None)
-    lengths = jnp.asarray(t, jnp.int32).reshape(1)
-    mask_args = []
-    if cache_mask is not None:
-        mask_args = [jnp.asarray(cache_mask, jnp.float32
-                                 ).reshape(b, s_max)]
-    # aliasing: inputs are indexed INCLUDING the scalar-prefetch arg
-    # (lengths=0, x=1, ..., k_in=8, v_in=9; mask, when present, is 10);
-    # outputs (y=0, k=1, v=2)
-    y, k2, v2 = pl.pallas_call(
-        kernel,
-        name="fused_decode_layer",
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hd), x.dtype),
-            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-        ],
-        input_output_aliases={8: 1, 9: 2},
-        interpret=_interpret(),
-    )(lengths, x, ln_w, ln_b, wqkv, bqkv, wo, bo, k_cache, v_cache,
-      *mask_args)
-    return y, k2, v2
-
-
-def _fused_decode_layer_ok(x, wqkv, k_cache, v_cache, n_heads) -> bool:
-    """Geometry/flag gate for the fused per-layer decode kernel.
-    PTPU_FUSED_DECODE=1 enables (default off until the on-chip A/B
-    promotes it); =0 hard-off."""
-    import os
-
-    if os.environ.get("PTPU_FUSED_DECODE") != "1":
-        return False
-    if not (_on_tpu() or _interpret()):
-        _count_path("fused_decode_fallback:off_tpu")
-        return False
-    b, hd = x.shape[0], x.shape[-1]
-    d = hd // n_heads
-    if d not in (64, 128, 256) or hd % 128 != 0:
-        _count_path("fused_decode_fallback:head_geometry")
-        return False
-    if k_cache.ndim != 3 or k_cache.shape[1] % 128 != 0:
-        _count_path("fused_decode_fallback:cache_shape")
-        return False
-    if not (x.dtype == wqkv.dtype == k_cache.dtype == v_cache.dtype):
-        _count_path("fused_decode_fallback:dtype_mix")
-        return False
-    if x.dtype not in (jnp.bfloat16, jnp.float32):
-        # the kernel's compute-dtype pick only handles bf16/f32; a uniform
-        # f16 model would hand _dot_f32 mixed f32xf16 operands
-        _count_path("fused_decode_fallback:dtype_unsupported")
-        return False
-    # resident weights must leave room for the two-block cache slabs
-    wbytes = (hd * 3 * hd + hd * hd) * jnp.dtype(wqkv.dtype).itemsize
-    if wbytes > 8 * 2**20:
-        _count_path("fused_decode_fallback:weights_vmem")
-        return False
-    _count_path("fused_decode_kernel")
-    return True
-
-
-# ---------------------------------------------------------------------------
-# Fused layernorm (SURVEY §7 phase 7; reference fused op family:
-# paddle/fluid/operators/fused/fused_bias_dropout_residual_layer_norm —
-# single-pass row statistics + affine, fp32 accumulation, one kernel
-# instead of the mean/var/normalize/scale chain)
-# ---------------------------------------------------------------------------
-
-def _ln_fwd_kernel(x_ref, w_ref, b_ref, y_ref, mu_ref, rs_ref, *, eps):
-    x = x_ref[...].astype(jnp.float32)                    # [bm, H]
-    mu = jnp.mean(x, axis=-1)
-    xc = x - mu[:, None]
-    var = jnp.mean(xc * xc, axis=-1)
-    rs = jax.lax.rsqrt(var + eps)
-    y = xc * rs[:, None] * w_ref[...].astype(jnp.float32)[None, :] \
-        + b_ref[...].astype(jnp.float32)[None, :]
-    y_ref[...] = y.astype(y_ref.dtype)
-    mu_ref[...] = mu[:, None]
-    rs_ref[...] = rs[:, None]
-
-
-def _ln_bwd_kernel(x_ref, w_ref, mu_ref, rs_ref, dy_ref, dx_ref, dwp_ref,
-                   dbp_ref):
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)[None, :]
-    mu = mu_ref[...]                                      # [bm, 1]
-    rs = rs_ref[...]
-    dy = dy_ref[...].astype(jnp.float32)
-    xhat = (x - mu) * rs
-    g = dy * w
-    h = x.shape[-1]
-    m1 = jnp.sum(g, axis=-1, keepdims=True) / h
-    m2 = jnp.sum(g * xhat, axis=-1, keepdims=True) / h
-    dx = rs * (g - m1 - xhat * m2)
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-    dwp_ref[...] = jnp.sum(dy * xhat, axis=0)[None, :]
-    dbp_ref[...] = jnp.sum(dy, axis=0)[None, :]
-
-
-def _ln_block_rows(n):
-    for bm in (256, 128, 8):
-        if n % bm == 0:
-            return bm
-    return None
-
-
-def ln_geometry_ok(n, h):
-    """Gate for the fused layernorm kernel: whole lane tiles in H,
-    divisible row blocks, a live TPU (or interpret mode)."""
-    if not (_on_tpu() or _interpret()):
-        _count_path("ln_fallback:off_tpu")
-        return False
-    if h % 128 != 0 or _ln_block_rows(n) is None:
-        _count_path("ln_fallback:geometry")
-        return False
-    _count_path("ln_kernel")
-    return True
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_layernorm_2d(x2, w, b, eps):
-    y, _, _ = _ln_fwd(x2, w, b, eps)
-    return y
-
-
-def _ln_fwd(x2, w, b, eps):
-    from jax.experimental import pallas as pl
-
-    n, h = x2.shape
-    bm = _ln_block_rows(n)
-    # match the XLA path's promotion: bf16 x with fp32 norm params (the
-    # keep-norm-params-fp32 recipe) produces fp32 output on both paths
-    out_dt = jnp.promote_types(jnp.promote_types(x2.dtype, w.dtype), b.dtype)
-    return pl.pallas_call(
-        functools.partial(_ln_fwd_kernel, eps=eps),
-        name="layer_norm_fwd",
-        grid=(n // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h), out_dt),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(x2, w, b)
-
-
-def _ln_vjp_fwd(x2, w, b, eps):
-    y, mu, rs = _ln_fwd(x2, w, b, eps)
-    return y, (x2, w, b, mu, rs)
-
-
-def _ln_vjp_bwd(eps, res, dy):
-    from jax.experimental import pallas as pl
-
-    x2, w, b, mu, rs = res
-    n, h = x2.shape
-    bm = _ln_block_rows(n)
-    grid = n // bm
-    dx, dwp, dbp = pl.pallas_call(
-        _ln_bwd_kernel,
-        name="layer_norm_bwd",
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((1, h), lambda i: (i, 0)),
-            pl.BlockSpec((1, h), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h), x2.dtype),
-            jax.ShapeDtypeStruct((grid, h), jnp.float32),
-            jax.ShapeDtypeStruct((grid, h), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(x2, w, mu, rs, dy)
-    dw = jnp.sum(dwp, axis=0).astype(w.dtype)
-    db = jnp.sum(dbp, axis=0).astype(b.dtype)
-    return dx, dw, db
-
-
-fused_layernorm_2d.defvjp(_ln_vjp_fwd, _ln_vjp_bwd)
-
-
-def fused_layernorm_arrays(x, w, b, eps=1e-5):
-    """LayerNorm over the LAST axis with the Pallas kernel. Callers gate
-    on ln_geometry_ok first (PTPU_ATTN_DEBUG counts the decisions)."""
-    h = x.shape[-1]
-    x2 = x.reshape(-1, h)
-    y = fused_layernorm_2d(x2, w, b, float(eps))
-    return y.reshape(x.shape)
-
-
-# ---------------------------------------------------------------------------
-# Fused FFN (SURVEY §7 phase 7; reference: fused_feedforward_op.cu) —
-# y = act(x @ W1 + b1) @ W2 (+ caller's bias): row-blocked with the
-# intermediate accumulated per block, so the [tokens, I] activation never
-# round-trips HBM in the forward. Backward recomputes it in XLA (the
-# remat trade the kernel exists to make).
-# ---------------------------------------------------------------------------
-
-def _ffn_act(u, act):
-    if act == "gelu":
-        # erf-exact: matches F.gelu's default (approximate=False)
-        return jax.nn.gelu(u, approximate=False)
-    if act == "gelu_tanh":
-        return jax.nn.gelu(u, approximate=True)
-    if act == "relu":
-        return jnp.maximum(u, 0.0)
-    raise ValueError(f"fused_ffn: unsupported activation {act!r}")
-
-
-def _ffn_fwd_kernel(x_ref, w1_ref, b1_ref, w2_ref, y_ref, *, block_i, act):
-    x = x_ref[...]                                    # [bm, H]
-    n_ib = w1_ref.shape[1] // block_i
-    acc = jnp.zeros((x.shape[0], w2_ref.shape[1]), jnp.float32)
-
-    def body(ib, acc):
-        from jax.experimental import pallas as pl
-
-        w1 = w1_ref[:, pl.dslice(ib * block_i, block_i)]     # [H, bi]
-        b1 = b1_ref[pl.dslice(ib * block_i, block_i)]        # [bi]
-        w2 = w2_ref[pl.dslice(ib * block_i, block_i), :]     # [bi, H2]
-        u = _dot_f32(x, w1) + b1[None, :].astype(jnp.float32)
-        h = _ffn_act(u, act).astype(x.dtype)
-        return acc + _dot_f32(h, w2)
-
-    acc = jax.lax.fori_loop(0, n_ib, body, acc)
-    y_ref[...] = acc.astype(y_ref.dtype)
-
-
-def ffn_geometry_ok(n_rows, h, i, h2):
-    if not (_on_tpu() or _interpret()):
-        _count_path("ffn_fallback:off_tpu")
-        return False
-    if (h % 128 or i % 128 or h2 % 128
-            or _ln_block_rows(n_rows) is None):
-        _count_path("ffn_fallback:geometry")
-        return False
-    _count_path("ffn_kernel")
-    return True
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def fused_ffn_2d(x2, w1, b1, w2, act):
-    from jax.experimental import pallas as pl
-
-    n, h = x2.shape
-    i = w1.shape[1]
-    h2 = w2.shape[1]
-    bm = _ln_block_rows(n)
-    block_i = 512 if i % 512 == 0 else 128
-    return pl.pallas_call(
-        functools.partial(_ffn_fwd_kernel, block_i=block_i, act=act),
-        name="ffn_fwd",
-        grid=(n // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, h), lambda r: (r, 0)),
-            pl.BlockSpec((h, i), lambda r: (0, 0)),
-            pl.BlockSpec((i,), lambda r: (0,)),
-            pl.BlockSpec((i, h2), lambda r: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, h2), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, h2), x2.dtype),
-        interpret=_interpret(),
-    )(x2, w1, b1, w2)
-
-
-def _ffn_vjp_fwd(x2, w1, b1, w2, act):
-    return fused_ffn_2d(x2, w1, b1, w2, act), (x2, w1, b1, w2)
-
-
-def _ffn_vjp_bwd(act, res, dy):
-    # recompute-based backward in plain XLA: materializes [n, I] here
-    # (standard remat trade; the fwd saved that HBM round-trip)
-    x2, w1, b1, w2 = res
-
-    def ref(x2, w1, b1, w2):
-        u = (x2.astype(jnp.float32) @ w1.astype(jnp.float32)
-             + b1.astype(jnp.float32)[None, :])
-        h = _ffn_act(u, act).astype(x2.dtype)
-        return (h @ w2).astype(x2.dtype)
-
-    _, vjp = jax.vjp(ref, x2, w1, b1, w2)
-    return vjp(dy)
-
-
-fused_ffn_2d.defvjp(_ffn_vjp_fwd, _ffn_vjp_bwd)
-
-
-def fused_ffn_arrays(x, w1, b1, w2, act="gelu"):
-    """Row-blocked fused FFN over the last axis. Callers gate on
-    ffn_geometry_ok first. Returns act(x @ w1 + b1) @ w2 (caller adds
-    the second bias / dropout / residual)."""
-    h = x.shape[-1]
-    x2 = x.reshape(-1, h)
-    y = fused_ffn_2d(x2, w1, b1, w2, act)
-    return y.reshape(x.shape[:-1] + (w2.shape[1],))
-
-
-def maybe_fused_ffn(x, w1, b1, w2, act):
-    """Shared gate + dispatch for Tensor-level callers (GPTMLP,
-    incubate.FusedFeedForward): returns act(x@w1+b1)@w2 through the
-    kernel when the flag/bias/dtype/geometry contract holds, else None —
-    the caller then runs its own XLA formulation. Dispatches under
-    'linear' so AMP treats both paths identically."""
-    if _os.environ.get("PTPU_PALLAS_FFN") != "1":
-        return None
-    if b1 is None:
-        return None
-    if not (x.dtype == w1.dtype == w2.dtype):
-        _count_path("ffn_fallback:dtype_mix")
-        return None
-    n_rows = 1
-    for d in x.shape[:-1]:
-        n_rows *= int(d)
-    if not ffn_geometry_ok(n_rows, int(x.shape[-1]), int(w1.shape[-1]),
-                           int(w2.shape[-1])):
-        return None
-    return apply(
-        lambda a, wa, ba, wb: fused_ffn_arrays(a, wa, ba, wb, act=act),
-        x, w1, b1, w2, name="linear")

@@ -7,10 +7,10 @@ block loads instead of as a separate gather-dequantize pass.
 
 This is the serving decode workhorse ISSUE 8 / ROADMAP item 1 calls for:
 the round-2 bisect pinned ~2.77 ms of the 3.34 ms decode step to the
-gather-blocks → masked-attention → cache-scatter triple, and the
-power-of-2 batch bucketing recompiles a fresh program every time the
-running-request count crosses a boundary.  Here the engine compiles ONE
-program at ``[max_num_seqs, 1]`` and every batch composition runs it.
+gather-blocks → masked-attention → cache-scatter triple, and a batch
+padded to the running-request count recompiles whenever that count
+changes.  Here the engine compiles ONE program at ``[max_num_seqs, 1]``
+and every batch composition runs it.
 
 Two implementations behind one entry point, selected like
 ``pallas_ops._pallas_ok`` (PTPU_ATTN_DEBUG=1 counts every gate decision):
@@ -574,8 +574,8 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
 def _folded_quant_attention(q, k_blocks, v_blocks, k_scales, v_scales,
                             block_table, pos0, scale):
     """int8 paged attention WITHOUT the dequantizing gather: int8 CODES
-    are gathered (¼ of the fp32 dequant materialization the bucketed
-    path's `quantized_gather_kv_arrays` pays) and the per-block-per-head
+    are gathered (¼ of the fp32 dequant materialization
+    `quantized_gather_kv_arrays` pays) and the per-block-per-head
     scales fold into the logits (K side) and probabilities (V side) —
     exact in real arithmetic because the scale is constant along the
     contracted head_dim axis."""

@@ -23,9 +23,10 @@ Layers (each its own module, each independently testable):
 - `spec.propose_ngram`   — stdlib n-gram/prompt-lookup draft proposal
   for speculative decoding (no second model).
 - `engine.LLMEngine`     — jitted prefill/decode/sample step programs over
-  `ops.ragged_paged_attention` (default: ONE fixed-shape fused
-  update+attend decode program; `ops.paged_attention` is the bucketed
-  fallback), token-for-token equal to the dense
+  `ops.ragged_paged_attention` (ONE fixed-shape fused update+attend
+  decode program; `ops.paged_attention` holds its XLA fallback and the
+  pool writers the prefill program calls), token-for-token equal to the
+  dense
   `GPTForCausalLM.generate` (tests/test_serving.py pins it); with
   `EngineConfig(speculative_tokens=k)` a fixed-shape multi-token verify
   program emits several accepted tokens per decode step.

@@ -3,10 +3,11 @@
 The dense path (`GPTForCausalLM.generate`) runs ONE fixed batch to
 completion: no admission, no batching across arrivals, O(S_max) cache per
 request.  This engine serves an ever-changing request mix through a small
-set of jitted step programs of fixed padded shape (XLA recompiles only
-per bucket), with the scheduler — waiting queue, token-budget admission,
-preemption — living OUTSIDE the compiled step (the MPK structure from
-PAPERS.md: runtime scheduling around static tensor programs).
+set of jitted step programs of fixed padded shape (XLA compiles one
+decode program, and one prefill a prompt length), with the scheduler —
+waiting queue, token-budget admission, preemption — living OUTSIDE the
+compiled step (the MPK structure from PAPERS.md: runtime scheduling
+around static tensor programs).
 
 The engine names no model (ROADMAP D2).  A model hands it a serving form
 (`models.serving_form.ServingForm`, from `model.serving_form()`): the
@@ -30,21 +31,20 @@ Step programs (all array-level, weights threaded as inputs):
   matches dense generate" from a tolerance into token-for-token equality
   (tests/test_serving.py).  One compile per distinct prompt length — the
   prefill-compile price of exactness; decode, the steady-state loop, is
-  ONE fixed-shape program (ragged) or bucketed (fallback).
-- ``ragged(B, 1)`` — the decode workhorse (default,
-  ``EngineConfig(attention_impl="ragged")`` / env ``PTPU_RAGGED``): per
-  layer ONE fused `ops.ragged_paged_attention` call writes the new
-  tokens' K/V to their slots and attends the ragged batch against the
-  paged pools (int8 dequant folded into the block loads — no separate
+  ONE fixed-shape program (ragged).
+- ``ragged(B, 1)`` — the decode program: per layer ONE fused
+  `ops.ragged_paged_attention` call writes the new tokens' K/V to their
+  slots and attends the ragged batch against the paged pools (int8
+  dequant folded into the block loads — no separate
   `quantized_gather_kv_arrays` pass).  B is pinned to ``max_num_seqs``,
   so ONE compiled program serves every batch composition — no
-  power-of-2 bucket recompiles when the running-request count crosses a
-  boundary.  ``ragged(1, C)`` serves chunked-prefill continuations.
-- ``chunk(B, C)``  — the bucketed fallback
-  (``attention_impl="bucketed"``): gather-blocks + masked attention via
-  `ops.paged_attention` with the batch padded to power-of-two buckets
-  (the PR-2 dispatch).  Padding rows scatter to a dropped slot and
-  their outputs are ignored in both implementations.
+  recompile when the running-request count changes.  Padding rows
+  scatter to a dropped slot and their outputs are ignored.
+  ``ragged(1, C)`` serves chunked-prefill continuations.
+- ``spec_verify(B, k+1)`` — the same ragged body over 1 + k query
+  positions a row (`EngineConfig.speculative_tokens`), returning every
+  position's greedy token (and position 0's logits for the sampler), so
+  the accept is decided in one step.
 - ``sample(B)``    — per-row replication of the dense `_sample_next`
   (greedy argmax / temperature / top-k / top-p + per-request PRNG key
   threading), vmapped so every request reproduces the sampling stream of
@@ -73,7 +73,7 @@ Monitor wiring (PR-1 StatRegistry): `serving/queue_depth`,
 `serving/step_time` histograms labeled by phase.  ISSUE-12 goodput and
 launch accounting: `serving/kernels_per_step` (distinct compiled
 programs one decode step dispatches — the mega-kernel before/after
-number, flat across batch compositions on the ragged default),
+number, flat across batch compositions),
 `serving/padding_waste{kind=rows|tokens}` (padded fraction of the
 fixed-shape decode program — rows and tokens diverge under speculative
 decoding, where a row carries 1+drafts query positions),
@@ -116,8 +116,8 @@ the other six sum to the step's host gap.  Gates: PTPU_MONITOR (default
 on) puts each duration into `serving/host_time{phase}`, nothing synced
 for it; an open profiler session gets a host event `ptpu:<phase>` on the
 device operations' clock (the programs are named for that view:
-prefill_<len>, ragged_decode, ragged_prefill_<c>, chunk_decode,
-chunk_prefill_<c>, spec_verify, sample); PTPU_TRACE=1 adds a
+prefill_<len>, ragged_decode, ragged_prefill_<c>, spec_verify,
+sample); PTPU_TRACE=1 adds a
 `serving/step` span per step (`phase`, `rows`, the riders' `trace_ids`),
 the phases its children, filed under every rider's trace.
 
@@ -147,7 +147,6 @@ tail-based sampling.  All default-off.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 from typing import Optional
 
@@ -163,8 +162,7 @@ from ..monitor import slo as mslo
 from ..monitor import memory as mmem
 from ..resilience import faults
 from ..resilience.retry import Deadline
-from ..ops.paged_attention import (paged_attention_arrays,
-                                   paged_cache_update_arrays,
+from ..ops.paged_attention import (paged_cache_update_arrays,
                                    quantized_cache_update_arrays)
 from ..ops.ragged_paged_attention import ragged_paged_attention_arrays
 from .kv_cache import BlockKVCache, CacheGroups, prefix_block_keys
@@ -196,31 +194,23 @@ class EngineConfig:
     # /traces/<id>) on this port when the engine boots; 0 = ephemeral
     # (read it back from engine.metrics_server.port), None = no server.
     metrics_port: Optional[int] = None
-    # decode attention program (ISSUE 8): "ragged" runs ONE fixed-shape
-    # fused program (ops.ragged_paged_attention — in-program cache update,
-    # int8 dequant folded in, batch padded to max_num_seqs once) for every
-    # batch composition; "bucketed" keeps the PR-2 power-of-2-bucketed
-    # gather+attend dispatch as the fallback.  None resolves from env
-    # PTPU_RAGGED ("0"/"false"/"off" -> bucketed); default ragged.
-    attention_impl: Optional[str] = None
     # ISSUE 15 (a): automatic prefix caching — index full KV blocks by
     # chained content keys as prefill fills them; new requests adopt
     # their longest cached prefix by refcount bump and prefill only the
     # uncached tail (N requests sharing a system prompt pay its prefill
     # once).  Unreferenced prefix blocks park on an LRU and are
-    # reclaimed last.  None resolves from env PTPU_PREFIX_CACHE;
-    # default OFF (finished requests then pin pool blocks in the index,
-    # which changes the blocks_in_use==0-at-idle invariant suites pin).
-    enable_prefix_caching: Optional[bool] = None
+    # reclaimed last.  Default OFF (finished requests then pin pool
+    # blocks in the index, which changes the blocks_in_use==0-at-idle
+    # invariant suites pin).
+    enable_prefix_caching: bool = False
     # ISSUE 15 (b): speculative decoding — k n-gram/prompt-lookup draft
     # tokens per greedy row, verified in ONE fixed-shape ragged
     # (max_num_seqs, k+1) multi-token program; the longest matching
     # greedy run (plus the correction token) is accepted per step.
     # Token-identical to dense greedy generate(); sampling rows get no
     # drafts (their PRNG stream is preserved exactly — documented
-    # scope).  0 = off.  None resolves from env PTPU_SPEC_TOKENS.
-    # Requires attention_impl="ragged".
-    speculative_tokens: Optional[int] = None
+    # scope).  0 = off.
+    speculative_tokens: int = 0
     # n-gram proposer knobs: longest/shortest suffix n-gram tried, and
     # how far back the per-row host scan looks
     spec_ngram_max: int = 3
@@ -274,31 +264,9 @@ class LLMEngine:
                 f'kv_cache_dtype must be None or "int8", got '
                 f'{c.kv_cache_dtype!r}')
         self._kv_quant = c.kv_cache_dtype
-        impl = c.attention_impl
-        if impl is None:
-            impl = ("bucketed"
-                    if os.environ.get("PTPU_RAGGED", "1").lower()
-                    in ("0", "false", "off") else "ragged")
-        if impl not in ("ragged", "bucketed"):
-            raise ValueError(
-                f'attention_impl must be "ragged" or "bucketed", got '
-                f'{impl!r}')
-        self.attention_impl = impl
         wdtype = form.dtype
-        pc = c.enable_prefix_caching
-        if pc is None:
-            pc = os.environ.get("PTPU_PREFIX_CACHE", "0").lower() in (
-                "1", "true", "on")
-        self.prefix_caching = bool(pc)
-        st = c.speculative_tokens
-        if st is None:
-            st = int(os.environ.get("PTPU_SPEC_TOKENS", "0") or 0)
-        self.spec_tokens = max(0, int(st))
-        if self.spec_tokens and self.attention_impl != "ragged":
-            raise ValueError(
-                "speculative decoding needs the ragged attention path "
-                "(the fixed-shape multi-token verify program); "
-                'attention_impl="bucketed" cannot serve it')
+        self.prefix_caching = bool(c.enable_prefix_caching)
+        self.spec_tokens = max(0, int(c.speculative_tokens))
         on = {"kv_cache_dtype": self._kv_quant,
               "speculative_tokens": self.spec_tokens,
               "enable_prefix_caching": self.prefix_caching}
@@ -390,14 +358,10 @@ class LLMEngine:
             "by tenant")
         self._m_compiles = m.counter("serving/compiles",
                                      "step-program cache misses")
-        self._m_attn_impl = m.counter(
-            "serving/attention_impl",
-            "decode steps served, by attention path")
         # ISSUE 12 goodput/launch accounting: how many separate compiled
         # programs one decode step dispatches (the mega-kernel PR's
-        # before/after number — FLAT across batch compositions on the
-        # ragged default), and how much of the fixed-shape decode
-        # program is padding
+        # before/after number — FLAT across batch compositions), and how
+        # much of the fixed-shape decode program is padding
         self._m_kernels = m.gauge(
             "serving/kernels_per_step",
             "distinct compiled programs dispatched per decode step")
@@ -1113,18 +1077,11 @@ class LLMEngine:
                 tables = tuple(jnp.asarray(np.asarray(
                     [k.padded_table(req.req_id, self.blocks_per_seq)],
                     np.int32)) for k in self.caches.values())
-                if self.attention_impl == "ragged":
-                    fn = self._get_ragged_exec(1, chunk)
-                    logits, kv_out, stats = fn(
-                        self._param_arrays(), kv, jnp.asarray(ids),
-                        jnp.asarray([start], jnp.int32),
-                        jnp.asarray([start + chunk], jnp.int32), tables,
-                        slots)
-                else:
-                    fn = self._get_chunk_exec(1, chunk)
-                    logits, kv_out = fn(
-                        self._param_arrays(), kv, jnp.asarray(ids),
-                        jnp.asarray([start], jnp.int32), tables, slots)
+                fn = self._get_ragged_exec(1, chunk)
+                logits, kv_out, stats = fn(
+                    self._param_arrays(), kv, jnp.asarray(ids),
+                    jnp.asarray([start], jnp.int32),
+                    jnp.asarray([start + chunk], jnp.int32), tables, slots)
             self._store_kv(kv_out)
             req.num_computed = start + chunk
             if req.prefix_keys:
@@ -1178,35 +1135,19 @@ class LLMEngine:
         # records its cache key; the gauge is the LIVE twin of the
         # round-2 hand count — len() only, never iterated
         self._launches_this_step = set() if mon else None
-        ragged = self.attention_impl == "ragged"
-        # ragged: ONE fixed shape (max_num_seqs) serves every batch
-        # composition — no per-bucket recompiles when the running-request
-        # count crosses a power of 2; only the bucketed fallback derives
-        # bb from len(rows), and its pow-2 buckets bound the program count
-        # at log2(max_num_seqs) BY DESIGN (pinned by the bucket-crossing
-        # recompile tests)
-        bb = (self.scheduler.max_num_seqs if ragged
-              else self._bucket_batch(n))
-        self._m_attn_impl.labels(kind=self.attention_impl).inc()
+        # ONE fixed shape (max_num_seqs) serves every batch composition:
+        # no recompile when the running-request count changes
+        bb = self.scheduler.max_num_seqs
         with mtrace.phase("engine/prepare"):
             toks, pos0, lens, tables, slots = self._decode_inputs(
                 rows, [()] * n, bb, 1)
-            if ragged:
-                fn = self._get_ragged_exec(bb, 1)
-                if mon:
-                    self._launches_this_step.add(("ragged", bb, 1))
-                logits, kv_out, stats = fn(
-                    self._param_arrays(), self._kv_flat(),
-                    jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(lens),
-                    tables, slots)
-            else:
-                fn = self._get_chunk_exec(bb, 1)
-                if mon:
-                    self._launches_this_step.add(("chunk", bb, 1))
-                stats = None
-                logits, kv_out = fn(self._param_arrays(), self._kv_flat(),
-                                    jnp.asarray(toks), jnp.asarray(pos0),
-                                    tables, slots)
+            fn = self._get_ragged_exec(bb, 1)
+            if mon:
+                self._launches_this_step.add(("ragged", bb, 1))
+            logits, kv_out, stats = fn(
+                self._param_arrays(), self._kv_flat(),
+                jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(lens),
+                tables, slots)
             self._store_kv(kv_out)
             if mon:
                 for k, (_, live, held) in zip(self.caches.values(),
@@ -1295,7 +1236,6 @@ class LLMEngine:
         self._launches_this_step = set() if mon else None
         cw = self.spec_tokens + 1      # verify chunk width, fixed
         bb = self.scheduler.max_num_seqs
-        self._m_attn_impl.labels(kind=self.attention_impl).inc()
         with mtrace.phase("engine/prepare"):
             toks, pos0, lens, tables, slots = self._decode_inputs(
                 rows, drafts, bb, cw)
@@ -1466,9 +1406,9 @@ class LLMEngine:
         estimates (the fused program may never materialize the gather),
         which is precisely their job.
 
-        On the ragged path (ISSUE 8) the dict additionally carries
-        ``"ragged_fused"`` — the fused update+attention program of
-        `ops.ragged_paged_attention` per layer — so the before-side trio
+        The dict also carries ``"ragged_fused"`` — the fused
+        update+attention program of `ops.ragged_paged_attention` per
+        layer — so the before-side trio
         (block_gather/attention/cache_update) and the after-side fusion
         sit in ONE report and the fusion win is readable as
         ``ragged_fused.wall_time_s`` vs the trio's sum.
@@ -1481,11 +1421,7 @@ class LLMEngine:
         L = len(self.form.layer_specs)
         nh = self.form.layer_specs[0].num_heads
         hd = self.form.layer_specs[0].head_dim
-        ragged = self.attention_impl == "ragged"
-        # the LIVE decode batch width: the ragged program runs at
-        # max_num_seqs, the bucketed fallback at its full-batch bucket
-        bb = (self.scheduler.max_num_seqs if ragged
-              else self._bucket_batch(self.scheduler.max_num_seqs))
+        bb = self.scheduler.max_num_seqs    # the LIVE decode batch width
         s_pad = self.blocks_per_seq * self.cache.block_size
         num_slots = self.cache.num_slots
         wdtype = self.form.dtype
@@ -1577,54 +1513,46 @@ class LLMEngine:
                 donate_argnums=(0,)),
         }
         lens = jnp.full((bb,), s_pad, jnp.int32)
-        if ragged:
-            # the ISSUE-8 after-side: ONE fused program per layer doing
-            # update + attention (+ int8 dequant at the loads) — measured
-            # against the same roofline as the before-side trio above
-            def ragged_fn(kv, q_, rows_, slots_):
-                kvo = list(kv)
-                acc = jnp.float32(0.0)
-                for l in range(L):
-                    ql = q_ + jnp.asarray(l, q_.dtype)   # defeat CSE
-                    part = kv[stride * l:stride * (l + 1)]
-                    if quant:
-                        o, k2, v2, ks2, vs2 = ragged_paged_attention_arrays(
-                            ql, rows_, rows_, part[0], part[1], tables,
-                            pos0, lens, slots_,
-                            k_scales=part[2], v_scales=part[3])
-                        kvo[stride * l:stride * (l + 1)] = [k2, v2, ks2,
-                                                            vs2]
-                    else:
-                        o, k2, v2 = ragged_paged_attention_arrays(
-                            ql, rows_, rows_, part[0], part[1], tables,
-                            pos0, lens, slots_)
-                        kvo[stride * l:stride * (l + 1)] = [k2, v2]
-                    acc += jnp.sum(o.astype(jnp.float32))
-                return tuple(kvo), acc
 
-            kv_copy_r = tuple(jnp.array(a, copy=True) for a in kv_flat)
-            out["ragged_fused"] = mperf.measure(
-                ragged_fn, kv_copy_r, q, rows, slots,
-                label="decode:ragged_fused", reps=reps,
-                donate_argnums=(0,),
-                rearm=lambda args, o: (o[0],) + args[1:])
+        # the ISSUE-8 after-side: ONE fused program per layer doing
+        # update + attention (+ int8 dequant at the loads) — measured
+        # against the same roofline as the before-side trio above
+        def ragged_fn(kv, q_, rows_, slots_):
+            kvo = list(kv)
+            acc = jnp.float32(0.0)
+            for l in range(L):
+                ql = q_ + jnp.asarray(l, q_.dtype)   # defeat CSE
+                part = kv[stride * l:stride * (l + 1)]
+                if quant:
+                    o, k2, v2, ks2, vs2 = ragged_paged_attention_arrays(
+                        ql, rows_, rows_, part[0], part[1], tables,
+                        pos0, lens, slots_,
+                        k_scales=part[2], v_scales=part[3])
+                    kvo[stride * l:stride * (l + 1)] = [k2, v2, ks2, vs2]
+                else:
+                    o, k2, v2 = ragged_paged_attention_arrays(
+                        ql, rows_, rows_, part[0], part[1], tables,
+                        pos0, lens, slots_)
+                    kvo[stride * l:stride * (l + 1)] = [k2, v2]
+                acc += jnp.sum(o.astype(jnp.float32))
+            return tuple(kvo), acc
+
+        kv_copy_r = tuple(jnp.array(a, copy=True) for a in kv_flat)
+        out["ragged_fused"] = mperf.measure(
+            ragged_fn, kv_copy_r, q, rows, slots,
+            label="decode:ragged_fused", reps=reps,
+            donate_argnums=(0,),
+            rearm=lambda args, o: (o[0],) + args[1:])
         # the real step programs, measured as compiled (donated pools
         # ping-ponged through the output so the engine's live cache is
         # never consumed)
         toks = jnp.zeros((bb, 1), jnp.int32)
         kv_copy2 = tuple(jnp.array(a, copy=True) for a in kv_flat)
-        if ragged:
-            out["step"] = mperf.measure(
-                self._get_ragged_exec(bb, 1),
-                self._param_arrays(), kv_copy2, toks, pos0, lens, (tables,),
-                (slots,), label="decode:step", reps=reps,
-                rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
-        else:
-            out["step"] = mperf.measure(
-                self._get_chunk_exec(bb, 1),
-                self._param_arrays(), kv_copy2, toks, pos0, (tables,),
-                (slots,), label="decode:step", reps=reps,
-                rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
+        out["step"] = mperf.measure(
+            self._get_ragged_exec(bb, 1),
+            self._param_arrays(), kv_copy2, toks, pos0, lens, (tables,),
+            (slots,), label="decode:step", reps=reps,
+            rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
         logits = jnp.zeros((bb, self.form.vocab_size), jnp.float32)
         out["sampler"] = mperf.measure(
             self._get_sample_exec(bb),
@@ -1661,21 +1589,10 @@ class LLMEngine:
 
     # -- jitted step programs ----------------------------------------------
 
-    def _bucket_batch(self, n: int) -> int:
-        """Power-of-2 decode bucket — the PR-2 dispatch, reachable only
-        through the "bucketed" fallback path (the ragged program always
-        runs at max_num_seqs, so batch-composition changes never
-        recompile)."""
-        bb = 1
-        while bb < n:
-            bb *= 2
-        return min(max(bb, 1), self.scheduler.max_num_seqs)
-
     # key-tuple field names per program kind — the engine's jit-cache key
     # IS its compile signature, so the recompile explainer (ISSUE 12)
     # diffs keys instead of arg signatures
     _KEY_FIELDS = {"prefill": ("prompt_len",),
-                   "chunk": ("batch", "chunk_len"),
                    "ragged": ("batch", "chunk_len"),
                    "verify": ("batch", "chunk_len"),
                    "sample": ("batch",)}
@@ -1684,13 +1601,13 @@ class LLMEngine:
         """A step-program cache miss: counted as `serving/compiles{kind}`
         AND into the framework-wide `jit/recompiles{fn}` attribution (the
         engine drives jax.jit directly, bypassing jit.CompiledFunction's
-        counter — the bucket-crossing regression test reads this).
+        counter — the flat-across-compositions regression test reads this).
 
         With `key` (the jit-cache tuple, not yet inserted), the miss is
         additionally EXPLAINED when a same-kind program already exists:
         the first differing key field names the varying axis
-        (`jit/recompile_cause{fn,axis}`, e.g. the bucketed fallback's
-        "batch 4→8" at a bucket crossing), and a breadcrumb lands in the
+        (`jit/recompile_cause{fn,axis}`, e.g. prefill's "prompt_len
+        96→128"), and a breadcrumb lands in the
         flight ring so post-mortem dumps explain compile storms.  The
         ragged decode program never varies by batch, so its cause series
         stays empty across compositions — the regression-tested
@@ -1819,49 +1736,6 @@ class LLMEngine:
                 donate_argnums=(1,))
         return self._jit_cache[key]
 
-    def _get_chunk_exec(self, b, c):
-        key = ("chunk", b, c)
-        if key not in self._jit_cache:
-            self._count_compile("chunk", key)
-
-            def chunk(params, kv_flat, ids, pos0, tables, slots):
-                pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-                x = self.form.embed(params, ids, pos)
-
-                def builder(spec, g, kc, vc, ksc=None, vsc=None):
-                    def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc,
-                                tables=tables[g], slots=slots[g]):
-                        # write-then-attend, the dense cache ordering
-                        if ksc is None:
-                            kc2 = paged_cache_update_arrays(kc, k, slots)
-                            vc2 = paged_cache_update_arrays(vc, v, slots)
-                            o = paged_attention_arrays(
-                                q, kc2, vc2, tables, pos0,
-                                **self._window_kw(spec))
-                            return o, (kc2, vc2)
-                        # lowbit KV: quantizing write, dequantizing
-                        # gather — the current chunk's own K/V round-trip
-                        # through int8 too (attend-from-pool, so every
-                        # position sees ONE consistent representation)
-                        kc2, ks2 = quantized_cache_update_arrays(
-                            kc, ksc, k, slots)
-                        vc2, vs2 = quantized_cache_update_arrays(
-                            vc, vsc, v, slots)
-                        o = paged_attention_arrays(
-                            q, kc2, vc2, tables, pos0,
-                            k_scales=ks2, v_scales=vs2)
-                        return o, (kc2, vc2, ks2, vs2)
-                    return attn_fn
-
-                h, kv_out, _ = self._run_blocks(params, kv_flat, x, pos,
-                                                builder)
-                return self._model_tail(params, h), kv_out
-
-            self._jit_cache[key] = jax.jit(self._named(
-                chunk, "chunk_decode" if c == 1 else f"chunk_prefill_{c}"),
-                donate_argnums=(1,))
-        return self._jit_cache[key]
-
     def _ragged_blocks(self, c, params, kv_flat, ids, pos0, lens, tables,
                        slots):
         """Embeddings, then every block with ONE fused
@@ -1879,7 +1753,7 @@ class LLMEngine:
                     if ksc is None:
                         o, kc2, vc2 = ragged_paged_attention_arrays(
                             q, k, v, kc, vc, tables, pos0, lens, slots,
-                            **self._window_kw(spec))
+                            window=spec.window)
                         return o, (kc2, vc2)
                     o, kc2, vc2, ks2, vs2 = ragged_paged_attention_arrays(
                         q, k, v, kc, vc, tables, pos0, lens, slots,
@@ -1890,10 +1764,6 @@ class LLMEngine:
         # a padding row of the fixed-shape batch has no keys
         return self._run_blocks(params, kv_flat, x, pos, builder,
                                 valid=lens > 0)
-
-    @staticmethod
-    def _window_kw(spec) -> dict:
-        return {} if spec.window is None else {"window": spec.window}
 
     def _get_ragged_exec(self, b, c):
         """The ISSUE-8 decode program (`_ragged_blocks` + the last

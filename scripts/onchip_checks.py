@@ -11,9 +11,7 @@ Interpret mode (the CPU suite) validates numerics but skips every Mosaic
 legality rule — block shapes' (8,128) divisibility, memref slice/tiling
 alignment, transpose legalization — the class that has failed at every
 first hardware contact so far.  One "OK <name>" line per check; any
-failure exits non-zero.  The default-off kernels (fused decode, LN, FFN)
-are compiled once each and their outcome printed as "COMPILES"/"REFUSED"
-without failing the run: promoting or deleting them is another PR's.
+failure exits non-zero.
 
 `--writes` runs the KV pool writers alone (`check_writes`) and prints what
 one write and one layer of the C > 1 fallback cost; `--tree DIR` takes
@@ -727,46 +725,6 @@ def check_generate():
     print("OK generate", flush=True)
 
 
-def try_default_off(wname, h, d):
-    """Compile the default-off kernels once each; print, never fail."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops import pallas_ops as po
-
-    hd = h * d
-    b, s = (8, 1024) if h == 12 else (2, 2048)
-    bf = jnp.bfloat16
-    z = lambda *shape: jnp.zeros(shape, bf)   # noqa: E731
-
-    def ln_loss(x, w, bias):
-        return po.fused_layernorm_2d(x.reshape(-1, hd), w, bias, 1e-5
-                                     ).astype(jnp.float32).sum()
-
-    cases = {
-        "fused_decode": (lambda x, lw, lb, wq, bq, wo, bo, kc, vc:
-                         po.fused_decode_layer_arrays(
-                             x, lw, lb, wq, bq, wo, bo, kc, vc,
-                             jnp.int32(5), h),
-                         (z(8, hd), z(hd), z(hd), z(hd, 3 * hd), z(3 * hd),
-                          z(hd, hd), z(hd), z(8, 1024, hd), z(8, 1024, hd))),
-        "ln_fwd_bwd": (jax.grad(ln_loss, argnums=(0, 1, 2)),
-                       (z(b, s, hd), z(hd), z(hd))),
-        "ffn_fwd": (lambda x, w1, b1, w2: po.fused_ffn_arrays(x, w1, b1, w2),
-                    (z(b, s, hd), z(hd, 4 * hd), z(4 * hd), z(4 * hd, hd))),
-    }
-    for name, (fn, args) in cases.items():
-        try:
-            if AOT:
-                _compile_only(fn, args)
-            else:
-                jax.block_until_ready(jax.jit(fn)(*args))
-            print(f"COMPILES {name}_{wname}", flush=True)
-        except Exception as e:   # the point is to record WHAT refuses
-            first = str(e).strip().splitlines()[0][:300]
-            print(f"REFUSED {name}_{wname}: {type(e).__name__}: {first}",
-                  flush=True)
-
-
 def main():
     global _AOT_SHARDING
     import jax
@@ -804,8 +762,6 @@ def main():
         checks.append(("check_generate", check_generate, ()))
     if not AFMOE_ONLY:
         checks.append(("check_writes", check_writes, ()))
-        checks += [(f"try_default_off_{wname}", try_default_off,
-                    (wname, h, d)) for wname, (h, d) in WIDTHS.items()]
     if WRITES_ONLY:
         checks = [c for c in checks if c[1] is check_writes]
     if CELLS_ONLY:

@@ -276,18 +276,17 @@ def check_perf(engine, snap, cfg):
 
     # off-line attribution of the fused step at live shapes
     bd = engine.decode_breakdown(reps=1)
-    segs = ("block_gather", "attention", "cache_update", "step", "sampler")
-    if engine.attention_impl == "ragged":
-        # ISSUE 8: the fused update+attention program must sit in the
-        # same report as the before-side trio it replaces
-        segs += ("ragged_fused",)
-        rec = perf.get("decode:ragged_fused")
-        assert rec is not None and rec.calls > 0, (
-            "decode:ragged_fused segment not populated on the ragged path")
-        print(f"attention_impl=ragged: fused update+attention "
-              f"{bd['ragged_fused']['wall_time_s']*1e3:.2f} ms vs "
-              f"gather+attn+update "
-              f"{(bd['block_gather']['wall_time_s'] + bd['attention']['wall_time_s'] + bd['cache_update']['wall_time_s'])*1e3:.2f} ms")
+    # ISSUE 8: the fused update+attention program must sit in the same
+    # report as the before-side trio it replaces
+    segs = ("block_gather", "attention", "cache_update", "step", "sampler",
+            "ragged_fused")
+    rec = perf.get("decode:ragged_fused")
+    assert rec is not None and rec.calls > 0, (
+        "decode:ragged_fused segment not populated")
+    print(f"fused update+attention "
+          f"{bd['ragged_fused']['wall_time_s']*1e3:.2f} ms vs "
+          f"gather+attn+update "
+          f"{(bd['block_gather']['wall_time_s'] + bd['attention']['wall_time_s'] + bd['cache_update']['wall_time_s'])*1e3:.2f} ms")
     for name in segs:
         assert name in bd and bd[name]["wall_time_s"] > 0, (name, bd.get(name))
     if all(bd[name]["available"] for name in segs):

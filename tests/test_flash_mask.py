@@ -243,93 +243,6 @@ def test_flash_decode_kernel_vs_reference_shapes():
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_fused_layernorm_parity(monkeypatch):
-    """Fused Pallas layernorm (interpret mode): values and grads vs the
-    XLA path, fp32 and bf16, through the public F.layer_norm gate."""
-    import paddle_tpu as paddle
-    import paddle_tpu.nn.functional as F
-
-    rng = np.random.RandomState(50)
-    for dtype in ("float32", "bfloat16"):
-        x_np = rng.randn(16, 256).astype(np.float32)
-        w_np = (1.0 + 0.1 * rng.randn(256)).astype(np.float32)
-        b_np = (0.1 * rng.randn(256)).astype(np.float32)
-
-        def run(use_pallas):
-            if use_pallas:
-                monkeypatch.setenv("PTPU_PALLAS_LN", "1")
-            else:
-                monkeypatch.delenv("PTPU_PALLAS_LN", raising=False)
-            x = paddle.to_tensor(x_np).astype(dtype)
-            w = paddle.to_tensor(w_np).astype(dtype)
-            b = paddle.to_tensor(b_np).astype(dtype)
-            for t in (x, w, b):
-                t.stop_gradient = False
-            y = F.layer_norm(x, 256, weight=w, bias=b)
-            (y.astype("float32") ** 2).sum().backward()
-            return (np.asarray(y.astype("float32").numpy()),
-                    np.asarray(x.grad.astype("float32").numpy()),
-                    np.asarray(w.grad.astype("float32").numpy()),
-                    np.asarray(b.grad.astype("float32").numpy()))
-
-        ref = run(False)
-        got = run(True)
-        # bf16: the XLA path rounds xhat to bf16 before the affine while
-        # the kernel stays fp32 end-to-end — grads can differ by a few
-        # bf16 ulps (~0.06 at |x|≈2) on a fraction of elements
-        tol = 2e-5 if dtype == "float32" else 3e-2
-        atol = 2e-5 if dtype == "float32" else 0.13
-        for r, g in zip(ref, got):
-            np.testing.assert_allclose(g, r, rtol=tol, atol=atol)
-
-
-def test_fused_layernorm_mixed_dtype(monkeypatch):
-    """bf16 activations with fp32 norm params (keep-norm-params-fp32):
-    output dtype and grads must match the XLA path, including the fp32
-    promotion."""
-    import paddle_tpu as paddle
-    import paddle_tpu.nn.functional as F
-
-    rng = np.random.RandomState(60)
-    x_np = rng.randn(16, 256).astype(np.float32)
-    w_np = (1.0 + 0.1 * rng.randn(256)).astype(np.float32)
-    b_np = (0.1 * rng.randn(256)).astype(np.float32)
-
-    def run(flag):
-        if flag:
-            monkeypatch.setenv("PTPU_PALLAS_LN", "1")
-        else:
-            monkeypatch.delenv("PTPU_PALLAS_LN", raising=False)
-        x = paddle.to_tensor(x_np).astype("bfloat16")
-        w = paddle.to_tensor(w_np)   # fp32
-        b = paddle.to_tensor(b_np)   # fp32
-        for t in (x, w, b):
-            t.stop_gradient = False
-        y = F.layer_norm(x, 256, weight=w, bias=b)
-        (y.astype("float32") ** 2).sum().backward()
-        return y, b.grad
-    y_ref, db_ref = run(False)
-    y_got, db_got = run(True)
-    assert str(y_got.dtype) == str(y_ref.dtype), (y_got.dtype, y_ref.dtype)
-    assert str(db_got.dtype) == str(db_ref.dtype)
-    np.testing.assert_allclose(np.asarray(y_got.astype("float32").numpy()),
-                               np.asarray(y_ref.astype("float32").numpy()),
-                               rtol=3e-2, atol=0.13)
-
-
-def test_fused_layernorm_gate(monkeypatch):
-    from paddle_tpu.ops import pallas_ops as po2
-
-    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
-    po2.reset_attention_path_counts()
-    assert po2.ln_geometry_ok(16, 256)      # interpret-mode fixture active
-    assert not po2.ln_geometry_ok(16, 100)  # lanes not tiled
-    assert not po2.ln_geometry_ok(13, 256)  # rows not divisible
-    counts = po2.attention_path_counts()
-    assert counts.get("ln_kernel") == 1
-    assert counts.get("ln_fallback:geometry") == 2
-
-
 def test_decode_auto_policy_smax_threshold(monkeypatch):
     """Auto path selection: short caches stay on XLA (fixed-cost regime),
     long caches take the prefix-skipping kernel; env forces override."""
@@ -353,90 +266,6 @@ def test_decode_auto_policy_smax_threshold(monkeypatch):
     monkeypatch.setenv("PTPU_FLASH_DECODE", "0")
     kc, vc = caches(2048)
     assert not po2._decode_ok(q, kc, vc)          # forced off
-
-
-def test_fused_ffn_parity(monkeypatch):
-    """Row-blocked fused FFN kernel (interpret mode): values + grads vs
-    the XLA path through the public FusedFeedForward gate."""
-    monkeypatch.setenv("PTPU_PALLAS_FFN", "1")
-    import paddle_tpu as paddle
-    from paddle_tpu.incubate.nn import FusedFeedForward
-
-    rng = np.random.RandomState(70)
-    x_np = rng.randn(4, 8, 128).astype(np.float32) * 0.5
-
-    def run(flag):
-        if flag:
-            monkeypatch.setenv("PTPU_PALLAS_FFN", "1")
-        else:
-            monkeypatch.delenv("PTPU_PALLAS_FFN", raising=False)
-        paddle.seed(3)
-        ffn = FusedFeedForward(128, 256, dropout_rate=0.0,
-                               act_dropout_rate=0.0, activation="gelu",
-                               normalize_before=True)
-        x = paddle.to_tensor(x_np)
-        x.stop_gradient = False
-        y = ffn(x)
-        (y ** 2).sum().backward()
-        grads = {n: p.grad.numpy().copy()
-                 for n, p in ffn.named_parameters() if p.grad is not None}
-        return y.numpy(), x.grad.numpy(), grads
-
-    y_ref, dx_ref, g_ref = run(False)
-    y_got, dx_got, g_got = run(True)
-    np.testing.assert_allclose(y_got, y_ref, rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(dx_got, dx_ref, rtol=5e-3, atol=5e-4)
-    assert set(g_got) == set(g_ref)
-    for n in g_ref:
-        np.testing.assert_allclose(g_got[n], g_ref[n], rtol=5e-3,
-                                   atol=5e-4, err_msg=n)
-
-
-def test_fused_ffn_gate(monkeypatch):
-    from paddle_tpu.ops import pallas_ops as po3
-
-    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
-    po3.reset_attention_path_counts()
-    assert po3.ffn_geometry_ok(16, 128, 256, 128)
-    assert not po3.ffn_geometry_ok(16, 100, 256, 128)
-    assert not po3.ffn_geometry_ok(13, 128, 256, 128)
-    counts = po3.attention_path_counts()
-    assert counts.get("ffn_kernel") == 1
-    assert counts.get("ffn_fallback:geometry") == 2
-
-
-def test_gpt_mlp_fused_ffn_parity(monkeypatch):
-    """The GPT MLP (headline-bench path) rides the fused kernel under
-    the flag at mp=1; logits match the XLA path; TP (mp>1) stays GSPMD."""
-    import paddle_tpu as paddle
-    from paddle_tpu import parallel
-    from paddle_tpu.models import GPTForCausalLM, gpt_test_config
-
-    x_ids = np.random.RandomState(80).randint(0, 256, (2, 8)).astype("int32")
-
-    def run(flag):
-        if flag:
-            monkeypatch.setenv("PTPU_PALLAS_FFN", "1")
-        else:
-            monkeypatch.delenv("PTPU_PALLAS_FFN", raising=False)
-        paddle.seed(5)
-        parallel.init_mesh()
-        # hidden/intermediate must tile 128 lanes or the gate (rightly)
-        # falls back and the test would compare XLA to itself
-        cfg = gpt_test_config(num_hidden_layers=2, stacked_blocks=False,
-                              hidden_size=128, intermediate_size=256,
-                              num_attention_heads=2)
-        m = GPTForCausalLM(cfg)
-        m.eval()
-        return m(paddle.to_tensor(x_ids)).numpy()
-
-    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
-    ref = run(False)
-    po.reset_attention_path_counts()
-    got = run(True)
-    assert po.attention_path_counts().get("ffn_kernel", 0) >= 1, \
-        po.attention_path_counts()   # the kernel actually ran
-    np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
 # ---------------------------------------------------------------------------

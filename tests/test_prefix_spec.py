@@ -374,11 +374,6 @@ class TestSpecEngineParity:
             [prompt], SamplingParams(max_new_tokens=NEW, eos_token_id=eos))
         np.testing.assert_array_equal(dense, out)
 
-    def test_spec_requires_ragged(self, model):
-        with pytest.raises(ValueError, match="ragged"):
-            LLMEngine(model, EngineConfig(attention_impl="bucketed",
-                                          speculative_tokens=2))
-
     def test_compiles_flat_across_spec_rounds_and_crossings(self, model):
         monitor.enable(True)
         try:

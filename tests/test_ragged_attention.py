@@ -11,16 +11,14 @@ The bars:
 - the Pallas kernel (interpret mode, CPU, fast tier) writes pools and
   scales bit-identically to the references and matches the fallback's
   attention within float tolerance;
-- the engine's ragged path is token-identical to the bucketed path and
-  to solo dense `generate()` (greedy + fixed-seed sampling), fp32 and
-  int8 KV per the PR-2/PR-4 conventions;
+- the engine's ragged path is token-identical to solo dense
+  `generate()` (greedy + fixed-seed sampling), fp32 and int8 KV per the
+  PR-2/PR-4 conventions;
 - ONE compiled decode program regardless of batch composition: driving
-  the engine across a power-of-2 bucket boundary leaves
-  `serving/compiles` and `jit/recompiles{fn=serving:*}` FLAT on the
-  ragged path while the bucketed path recompiles;
+  the engine across a power of two of running rows leaves
+  `serving/compiles` and `jit/recompiles{fn=serving:*}` FLAT;
 - the int8 ragged path never runs the separate dequant pass
-  (`lowbit/dequant_calls{site="paged_gather"}` stays absent) while the
-  bucketed path increments it.
+  (`lowbit/dequant_calls{site="paged_gather"}` stays absent).
 """
 import zlib
 
@@ -528,42 +526,36 @@ def _dense_solo(model, prompt, **kw):
 
 
 class TestEngineRaggedParity:
-    def test_default_impl_and_env_override(self, model, monkeypatch):
-        assert LLMEngine(model, EngineConfig()).attention_impl == "ragged"
-        monkeypatch.setenv("PTPU_RAGGED", "0")
-        assert LLMEngine(model, EngineConfig()).attention_impl == "bucketed"
-        monkeypatch.delenv("PTPU_RAGGED")
-        assert LLMEngine(model, EngineConfig(
-            attention_impl="bucketed")).attention_impl == "bucketed"
-        with pytest.raises(ValueError, match="attention_impl"):
-            LLMEngine(model, EngineConfig(attention_impl="paged"))
+    def test_four_kinds_of_program(self, model, prompts):
+        """Whole-prompt prefill, its chunked continuation, decode with
+        and without drafts, sampling: `prefill`, `ragged`, `verify` and
+        `sample`, and nothing else."""
+        eng = LLMEngine(model, EngineConfig(
+            block_size=16, max_num_seqs=2, max_num_batched_tokens=4,
+            speculative_tokens=2))
+        repeat = np.tile(prompts[0], 3)         # n-gram drafts hit
+        eng.generate([repeat, prompts[0]], SamplingParams(max_new_tokens=4))
+        assert {key[0] for key in eng._jit_cache} == {
+            "prefill", "ragged", "verify", "sample"}
 
     @pytest.mark.slow
-    def test_ragged_matches_bucketed_and_dense(self, model, prompts):
-        """fp32: ragged == bucketed token for token, greedy AND
-        fixed-seed sampling, on a mixed-length batch — plus one solo
-        dense oracle row as the anchor.  (The FULL ragged-vs-dense
-        parity surface — all 8 rows, greedy + sampled, staggered
-        arrivals, preemption — is tests/test_serving.py, which runs the
-        ragged DEFAULT; this test pins the two impls against each other
-        and the anchor explicitly.)"""
+    def test_ragged_matches_dense(self, model, prompts):
+        """fp32: the engine == each row's solo dense `generate()` token
+        for token, greedy AND fixed-seed sampling, on a mixed-length
+        batch.  (The full parity surface — staggered arrivals,
+        preemption — is tests/test_serving.py.)"""
+        kw = dict(do_sample=True, temperature=0.8, top_k=20, top_p=0.9)
         sps = [SamplingParams(max_new_tokens=NEW)] * 4 + [
-            SamplingParams(max_new_tokens=NEW, do_sample=True,
-                           temperature=0.8, top_k=20, top_p=0.9,
-                           seed=7 + i) for i in range(4, 8)]
-        dense0 = _dense_solo(model, prompts[0])
-        ragged = LLMEngine(model, EngineConfig(
-            block_size=16, max_num_seqs=8, attention_impl="ragged"))
-        bucketed = LLMEngine(model, EngineConfig(
-            block_size=16, max_num_seqs=8, attention_impl="bucketed"))
-        o_r = ragged.generate(prompts, sps)
-        o_b = bucketed.generate(prompts, sps)
-        np.testing.assert_array_equal(dense0, o_r[0],
-                                      err_msg="ragged vs dense 0")
-        for i in range(8):
-            np.testing.assert_array_equal(o_b[i], o_r[i],
-                                          err_msg=f"ragged vs bucketed {i}")
-        assert ragged.cache.blocks_in_use == 0
+            SamplingParams(max_new_tokens=NEW, seed=7 + i, **kw)
+            for i in range(4, 8)]
+        eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=8))
+        outs = eng.generate(prompts, sps)
+        for i, p in enumerate(prompts):
+            dense = (_dense_solo(model, p) if i < 4
+                     else _dense_solo(model, p, seed=7 + i, **kw))
+            np.testing.assert_array_equal(dense, outs[i],
+                                          err_msg=f"ragged vs dense {i}")
+        assert eng.cache.blocks_in_use == 0
 
     @pytest.mark.slow
     def test_ragged_chunked_prefill_matches_whole(self, model, prompts):
@@ -572,10 +564,9 @@ class TestEngineRaggedParity:
         tests/test_serving.py's chunked-prefill test runs the ragged
         DEFAULT in the fast tier.)"""
         whole = LLMEngine(model, EngineConfig(
-            block_size=16, max_num_seqs=1, attention_impl="ragged"))
+            block_size=16, max_num_seqs=1))
         chunked = LLMEngine(model, EngineConfig(
-            block_size=16, max_num_seqs=1, max_num_batched_tokens=3,
-            attention_impl="ragged"))
+            block_size=16, max_num_seqs=1, max_num_batched_tokens=3))
         [a] = whole.generate([prompts[2]],
                              SamplingParams(max_new_tokens=NEW))
         [b] = chunked.generate([prompts[2]],
@@ -590,11 +581,9 @@ class TestEngineRaggedParity:
         tests/test_lowbit.py's engine suite, which runs the ragged
         DEFAULT (plus TestDequantPassEliminated here drives the int8
         ragged engine directly)."""
-        fp = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=8,
-                                           attention_impl="ragged"))
+        fp = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=8))
         q8 = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=8,
-                                           kv_cache_dtype="int8",
-                                           attention_impl="ragged"))
+                                           kv_cache_dtype="int8"))
         sp = SamplingParams(max_new_tokens=NEW)
         o_fp = fp.generate(prompts, sp)
         o_q8 = q8.generate(prompts, sp)
@@ -630,19 +619,18 @@ class TestRecompileRegression:
                 out[axis] = out.get(axis, 0) + v
         return out
 
-    def _drive(self, model, prompts, impl):
-        """Warm on a batch of 3 (bucketed: bucket 4), then cross the
-        power-of-2 boundary with a batch of 5 (bucketed: bucket 8).
-        Returns (compiles during warm, compiles after the crossing),
+    def _drive(self, model, prompts):
+        """Warm on a batch of 3, then cross the power-of-2 boundary with
+        a batch of 5.  Returns (compiles during warm, compiles after the crossing),
         the jit/recompiles twins, the recompile-cause delta across the
         crossing (ISSUE 12's explainer), and the kernels_per_step gauge
         at both compositions."""
         monitor.enable(True)
         try:
             eng = LLMEngine(model, EngineConfig(
-                block_size=16, max_num_seqs=8, attention_impl=impl))
+                block_size=16, max_num_seqs=8))
             sp = SamplingParams(max_new_tokens=2)
-            kind = "ragged" if impl == "ragged" else "chunk"
+            kind = "ragged"
             jit_child = monitor.counter("jit/recompiles").labels(
                 fn=f"serving:{kind}")
             kern = monitor.gauge("serving/kernels_per_step")
@@ -670,26 +658,15 @@ class TestRecompileRegression:
     @pytest.mark.slow
     def test_bucket_crossing_flat_on_ragged(self, model, prompts):
         """ISSUE 8 acceptance, extended by ISSUE 12: ONE compiled decode
-        program regardless of batch composition.  Crossing a bucket
-        boundary (3 → 5 running rows) adds ZERO compiles on the ragged
-        path, leaves `jit/recompile_cause{fn=serving:*}` EMPTY, and
-        keeps `serving/kernels_per_step` FLAT — while the bucketed path
-        pays fresh decode+sampler programs for the new bucket AND the
-        explainer names the varying axis ("batch")."""
-        w, a, jw, ja, cause, k3, k5 = self._drive(model, prompts,
-                                                  "ragged")
+        program regardless of batch composition.  Crossing a power of
+        two (3 → 5 running rows) adds ZERO compiles, leaves
+        `jit/recompile_cause{fn=serving:ragged}` EMPTY, and keeps
+        `serving/kernels_per_step` FLAT."""
+        w, a, jw, ja, cause, k3, k5 = self._drive(model, prompts)
         assert a == w, (w, a)
         assert ja == jw, (jw, ja)
         assert cause == {}, cause           # nothing to explain
         assert k3 == k5 == 2.0, (k3, k5)    # decode program + sampler
-        w, a, jw, ja, cause, k3, k5 = self._drive(model, prompts,
-                                                  "bucketed")
-        assert a > w, (w, a)
-        assert ja > jw, (jw, ja)
-        # the miss is EXPLAINED: the decode program recompiled because
-        # the batch bucket changed (4 → 8)
-        assert cause.get("batch", 0) >= 1, cause
-        assert k3 == k5 == 2.0, (k3, k5)    # count flat; IDENTITY varied
 
 
 class TestDequantPassEliminated:
@@ -701,32 +678,24 @@ class TestDequantPassEliminated:
 
     @pytest.mark.slow
     def test_no_paged_gather_dequant_on_ragged(self, model, prompts):
-        """ISSUE 8 acceptance: the int8 ragged ENGINE makes NO
+        """ISSUE 8 acceptance: the int8 ENGINE makes NO
         `lowbit/dequant_calls{site="paged_gather"}` increments (the
-        dequant is folded into the attention program); the bucketed path
-        still pays the separate dequantizing gather per compiled
-        program.  One short prompt per engine: the counter ticks at
-        TRACE time, so compiling each path's programs once is the whole
-        measurement."""
-        sp = SamplingParams(max_new_tokens=2)
-        counts = {}
-        for impl in ("ragged", "bucketed"):
-            monitor.enable(True)
-            try:
-                # the registry is process-global and cumulative: diff
-                # around THIS engine's run (counting is at trace time,
-                # and each fresh engine retraces its own programs)
-                before = self._gather_count(monitor.snapshot())
-                eng = LLMEngine(model, EngineConfig(
-                    block_size=16, max_num_seqs=2, kv_cache_dtype="int8",
-                    attention_impl=impl))
-                eng.generate(prompts[:1], sp)
-                counts[impl] = self._gather_count(monitor.snapshot()) \
-                    - before
-            finally:
-                monitor.refresh()
-        assert counts["ragged"] == 0, counts
-        assert counts["bucketed"] > 0, counts
+        dequant is folded into the attention program).  One short
+        prompt: the counter ticks at TRACE time, so compiling the
+        engine's programs once is the whole measurement."""
+        monitor.enable(True)
+        try:
+            # the registry is process-global and cumulative: diff
+            # around THIS engine's run (counting is at trace time,
+            # and each fresh engine retraces its own programs)
+            before = self._gather_count(monitor.snapshot())
+            eng = LLMEngine(model, EngineConfig(
+                block_size=16, max_num_seqs=2, kv_cache_dtype="int8"))
+            eng.generate(prompts[:1], SamplingParams(max_new_tokens=2))
+            after = self._gather_count(monitor.snapshot())
+        finally:
+            monitor.refresh()
+        assert after == before, (before, after)
 
     def test_op_level_lowering_counts(self):
         """Same invariant at the op level, no engine: lowering the
@@ -757,20 +726,6 @@ class TestDequantPassEliminated:
 
 
 class TestMonitorWiring:
-    def test_attention_impl_counter(self, model, prompts):
-        monitor.enable(True)
-        try:
-            eng = LLMEngine(model, EngineConfig(
-                block_size=16, max_num_seqs=4, attention_impl="ragged"))
-            eng.generate(prompts[:2], SamplingParams(max_new_tokens=2))
-            snap = monitor.snapshot()
-        finally:
-            monitor.refresh()
-        v = snap.get("serving/attention_impl")
-        # prefill steps emit the first token, so max_new_tokens=2 runs
-        # exactly ONE ragged decode step for the batch
-        assert isinstance(v, dict) and v.get("kind=ragged", 0) >= 1, v
-
     @pytest.mark.slow
     def test_decode_breakdown_has_ragged_fused(self, model, prompts):
         # slow tier: the fast tier asserts the same surface through the
@@ -781,7 +736,7 @@ class TestMonitorWiring:
         monitor.enable(True)
         try:
             eng = LLMEngine(model, EngineConfig(
-                block_size=16, max_num_seqs=2, attention_impl="ragged"))
+                block_size=16, max_num_seqs=2))
             eng.generate(prompts[:1], SamplingParams(max_new_tokens=2))
             bd = eng.decode_breakdown(reps=1)
         finally:

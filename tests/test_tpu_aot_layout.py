@@ -6,6 +6,14 @@ was relaid (`reshape` in, `copy` out: two tilings of the same bytes) around
 every ragged kernel call, two fifths of a decode step (PERF.md, PR 27); no
 CPU test could see it.
 
+`test_kernel_compiles_for_the_chip` holds the tree to a rule: a kernel
+that does not compile for the chip does not live in the tree.  Interpret
+mode on a CPU skips every Mosaic legality rule, and three kernels passed
+their CPU tests for five rounds while the v5e compiler refused each of
+them (ROADMAP S3, S7; deleted in PR 30).  Every Pallas kernel under
+`paddle_tpu/ops/` has a case here, or in the tests above it, at a call
+shape a cell or a listed model makes.
+
 The topology is described inside a fixture, never at import, and every
 test of this kind lives in this one file (one xdist worker loads libtpu).
 Nothing here is a measurement."""
@@ -17,6 +25,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.ops import pallas_ops as po
 from paddle_tpu.ops import ragged_paged_attention as rp
 from paddle_tpu.ops.paged_attention import (paged_cache_update_arrays,
                                             quantized_cache_update_arrays)
@@ -238,3 +247,138 @@ def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
     with open(os.path.join(os.path.dirname(__file__), "fixtures",
                            "gpt_engine_ops_pr27.json")) as f:
         assert got == json.load(f)
+
+
+# -- PR 30: every kernel that stays compiles for the chip ---------------------
+
+def _flash(**kw):
+    return lambda q, k, v, *mask: po.flash_attention_arrays(
+        q, k, v, *mask, **kw)
+
+
+def _grads(fn):
+    """`fn`'s forward and backward kernels (flash_fwd, flash_bwd_dq,
+    flash_bwd_dkv) in one program."""
+    return lambda q, k, v, *rest: jax.grad(
+        lambda *qkv: fn(*qkv, *rest).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _ragged(q, kn, vn, kb, vb, tables, pos0, lens, slots, *scales):
+    kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
+    return rp.ragged_paged_attention_arrays(
+        q, kn, vn, kb, vb, tables, pos0, lens, slots, **kw)
+
+
+def _qkv(b, sq, h, d, sk=None):
+    sk = sq if sk is None else sk
+    return [((b, sq, h, d), jnp.bfloat16)] + [((b, sk, h, d),
+                                               jnp.bfloat16)] * 2
+
+
+def _decode_args(b, smax, h, d):
+    return [((b, 1, h, d), jnp.bfloat16)] + [
+        ((b, smax, h * d), jnp.bfloat16)] * 2 + [((), jnp.int32)]
+
+
+def _ragged_args(b, h, d, nb, bs, pool_dt=jnp.bfloat16):
+    """One layer's decode call over pools `[nb, bs, h*d]`, tables sized
+    for 2,048 tokens a row; int8 pools bring their `[nb, h]` scales."""
+    row = ((b, 1, h, d), jnp.bfloat16)
+    pool = ((nb, bs, h * d), pool_dt)
+    args = [row, row, row, pool, pool, ((b, 2048 // bs), jnp.int32),
+            ((b,), jnp.int32), ((b,), jnp.int32), ((b, 1), jnp.int32)]
+    if pool_dt == jnp.int8:
+        args += [((nb, h), jnp.float32)] * 2
+    return args
+
+
+def _case(name, fn, shapes, n_calls, counted=None, **kw):
+    """A call, its argument shapes, the Mosaic calls in the compiled
+    program, and what the decode kernel's gate counted."""
+    return pytest.param(fn, shapes, n_calls, counted or {}, id=name, **kw)
+
+
+_MASK = [((2, 1, 256, 256), jnp.float32)]
+_HEADS = {"ragged_kernel": 1, "ragged_kernel:head_products": 1}
+_SEGMENTS = {"ragged_kernel": 1, "ragged_kernel:segment_products": 1}
+_REFUSED = pytest.mark.xfail(strict=True, reason=(
+    "the flash kernels' [B, 1] / [B, S] int32 operand: `block shape ... "
+    "divisible by 8 and 128` (ROADMAP S9)"))
+KERNEL_CASES = [
+    # gpt3-1.3b.pretrain-2k's call and GPT-2 124M's: forward and backward
+    _case("flash_fwd_bwd_pretrain2k", _grads(_flash(is_causal=True)),
+          _qkv(2, 2048, 16, 128), 3),
+    _case("flash_fwd_bwd_h12_d64", _grads(_flash(is_causal=True)),
+          _qkv(8, 1024, 12, 64), 3),
+    # chat-c16's prefills (H16) at each block geometry the kernel picks
+    # for them, and the longest of docqa-c8 (H32)
+    _case("flash_fwd_prefill_h16_s128", _flash(is_causal=True),
+          _qkv(1, 128, 16, 128), 1),
+    _case("flash_fwd_prefill_h16_s256", _flash(is_causal=True),
+          _qkv(1, 256, 16, 128), 1),
+    _case("flash_fwd_prefill_h16_s1024", _flash(is_causal=True),
+          _qkv(1, 1024, 16, 128), 1),
+    _case("flash_fwd_prefill_h32_s1536", _flash(is_causal=True),
+          _qkv(1, 1536, 32, 128), 1),
+    # the kernel's other arguments: an additive mask (forward, and with
+    # its backward), keys of another length, causal over a longer cache,
+    # a window over ungrouped heads, and the two the compiler refuses
+    _case("flash_masked", _flash(), _qkv(2, 256, 16, 128) + _MASK, 1),
+    _case("flash_masked_bwd", _grads(_flash()),
+          _qkv(2, 256, 16, 128) + _MASK, 3),
+    _case("flash_cross", _flash(), _qkv(2, 256, 16, 128, sk=128), 1),
+    _case("flash_causal_cross", _flash(is_causal=True),
+          _qkv(2, 256, 16, 128, sk=512), 1),
+    _case("flash_window", _flash(is_causal=True, window=1024),
+          _qkv(1, 4096, 16, 128), 1),
+    _case("flash_kv_lens",
+          lambda q, k, v, n: po.flash_attention_arrays(q, k, v, kv_lens=n),
+          _qkv(2, 2048, 16, 128) + [((2,), jnp.int32)], 1, marks=_REFUSED),
+    _case("flash_segment_ids",
+          lambda q, k, v, ids: po.flash_attention_arrays(
+              q, k, v, is_causal=True, segment_ids=ids),
+          _qkv(2, 2048, 16, 128) + [((2, 2048), jnp.int32)], 1,
+          marks=_REFUSED),
+    # generate()'s dense decode at the listed GPT widths
+    _case("flash_decode_h16_d128", lambda *a: po.flash_decode_arrays(*a),
+          _decode_args(8, 2048, 16, 128), 1),
+    _case("flash_decode_h12_d64", lambda *a: po.flash_decode_arrays(*a),
+          _decode_args(8, 1024, 12, 64), 1),
+    _case("flash_decode_h32_d128", lambda *a: po.flash_decode_arrays(*a),
+          _decode_args(8, 2048, 32, 128), 1),
+    # the decode kernel's tile of pool blocks at the block sizes above the
+    # cells' 16: two blocks a tile, one, and a block larger than the tile
+    _case("ragged_bs32", _ragged, _ragged_args(16, 16, 128, 1024, 32), 1,
+          _HEADS),
+    _case("ragged_bs64", _ragged, _ragged_args(16, 16, 128, 512, 64), 1,
+          _HEADS),
+    _case("ragged_bs128", _ragged, _ragged_args(16, 16, 128, 256, 128), 1,
+          _HEADS),
+    # its segment-indicator body, which no cell runs: int8 pools at the
+    # two GPT cells' shapes (block 32, int8's sublane tile), and
+    # full-precision heads of 64 lanes
+    _case("ragged_int8_gpt3-1.3b", _ragged,
+          _ragged_args(16, 16, 128, 1024, 32, jnp.int8), 1, _SEGMENTS),
+    _case("ragged_int8_gpt3-6.7b", _ragged,
+          _ragged_args(8, 32, 128, 512, 32, jnp.int8), 1, _SEGMENTS),
+    _case("ragged_h12_d64", _ragged, _ragged_args(8, 12, 64, 1024, 16), 1,
+          _SEGMENTS),
+]
+
+
+@pytest.mark.parametrize("fn,shapes,n_calls,counted", KERNEL_CASES)
+def test_kernel_compiles_for_the_chip(S, monkeypatch, fn, shapes, n_calls,
+                                      counted):
+    """Mosaic takes the kernel at this call, and the compiled program
+    holds it: the gate chose the kernel and not its XLA fallback."""
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)   # the gates ask JAX
+    monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
+    po.reset_attention_path_counts()
+    text = jax.jit(fn).lower(
+        *(S(shape, dt) for shape, dt in shapes)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    ragged = {k: v for k, v in po.attention_path_counts().items()
+              if k.startswith("ragged")}
+    assert ragged == counted

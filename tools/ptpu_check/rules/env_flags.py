@@ -3,7 +3,7 @@ README, and every README flag must still exist in code.  Both
 directions.
 
 The bug class: the flag surface grew one env var per PR
-(``PTPU_MONITOR``, ``PTPU_TRACE``, ``PTPU_FAULTS``, ``PTPU_RAGGED``,
+(``PTPU_MONITOR``, ``PTPU_TRACE``, ``PTPU_FAULTS``, ``PTPU_MEMOBS``,
 ...) and the README's documented set drifted behind the code's read
 set — an operator tuning a fleet cannot discover half the knobs, and a
 documented knob that silently stopped being read is worse (set it,

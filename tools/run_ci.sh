@@ -55,9 +55,6 @@ case "${1:-fast}" in
     # config is tiny and shared-host noisy; it catches cliffs, the real
     # lane in `gates` catches percent-level drift on chip hosts.
     python bench.py
-    # ragged-vs-bucketed decode A/B (ISSUE 8): its tokens/s lines join
-    # the same smoke-lane history gate below
-    python bench.py --config ragged_decode
     # router fan-out (ISSUE 17): host-side dispatch throughput over fake
     # in-process replicas — backend-free, so the CPU lane IS the lane;
     # self-asserts sticky routing actually engaged before emitting
