@@ -96,28 +96,41 @@ its tokens - no sync of their own; and `serving/kv_block_steps{group}`
 Host phases (monitor.trace.phase): every boundary of `step()` is one
 phase, and the API pump adds two of its own around it:
 
-    api/drain_submits       the pump's _drain_submits, blocking get included
+    api/drain_submits       the pump's _drain_submits, blocking get included;
+                            add_request touches the device only for a
+                            sampling request's key (read once, there)
     api/push_progress       _push_engine_progress: a queue.put per stream
     engine/schedule         deadline sweep, shedding, scheduler.schedule(),
                             preemption counts
-    engine/prepare          the step's input arrays (a prefill's ids/slots,
-                            a spec step's n-gram drafts), their uploads, the
-                            model program's dispatch call, _store_kv
-    engine/sample_dispatch  the sampler's arrays and its dispatch — the
+    engine/prepare          the step's inputs as host arrays (tokens,
+                            positions, lengths; a table and a slot array a
+                            cache group, slots by array arithmetic; a spec
+                            step's n-gram drafts), the model program's
+                            dispatch call, which uploads them (_run: one
+                            crossing), _store_kv
+    engine/sample_dispatch  the sampler's five host arrays (the rows' keys
+                            are host words: nothing is read for them) and
+                            its dispatch, the second and last upload — the
                             device is busy with the model program
-    engine/readback         np.asarray of tokens/keys: blocked on the device
-    engine/emit             per row: key upload, record_token, TTFT/TPOT
+    engine/readback         _to_host of (tokens, keys[, greedy][, stats]) in
+                            one call: the step's only device-to-host wait
+    engine/emit             per row, host only: the new key (a view of the
+                            array read back), record_token, TTFT/TPOT
                             (spec: acceptance and the table roll-back)
     engine/retire           retire_finished, _finish_request, the SLO tick,
                             the step's counters and gauges
 
 Except in sample_dispatch and readback the device has nothing queued:
-the other six sum to the step's host gap.  Gates: PTPU_MONITOR (default
-on) puts each duration into `serving/host_time{phase}`, nothing synced
-for it; an open profiler session gets a host event `ptpu:<phase>` on the
-device operations' clock (the programs are named for that view:
-prefill_<len>, ragged_decode, ragged_prefill_<c>, spec_verify,
-sample); PTPU_TRACE=1 adds a
+the other six sum to the step's host gap.  A step crosses to the device
+a fixed number of times, whatever the batch holds: a decode step twice
+up (model inputs, sampler inputs) and once down; a prefill step once up,
+and once more up and once down when it samples the first token
+(`serving/device_calls{dir=h2d|d2h}`, counted by `_run` / `_to_host`).
+Gates: PTPU_MONITOR (default on) puts each duration into
+`serving/host_time{phase}`, nothing synced for it; an open profiler
+session gets a host event `ptpu:<phase>` on the device operations' clock
+(the programs are named for that view: prefill_<len>, ragged_decode,
+ragged_prefill_<c>, spec_verify, sample); PTPU_TRACE=1 adds a
 `serving/step` span per step (`phase`, `rows`, the riders' `trace_ids`),
 the phases its children, filed under every rider's trace.
 
@@ -165,7 +178,8 @@ from ..resilience.retry import Deadline
 from ..ops.paged_attention import (paged_cache_update_arrays,
                                    quantized_cache_update_arrays)
 from ..ops.ragged_paged_attention import ragged_paged_attention_arrays
-from .kv_cache import BlockKVCache, CacheGroups, prefix_block_keys
+from .kv_cache import (BlockAllocatorError, BlockKVCache, CacheGroups,
+                       prefix_block_keys)
 from .scheduler import (Request, SamplingParams, Scheduler, priority_rank,
                         should_shed, worst_fast_burn)
 from .spec import propose_ngram
@@ -417,6 +431,12 @@ class LLMEngine:
             "serving/kv_window_released",
             "blocks given back from behind a sliding window")
         self._released_seen = 0
+        calls = m.counter(
+            "serving/device_calls",
+            "host/device crossings of the engine (_run, _to_host): "
+            "a decode step makes 2 h2d and 1 d2h whatever the batch holds")
+        self._m_h2d = calls.labels(dir="h2d")
+        self._m_d2h = calls.labels(dir="d2h")
         self._m_stats = {
             # ptpu-check[metric-hygiene]: names and labels are the form's `stat_counters`: literals in the model's file
             phase: [m.counter(name).labels(phase=phase, **labels)
@@ -586,7 +606,7 @@ class LLMEngine:
             "prompt_ids": list(req.prompt_ids),
             "output_ids": list(req.output_ids),
             "params": req.params,
-            "key": np.asarray(req.key, np.uint32),
+            "key": req.key.copy(),
             "kv": self.kv.swap_out(req_id),
         }
         self.scheduler.running.remove(req)
@@ -622,7 +642,7 @@ class LLMEngine:
         req = Request(self._next_id, prompt, params)
         self._next_id += 1
         req.output_ids = out
-        req.key = jnp.asarray(np.asarray(key, np.uint32))
+        req.key = np.array(key, np.uint32).reshape(2)
         if params.deadline_s is not None:
             req.deadline = Deadline(params.deadline_s)
         # the exporter's cache covered positions [0, total_len-1) — the
@@ -730,15 +750,19 @@ class LLMEngine:
         tid = self._trace_ids.get(req_id)
         return [] if tid is None else mtrace.get_trace(tid)
 
-    @staticmethod
-    def _init_key(params: SamplingParams):
+    def _init_key(self, params: SamplingParams) -> np.ndarray:
+        """The request's sampling key as it rests between steps: the two
+        words of `jax.random.PRNGKey(seed)` (or of the global generator's
+        next key), read to the host once, here.  A greedy row never
+        consumes its key and gets `PRNGKey(0)`'s words, zeros, with no
+        device work."""
         from ..core import random as _rng
 
-        if params.do_sample:
-            if params.seed is not None:
-                return jax.random.PRNGKey(params.seed)
-            return _rng.next_key()
-        return jax.random.PRNGKey(0)    # greedy never consumes it
+        if not params.do_sample:
+            return np.zeros(2, np.uint32)
+        key = (jax.random.PRNGKey(params.seed) if params.seed is not None
+               else _rng.next_key())
+        return np.array(self._to_host(key), np.uint32)
 
     def request_output(self, req_id) -> np.ndarray:
         """[prompt + generated] int32 ids (dense generate's row shape)."""
@@ -1060,10 +1084,10 @@ class LLMEngine:
             whole = start == 0 and chunk == req.prompt_len
             # one slot array a cache group: a whole prompt writes a window
             # group from `tail_start` on, the rest of it is never read
-            slots = tuple(jnp.asarray(np.asarray(
-                [[k.slot(req.req_id, p) for p in range(
-                    k.tail_start(chunk) if whole else start,
-                    start + chunk)]], np.int32))
+            slots = tuple(
+                self._slot_row(k, req.req_id,
+                               k.tail_start(chunk) if whole else start,
+                               start + chunk)
                 for k in self.caches.values())
             kv = self._kv_flat()
             stats = None
@@ -1071,17 +1095,17 @@ class LLMEngine:
                 # whole prompt in one chunk: flash within the chunk, the
                 # dense prefill's exact arithmetic
                 fn = self._get_prefill_exec(chunk)
-                logits, kv_out, stats = fn(self._param_arrays(), kv,
-                                           jnp.asarray(ids), slots)
+                logits, kv_out, stats = self._run(
+                    fn, self._param_arrays(), kv, ids, slots)
             else:
-                tables = tuple(jnp.asarray(np.asarray(
-                    [k.padded_table(req.req_id, self.blocks_per_seq)],
-                    np.int32)) for k in self.caches.values())
+                tables = tuple(
+                    self._table_row(k, req.req_id)[None]
+                    for k in self.caches.values())
                 fn = self._get_ragged_exec(1, chunk)
-                logits, kv_out, stats = fn(
-                    self._param_arrays(), kv, jnp.asarray(ids),
-                    jnp.asarray([start], jnp.int32),
-                    jnp.asarray([start + chunk], jnp.int32), tables, slots)
+                logits, kv_out, stats = self._run(
+                    fn, self._param_arrays(), kv, ids,
+                    np.asarray([start], np.int32),
+                    np.asarray([start + chunk], np.int32), tables, slots)
             self._store_kv(kv_out)
             req.num_computed = start + chunk
             if req.prefix_keys:
@@ -1144,10 +1168,9 @@ class LLMEngine:
             fn = self._get_ragged_exec(bb, 1)
             if mon:
                 self._launches_this_step.add(("ragged", bb, 1))
-            logits, kv_out, stats = fn(
-                self._param_arrays(), self._kv_flat(),
-                jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(lens),
-                tables, slots)
+            logits, kv_out, stats = self._run(
+                fn, self._param_arrays(), self._kv_flat(),
+                toks, pos0, lens, tables, slots)
             self._store_kv(kv_out)
             if mon:
                 for k, (_, live, held) in zip(self.caches.values(),
@@ -1169,12 +1192,35 @@ class LLMEngine:
             self._launches_this_step = None
         return n
 
+    def _table_row(self, k, req_id) -> np.ndarray:
+        """A request's block table in cache `k`, padded to the programs'
+        table width with `num_blocks` (an out-of-range id: the gathers
+        clip it, the masks cover it)."""
+        t = k.block_table(req_id)
+        if len(t) > self.blocks_per_seq:
+            raise BlockAllocatorError(
+                f"sequence {req_id} spans {len(t)} blocks > table width "
+                f"{self.blocks_per_seq}")
+        row = np.full((self.blocks_per_seq,), k.num_blocks, np.int32)
+        row[:len(t)] = t
+        return row
+
+    def _slot_row(self, k, req_id, lo, hi) -> np.ndarray:
+        """Physical slots of positions [lo, hi) of a request in cache `k`,
+        as one [1, hi - lo] row: `table[p // bs] * bs + p % bs`."""
+        pos = np.arange(lo, hi, dtype=np.int32)
+        bs = k.block_size
+        table = np.asarray(k.block_table(req_id), np.int32)
+        return (table[pos // bs] * bs + pos % bs)[None]
+
     def _decode_inputs(self, rows, drafts, bb, cw):
-        """Inputs of one decode program of fixed shape [bb, cw]: row i
-        feeds its last token and `drafts[i]`; padding rows and unused
-        draft positions keep the dropped-slot sentinel (no write, outputs
-        never read).  Tables and slots are one device array a cache
-        group."""
+        """Inputs of one decode program of fixed shape [bb, cw], as host
+        arrays: row i feeds its last token and `drafts[i]`; padding rows
+        and unused draft positions keep the dropped-slot sentinel (no
+        write, outputs never read).  Tables and slots are one array a
+        cache group; a slot is `table[p // bs] * bs + p % bs`, computed
+        over the whole batch at once."""
+        n = len(rows)
         toks = np.zeros((bb, cw), np.int32)
         pos0 = np.zeros((bb,), np.int32)
         lens = np.zeros((bb,), np.int32)
@@ -1188,15 +1234,21 @@ class LLMEngine:
             m = len(drafts[i])
             if m:
                 toks[i, 1:1 + m] = drafts[i]
-            p = req.total_len - 1
-            pos0[i] = p
+            pos0[i] = req.total_len - 1
             lens[i] = req.total_len + m
-            for k, tbl, slt in zip(caches, tables, slots):
-                tbl[i] = k.padded_table(req.req_id, self.blocks_per_seq)
-                for j in range(1 + m):
-                    slt[i, j] = k.slot(req.req_id, p + j)
-        return (toks, pos0, lens, tuple(jnp.asarray(t) for t in tables),
-                tuple(jnp.asarray(sl) for sl in slots))
+            for k, tbl in zip(caches, tables):
+                tbl[i] = self._table_row(k, req.req_id)
+        offs = np.arange(cw, dtype=np.int32)
+        pos = pos0[:n, None] + offs                 # [n, cw]
+        fed = offs[None] < (lens - pos0)[:n, None]  # the positions a row feeds
+        row = np.arange(n)[:, None]
+        for k, tbl, slt in zip(caches, tables, slots):
+            bs = k.block_size
+            # an unfed position may lie past the table: its entry is
+            # clipped here and its slot is the sentinel
+            blk = tbl[row, np.minimum(pos // bs, self.blocks_per_seq - 1)]
+            slt[:n] = np.where(fed, blk * bs + pos % bs, k.num_slots)
+        return toks, pos0, lens, tuple(tables), tuple(slots)
 
     # -- speculative decoding (ISSUE 15 b) ----------------------------------
 
@@ -1242,9 +1294,9 @@ class LLMEngine:
             fn = self._get_verify_exec(bb, cw)
             if mon:
                 self._launches_this_step.add(("verify", bb, cw))
-            logits0, greedy, kv_out = fn(
-                self._param_arrays(), self._kv_flat(), jnp.asarray(toks),
-                jnp.asarray(pos0), jnp.asarray(lens), tables, slots)
+            logits0, greedy, kv_out = self._run(
+                fn, self._param_arrays(), self._kv_flat(),
+                toks, pos0, lens, tables, slots)
             self._store_kv(kv_out)
         emitted = self._emit_spec(rows, drafts, logits0, greedy)
         if mon:
@@ -1269,14 +1321,13 @@ class LLMEngine:
         the shared-block refcounts exact either way)."""
         toks, new_keys = self._dispatch_sampler(rows, logits0)
         with mtrace.phase("engine/readback"):
-            toks = np.asarray(toks)
-            new_keys = np.asarray(new_keys)
-            greedy_h = np.asarray(greedy)
+            toks, new_keys, greedy_h = self._to_host(
+                (toks, new_keys, greedy))
         now = time.perf_counter()
         emitted = proposed = accepted = 0
         with mtrace.phase("engine/emit"):
             for i, req in enumerate(rows):
-                req.key = jnp.asarray(new_keys[i], jnp.uint32)
+                req.key = new_keys[i]
                 out = [int(toks[i])]
                 m = len(drafts[i])
                 proposed += m
@@ -1351,7 +1402,7 @@ class LLMEngine:
             topp = np.ones((bb,), np.float32)
             for i, req in enumerate(rows):
                 p = req.params
-                keys[i] = np.asarray(req.key, np.uint32)
+                keys[i] = req.key
                 ds[i] = p.do_sample
                 temp[i] = p.temperature
                 topk[i] = p.top_k
@@ -1361,26 +1412,24 @@ class LLMEngine:
                 # accounting only; the prefill path samples too but is not
                 # the steady-state loop the kernel count instruments
                 self._launches_this_step.add(("sample", bb))
-            return fn(logits, jnp.asarray(keys), jnp.asarray(ds),
-                      jnp.asarray(temp), jnp.asarray(topk),
-                      jnp.asarray(topp))
+            return self._run(fn, logits, keys, ds, temp, topk, topp)
 
     def _sample_rows(self, rows, logits, stats=None, phase="decode"):
         """Sample one token per live row and emit it.  `stats`: what the
         form's layers counted in the program that made `logits`, read
         back behind the tokens (that program has ended by then)."""
         toks, new_keys = self._dispatch_sampler(rows, logits)
+        if not monitor.enabled():
+            stats = None
         with mtrace.phase("engine/readback"):   # blocked on the device
-            toks = np.asarray(toks)
-            new_keys = np.asarray(new_keys)
-            if stats is not None and monitor.enabled():
-                for counter, n in zip(self._m_stats[phase],
-                                      np.asarray(stats)):
+            toks, new_keys, stats = self._to_host((toks, new_keys, stats))
+            if stats is not None:
+                for counter, n in zip(self._m_stats[phase], stats):
                     counter.inc(int(n))
         now = time.perf_counter()
         with mtrace.phase("engine/emit"):
             for i, req in enumerate(rows):
-                req.key = jnp.asarray(new_keys[i], jnp.uint32)
+                req.key = new_keys[i]
                 req.record_token(int(toks[i]))
                 self._record_latency(req, now)
 
@@ -1571,6 +1620,25 @@ class LLMEngine:
         return out
 
     # -- array plumbing -----------------------------------------------------
+
+    def _run(self, fn, *args):
+        """Dispatch a step program.  Its inputs that rest on the host are
+        numpy arrays among `args`, and the jitted call's own C++ path
+        moves them: one crossing a program, whatever the batch holds (on
+        the chip 0.4-0.7 ms a call under one `jax.device_put` of the
+        tuple, PERF.md PR 31).  Every host-to-device crossing of a step
+        goes through here and counts one
+        `serving/device_calls{dir="h2d"}`."""
+        self._m_h2d.inc()
+        return fn(*args)
+
+    def _to_host(self, arrays):
+        """A pytree of device arrays as host arrays: their copies are
+        started together and waited for once.  Every device-to-host
+        crossing of the engine goes through here and counts one
+        `serving/device_calls{dir="d2h"}`."""
+        self._m_d2h.inc()
+        return jax.device_get(arrays)
 
     def _param_arrays(self):
         return self.form.params()

@@ -318,16 +318,6 @@ class BlockKVCache:
     def block_table(self, seq_id):
         return list(self._tables[seq_id])
 
-    def padded_table(self, seq_id, width):
-        """Block table padded to `width` entries with num_blocks (an
-        out-of-range id — `paged_gather` clips it, masks cover it)."""
-        t = self._tables[seq_id]
-        if len(t) > width:
-            raise BlockAllocatorError(
-                f"sequence {seq_id} spans {len(t)} blocks > table width "
-                f"{width}")
-        return t + [self.num_blocks] * (width - len(t))
-
     def slot(self, seq_id, position) -> int:
         """Physical slot of an (allocated) token position."""
         t = self._tables[seq_id]

@@ -152,7 +152,8 @@ class Request:
         self.state = Request.WAITING
         self.output_ids: list = []         # generated tokens (incl. eos)
         self.num_computed = 0              # prompt tokens prefilled so far
-        self.key = None                    # per-request PRNG key (engine)
+        self.key = None                    # per-request PRNG key: a host
+        #                                    numpy uint32[2] (engine)
         self.swap = None                   # host KV snapshot while evicted
         self.prefix_keys = None            # chained block keys (engine;
         #                                    set only with prefix caching)

@@ -578,3 +578,69 @@ class TestInt8PrefixSpec:
         agree = np.mean([float((r[len(p):] == o[len(p):]).mean())
                          for r, o, p in zip(ref, outs, prompts)])
         assert agree >= 0.9, agree
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: the speculative step crosses to the device a fixed number of times
+# ---------------------------------------------------------------------------
+
+def test_spec_step_crosses_the_same_number_of_times_at_any_batch(model):
+    """A verify step uploads twice (verify inputs, sampler inputs) and
+    reads tokens, keys and the greedy run back in one call, at one live
+    row and at `max_num_seqs`, whatever each row drafts (the drafts here
+    are made up, of unequal length, one row with none: the verify program
+    rejects them, which changes no count)."""
+    def calls():
+        snap = monitor.snapshot().get("serving/device_calls", {})
+        return np.array([snap.get("dir=h2d", 0), snap.get("dir=d2h", 0)],
+                        int)
+
+    monitor.enable(True)
+    try:
+        eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=4,
+                                            speculative_tokens=3))
+        spec_rows = []
+        body = eng._decode_body_spec
+
+        def watched(rows, drafts):
+            spec_rows.append((len(rows), sorted(len(d) for d in drafts)))
+            return body(rows, drafts)
+
+        want = {0: 3}                  # request id -> drafts it proposes
+
+        def propose(req):
+            budget = min(want[req.req_id],
+                         req.params.max_new_tokens - len(req.output_ids) - 1,
+                         eng.max_model_len - req.total_len)
+            return [1] * max(budget, 0)
+
+        eng._decode_body_spec = watched
+        eng._propose = propose
+        rng = np.random.RandomState(2)
+        sp = SamplingParams(max_new_tokens=10)
+        ids = [eng.add_request(rng.randint(0, model.cfg.vocab_size, (6,)),
+                               sp)]
+        eng.step()                                     # its prefill
+        before = calls()
+        eng.step()
+        assert tuple(calls() - before) == (2, 1)
+        assert spec_rows == [(1, [3])]
+        for n in (4, 9, 5):
+            ids.append(eng.add_request(
+                rng.randint(0, model.cfg.vocab_size, (n,)), sp))
+        want.update({0: 0, 1: 1, 2: 2, 3: 3})
+        for _ in range(3):
+            eng.step()                                 # their prefills
+        before = calls()
+        eng.step()
+        assert tuple(calls() - before) == (2, 1)
+        assert spec_rows[-1] == (4, [0, 1, 2, 3])
+        while eng.has_unfinished():
+            before = calls()
+            eng.step()
+            assert tuple(calls() - before) == (2, 1)
+        assert eng.cache.blocks_in_use == 0
+        for i in ids:
+            eng.release_request(i)
+    finally:
+        monitor.refresh()

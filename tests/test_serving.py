@@ -785,3 +785,274 @@ class TestEngineGuards:
         with pytest.raises(ValueError, match="max_model_len"):
             engine.add_request(list(range(60)),
                                SamplingParams(max_new_tokens=60))
+
+
+# -- ISSUE 31: a step crosses to the device a fixed number of times ----------
+
+from paddle_tpu.serving.scheduler import Request  # noqa: E402
+
+
+@pytest.fixture
+def monitored():
+    monitor.enable(True)
+    yield
+    monitor.refresh()
+
+
+def _device_calls():
+    """(h2d, d2h) of `serving/device_calls` so far."""
+    snap = monitor.snapshot().get("serving/device_calls", {})
+    return np.array([snap.get("dir=h2d", 0), snap.get("dir=d2h", 0)], int)
+
+
+def _watch_schedule(eng):
+    """Record (kind, rows) of every step the engine's scheduler decides."""
+    kinds = []
+    inner = eng.scheduler.schedule
+
+    def schedule():
+        out = inner()
+        kinds.append((out.kind, len(out.decode_requests)))
+        return out
+
+    eng.scheduler.schedule = schedule
+    return kinds
+
+
+def _step_calls(eng):
+    before = _device_calls()
+    eng.step()
+    return tuple(_device_calls() - before)
+
+
+@pytest.fixture(scope="module", params=["gpt-one-group", "afmoe-two-groups"])
+def family(request, model):
+    """(model, EngineConfig keywords): GPT tiny is one cache group, afmoe
+    tiny (tests/test_afmoe_serving.py's size) a full group and a window
+    group of 8 tokens."""
+    if request.param == "gpt-one-group":
+        return model, dict(block_size=16)
+    from paddle_tpu.models import AfmoeForCausalLM, afmoe_test_config
+
+    paddle.seed(0)
+    m = AfmoeForCausalLM(afmoe_test_config())
+    m.eval()
+    return m, dict(block_size=4, max_model_len=64)
+
+
+class TestDeviceCrossings:
+    def test_a_step_crosses_the_same_number_of_times_at_any_batch(
+            self, family, monitored):
+        """A decode step uploads twice (model inputs, sampler inputs) and
+        reads back once at 1 live row and at `max_num_seqs` live rows; a
+        whole-prompt prefill step the same whatever the prompt's length."""
+        m, kw = family
+        eng = LLMEngine(m, EngineConfig(max_num_seqs=4, **kw))
+        assert list(eng.caches) in (["full"], ["full", "window"])
+        kinds = _watch_schedule(eng)
+        rng = np.random.RandomState(3)
+        sp = SamplingParams(max_new_tokens=8)
+        ids = [eng.add_request(rng.randint(0, m.cfg.vocab_size, (5,)), sp)]
+        assert _step_calls(eng) == (2, 1) and kinds[-1] == ("prefill", 0)
+        assert _step_calls(eng) == (2, 1) and kinds[-1] == ("decode", 1)
+        for n in (3, 11, 17):          # 17 passes afmoe tiny's window of 8
+            ids.append(eng.add_request(
+                rng.randint(0, m.cfg.vocab_size, (n,)), sp))
+        seen = set()
+        while eng.has_unfinished():
+            calls = _step_calls(eng)
+            assert calls == (2, 1), (kinds[-1], calls)
+            seen.add(kinds[-1])
+        assert ("decode", 4) in seen and ("prefill", 0) in seen
+        for i in ids:
+            eng.release_request(i)
+
+    def test_a_prefill_chunk_that_samples_nothing_uploads_once(
+            self, family, monitored):
+        m, kw = family
+        eng = LLMEngine(m, EngineConfig(max_num_seqs=2,
+                                        max_num_batched_tokens=8, **kw))
+        kinds = _watch_schedule(eng)
+        rid = eng.add_request(list(range(1, 20)),
+                              SamplingParams(max_new_tokens=2))
+        assert _step_calls(eng) == (1, 0)      # positions 0-7
+        assert _step_calls(eng) == (1, 0)      # 8-15
+        assert _step_calls(eng) == (2, 1)      # 16-18 and the first token
+        assert [k for k, _ in kinds] == ["prefill"] * 3
+        assert _step_calls(eng) == (2, 1) and kinds[-1] == ("decode", 1)
+        eng.release_request(rid)
+
+    def test_add_request_reads_the_device_for_a_sampling_key_only(
+            self, engine, monitored):
+        before = _device_calls()
+        a = engine.add_request([1, 2, 3], SamplingParams(max_new_tokens=2))
+        assert tuple(_device_calls() - before) == (0, 0)
+        b = engine.add_request([1, 2, 3], SamplingParams(
+            max_new_tokens=2, do_sample=True, seed=11))
+        assert tuple(_device_calls() - before) == (0, 1)
+        for i in (a, b):
+            engine.release_request(i)
+
+
+def _parent_padded_table(k, seq_id, width):
+    """The parent's `BlockKVCache.padded_table`: the block table padded to
+    `width` entries with `num_blocks`, by list concatenation."""
+    t = k.block_table(seq_id)
+    return t + [k.num_blocks] * (width - len(t))
+
+
+def _parent_decode_inputs(eng, rows, drafts, bb, cw):
+    """`LLMEngine._decode_inputs` as the parent of PR 31 built it, row by
+    row: a padded list a row, one `slot()` call a position."""
+    toks = np.zeros((bb, cw), np.int32)
+    pos0 = np.zeros((bb,), np.int32)
+    lens = np.zeros((bb,), np.int32)
+    caches = list(eng.caches.values())
+    tables = [np.full((bb, eng.blocks_per_seq), k.num_blocks, np.int32)
+              for k in caches]
+    slots = [np.full((bb, cw), k.num_slots, np.int32) for k in caches]
+    for i, req in enumerate(rows):
+        toks[i, 0] = req.output_ids[-1] if req.output_ids \
+            else req.prompt_ids[-1]
+        m = len(drafts[i])
+        if m:
+            toks[i, 1:1 + m] = drafts[i]
+        p = req.total_len - 1
+        pos0[i] = p
+        lens[i] = req.total_len + m
+        for k, tbl, slt in zip(caches, tables, slots):
+            tbl[i] = _parent_padded_table(k, req.req_id, eng.blocks_per_seq)
+            for j in range(1 + m):
+                slt[i, j] = k.slot(req.req_id, p + j)
+    return toks, pos0, lens, tuple(tables), tuple(slots)
+
+
+def _assert_same_inputs(got, want):
+    for g, w in zip(got[:3], want[:3]):
+        assert isinstance(g, np.ndarray) and g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+    for g_group, w_group in zip(got[3:], want[3:]):
+        assert len(g_group) == len(w_group)
+        for g, w in zip(g_group, w_group):
+            assert isinstance(g, np.ndarray) and g.dtype == np.int32
+            assert g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+
+
+class TestStepInputsByArrayArithmetic:
+    """`_decode_inputs` and the prefill body's slot row against the
+    parent's row-by-row construction, kept above."""
+
+    def _rows(self, eng, shapes):
+        """Requests of (prompt, emitted, room for drafts) tokens, their
+        blocks taken from the engine's allocator the way the scheduler
+        does: a whole prompt's tail, then one `grow_to` a decode step."""
+        rows = []
+        for rid, (plen, nout, room) in enumerate(shapes):
+            req = Request(rid, [7 + rid + t for t in range(plen)],
+                          SamplingParams())
+            eng.kv.allocate(rid, plen, tail_only=True)
+            for t in range(nout):
+                req.output_ids.append(100 + rid + t)
+                eng.kv.grow_to(rid, req.total_len + room)
+            rows.append(req)
+        return rows
+
+    @pytest.mark.parametrize("cw", [1, 4])
+    def test_decode_inputs_equal_the_parents(self, family, cw):
+        m, kw = family
+        eng = LLMEngine(m, EngineConfig(max_num_seqs=6, **kw))
+        # short, at a block's edge, past afmoe tiny's window of 8 (its
+        # window group has given blocks back), and two rows left unused
+        rows = self._rows(eng, [(3, 1, cw - 1), (4, 4, cw - 1),
+                                (13, 9, cw - 1), (21, 6, cw - 1)])
+        drafts = [[], [5], [9, 8, 7], [4, 4]] if cw > 1 else [()] * 4
+        got = eng._decode_inputs(rows, drafts, 6, cw)
+        _assert_same_inputs(got, _parent_decode_inputs(eng, rows, drafts,
+                                                       6, cw))
+        for k, tbl, slt in zip(eng.caches.values(), got[3], got[4]):
+            assert (tbl[4:] == k.num_blocks).all()       # padding rows
+            assert (slt[4:] == k.num_slots).all()
+            if cw > 1:                                   # unused drafts
+                assert (slt[0, 1:] == k.num_slots).all()
+                assert (slt[1, 2:] == k.num_slots).all()
+        if len(eng.caches) == 2:
+            win = eng.caches["window"]
+            assert win.released > 0
+            assert (got[3][1][2] == win.num_blocks).any()   # given back
+
+    def test_a_table_wider_than_the_programs_raises(self, model):
+        eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=2))
+        row, = self._rows(eng, [(3, 1, 0)])
+        eng.cache._tables[0] += [0] * eng.blocks_per_seq
+        with pytest.raises(BlockAllocatorError, match="table width"):
+            eng._decode_inputs([row], [()], 2, 1)
+
+    @pytest.mark.parametrize("plen,start", [(5, 0), (30, 0), (20, 12)])
+    def test_prefill_slot_row_equals_the_parents(self, family, plen, start):
+        """A whole prompt writes a window group from `tail_start` on; a
+        later chunk writes from where it starts."""
+        m, kw = family
+        eng = LLMEngine(m, EngineConfig(max_num_seqs=2, **kw))
+        whole = start == 0
+        if whole:
+            eng.kv.allocate(0, plen, tail_only=True)
+        else:                       # the chunks before, then this one
+            eng.kv.allocate(0, start)
+            eng.kv.grow_to(0, plen)
+        for k in eng.caches.values():
+            lo = k.tail_start(plen) if whole else start
+            want = np.asarray([[k.slot(0, p) for p in range(lo, plen)]],
+                              np.int32)
+            got = eng._slot_row(k, 0, lo, plen)
+            assert isinstance(got, np.ndarray) and got.dtype == np.int32
+            assert got.shape == want.shape == (1, plen - lo)
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(
+                eng._table_row(k, 0),
+                np.asarray(_parent_padded_table(k, 0, eng.blocks_per_seq),
+                           np.int32))
+
+
+def _is_host_key(key):
+    return (isinstance(key, np.ndarray) and key.dtype == np.uint32
+            and key.shape == (2,))
+
+
+class TestKeysRestOnTheHost:
+    def test_key_is_host_words_from_admission_to_adoption(self, model):
+        import jax
+
+        eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=4))
+        sp = SamplingParams(max_new_tokens=6, do_sample=True, seed=5,
+                            temperature=0.9)
+        greedy = eng._requests[eng.add_request(
+            [1, 2, 3], SamplingParams(max_new_tokens=6))]
+        seeded = eng._requests[eng.add_request([4, 5, 6, 7], sp)]
+        unseeded = eng._requests[eng.add_request(
+            [4, 5], SamplingParams(max_new_tokens=6, do_sample=True))]
+        assert _is_host_key(greedy.key) and not greedy.key.any()
+        np.testing.assert_array_equal(
+            greedy.key, np.asarray(jax.random.PRNGKey(0)))
+        assert _is_host_key(seeded.key) and _is_host_key(unseeded.key)
+        np.testing.assert_array_equal(
+            seeded.key, np.asarray(jax.random.PRNGKey(5)))
+        for _ in range(4):                    # three prefills and a decode
+            eng.step()
+        assert all(_is_host_key(r.key) for r in (greedy, seeded, unseeded))
+        assert not greedy.key.any()           # a greedy row's key stands
+        assert (seeded.key != np.asarray(jax.random.PRNGKey(5))).any()
+        child = eng._requests[eng.fork_request(seeded.req_id, sp)]
+        assert _is_host_key(child.key)
+        handoff = eng.export_request(seeded.req_id)
+        assert _is_host_key(handoff["key"])
+        np.testing.assert_array_equal(handoff["key"], seeded.key)
+        other = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=4))
+        adopted = other._requests[other.adopt_request(
+            handoff["prompt_ids"], handoff["params"], handoff["output_ids"],
+            list(handoff["key"]), handoff["kv"])]
+        assert _is_host_key(adopted.key)
+        np.testing.assert_array_equal(adopted.key, handoff["key"])
+        other.step()
+        assert _is_host_key(adopted.key)
+        assert (adopted.key != handoff["key"]).any()   # handoff not aliased
