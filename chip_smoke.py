@@ -1,4 +1,4 @@
-"""Does the system still start on the chip?  One command, two legs:
+"""Does the system still start on the chip?  One command, three legs:
 
     python chip_smoke.py             # one TPU chip; anything else is an error
     python chip_smoke.py --chips 4   # train leg only, under init_mesh(dp=2, mp=2)
@@ -16,10 +16,20 @@ serve  the same model behind `LLMEngine(EngineConfig())` +
        concurrent — then a second engine with int8 KV at block_size=32.
        Served tokens are checked against a teacher-forced dense forward of
        the same model: every greedy token must sit in the reference top-k.
+families  the second and third served family, each a small model at its
+       REAL head geometry through `LLMEngine.generate`: afmoe (48 query
+       heads over 8 K/V heads of 128, a sliding and a full layer: two
+       cache groups) and lfm2_moe (32 over 8 heads of 64, gated short
+       convolutions: a K/V group and a state group), hidden 256, 8 experts
+       top-2.  Two prompts of 128 and 256 in a batch of 4 rows, 32 tokens
+       each, against the family's plain float32 reference
+       (`benchmark/lib/reference_afmoe.py`, `reference_lfm2.py`): every
+       served token must sit in the reference top-k.
 
-Both legs run with PTPU_ATTN_DEBUG=1 and assert the attention gates took
+All legs run with PTPU_ATTN_DEBUG=1 and assert the attention gates took
 the Pallas kernels (flash in the train step and prefill, ragged in decode,
-fp and int8) — a kernel that gives way to its XLA reference fails the smoke.
+fp and int8, grouped heads of 128 and of 64 lanes) — a kernel that gives
+way to its XLA reference fails the smoke.
 
 The parent never imports JAX: a chip belongs to one process, so each leg is
 its own child, one after the other, sharing the persistent compile cache.
@@ -104,7 +114,7 @@ def parent_main(args):
         sys.exit("chip_smoke: no paddle_tpu package beside this script — "
                  "run it from a checkout of the repo")
     t0 = time.monotonic()
-    legs = ["train"] if args.chips > 1 else ["train", "serve"]
+    legs = ["train"] if args.chips > 1 else ["train", "serve", "families"]
     results = []
     for leg in legs:
         res = _run_leg(leg, args, t0 + BUDGET_S)
@@ -470,11 +480,98 @@ def serve_leg(args):
     return out
 
 
+def _family_models():
+    """name -> (model class, its configuration, the reference module):
+    small models at each family's real head geometry."""
+    from benchmark.lib import reference_afmoe, reference_lfm2
+    from paddle_tpu.models import (AfmoeConfig, AfmoeForCausalLM,
+                                   Lfm2MoeConfig, Lfm2MoeForCausalLM)
+
+    small = dict(vocab_size=512, hidden_size=256, intermediate_size=512,
+                 moe_intermediate_size=128, num_experts=8,
+                 num_experts_per_tok=2, num_dense_layers=1,
+                 max_position_embeddings=1024, initializer_range=0.05)
+    return {
+        "afmoe": (AfmoeForCausalLM, AfmoeConfig(
+            num_hidden_layers=3, num_attention_heads=48,
+            num_key_value_heads=8, head_dim=128, sliding_window=128,
+            layer_types=["sliding_attention", "full_attention",
+                         "sliding_attention"], **small), reference_afmoe),
+        "lfm2": (Lfm2MoeForCausalLM, Lfm2MoeConfig(
+            num_hidden_layers=4, num_attention_heads=32,
+            num_key_value_heads=8, head_dim=64,
+            layer_types=["conv", "full_attention", "conv", "conv"],
+            **small), reference_lfm2)}
+
+
+def families_leg(args):
+    import dataclasses
+
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.ops.pallas_ops import (attention_path_counts,
+                                           reset_attention_path_counts)
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+    from paddle_tpu.serving.scheduler import SamplingParams
+
+    n_new = 8 if args.tiny else 32
+    out = {}
+    for name, (cls, cfg, ref) in _family_models().items():
+        paddle.seed(0)
+        model = cls(cfg)
+        if jax.devices()[0].platform == "tpu":
+            model.bfloat16()
+        model.eval()
+        rng = np.random.RandomState(2)
+        prompts = [rng.randint(0, cfg.vocab_size, (n,)).tolist()
+                   for n in (128, 256)]
+        reset_attention_path_counts()
+        engine = LLMEngine(model, EngineConfig(
+            block_size=64, max_num_seqs=4, max_model_len=512))
+        served = engine.generate(prompts,
+                                 SamplingParams(max_new_tokens=n_new))
+        counts = attention_path_counts()
+        _check_paths(counts,
+                     need=["attn_kernel:grouped", "ragged_kernel",
+                           "ragged_kernel:head_products"],
+                     allowed_fallbacks=("ragged_fallback:chunk_gt_1",))
+        file_like = dataclasses.asdict(cfg)
+        file_like["harness"] = {"kwargs": {
+            "first_expert": cfg.first_expert,
+            "router_experts": cfg.router_experts}}
+        params = ref.params_from_model(model)
+        worst = 0
+        for prompt, seq in zip(prompts, served):
+            logits = np.asarray(ref.logits(params, jnp.asarray(seq),
+                                           file_like))
+            rows = logits[len(prompt) - 1:-1]
+            toks = seq[len(prompt):]
+            _require(len(toks) == n_new and np.isfinite(rows).all(),
+                     f"{name}: {len(toks)} tokens, finite "
+                     f"{np.isfinite(rows).all()}")
+            ranks = [int((row > row[t]).sum()) for row, t in zip(rows, toks)]
+            worst = max(worst, max(ranks))
+            print(f"{name} prompt {len(prompt)}: "
+                  f"{sum(r == 0 for r in ranks)}/{n_new} tokens are the "
+                  f"reference argmax, worst rank {max(ranks)}", flush=True)
+        _require(worst < TOP_K, f"{name}: served tokens off-reference, "
+                                f"worst rank {worst}")
+        out[name] = {"attention_paths": counts,
+                     "worst_reference_rank": worst,
+                     "cache_groups": list(engine.caches) + list(
+                         engine.states)}
+    return out
+
+
 def child_main(args):
     info, stats = _start_child(args)
     result = {"leg": args.leg, "ok": False}
     try:
-        result.update({"train": train_leg, "serve": serve_leg}[args.leg](args))
+        result.update({"train": train_leg, "serve": serve_leg,
+                       "families": families_leg}[args.leg](args))
         result["ok"] = True
     except Exception as e:   # the leg boundary: report, then fail the child
         import traceback
@@ -491,7 +588,7 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--tiny", action="store_true")
-    ap.add_argument("--leg", choices=("train", "serve"),
+    ap.add_argument("--leg", choices=("train", "serve", "families"),
                     help=argparse.SUPPRESS)    # set by the parent
     args = ap.parse_args()
     if args.leg:

@@ -15,7 +15,8 @@ from .gpt import (
     gpt3_6p7b_config,
 )
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, afmoe_test_config
-from .serving_form import LayerSpec, ServingForm
+from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_test_config
+from .serving_form import LayerSpec, ServingForm, StateSpec
 from .bert import BertConfig, BertModel, BertForSequenceClassification, bert_base_config
 
 __all__ = [
@@ -23,7 +24,8 @@ __all__ = [
     "gpt_test_config", "gpt2_124m_config", "gpt3_1p3b_config",
     "gpt3_6p7b_config",
     "AfmoeConfig", "AfmoeForCausalLM", "afmoe_test_config",
-    "LayerSpec", "ServingForm",
+    "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_test_config",
+    "LayerSpec", "ServingForm", "StateSpec",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "bert_base_config",
 ]

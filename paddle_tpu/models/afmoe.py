@@ -238,7 +238,7 @@ class AfmoeServingForm(ServingForm):
                 cfg.first_expert, cfg.num_experts, cfg.num_experts_per_tok,
                 cfg.route_scale,
                 valid=None if valid is None else jnp.repeat(valid, s),
-                scope="afmoe")
+                scope="afmoe", norm_eps=1e-20)
             with jax.named_scope("afmoe/shared_expert"):
                 shared = swiglu(flat, e["shared_gate_w"], e["shared_up_w"],
                                 e["shared_down_w"])

@@ -113,8 +113,12 @@ def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
         # query heads must be a whole number of groups over the K/V heads
         _count_path("ragged_fallback:kv_head_groups")
         return False
-    if h * d != hd_kv and d % 128:
-        # a grouped K/V head is read as whole lane tiles of a block
+    grouped = h * d != hd_kv
+    if grouped and d % 128 and not (
+            d == 64 and 2 * (h * d // hd_kv) <= _GROUP_ROWS):
+        # a grouped K/V head is read as whole lane tiles of a block, or
+        # two heads of 64 lanes as one tile whose 2 x G query heads fit
+        # the products' rows
         _count_path("ragged_fallback:grouped_head_dim")
         return False
     if quant and (h * d != hd_kv or window is not None):
@@ -138,7 +142,8 @@ def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
         _count_path("ragged_fallback:dtype_mix")
         return False
     _count_path("ragged_kernel")
-    _count_path("ragged_kernel:head_products" if _head_products_ok(d, quant)
+    _count_path("ragged_kernel:head_products"
+                if _head_products_ok(d, quant, grouped)
                 else "ragged_kernel:segment_products")
     return True
 
@@ -152,14 +157,17 @@ def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
 _TILE_TOKENS = 64
 
 
-def _head_products_ok(d, quant) -> bool:
+def _head_products_ok(d, quant, grouped=False) -> bool:
     """Which body the stream takes, from what the call can see.  A head
     that is whole lane tiles of a full-precision pool row is sliced out
     and multiplied on the MXU (`_head_stream`), whatever the number of
     query heads that read it, one included.  A head of 64 lanes cannot be
-    sliced out, and the int8 pools' scales are gathered per head beside
-    the codes: those keep the segment-indicator body."""
-    return d % 128 == 0 and not quant
+    sliced out; where query heads share it (`grouped`), two such heads
+    are one lane tile and the stream multiplies the pair (`_pair_members`).
+    Ungrouped heads of 64 lanes and the int8 pools, whose scales are
+    gathered per head beside the codes, keep the segment-indicator
+    body."""
+    return (d % 128 == 0 or (grouped and d == 64)) and not quant
 
 
 # ---------------------------------------------------------------------------
@@ -466,6 +474,37 @@ def _head_stream(r, tbl_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
             o_ref.dtype)
 
 
+def _pair_members(q, h, g):
+    """Grouped heads of 64 lanes as `_head_stream`'s rows.  A 128-lane
+    tile t of a pool row holds K/V heads 2t and 2t + 1, and their 2 x G
+    query heads are the tile's members: member p * G + m is query head
+    (2t + p) * G + m, ZERO outside half p of the tile's lanes, so the
+    tile's product `q [GP, 128] x K^T` gives each member its own head's
+    scores, and `p x V [T, 128]` its own head's values in half p (the
+    other half, the neighbour's values under this head's weights, is
+    dropped by `_pair_outputs`).  q [B, Hq, 64] -> [B, GP, H * 64]."""
+    b, _, d = q.shape
+    qr = q.reshape(b, h // 2, 2, g, d)            # tile, half, member
+    zero = jnp.zeros_like(qr[:, :, 0])
+    own = jnp.concatenate(
+        [jnp.stack([qr[:, :, 0], zero], axis=3),
+         jnp.stack([zero, qr[:, :, 1]], axis=3)], axis=2)  # [B,t,2G,2,d]
+    rows = jnp.swapaxes(own.reshape(b, h // 2, 2 * g, 2 * d), 1,
+                        2).reshape(b, 2 * g, h * d)
+    return jnp.pad(rows, ((0, 0), (0, _GROUP_ROWS - 2 * g), (0, 0)))
+
+
+def _pair_outputs(o, h, g):
+    """`_pair_members`' inverse on the kernel's output: member p * G + m
+    of tile t keeps half p.  o [B, GP, H * 64] -> [B, Hq, 64]."""
+    b = o.shape[0]
+    d = o.shape[2] // h
+    o6 = o[:, :2 * g].reshape(b, 2, g, h // 2, 2, d)
+    kept = jnp.stack([o6[:, 0, :, :, 0], o6[:, 1, :, :, 1]],
+                     axis=3)                      # [B, G, t, half, d]
+    return kept.transpose(0, 2, 3, 1, 4).reshape(b, h * g, d)
+
+
 # jitted on its own: a model's layers call it with the same shapes, and the
 # kernel (the per-head body unrolls 16 or 32 heads, twice) is then traced
 # and lowered once a program, not once a layer - 0.3 s a layer on the host
@@ -490,10 +529,14 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
     slots_i = jnp.asarray(slots, jnp.int32).reshape(b)
     row = pl.BlockSpec((1, 1, hd), lambda r, *pre: (r, 0, 0))
     pool = pl.BlockSpec(memory_space=pltpu.HBM)
-    heads = _head_products_ok(d, quant)
+    heads = _head_products_ok(d, quant, g > 1)
+    paired = heads and d % 128 != 0     # grouped heads of 64 lanes
     t_rows = max(1, _TILE_TOKENS // bs) * bs if heads else bs
     if g == 1:
         q_row, q_members = row, q.reshape(b, c, hd)
+    elif paired:
+        q_row = pl.BlockSpec((1, _GROUP_ROWS, hd), lambda r, *pre: (r, 0, 0))
+        q_members = _pair_members(q.reshape(b, h_q, d), h, g)
     else:
         # query head j * G + m -> member m, lane segment j; the members
         # padded with zero rows to one sublane tile
@@ -544,9 +587,11 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
         out_specs=[q_row, pool, pool],
         scratch_shapes=scratch,
     )
-    kernel = functools.partial(_ragged_fused_kernel, bs=bs, h=h, d=d,
-                               nb=nb, maxb=maxb, scale=scale, quant=quant,
-                               window=window, heads=heads)
+    # a pair of 64-lane heads is, to the stream, one head of a lane tile
+    kernel = functools.partial(
+        _ragged_fused_kernel, bs=bs, h=h // 2 if paired else h,
+        d=2 * d if paired else d, nb=nb, maxb=maxb, scale=scale,
+        quant=quant, window=window, heads=heads)
     # aliasing indices INCLUDE the scalar-prefetch args (lens=0, slots=1,
     # tables=2, q=3, k_new=4, v_new=5, pools=6/7)
     outs = pl.pallas_call(
@@ -559,8 +604,13 @@ def _ragged_kernel_call(q, k_new, v_new, k_blocks, v_blocks, block_table,
         input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
     )(lens_i, slots_i, tbl, *args)
-    o = outs[0].reshape(b, c, h_q, d) if g == 1 else jnp.swapaxes(
-        outs[0][:, :g].reshape(b, g, h, d), 1, 2).reshape(b, c, h_q, d)
+    if g == 1:
+        o = outs[0].reshape(b, c, h_q, d)
+    elif paired:
+        o = _pair_outputs(outs[0], h, g).reshape(b, c, h_q, d)
+    else:
+        o = jnp.swapaxes(outs[0][:, :g].reshape(b, g, h, d), 1,
+                         2).reshape(b, c, h_q, d)
     k2, v2 = outs[1], outs[2]
     if quant:
         return o, k2, v2, new_scales[0], new_scales[1]

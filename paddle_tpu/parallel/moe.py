@@ -223,22 +223,24 @@ def _moe_mlp_dispatch(x, gate_logits, w_in, w_out, top_k, capacity_factor,
 _SPLIT_ROWS = 1024
 
 
-def route_top_k(m, router_w, bias, top_k, route_scale):
+def route_top_k(m, router_w, bias, top_k, route_scale, norm_eps):
     """Sigmoid routing over the router's full width -> (sel [T,k] int32,
     w [T,k] float32).  Scores are float32 whatever `m` is: a bf16 score
     moves the top-k across near-ties.  `bias` enters the selection only;
-    the weights are the selected scores normalised over all `top_k`
-    (held here or not) times `route_scale`."""
+    the weights are the selected scores over their sum plus `norm_eps`
+    (the family's constant: afmoe 1e-20, lfm2_moe 1e-6), taken over all
+    `top_k` (held here or not), times `route_scale`."""
     scores = jax.nn.sigmoid(jnp.dot(
         m, router_w, preferred_element_type=jnp.float32))
     _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, sel, axis=-1)
-    w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
     return sel.astype(jnp.int32), w * route_scale
 
 
 def held_experts_arrays(m, router_w, bias, experts, first, n, top_k,
-                        route_scale, valid=None, scope="moe"):
+                        route_scale, valid=None, scope="moe",
+                        norm_eps=1e-20):
     """What experts `first .. first + n - 1` add for the tokens `m`.
 
     m:        [T, H] tokens (after the pre-MLP norm)
@@ -246,6 +248,7 @@ def held_experts_arrays(m, router_w, bias, experts, first, n, top_k,
     bias:     [E] selection bias (never enters the weights)
     experts:  (gate_w [n,H,I], up_w [n,H,I], down_w [n,I,H]) SwiGLU experts
     valid:    optional [T] bool; a False row (batch padding) routes nowhere
+    norm_eps: the constant under the routing weights (`route_top_k`)
     -> (y [T, H] float32, stats int32 [4] = pairs held, pairs absent,
         distinct held experts with at least one token, tokens routed;
         held + absent == top_k * tokens when no pair was dropped)
@@ -259,7 +262,8 @@ def held_experts_arrays(m, router_w, bias, experts, first, n, top_k,
     t, h = m.shape
     gate_w, up_w, down_w = experts
     with jax.named_scope(f"{scope}/router"):
-        sel, w = route_top_k(m, router_w, bias, top_k, route_scale)
+        sel, w = route_top_k(m, router_w, bias, top_k, route_scale,
+                             norm_eps)
         local = sel - first
         here = (local >= 0) & (local < n)
         real = (jnp.ones((t, 1), bool) if valid is None

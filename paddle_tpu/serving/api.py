@@ -135,6 +135,20 @@ class _Stream:
         self.submitted_t = None    # perf_counter at submit()
 
 
+class _Httpd(ThreadingHTTPServer):
+    """The API's listening socket.  `socketserver`'s accept queue holds 5
+    connections: when more callers connect at once than the accept loop,
+    which shares the interpreter with the pump and every handler, has
+    taken, the kernel drops the handshake's last step and the caller's
+    first write is answered with a reset (`ConnectionResetError`, no byte
+    served: 15 of 2,738 requests of 64 closed-loop callers, 3 of ~550 on
+    the chip, PERF.md PR 32).  The queue is sized for a full batch of
+    callers reconnecting together, many times over."""
+
+    request_queue_size = 1024
+    daemon_threads = True
+
+
 class ApiServer:
     """The HTTP tier.  ``engine`` XOR ``router``; ``port=0`` binds an
     ephemeral port (read ``.port``/``.url``).  ``api_keys`` overrides
@@ -175,8 +189,7 @@ class ApiServer:
         self._stop = threading.Event()
         self._pump_thread = threading.Thread(
             target=self._pump, name="ptpu-api-pump", daemon=True)
-        self._httpd = ThreadingHTTPServer((host, int(port)), _ApiHandler)
-        self._httpd.daemon_threads = True
+        self._httpd = _Httpd((host, int(port)), _ApiHandler)
         self._httpd.api = self
         self.host, self.port = self._httpd.server_address[:2]
         self._http_thread = threading.Thread(

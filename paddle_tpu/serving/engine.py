@@ -19,8 +19,15 @@ table; a request holds a table in each, and every program takes one table
 and one slot array per group.  A stacked-blocks GPT is one group of full
 layers and allocates exactly as before; afmoe (models/afmoe.py) is a
 `full` and a `window` group, the second bounded by the window whatever a
-sequence's length (kv_cache.py).  Options a family does not carry
-(`ServingForm.unsupported`) raise at construction by name.
+sequence's length (kv_cache.py).  A layer that keeps no K/V but a state of
+fixed size a sequence names a `StateSpec` instead, and the engine keeps a
+`StateCache` per state group beside the K/V groups, a slot a request: every
+program takes one slot-index array a state group, a layer's state pool
+travels among the donated pools in layer order, and the layer's step is
+handed the rows' states and hands back the new ones (lfm2_moe's gated
+short convolutions, models/lfm2.py).  A model with no such layer builds
+no state group and its programs are what they were.  Options a family does
+not carry (`ServingForm.unsupported`) raise at construction by name.
 
 Step programs (all array-level, weights threaded as inputs):
 
@@ -91,7 +98,11 @@ window group), and what a form's layers count a step
 absent}`, `serving/moe_experts_touched{phase}`, `serving/moe_tokens{phase}`,
 phase the kind of step), returned by the step's program and read back with
 its tokens - no sync of their own; and `serving/kv_block_steps{group}`
-(blocks held, summed over decode steps).
+(blocks held, summed over decode steps).  ISSUE 32, per state group:
+`serving/state_slots_in_use{group}`, `serving/state_slot_steps{group}`
+(slots held, summed over decode steps), `serving/state_bytes{group}` (the
+pools, set once), and `serving/state_swaps{dir=out|in}` (counted by the
+cache).
 
 Host phases (monitor.trace.phase): every boundary of `step()` is one
 phase, and the API pump adds two of its own around it:
@@ -131,8 +142,9 @@ Gates: PTPU_MONITOR (default on) puts each duration into
 session gets a host event `ptpu:<phase>` on the device operations' clock
 (the programs are named for that view: prefill_<len>, ragged_decode,
 ragged_prefill_<c>, spec_verify, sample); PTPU_TRACE=1 adds a
-`serving/step` span per step (`phase`, `rows`, the riders' `trace_ids`),
-the phases its children, filed under every rider's trace.
+`serving/step` span per step (`phase`, `rows`, the riders' `trace_ids`,
+`state_slots` where the model has state groups), the phases its children,
+filed under every rider's trace.
 
 Observability v2 (monitor.trace): with PTPU_TRACE=1 every request gets a
 trace — root `serving/request` span with `serving/queue_wait`,
@@ -178,8 +190,9 @@ from ..resilience.retry import Deadline
 from ..ops.paged_attention import (paged_cache_update_arrays,
                                    quantized_cache_update_arrays)
 from ..ops.ragged_paged_attention import ragged_paged_attention_arrays
+from ..models.serving_form import StateSpec
 from .kv_cache import (BlockAllocatorError, BlockKVCache, CacheGroups,
-                       prefix_block_keys)
+                       StateCache, prefix_block_keys)
 from .scheduler import (Request, SamplingParams, Scheduler, priority_rank,
                         should_shed, worst_fast_burn)
 from .spec import propose_ngram
@@ -258,12 +271,23 @@ class LLMEngine:
         # cache groups, in the order the layers first name them; a layer's
         # pools are entry `_layer_slot[l]` of its group's cache
         specs = list(form.layer_specs)
-        self._groups: dict = {}
+        self._groups: dict = {}          # K/V groups: name -> [LayerSpec]
+        state_groups: dict = {}          # state groups: name -> [StateSpec]
         self._layer_slot = []
         for spec in specs:
-            layers = self._groups.setdefault(spec.group, [])
+            kind = (state_groups if isinstance(spec, StateSpec)
+                    else self._groups)
+            layers = kind.setdefault(spec.group, [])
             self._layer_slot.append(len(layers))
             layers.append(spec)
+        if not self._groups:
+            raise ValueError(
+                f"{type(model).__name__}'s serving form names no attention "
+                "layer: the engine schedules by K/V blocks")
+        shared = sorted(set(self._groups) & set(state_groups))
+        if shared:
+            raise ValueError(
+                f"a K/V group and a state group share a name: {shared}")
         # full groups first: `self.cache` is a full group where one exists
         self._groups = dict(sorted(
             self._groups.items(), key=lambda kv: kv[1][0].window is not None))
@@ -299,9 +323,18 @@ class LLMEngine:
             for name, layers in self._groups.items()}
         # the first group's cache: the only one of a one-group model
         self.cache = next(iter(self.caches.values()))
+        # a slot a running sequence, and the dropped slot of padding rows
+        self.states = {}
+        for name, layers in state_groups.items():
+            if len({(tuple(s.shape), s.dtype) for s in layers}) != 1:
+                raise ValueError(f"state group {name!r}: its layers must "
+                                 "share a shape and a dtype")
+            self.states[name] = StateCache(
+                len(layers), c.max_num_seqs, layers[0].shape,
+                layers[0].dtype or wdtype, name=name)
         # what the scheduler allocates from: all groups or none
-        self.kv = (self.cache if len(self.caches) == 1
-                   else CacheGroups(self.caches))
+        self.kv = (self.cache if len(self.caches) == 1 and not self.states
+                   else CacheGroups({**self.caches, **self.states}))
         num_blocks = self.cache.num_blocks
         if monitor.enabled():
             monitor.gauge("lowbit/kv_blocks",
@@ -430,6 +463,20 @@ class LLMEngine:
         self._m_kv_released = m.counter(
             "serving/kv_window_released",
             "blocks given back from behind a sliding window")
+        # ISSUE 32: per state group (slots held now, and summed over
+        # decode steps; the pools' bytes, which never change)
+        per_state = (
+            m.gauge("serving/state_slots_in_use",
+                    "state slots held, by state group"),
+            m.counter("serving/state_slot_steps",
+                      "state slots held, summed over decode steps, by "
+                      "state group"))
+        self._m_state = [tuple(x.labels(group=g) for x in per_state)
+                         for g in self.states]
+        for g, st in self.states.items():
+            m.gauge("serving/state_bytes",
+                    "bytes of a state group's pools").labels(
+                group=g).set(st.pool_bytes)
         self._released_seen = 0
         calls = m.counter(
             "serving/device_calls",
@@ -443,10 +490,10 @@ class LLMEngine:
                     for name, labels in form.stat_counters]
             for phase in ("prefill", "decode")}
         # every layer's (cache, index in it), in layer order
-        self._layer_pools = [(self.caches[spec.group], i)
-                             for spec, i in zip(specs, self._layer_slot)]
-        self._pool_names = ("k_blocks", "v_blocks") + (
-            ("k_scales", "v_scales") if self._kv_quant else ())
+        self._layer_pools = [
+            ((self.states if isinstance(spec, StateSpec)
+              else self.caches)[spec.group], i)
+            for spec, i in zip(specs, self._layer_slot)]
         self._tenant_kv_peak: dict = {}
         self._storm = mmem.StormDetector()
         self._memobs_prev = {"evict": 0, "swap_in": 0}
@@ -912,6 +959,9 @@ class LLMEngine:
                 for r in riders:
                     step_span.link(r.trace)
                 step_span.attrs.update(phase=out.kind, rows=len(riders))
+                if self.states:
+                    step_span.attrs.update(state_slots=sum(
+                        st.slots_in_use for st in self.states.values()))
         toks = 0
         if out.kind == "prefill":
             self._step_prefill(out, step_span)
@@ -975,6 +1025,8 @@ class LLMEngine:
             self._released_seen = released
         for p, (in_use, _, _) in zip(per, self._m_group):
             in_use.set(p["in_use"])
+        for st, (in_use, _) in zip(self.states.values(), self._m_state):
+            in_use.set(st.slots_in_use)
         self._m_blocks.set(c["in_use"])
         self._m_util.set(c["in_use"] / max(c["total"], 1))
         self._m_kv_free.set(c["free"])
@@ -1089,6 +1141,8 @@ class LLMEngine:
                                k.tail_start(chunk) if whole else start,
                                start + chunk)
                 for k in self.caches.values())
+            srows = tuple(np.asarray([st.slot_of(req.req_id)], np.int32)
+                          for st in self.states.values())
             kv = self._kv_flat()
             stats = None
             if whole:
@@ -1096,7 +1150,7 @@ class LLMEngine:
                 # dense prefill's exact arithmetic
                 fn = self._get_prefill_exec(chunk)
                 logits, kv_out, stats = self._run(
-                    fn, self._param_arrays(), kv, ids, slots)
+                    fn, self._param_arrays(), kv, ids, slots, srows)
             else:
                 tables = tuple(
                     self._table_row(k, req.req_id)[None]
@@ -1105,7 +1159,8 @@ class LLMEngine:
                 logits, kv_out, stats = self._run(
                     fn, self._param_arrays(), kv, ids,
                     np.asarray([start], np.int32),
-                    np.asarray([start + chunk], np.int32), tables, slots)
+                    np.asarray([start + chunk], np.int32), tables, slots,
+                    srows)
             self._store_kv(kv_out)
             req.num_computed = start + chunk
             if req.prefix_keys:
@@ -1163,14 +1218,14 @@ class LLMEngine:
         # no recompile when the running-request count changes
         bb = self.scheduler.max_num_seqs
         with mtrace.phase("engine/prepare"):
-            toks, pos0, lens, tables, slots = self._decode_inputs(
+            toks, pos0, lens, tables, slots, srows = self._decode_inputs(
                 rows, [()] * n, bb, 1)
             fn = self._get_ragged_exec(bb, 1)
             if mon:
                 self._launches_this_step.add(("ragged", bb, 1))
             logits, kv_out, stats = self._run(
                 fn, self._param_arrays(), self._kv_flat(),
-                toks, pos0, lens, tables, slots)
+                toks, pos0, lens, tables, slots, srows)
             self._store_kv(kv_out)
             if mon:
                 for k, (_, live, held) in zip(self.caches.values(),
@@ -1178,6 +1233,9 @@ class LLMEngine:
                     live.inc(int(lens.sum()) if k.window is None else
                              int(np.minimum(lens, k.window).sum()))
                     held.inc(k.blocks_in_use)
+                for st, (_, held) in zip(self.states.values(),
+                                         self._m_state):
+                    held.inc(st.slots_in_use)
         self._sample_rows(rows, logits, stats, "decode")
         if mon:
             # padding accounting: bb rows ran, n were real — the
@@ -1219,7 +1277,8 @@ class LLMEngine:
         and unused draft positions keep the dropped-slot sentinel (no
         write, outputs never read).  Tables and slots are one array a
         cache group; a slot is `table[p // bs] * bs + p % bs`, computed
-        over the whole batch at once."""
+        over the whole batch at once.  The last entry holds one array a
+        STATE group: each row's state slot, padding rows the dropped one."""
         n = len(rows)
         toks = np.zeros((bb, cw), np.int32)
         pos0 = np.zeros((bb,), np.int32)
@@ -1238,6 +1297,10 @@ class LLMEngine:
             lens[i] = req.total_len + m
             for k, tbl in zip(caches, tables):
                 tbl[i] = self._table_row(k, req.req_id)
+        srows = tuple(
+            np.asarray([st.slot_of(r.req_id) for r in rows]
+                       + [st.num_slots] * (bb - n), np.int32)
+            for st in self.states.values())
         offs = np.arange(cw, dtype=np.int32)
         pos = pos0[:n, None] + offs                 # [n, cw]
         fed = offs[None] < (lens - pos0)[:n, None]  # the positions a row feeds
@@ -1248,7 +1311,7 @@ class LLMEngine:
             # clipped here and its slot is the sentinel
             blk = tbl[row, np.minimum(pos // bs, self.blocks_per_seq - 1)]
             slt[:n] = np.where(fed, blk * bs + pos % bs, k.num_slots)
-        return toks, pos0, lens, tuple(tables), tuple(slots)
+        return toks, pos0, lens, tuple(tables), tuple(slots), srows
 
     # -- speculative decoding (ISSUE 15 b) ----------------------------------
 
@@ -1289,14 +1352,14 @@ class LLMEngine:
         cw = self.spec_tokens + 1      # verify chunk width, fixed
         bb = self.scheduler.max_num_seqs
         with mtrace.phase("engine/prepare"):
-            toks, pos0, lens, tables, slots = self._decode_inputs(
+            toks, pos0, lens, tables, slots, srows = self._decode_inputs(
                 rows, drafts, bb, cw)
             fn = self._get_verify_exec(bb, cw)
             if mon:
                 self._launches_this_step.add(("verify", bb, cw))
             logits0, greedy, kv_out = self._run(
                 fn, self._param_arrays(), self._kv_flat(),
-                toks, pos0, lens, tables, slots)
+                toks, pos0, lens, tables, slots, srows)
             self._store_kv(kv_out)
         emitted = self._emit_spec(rows, drafts, logits0, greedy)
         if mon:
@@ -1462,7 +1525,8 @@ class LLMEngine:
         sit in ONE report and the fusion win is readable as
         ``ragged_fused.wall_time_s`` vs the trio's sum.
         """
-        if len(self.caches) > 1 or self.form.layer_specs[0].num_heads \
+        if len(self.caches) > 1 or self.states \
+                or self.form.layer_specs[0].num_heads \
                 != self.form.layer_specs[0].num_kv_heads:
             raise ValueError(
                 "decode_breakdown covers one cache group of layers with as "
@@ -1644,15 +1708,16 @@ class LLMEngine:
         return self.form.params()
 
     def _kv_flat(self):
-        """Every layer's pools in layer order: (k, v) a layer, or (k, v,
-        k_scales, v_scales) under int8."""
+        """Every layer's pools in layer order: (k, v) an attention layer,
+        or (k, v, k_scales, v_scales) under int8; (state,) a state
+        layer."""
         return tuple(getattr(c, n)[i] for c, i in self._layer_pools
-                     for n in self._pool_names)
+                     for n in c.pool_names)
 
     def _store_kv(self, kv_out):
         it = iter(kv_out)
         for c, i in self._layer_pools:
-            for n in self._pool_names:
+            for n in c.pool_names:
                 getattr(c, n)[i] = next(it)
 
     # -- jitted step programs ----------------------------------------------
@@ -1729,26 +1794,48 @@ class LLMEngine:
         return self.form.last_logits(params, h).astype(jnp.float32)
 
     def _run_blocks(self, params, kv_flat, x, pos, attn_builder,
-                    valid=None):
+                    valid=None, srows=(), fresh=False):
         """Every layer of the form over `x`; `attn_builder(spec, group
-        index, *the layer's pools)` makes the attention that layer's step
-        calls.  -> (h, the updated pools in layer order, the layers'
-        summed counts or None)."""
-        stride = 4 if self._kv_quant else 2
-        groups = list(self._groups)
+        index, *the layer's pools)` makes the attention an attention
+        layer's step calls, `_state_fn` what a state layer's step calls
+        over its pool and its group's entry of `srows` (`fresh`: the rows
+        start from zeros, a whole-prompt prefill).  -> (h, the updated
+        pools in layer order, the layers' summed counts or None)."""
+        groups, state_groups = list(self._groups), list(self.states)
         h = x
         outs = []
         stats = None
+        at = 0
         for l, spec in enumerate(self.form.layer_specs):
-            layer_kv = kv_flat[stride * l:stride * (l + 1)]
-            attn_fn = attn_builder(spec, groups.index(spec.group),
-                                   *layer_kv)
+            width = len(self._layer_pools[l][0].pool_names)
+            layer_kv = kv_flat[at:at + width]
+            at += width
+            if isinstance(spec, StateSpec):
+                attn_fn = self._state_fn(
+                    layer_kv[0], srows[state_groups.index(spec.group)],
+                    fresh)
+            else:
+                attn_fn = attn_builder(spec, groups.index(spec.group),
+                                       *layer_kv)
             h, extra, st = self.form.layer(l, params, h, pos, attn_fn,
                                            valid=valid)
             outs += list(extra)
             if st is not None:
                 stats = st if stats is None else stats + st
         return h, tuple(outs), stats
+
+    @staticmethod
+    def _state_fn(pool, rows, fresh):
+        """What a state layer's step calls (`ServingForm.layer`): the
+        rows' states out of the layer's `pool` (zeros when `fresh`), the
+        layer's own `step` over them, and the states it returns written
+        back to the same slots - the dropped slot for padding rows."""
+        def state_fn(step):
+            prev = (jnp.zeros((rows.shape[0],) + pool.shape[1:], pool.dtype)
+                    if fresh else pool[rows])
+            y, new = step(prev)
+            return y, (pool.at[rows].set(new.astype(pool.dtype)),)
+        return state_fn
 
     @staticmethod
     def _attn_scope(spec):
@@ -1762,7 +1849,7 @@ class LLMEngine:
             # a window group is written from its tail on (kv_cache.py)
             tails = [k.tail_start(p_len) for k in self.caches.values()]
 
-            def prefill(params, kv_flat, ids, slots):
+            def prefill(params, kv_flat, ids, slots, srows=()):
                 from ..ops.pallas_ops import flash_attention_arrays
 
                 pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
@@ -1795,8 +1882,9 @@ class LLMEngine:
                         return o, extra
                     return attn_fn
 
-                h, kv_out, stats = self._run_blocks(params, kv_flat, x, pos,
-                                                    builder)
+                h, kv_out, stats = self._run_blocks(
+                    params, kv_flat, x, pos, builder, srows=srows,
+                    fresh=True)
                 return self._model_tail(params, h), kv_out, stats
 
             self._jit_cache[key] = jax.jit(
@@ -1805,12 +1893,13 @@ class LLMEngine:
         return self._jit_cache[key]
 
     def _ragged_blocks(self, c, params, kv_flat, ids, pos0, lens, tables,
-                       slots):
+                       slots, srows=()):
         """Embeddings, then every block with ONE fused
         `ragged_paged_attention_arrays` call per layer: cache write +
         attention (+ int8 dequant at the block loads) — no separate
         `block_gather/attention/cache_update` triple.  `tables` and
-        `slots` hold one array a cache group.  -> (h, kv_out, stats)."""
+        `slots` hold one array a cache group, `srows` one a state group.
+        -> (h, kv_out, stats)."""
         pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
         x = self.form.embed(params, ids, pos)
 
@@ -1831,7 +1920,7 @@ class LLMEngine:
 
         # a padding row of the fixed-shape batch has no keys
         return self._run_blocks(params, kv_flat, x, pos, builder,
-                                valid=lens > 0)
+                                valid=lens > 0, srows=srows)
 
     def _get_ragged_exec(self, b, c):
         """The ISSUE-8 decode program (`_ragged_blocks` + the last

@@ -101,6 +101,15 @@ carried over a window group and raise.
 pool shape and table each) behind the allocator calls the scheduler
 makes: each call reaches every group or none.
 
+**State groups** (`StateCache`, ISSUE 32): the memory of layers that keep
+no K/V, a tensor of fixed size a sequence whatever its length (a gated
+short convolution's last two inputs).  A pool ``[slots + 1, *shape]`` a
+layer, a SLOT a sequence instead of blocks a token, behind the same
+allocator calls: `allocate` takes one slot and zeroes it, `grow_to` and
+`truncate_to` cost nothing, `swap_out` / `swap_in` and `fork` copy it
+bit-exactly.  The last slot belongs to nobody: padding rows of a
+fixed-shape program read and write it.
+
 **Quantized mode** (``kv_quant="int8"``, the `paddle_tpu.lowbit` KV
 wing): pools store int8 codes plus per-block-per-head float32 scales
 (``k_scales[l], v_scales[l] : [num_blocks, num_heads]``, value =
@@ -114,19 +123,21 @@ block is reallocated (`_reset_scales`).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import struct
 import time
 from collections import OrderedDict
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .. import monitor
 from ..monitor import memory as mmemory
 
 __all__ = ["BlockKVCache", "BlockAllocatorError", "CacheGroups",
-           "prefix_block_keys"]
+           "StateCache", "prefix_block_keys"]
 
 
 class BlockAllocatorError(RuntimeError):
@@ -236,6 +247,13 @@ class BlockKVCache:
             "parked prefix blocks reclaimed for fresh allocations")
 
     # -- introspection ------------------------------------------------------
+
+    @property
+    def pool_names(self) -> tuple:
+        """The attributes that hold a layer's device arrays, in the order
+        the engine's programs take and return them."""
+        return ("k_blocks", "v_blocks") + (
+            ("k_scales", "v_scales") if self.kv_quant else ())
 
     @staticmethod
     def block_bytes(block_size, num_heads, head_dim, dtype=jnp.float32,
@@ -716,19 +734,140 @@ class BlockKVCache:
 
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_slot(pools, slot, rows):
+    """Row `slot` of every layer's pool := that layer's `rows` entry."""
+    return [p.at[slot].set(r) for p, r in zip(pools, rows)]
+
+
+class StateCache:
+    """A state group: per layer a pool ``[num_slots + 1, *shape]`` and a
+    host-side free list over its slots, behind the allocator calls of
+    `BlockKVCache` that `CacheGroups` fans out.  A sequence holds ONE slot
+    whatever its length; slot `num_slots` is the dropped slot of padding
+    rows and is never handed out."""
+
+    pool_names = ("state",)
+
+    def __init__(self, num_layers, num_slots, shape, dtype, name="state"):
+        self.name = name
+        self.num_layers = int(num_layers)
+        self.num_slots = int(num_slots)
+        self.shape = tuple(int(n) for n in shape)
+        self.dtype = dtype
+        self.state = [jnp.zeros((self.num_slots + 1,) + self.shape, dtype)
+                      for _ in range(self.num_layers)]
+        self._zeros = [jnp.zeros(self.shape, dtype)] * self.num_layers
+        self._free = list(range(self.num_slots - 1, -1, -1))   # LIFO
+        self._tables: dict = {}        # seq_id -> slot
+        swaps = monitor.counter(
+            "serving/state_swaps",
+            "sequences whose state slot was saved to / restored from the "
+            "host")
+        self._m_swap = {d: swaps.labels(dir=d) for d in ("out", "in")}
+
+    # -- introspection ------------------------------------------------------
+
+    @property
+    def pool_bytes(self) -> int:
+        return sum(s.size * s.dtype.itemsize for s in self.state)
+
+    @property
+    def slots_in_use(self) -> int:
+        return self.num_slots - len(self._free)
+
+    @property
+    def num_free_blocks(self) -> int:
+        """Free SLOTS: what this group can still admit."""
+        return len(self._free)
+
+    def slot_of(self, seq_id) -> int:
+        return self._tables[seq_id]
+
+    # -- the allocator calls (token counts mean nothing here) ---------------
+
+    def can_allocate(self, num_tokens=0, tail_only=False) -> bool:
+        return bool(self._free)
+
+    def fits_empty(self, seq_id, num_tokens) -> bool:
+        return self.num_slots > 0
+
+    def can_grow_to(self, seq_id, num_tokens) -> bool:
+        return seq_id in self._tables or bool(self._free)
+
+    def _needs_cow(self, seq_id, num_tokens) -> bool:
+        return False
+
+    def _no_window(self, what):
+        pass
+
+    def _take(self, seq_id, rows) -> int:
+        if seq_id in self._tables:
+            raise BlockAllocatorError(f"sequence {seq_id} already allocated")
+        if not self._free:
+            raise BlockAllocatorError("out of state slots")
+        slot = self._tables[seq_id] = self._free.pop()
+        self.state = _write_slot(self.state, np.int32(slot), rows)
+        return slot
+
+    def allocate(self, seq_id, num_tokens=0, tail_only=False):
+        """One slot, zeroed: a sequence starts from no history, whoever
+        held the slot before."""
+        self._take(seq_id, self._zeros)
+
+    def grow_to(self, seq_id, num_tokens):
+        self._tables[seq_id]           # a sequence never admitted: KeyError
+
+    def truncate_to(self, seq_id, num_tokens):
+        pass
+
+    def privatize_last_block(self, seq_id):
+        pass
+
+    def free(self, seq_id):
+        self._free.append(self._tables.pop(seq_id))
+
+    def fork(self, parent_id, child_id):
+        """The child starts from a COPY of the parent's state."""
+        src = np.int32(self._tables[parent_id])
+        self._take(child_id, [s[src] for s in self.state])
+
+    # -- preemption swap ----------------------------------------------------
+
+    def swap_out(self, seq_id):
+        slot = np.int32(self._tables[seq_id])
+        saved = {"state": jax.device_get([s[slot] for s in self.state])}
+        self.free(seq_id)
+        self._m_swap["out"].inc()
+        return saved
+
+    @staticmethod
+    def swap_blocks(saved) -> int:
+        return 0                       # a slot is not counted in blocks
+
+    def can_swap_in(self, saved) -> bool:
+        return bool(self._free)
+
+    def swap_in(self, seq_id, saved):
+        """Restore an evicted sequence's state bit-exactly into a slot."""
+        self._take(seq_id, saved["state"])
+        self._m_swap["in"].inc()
+
+
 class CacheGroups:
     """The caches of a model's layer groups behind the allocator calls the
     scheduler and the engine make.  Every group has its own id space, pool
-    shape and table; a sequence holds a table in each, and each call
-    reaches all groups or none (the checks run over every group before
-    any group is touched)."""
+    shape and table; a sequence holds a table in each K/V group and a slot
+    in each state group, and each call reaches all groups or none (the
+    checks run over every group before any group is touched)."""
 
     def __init__(self, groups: dict):
-        self.groups = dict(groups)          # name -> BlockKVCache
+        self.groups = dict(groups)   # name -> BlockKVCache or StateCache
         self._all = list(self.groups.values())
-        self.first = self._all[0]
+        self._kv = [c for c in self._all if isinstance(c, BlockKVCache)]
+        self.first = self._kv[0]
         self.block_size = self.first.block_size
-        if any(c.block_size != self.block_size for c in self._all):
+        if any(c.block_size != self.block_size for c in self._kv):
             raise ValueError("cache groups share one block_size")
 
     # the scheduler's membership test and its messages read the first
@@ -743,7 +882,7 @@ class CacheGroups:
 
     @property
     def num_free_blocks(self):
-        return min(c.num_free_blocks for c in self._all)
+        return min(c.num_free_blocks for c in self._kv)
 
     def blocks_needed(self, num_tokens):
         return self.first.blocks_needed(num_tokens)

@@ -26,10 +26,11 @@ kernels at the published afmoe shapes (48 query heads over 8 K/V heads of
 with what the reference's deliberate faults read against the same tokens.
 The default run includes the kernel calls, not the engine leg.
 
-`--cells` times the decode kernel alone at the calls of the three serving
+`--cells` times the decode kernel alone at the calls of the four serving
 cells (chat: 16 rows x 16 heads over `[2048,16,2048]`; docqa: 8 rows x 32
 heads over `[1024,16,4096]`; afmoe: 32 rows x 48-over-8 heads over
-`[2080,64,1024]` with the window and `[4224,64,1024]` without), checks
+`[2080,64,1024]` with the window and `[4224,64,1024]` without; lfm2: 64
+rows x 32-over-8 heads of 64 lanes over `[4608,64,512]`), checks
 each against the XLA fallback, and prints ms a call, us a block and GB/s
 of live K/V.  `--cells --split` times it again with its matrix products
 cut out (DMA only), with its DMAs cut out (math only) and with rows of
@@ -326,6 +327,9 @@ def check_ragged_grouped(window):
 _SPREAD16 = [448 + round((r - 7.5) * 37) for r in range(16)]
 _SPREAD8 = [1120 + round((r - 3.5) * 100) for r in range(8)]
 _LONGMIX = [1100] * 13 + [4200] * 13 + [8300] * 6
+# agents-c64's rows by the time they stay: prompts 256 / 1024 / 4096 with
+# about half their answers decoded
+_AGENTS = [400] * 26 + [1200] * 29 + [4200] * 9
 CELLS = {
     "chat": dict(hq=16, hkv=16, bs=16, nb=2048, maxb=128, window=None,
                  lens=_SPREAD16),
@@ -335,6 +339,8 @@ CELLS = {
                          lens=_LONGMIX),
     "afmoe_full": dict(hq=48, hkv=8, bs=64, nb=4224, maxb=132, window=None,
                        lens=_LONGMIX),
+    "lfm2": dict(hq=32, hkv=8, d=64, bs=64, nb=4608, maxb=72, window=None,
+                 lens=_AGENTS),
 }
 
 
@@ -393,7 +399,7 @@ def check_ragged_cell(cell):
     c = CELLS[cell]
     hq, hkv, bs, nb, maxb, window = (c[k] for k in (
         "hq", "hkv", "bs", "nb", "maxb", "window"))
-    d, calls = 128, 24           # a 24-layer program's worth, back to back
+    d, calls = c.get("d", 128), 24    # a 24-layer program's worth, back to back
     rng = np.random.RandomState(17)
 
     def inputs(lens):

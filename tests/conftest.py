@@ -30,6 +30,20 @@ warnings.filterwarnings(
 )
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_mesh_left_by_another_file():
+    """Every test FILE starts with no mesh installed, as a process of its
+    own would.  Files that call `init_mesh` leave their mesh behind
+    (`tests/test_collective_api.py` leaves dp=4), and a flash-attention
+    test of batch 2 that a worker happens to run after one fails on it;
+    which file follows which depends on xdist's schedule, which every new
+    test file moves (PR 32: 14 tests of test_flash_mask.py)."""
+    from paddle_tpu import parallel
+
+    parallel.set_mesh(None)
+    yield
+
+
 @pytest.fixture(autouse=True)
 def _seed():
     import random

@@ -257,7 +257,7 @@ def test_bf16_scores_move_the_selection():
     w = jnp.asarray(0.02 * rng.standard_normal((256, 256)), jnp.bfloat16)
     from paddle_tpu.parallel.moe import route_top_k
 
-    sel, _ = route_top_k(m, w, jnp.zeros(256), 4, 1.0)
+    sel, _ = route_top_k(m, w, jnp.zeros(256), 4, 1.0, 1e-20)
     low = jax.lax.top_k(jax.nn.sigmoid(
         (m @ w).astype(jnp.float32)), 4)[1]
     moved = (np.sort(np.asarray(sel), -1) != np.sort(np.asarray(low), -1)
@@ -433,7 +433,9 @@ def test_ragged_gate_counts_the_new_refusals(_interpret_mode, monkeypatch):
     assert not rp._ragged_kernel_ok(q[:, :, :3], kb, 1, False)   # 3 over 2
     assert not rp._ragged_kernel_ok(q, kb, 1, False, window=0)
     assert not rp._ragged_kernel_ok(q, kb.astype(jnp.int8), 1, True)
-    q64, _, _, kb64, *_ = _ragged_case([9], d=64)    # half a lane tile
+    # heads of half a lane tile, 8 query heads each: a pair's 16 do not
+    # fit the products' 8 rows (4 over 2 does, since PR 32)
+    q64, _, _, kb64, *_ = _ragged_case([9], hq=16, d=64)
     assert not rp._ragged_kernel_ok(q64, kb64, 1, False)
     counts = po.attention_path_counts()
     assert counts["ragged_fallback:grouped_head_dim"] == 1

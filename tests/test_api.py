@@ -319,6 +319,32 @@ def server():
 
 
 class TestApiServer:
+    def test_a_batch_of_callers_connecting_at_once_is_queued(self):
+        """64 closed-loop callers reconnect together whenever a step ends
+        their requests.  With `socketserver`'s accept queue of 5, those
+        the accept loop had not taken yet were reset at their first write
+        (`ConnectionResetError`, 3 of ~550 requests on the chip, PERF.md
+        PR 32).  Here nobody accepts at all: every connection of a burst
+        of 200 must still complete its handshake from the queue."""
+        import socket
+
+        from paddle_tpu.serving.api import _ApiHandler, _Httpd
+
+        httpd = _Httpd(("127.0.0.1", 0), _ApiHandler)   # never served
+        conns = []
+        try:
+            assert httpd.request_queue_size >= 1024 and httpd.daemon_threads
+            for _ in range(200):
+                c = socket.create_connection(httpd.server_address[:2],
+                                             timeout=5)
+                c.sendall(b"POST /v1/completions HTTP/1.1\r\n")
+                conns.append(c)
+        finally:
+            for c in conns:
+                c.close()
+            httpd.server_close()
+        assert len(conns) == 200
+
     def test_models_endpoint(self, server):
         srv, _ = server
         doc = json.loads(urllib.request.urlopen(

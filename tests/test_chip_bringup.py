@@ -239,5 +239,7 @@ def test_chip_smoke_last_stdout_line_is_the_verdict(monkeypatch, capsys,
     assert exc.value.code == (0 if serve_ok else 1)
     report, verdict = map(json.loads, capsys.readouterr().out.splitlines())
     assert verdict == {"ok": serve_ok, "device": device}
-    assert set(report["legs"]) == {"train", "serve"}
+    # a failed leg ends the run: the families leg follows a good serve leg
+    assert set(report["legs"]) == {"train", "serve"} | (
+        {"families"} if serve_ok else set())
     assert report["versions"] == {"jax": "0.9.0"}

@@ -249,6 +249,131 @@ def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
         assert got == json.load(f)
 
 
+def _program_ops(compiled):
+    """{HLO opcode: count} of a compiled program."""
+    import collections
+
+    found = collections.Counter()
+    for line in compiled.as_text().splitlines():
+        m = re.match(
+            r"^\s*(?:ROOT )?%[\w.\-]+ = .*?[\]\})] ([\w\-]+)\(", line)
+        if m:
+            found[m.group(1)] += 1
+    return dict(found)
+
+
+def _afmoe_engine_ops(one_chip, monkeypatch):
+    """The decode and prefill programs of a 2-layer afmoe engine (a full
+    and a sliding layer, the second an expert layer) at the published
+    widths (8 of 256 experts held), compiled for the described v5e
+    -> {program: {opcode: count}}.  Run over the parent commit's tree it
+    wrote tests/fixtures/afmoe_engine_ops_pr31.json."""
+    from paddle_tpu.framework.compat import LazyGuard
+    from paddle_tpu.models import AfmoeConfig, AfmoeForCausalLM
+    from paddle_tpu.ops import pallas_ops as po
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+
+    monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)
+    with LazyGuard():
+        model = AfmoeForCausalLM(AfmoeConfig(
+            vocab_size=25024, num_hidden_layers=2, num_dense_layers=1,
+            layer_types=["full_attention", "sliding_attention"],
+            num_experts=8, router_experts=256))
+    model.to(dtype="bfloat16")
+    eng = LLMEngine(model, EngineConfig(block_size=64, max_num_seqs=32,
+                                        max_model_len=8448, num_blocks=256))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._param_arrays())
+    kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
+    table = i32(32, eng.blocks_per_seq)
+    return {
+        "jit_ragged_decode": _program_ops(eng._get_ragged_exec(32, 1).lower(
+            params, kv, i32(32, 1), i32(32), i32(32), (table, table),
+            (i32(32, 1), i32(32, 1))).compile()),
+        "jit_prefill_1024": _program_ops(eng._get_prefill_exec(1024).lower(
+            params, kv, i32(1, 1024),
+            (i32(1, 1024), i32(1, 1024))).compile())}
+
+
+def test_afmoe_engine_programs_keep_the_parents_operations(one_chip,
+                                                           monkeypatch):
+    """The state group (ISSUE 32) costs the afmoe cell nothing: a model
+    with no state layer builds no state group, and its programs compile to
+    the operation lists recorded from the parent commit."""
+    import json
+
+    got = _afmoe_engine_ops(one_chip, monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           "afmoe_engine_ops_pr31.json")) as f:
+        assert got == json.load(f)
+
+
+def test_state_group_decode_program_donates_pools_and_state(one_chip,
+                                                            monkeypatch):
+    """The decode program of an engine WITH a state group (lfm2_moe at
+    the published widths: a convolution layer, then an attention layer
+    over 8 of 64 experts; 64 rows) compiles for the described v5e; every
+    K/V pool and state pool goes in donated and comes out aliased; each
+    K/V pool enters one kernel call and nothing else of its size is
+    computed; a state pool is gathered from and scattered into, staged
+    through fast memory (`copy-start` / `copy-done`: it is half a
+    megabyte), and never relaid or copied whole by a `copy`."""
+    from paddle_tpu.framework.compat import LazyGuard
+    from paddle_tpu.models import Lfm2MoeConfig, Lfm2MoeForCausalLM
+    from paddle_tpu.ops import pallas_ops as po
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+
+    monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)
+    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
+    po.reset_attention_path_counts()
+    with LazyGuard():
+        model = Lfm2MoeForCausalLM(Lfm2MoeConfig(
+            vocab_size=8192, num_hidden_layers=2, num_dense_layers=1,
+            layer_types=["conv", "full_attention"], num_experts=8,
+            router_experts=64))
+    model.to(dtype="bfloat16")
+    rows = 64
+    eng = LLMEngine(model, EngineConfig(block_size=64, max_num_seqs=rows,
+                                        max_model_len=4608, num_blocks=500))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
+    state, k_pool, _ = kv
+    assert state.shape == (rows + 1, 2, 2048) and k_pool.shape == (
+        500, 64, 512)
+    compiled = eng._get_ragged_exec(rows, 1).lower(
+        jax.tree_util.tree_map(on_chip, eng._param_arrays()), kv,
+        i32(rows, 1), i32(rows), i32(rows), (i32(rows, eng.blocks_per_seq),),
+        (i32(rows, 1),), (i32(rows),)).compile()
+    assert po.attention_path_counts() == {
+        "ragged_kernel": 1, "ragged_kernel:head_products": 1}
+    text = compiled.as_text()
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == len(kv), header[:400]
+    pools = _pool_sized(text, k_pool.size)
+    assert pools.pop("custom-call") == 1, pools    # K and V: one call
+    assert set(pools) <= {"parameter", "get-tuple-element", "bitcast",
+                          "tuple"}, pools
+    states = _pool_sized(text, state.size)
+    assert states.get("fusion:scatter") == 1, states
+    assert set(states) <= {"parameter", "bitcast", "tuple", "scatter",
+                           "fusion:scatter", "copy-start",
+                           "copy-done"}, states
+
+
 # -- PR 30: every kernel that stays compiles for the chip ---------------------
 
 def _flash(**kw):
@@ -291,6 +416,16 @@ def _ragged_args(b, h, d, nb, bs, pool_dt=jnp.bfloat16):
     if pool_dt == jnp.int8:
         args += [((nb, h), jnp.float32)] * 2
     return args
+
+
+def _ragged_args_grouped(b, hq, hkv, d, nb, bs, max_len):
+    """One layer's decode call with `hq` query heads over pools of `hkv`
+    K/V heads, tables sized for `max_len` tokens a row."""
+    new = ((b, 1, hkv, d), jnp.bfloat16)
+    pool = ((nb, bs, hkv * d), jnp.bfloat16)
+    return [((b, 1, hq, d), jnp.bfloat16), new, new, pool, pool,
+            ((b, max_len // bs), jnp.int32), ((b,), jnp.int32),
+            ((b,), jnp.int32), ((b, 1), jnp.int32)]
 
 
 def _case(name, fn, shapes, n_calls, counted=None, **kw):
@@ -364,6 +499,15 @@ KERNEL_CASES = [
           _ragged_args(8, 32, 128, 512, 32, jnp.int8), 1, _SEGMENTS),
     _case("ragged_h12_d64", _ragged, _ragged_args(8, 12, 64, 1024, 16), 1,
           _SEGMENTS),
+    # lfm2-24b-a2b-l9.agents-c64's calls (PR 32): 64 rows of 32 query heads
+    # over 8 K/V heads of 64 lanes, two K/V heads a lane tile of the
+    # `[4608, 64, 512]` pools, through the per-head products; and its
+    # longest prefill
+    _case("ragged_gqa64_lfm2", _ragged,
+          _ragged_args_grouped(64, 32, 8, 64, 4608, 64, 4608), 1, _HEADS),
+    _case("flash_fwd_prefill_gqa64_s4096", _flash(is_causal=True),
+          [((1, 4096, 32, 64), jnp.bfloat16)]
+          + [((1, 4096, 8, 64), jnp.bfloat16)] * 2, 1),
 ]
 
 
