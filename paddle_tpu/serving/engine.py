@@ -102,7 +102,9 @@ its tokens - no sync of their own; and `serving/kv_block_steps{group}`
 `serving/state_slots_in_use{group}`, `serving/state_slot_steps{group}`
 (slots held, summed over decode steps), `serving/state_bytes{group}` (the
 pools, set once), and `serving/state_swaps{dir=out|in}` (counted by the
-cache).
+cache).  ISSUE 33: `serving/sampler_steps{path=argmax|categorical|
+truncated}`, one a sampler dispatch, by what the batch's rows made the
+sample program run (`_sample_program`).
 
 Host phases (monitor.trace.phase): every boundary of `step()` is one
 phase, and the API pump adds two of its own around it:
@@ -120,7 +122,10 @@ phase, and the API pump adds two of its own around it:
                             dispatch call, which uploads them (_run: one
                             crossing), _store_kv
     engine/sample_dispatch  the sampler's five host arrays (the rows' keys
-                            are host words: nothing is read for them) and
+                            are host words: nothing is read for them), the
+                            path they select (counted; the program branches
+                            on the same arrays: no sort for greedy or
+                            untruncated rows, one where a row truncates) and
                             its dispatch, the second and last upload — the
                             device is busy with the model program
     engine/readback         _to_host of (tokens, keys[, greedy][, stats]) in
@@ -172,6 +177,7 @@ tail-based sampling.  All default-off.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -200,6 +206,71 @@ from .spec import propose_ngram
 __all__ = ["EngineConfig", "LLMEngine"]
 
 _NEG_INF = -1e30
+
+
+def _sampler_path(ds, topk, topp):
+    """Which side of `_sample_program` a batch takes, from its rows'
+    parameters (numpy on the host, traced in the program): 0 no row
+    samples, 1 rows sample and none truncates, 2 a sampling row asks for
+    top-k or top-p.  `top_p` on a greedy row (the API passes a body's
+    through) truncates nothing."""
+    truncates = ds & ((topk > 0) | (topp < 1.0))
+    return ds.any().astype(np.int32) + truncates.any().astype(np.int32)
+
+
+_SAMPLER_PATHS = ("argmax", "categorical", "truncated")
+
+
+def _sample_program(logits, keys, ds, temp, topk, topp):
+    """The ("sample", B) program: a token and a next key for every row of
+    [B, V] fp32 logits.  A row's pair is what `models.gpt._sample_next`
+    gives on that row alone, bit for bit, so a request reproduces its solo
+    generate() stream.  What runs is decided once for the whole batch, by
+    `_sampler_path` over the rows' parameters:
+
+        argmax       no row samples: the argmax, the keys as they came
+        categorical  + a key split, logits / temperature, the draw
+        truncated    + ONE descending sort a row, for top-k and top-p both
+
+    The switch sits outside the `vmap`: on a row's own predicate it would
+    lower to a select that runs every side."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def row(truncated, l, key_, t, k, p):
+        new_key, sub = jax.random.split(key_)
+        ll = l[None, :] / jnp.maximum(t, jnp.float32(1e-6))
+        if truncated:
+            v = ll.shape[-1]
+            # sorted once: the top-k mask is monotone in the value, so
+            # masking the sorted row IS sorting the masked row.  Values
+            # alone, so stability orders nothing; asked for, the chip
+            # sorts an index beside them at twice the time
+            desc = jnp.sort(ll, axis=-1, stable=False)[:, ::-1]
+            kth = jnp.take_along_axis(
+                desc, jnp.clip(k - 1, 0, v - 1)[None, None], axis=-1)
+            ll = jnp.where(k > 0, jnp.where(ll < kth, _NEG_INF, ll), ll)
+            desc = jnp.where(
+                k > 0, jnp.where(desc < kth, _NEG_INF, desc), desc)
+            probs = jax.nn.softmax(desc, axis=-1)
+            cum = jnp.cumsum(probs, axis=-1)
+            keep = cum - probs <= p
+            thresh = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1,
+                             keepdims=True)
+            ll = jnp.where(p < 1.0,
+                           jnp.where(ll < thresh, _NEG_INF, ll), ll)
+        samp = jax.random.categorical(sub, ll, axis=-1).astype(jnp.int32)[0]
+        return samp, new_key
+
+    def draw(truncated):
+        samp, new_keys = jax.vmap(functools.partial(row, truncated))(
+            logits, keys, temp, topk, topp)
+        return (jnp.where(ds, samp, greedy),
+                jnp.where(ds[:, None], new_keys, keys))
+
+    return jax.lax.switch(
+        _sampler_path(ds, topk, topp),
+        [lambda: (greedy, keys), functools.partial(draw, False),
+         functools.partial(draw, True)])
 
 
 @dataclasses.dataclass
@@ -484,6 +555,11 @@ class LLMEngine:
             "a decode step makes 2 h2d and 1 d2h whatever the batch holds")
         self._m_h2d = calls.labels(dir="h2d")
         self._m_d2h = calls.labels(dir="d2h")
+        steps = m.counter(
+            "serving/sampler_steps",
+            "sampler dispatches by what the batch made the program run "
+            "(argmax|categorical|truncated)")
+        self._m_sampler = [steps.labels(path=p) for p in _SAMPLER_PATHS]
         self._m_stats = {
             # ptpu-check[metric-hygiene]: names and labels are the form's `stat_counters`: literals in the model's file
             phase: [m.counter(name).labels(phase=phase, **labels)
@@ -1455,7 +1531,10 @@ class LLMEngine:
     def _dispatch_sampler(self, rows, logits):
         """Launch the (\"sample\", B) program over [B, V] fp32 logits (B
         may exceed len(rows) by padding); returns its two device arrays.
-        The model program is still running when this returns."""
+        The model program is still running when this returns.  The rows'
+        own parameters decide what the program runs (`_sampler_path`: a
+        batch of greedy rows sorts nothing); the same arrays count the
+        dispatch into `serving/sampler_steps{path}` here."""
         with mtrace.phase("engine/sample_dispatch"):
             bb = int(logits.shape[0])
             keys = np.zeros((bb, 2), np.uint32)
@@ -1470,6 +1549,7 @@ class LLMEngine:
                 temp[i] = p.temperature
                 topk[i] = p.top_k
                 topp[i] = p.top_p
+            self._m_sampler[_sampler_path(ds, topk, topp)].inc()
             fn = self._get_sample_exec(bb)
             if self._launches_this_step is not None:   # decode-step launch
                 # accounting only; the prefill path samples too but is not
@@ -1666,6 +1746,7 @@ class LLMEngine:
             self._param_arrays(), kv_copy2, toks, pos0, lens, (tables,),
             (slots,), label="decode:step", reps=reps,
             rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
+        # every row greedy, as the rows above: the program's argmax path
         logits = jnp.zeros((bb, self.form.vocab_size), jnp.float32)
         out["sampler"] = mperf.measure(
             self._get_sample_exec(bb),
@@ -1966,34 +2047,7 @@ class LLMEngine:
         key = ("sample", b)
         if key not in self._jit_cache:
             self._count_compile("sample", key)
-
-            def row(l, key_, ds, t, k, p):
-                # replicates models.gpt._sample_next on a [1, V] row so a
-                # request reproduces its solo generate() stream exactly
-                l1 = l[None, :]
-                greedy = jnp.argmax(l1, axis=-1).astype(jnp.int32)[0]
-                ks = jax.random.split(key_)
-                new_key, sub = ks[0], ks[1]
-                ll = l1 / jnp.maximum(t, jnp.float32(1e-6))
-                v = ll.shape[-1]
-                asc = jnp.sort(ll, axis=-1)
-                kth = jnp.take_along_axis(
-                    asc, jnp.clip(v - k, 0, v - 1)[None, None], axis=-1)
-                ll = jnp.where(k > 0, jnp.where(ll < kth, _NEG_INF, ll), ll)
-                desc = jnp.sort(ll, axis=-1)[:, ::-1]
-                probs = jax.nn.softmax(desc, axis=-1)
-                cum = jnp.cumsum(probs, axis=-1)
-                keep = cum - probs <= p
-                thresh = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1,
-                                 keepdims=True)
-                ll = jnp.where(p < 1.0,
-                               jnp.where(ll < thresh, _NEG_INF, ll), ll)
-                samp = jax.random.categorical(sub, ll, axis=-1).astype(
-                    jnp.int32)[0]
-                tok = jnp.where(ds, samp, greedy)
-                out_key = jnp.where(ds, new_key, key_)
-                return tok, out_key
-
-            self._jit_cache[key] = jax.jit(
-                self._named(jax.vmap(row), "sample"))
+            # a wrapper of its own: _named renames what it is given
+            self._jit_cache[key] = jax.jit(self._named(
+                lambda *inputs: _sample_program(*inputs), "sample"))
         return self._jit_cache[key]

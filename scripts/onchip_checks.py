@@ -3,6 +3,7 @@
     chiprun -- python scripts/onchip_checks.py
     python scripts/onchip_checks.py --aot     # no chip: compile only
     chiprun -- python scripts/onchip_checks.py --writes [--tree DIR]
+    chiprun -- python scripts/onchip_checks.py --sampler
 
 Every Pallas kernel on the main path goes through actual Mosaic
 compilation and is compared with its XLA reference at the widths of both
@@ -38,6 +39,16 @@ one token (what a row costs before its stream), and, where the call takes
 the per-head body, once more with the segment-indicator body forced.
 `--tree DIR` as above: the parent's kernel, same inputs.
 
+`--sampler` runs the engine's sample program (`serving.engine.
+_sample_program`) at the four serving cells' logits (`[64,65536]`,
+`[16,50304]`, `[32,25024]`, `[8,50304]`) over three batches - every row
+greedy, every row temperature only, every row `top_k=50, top_p=0.9` -
+beside the program it replaced (two vocabulary sorts a row whatever the
+rows ask for: `tests/test_sampler.py::two_sort_row`): tokens and keys
+equal, then us a call of each on the device (the profiler's `XLA Modules`
+line).  No cell sends sampled
+traffic, so the two branches that draw are measured here alone.
+
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
 stops there.  That catches Mosaic refusals from a CPU-only sandbox; it
@@ -60,6 +71,7 @@ WRITES_ONLY = "--writes" in sys.argv[1:]
 AFMOE_ONLY = "--afmoe" in sys.argv[1:]
 CELLS_ONLY = "--cells" in sys.argv[1:]
 SPLIT = "--split" in sys.argv[1:]
+SAMPLER_ONLY = "--sampler" in sys.argv[1:]
 AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
 
@@ -707,6 +719,72 @@ def check_writes(h=16, d=128, layers=12):
                 f"  {k} {v:.1f} us" for k, v in took.items() if v), flush=True)
 
 
+SAMPLER_SHAPES = {"lfm2": (64, 65536), "chat": (16, 50304),
+                  "afmoe": (32, 25024), "docqa": (8, 50304)}
+# every row of the batch: (do_sample, temperature, top_k, top_p)
+SAMPLER_BATCHES = {"greedy": (False, 1.0, 0, 1.0),
+                   "temperature": (True, 0.8, 0, 1.0),
+                   "top_k50_top_p0.9": (True, 0.8, 50, 0.9)}
+
+
+def _module_us(fn, args, reps=10):
+    """Mean device time of `fn(*args)`, us, from the `XLA Modules` line
+    of a profiler trace over `reps` runs."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        try:
+            for _ in range(reps):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        [path] = glob.glob(os.path.join(tmp, "plugins", "profile", "*",
+                                        "*.xplane.pb"))
+        took = [ev.duration_ns
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/device:TPU:0")
+                for line in plane.lines if line.name == "XLA Modules"
+                for ev in line.events]
+    assert len(took) == reps, f"{len(took)} module events for {reps} runs"
+    return sum(took) / reps / 1e3
+
+
+def check_sampler(cell):
+    """The sample program at one cell's logits against the two-sort
+    program it replaced: equal tokens and keys, then what each costs."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.engine import _sample_program
+    from tests.test_sampler import two_sort_row
+
+    b, v = SAMPLER_SHAPES[cell]
+    programs = {"change": jax.jit(_sample_program),
+                "two_sorts": jax.jit(jax.vmap(two_sort_row))}
+    logits = 3.0 * jax.random.normal(jax.random.PRNGKey(33), (b, v),
+                                     jnp.float32)
+    keys = jnp.asarray(np.stack([np.asarray(jax.random.PRNGKey(i), np.uint32)
+                                 for i in range(b)]))
+    for batch, (ds, t, k, p) in SAMPLER_BATCHES.items():
+        args = (logits, keys, jnp.full((b,), ds), jnp.full((b,), t, jnp.float32),
+                jnp.full((b,), k, jnp.int32), jnp.full((b,), p, jnp.float32))
+        if AOT:
+            _compile_only(_sample_program, args)
+            continue
+        got, want = (jax.device_get(fn(*args)) for fn in programs.values())
+        for g, w in zip(got, want):
+            assert (g == w).all(), f"sampler_{cell} {batch}: {g} != {w}"
+        print(f"SAMPLER {cell} [{b},{v}] {batch}: tokens and keys equal; "
+              + ", ".join(f"{name} {_module_us(fn, args):.1f} us"
+                          for name, fn in programs.items()), flush=True)
+    print(f"OK sampler_{cell}", flush=True)
+
+
 def check_generate():
     """`generate()` with the flash-decode kernel forced on: the one
     integration check of the kernel-inside-generate routing."""
@@ -773,6 +851,9 @@ def main():
     if CELLS_ONLY:
         checks = [(f"check_ragged_cell_{c}", check_ragged_cell, (c,))
                   for c in CELLS]
+    if SAMPLER_ONLY:
+        checks = [(f"check_sampler_{c}", check_sampler, (c,))
+                  for c in SAMPLER_SHAPES]
     for name, fn, args in checks:
         with _Watchdog(name, 900.0 if fn in (check_writes, check_afmoe_engine,
                                              check_ragged_cell) else 240.0):

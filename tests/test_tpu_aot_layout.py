@@ -526,3 +526,36 @@ def test_kernel_compiles_for_the_chip(S, monkeypatch, fn, shapes, n_calls,
     ragged = {k: v for k, v in po.attention_path_counts().items()
               if k.startswith("ragged")}
     assert ragged == counted
+
+
+@pytest.mark.parametrize("rows,vocab", [(64, 65536), (16, 50304)],
+                         ids=["lfm2-agents-c64", "gpt3-chat-c16"])
+def test_sample_program_keeps_its_sort_under_the_conditional(S, rows, vocab):
+    """The engine's sample program at two cells' shapes: the chip's
+    compiler keeps the batch-level switch a `conditional` (it may flatten
+    one into selects that run every side), and the only vocabulary sort
+    lies in the third branch's computation, which a batch of greedy or
+    untruncated rows never enters."""
+    from paddle_tpu.serving.engine import _sample_program
+
+    text = jax.jit(_sample_program).lower(
+        S((rows, vocab), jnp.float32), S((rows, 2), jnp.uint32),
+        S((rows,), jnp.bool_), S((rows,), jnp.float32),
+        S((rows,), jnp.int32), S((rows,), jnp.float32)).compile().as_text()
+    holder = {}             # opcode -> the computations that hold one
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+        m = _INSTR.match(line)
+        if m and m.group(2) in ("sort", "conditional"):
+            holder.setdefault(m.group(2), []).append((name, line))
+    [(where, switch)] = holder["conditional"]
+    assert where == "ENTRY"
+    branches = re.search(r"branch_computations=\{([^}]*)\}", switch)
+    branches = [b.strip() for b in branches.group(1).split(",")]
+    assert len(branches) == 3
+    [(where, sort)] = holder["sort"]
+    assert where == branches[2], (where, branches)
+    assert f"f32[{rows},1,{vocab}]" in sort
