@@ -16,19 +16,23 @@ serve  the same model behind `LLMEngine(EngineConfig())` +
        concurrent — then a second engine with int8 KV at block_size=32.
        Served tokens are checked against a teacher-forced dense forward of
        the same model: every greedy token must sit in the reference top-k.
-families  the second and third served family, each a small model at its
-       REAL head geometry through `LLMEngine.generate`: afmoe (48 query
-       heads over 8 K/V heads of 128, a sliding and a full layer: two
-       cache groups) and lfm2_moe (32 over 8 heads of 64, gated short
-       convolutions: a K/V group and a state group), hidden 256, 8 experts
+families  the second, third and fourth served family, each a small model
+       at its REAL head geometry through `LLMEngine.generate`: afmoe (48
+       query heads over 8 K/V heads of 128, a sliding and a full layer:
+       two cache groups), lfm2_moe (32 over 8 heads of 64, gated short
+       convolutions: a K/V group and a state group) and mistral4 (32 heads
+       of 64 + 64 over a latent row of 256 + 64: ONE pool a layer, prefill
+       expanded through flash, decode absorbed through the latent kernel,
+       positions past a shrunk original context), hidden 256, 8 experts
        top-2.  Two prompts of 128 and 256 in a batch of 4 rows, 32 tokens
        each, against the family's plain float32 reference
-       (`benchmark/lib/reference_afmoe.py`, `reference_lfm2.py`): every
-       served token must sit in the reference top-k.
+       (`benchmark/lib/reference_afmoe.py`, `reference_lfm2.py`,
+       `reference_mistral4.py`): every served token must sit in the
+       reference top-k.
 
 All legs run with PTPU_ATTN_DEBUG=1 and assert the attention gates took
 the Pallas kernels (flash in the train step and prefill, ragged in decode,
-fp and int8, grouped heads of 128 and of 64 lanes) — a kernel that gives
+fp and int8, grouped heads of 128 and of 64 lanes, latent rows) — a kernel that gives
 way to its XLA reference fails the smoke.
 
 The parent never imports JAX: a chip belongs to one process, so each leg is
@@ -480,12 +484,19 @@ def serve_leg(args):
     return out
 
 
+_GROUPED_PATHS = ["attn_kernel:grouped", "ragged_kernel",
+                  "ragged_kernel:head_products"]
+
+
 def _family_models():
-    """name -> (model class, its configuration, the reference module):
-    small models at each family's real head geometry."""
-    from benchmark.lib import reference_afmoe, reference_lfm2
+    """name -> (model class, its configuration, the reference module, the
+    attention paths its programs must take): small models at each family's
+    real head geometry."""
+    from benchmark.lib import (reference_afmoe, reference_lfm2,
+                               reference_mistral4)
     from paddle_tpu.models import (AfmoeConfig, AfmoeForCausalLM,
-                                   Lfm2MoeConfig, Lfm2MoeForCausalLM)
+                                   Lfm2MoeConfig, Lfm2MoeForCausalLM,
+                                   Mistral4Config, Mistral4ForCausalLM)
 
     small = dict(vocab_size=512, hidden_size=256, intermediate_size=512,
                  moe_intermediate_size=128, num_experts=8,
@@ -496,12 +507,26 @@ def _family_models():
             num_hidden_layers=3, num_attention_heads=48,
             num_key_value_heads=8, head_dim=128, sliding_window=128,
             layer_types=["sliding_attention", "full_attention",
-                         "sliding_attention"], **small), reference_afmoe),
+                         "sliding_attention"], **small), reference_afmoe,
+                  _GROUPED_PATHS),
         "lfm2": (Lfm2MoeForCausalLM, Lfm2MoeConfig(
             num_hidden_layers=4, num_attention_heads=32,
             num_key_value_heads=8, head_dim=64,
             layer_types=["conv", "full_attention", "conv", "conv"],
-            **small), reference_lfm2)}
+            **small), reference_lfm2, _GROUPED_PATHS),
+        # the published heads and latent; the original context shrunk to
+        # 128 so that the prompts pass it (YaRN's ramp, a_t > 1)
+        "mistral4": (Mistral4ForCausalLM, Mistral4Config(
+            vocab_size=512, hidden_size=256, num_hidden_layers=2,
+            q_lora_rank=128, moe_intermediate_size=128, n_routed_experts=8,
+            num_experts_per_tok=2, max_position_embeddings=1024,
+            initializer_range=0.05, rope_parameters={
+                "rope_type": "yarn", "rope_theta": 10000.0, "factor": 8.0,
+                "original_max_position_embeddings": 128, "beta_fast": 32.0,
+                "beta_slow": 1.0, "mscale": 1.0, "mscale_all_dim": 1.0,
+                "llama_4_scaling_beta": 0.1}), reference_mistral4,
+            ["attn_kernel", "ragged_kernel",
+             "ragged_kernel:latent_products"])}
 
 
 def families_leg(args):
@@ -519,7 +544,7 @@ def families_leg(args):
 
     n_new = 8 if args.tiny else 32
     out = {}
-    for name, (cls, cfg, ref) in _family_models().items():
+    for name, (cls, cfg, ref, need) in _family_models().items():
         paddle.seed(0)
         model = cls(cfg)
         if jax.devices()[0].platform == "tpu":
@@ -534,9 +559,7 @@ def families_leg(args):
         served = engine.generate(prompts,
                                  SamplingParams(max_new_tokens=n_new))
         counts = attention_path_counts()
-        _check_paths(counts,
-                     need=["attn_kernel:grouped", "ragged_kernel",
-                           "ragged_kernel:head_products"],
+        _check_paths(counts, need=need,
                      allowed_fallbacks=("ragged_fallback:chunk_gt_1",))
         file_like = dataclasses.asdict(cfg)
         file_like["harness"] = {"kwargs": {

@@ -16,7 +16,9 @@ from .gpt import (
 )
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, afmoe_test_config
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_test_config
-from .serving_form import LayerSpec, ServingForm, StateSpec
+from .mistral4 import (Mistral4Config, Mistral4ForCausalLM,
+                       mistral4_test_config)
+from .serving_form import LatentSpec, LayerSpec, ServingForm, StateSpec
 from .bert import BertConfig, BertModel, BertForSequenceClassification, bert_base_config
 
 __all__ = [
@@ -25,7 +27,8 @@ __all__ = [
     "gpt3_6p7b_config",
     "AfmoeConfig", "AfmoeForCausalLM", "afmoe_test_config",
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_test_config",
-    "LayerSpec", "ServingForm", "StateSpec",
+    "Mistral4Config", "Mistral4ForCausalLM", "mistral4_test_config",
+    "LatentSpec", "LayerSpec", "ServingForm", "StateSpec",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "bert_base_config",
 ]

@@ -144,11 +144,13 @@ def rms_norm(x, w, eps):
     return y.astype(x.dtype) * w
 
 
-def rope(x, pos, theta):
+def rope(x, pos, theta, inv=None):
     """Rotate-half over the whole head.  x [B,S,heads,D]; pos [B,S], or
-    [S] where every row sits at the same positions."""
+    [S] where every row sits at the same positions.  `inv` [D/2]: the
+    pairs' frequencies where they are not `theta`'s plain ones."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[..., None] * inv              # [..,S,D/2]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]

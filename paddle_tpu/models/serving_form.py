@@ -16,14 +16,23 @@ group (`serving.kv_cache.StateCache`) and hands the layer step its rows.
 `GPTForCausalLM.serving_form()` (models/gpt.py) is the first form,
 `AfmoeForCausalLM.serving_form()` (models/afmoe.py) the second,
 `Lfm2MoeForCausalLM.serving_form()` (models/lfm2.py), the first with
-state layers, the third.
+state layers, the third, `Mistral4ForCausalLM.serving_form()`
+(models/mistral4.py), the first with latent layers, the fourth.
+
+A `LatentSpec` names an attention layer whose cache keeps ONE row a token,
+a latent from which every head's key and value are made (multi-head latent
+attention): what such a layer ATTENDS in a whole-prompt prefill, per-head
+keys and values expanded from the latents of the chunk itself, is not what
+it STORES, and every program that reads the pool attends the stored rows
+in the absorbed form, the expansion folded into the query and out of the
+result.  The layer hands the engine both (`ServingForm.layer`).
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional
 
-__all__ = ["LayerSpec", "StateSpec", "ServingForm"]
+__all__ = ["LayerSpec", "LatentSpec", "StateSpec", "ServingForm"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +45,41 @@ class LayerSpec:
     window: Optional[int]        # key j visible to query i iff 0 <= i-j < window
     group: str                   # cache group: layers of one group share
     #                              a pool shape, an id space and a table
+
+    latent = False               # the cache keeps K and V as attended
+
+    def pool_row(self) -> tuple:
+        """(heads, lanes a head, pools a layer) of the group's cache."""
+        return self.num_kv_heads, self.head_dim, 2
+
+    @property
+    def scope(self) -> str:
+        """The named scope of the layer's attention in a program."""
+        return "attn/window" if self.window else "attn/full"
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """One layer's latent attention, as the engine has to serve it: the
+    cache row of a token is `key_dim` numbers that every query head scores
+    against (one K/V "head" under `num_heads` query heads), and the row's
+    first `value_dim` are the value every head sums."""
+
+    num_heads: int               # query heads
+    key_dim: int                 # a cache row: what an absorbed query meets
+    value_dim: int               # its leading lanes that are the value
+    scale: float                 # the softmax's, in both forms
+    group: str = "latent"        # cache group: ONE pool a layer
+
+    latent = True                # the engine stores `rows`, not K and V
+    window = None                # every stored row is visible
+    scope = "attn/latent"
+
+    def pool_row(self) -> tuple:
+        """One "head" a token, the row in whole lane tiles, ONE pool."""
+        from ..ops.paged_attention import latent_pool_lanes
+
+        return 1, latent_pool_lanes(self.key_dim), 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +127,15 @@ class ServingForm:
         (y, new state)` is the layer's own, called once with the rows'
         states as they stood before this chunk (zeros at a sequence's
         start), and what it returns as the new state is kept for the next
-        one.  `valid` [B] bool marks real rows of a padded
+        one.  For a `LatentSpec` layer it passes `latent_fn(rows [B,S,
+        key_dim], whole, stored) -> (o, extra)`: `rows` are the chunk's
+        latents, which the engine stores; `whole(attend) -> o` is called
+        in a whole-prompt prefill with `attend(q, k, v [B,S,H,D]) -> [B,S,
+        H,D]`, causal attention within the chunk at the spec's scale;
+        `stored(attend) -> o` in every program that reads the pool, with
+        `attend(q [B,S,H,key_dim]) -> [B,S,H,value_dim]`, every head
+        against the stored rows.  The engine calls exactly one of the
+        two.  `valid` [B] bool marks real rows of a padded
         decode batch (None: all).  -> (h, extra, stats or None), `stats`
         an int32 vector along `stat_counters`."""
         raise NotImplementedError

@@ -32,6 +32,14 @@ exact 0 probability (exp underflows to 0.0), contributing exactly nothing
 to the reductions.  tests/test_serving.py pins this parity against
 `GPTModel.generate()`.
 
+Latent rows (ISSUE 34, latent attention): a layer may keep ONE pool whose
+row is a token's latent - `key_dim` numbers that every query head scores
+against, the first `value_dim` of them also the value every head sums
+(`latent_cache_update_arrays`, `latent_paged_attention_arrays`).  The pool
+is `latent_pool_lanes(key_dim)` wide, whole lane tiles, zeros past
+`key_dim`: a DMA moves whole tiles, and a row of 320 takes three of them in
+HBM however it is declared.
+
 No Pallas kernel here yet: at S_q = 1 the op is bandwidth-bound (MXU
 irrelevant), matching the dense decode path's design note; a fused
 gather+attention kernel is the obvious follow-up once serving shapes are
@@ -47,7 +55,9 @@ import jax.numpy as jnp
 
 __all__ = ["paged_attention_arrays", "paged_cache_update_arrays",
            "paged_gather_kv_arrays", "slot_mapping",
-           "quantized_cache_update_arrays", "quantized_gather_kv_arrays"]
+           "quantized_cache_update_arrays", "quantized_gather_kv_arrays",
+           "latent_pool_lanes", "latent_cache_update_arrays",
+           "latent_paged_attention_arrays"]
 
 _NEG_INF = -1e30
 
@@ -143,6 +153,15 @@ def paged_cache_update_arrays(blocks, rows, slots):
     """
     nb, bs, _ = blocks.shape
     _check_consecutive(slots, bs, nb * bs)
+    if isinstance(blocks, jax.core.Tracer) and not isinstance(
+            slots, jax.core.Tracer):
+        # slots closed over by the caller's jit (the engine's are traced):
+        # where the rows are constants of the program too, the TPU
+        # compiler folds `_block_window`'s slices of constant rows at
+        # constant offsets to ZEROS (the optimised HLO for a described v5e
+        # holds the broadcast; the CPU's is right; PERF.md, PR 34).  Behind
+        # a barrier the offsets are a run-time value and the slices taken.
+        slots = jax.lax.optimization_barrier(jnp.asarray(slots, jnp.int32))
     return _update(blocks, rows, slots)
 
 
@@ -317,4 +336,49 @@ def paged_attention_arrays(q, k_blocks, v_blocks, block_table, pos0,
     logits = jnp.where(seen[:, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(vg.dtype), vg)
+    return out.astype(q.dtype)
+
+
+# -- latent rows: one pool a layer, the value inside the key ----------------
+
+def latent_pool_lanes(key_dim) -> int:
+    """Width of a latent pool's row: `key_dim` rounded up to whole lane
+    tiles (320 -> 384)."""
+    return -(-int(key_dim) // 128) * 128
+
+
+def latent_cache_update_arrays(pool, rows, slots):
+    """Write latent rows [B, S, key_dim] into the pool [num_blocks,
+    block_size, lanes >= key_dim], zeros in the lanes past `key_dim`;
+    `slots` as `paged_cache_update_arrays` takes them."""
+    pad = int(pool.shape[2]) - int(rows.shape[-1])
+    return paged_cache_update_arrays(
+        pool, jnp.pad(rows, ((0, 0), (0, 0), (0, pad))), slots)
+
+
+def latent_paged_attention_arrays(q, pool, block_table, pos0, value_dim,
+                                  scale):
+    """Causal attention of absorbed queries against a latent pool.
+
+    q:     [B, S, H, key_dim]: every head scores against the SAME row
+    pool:  [num_blocks, block_size, lanes >= key_dim] (the chunk's rows
+           already written: write-then-attend)
+    -> [B, S, H, value_dim]: each head's weights over the rows' first
+       `value_dim` lanes.  The masked-softmax arithmetic is
+       `paged_attention_arrays`': additive -1e30 mask over the whole
+       padded extent, float32 softmax, weights cast to the pool's type.
+    """
+    b, s, h, dk = q.shape
+    rows = paged_gather_kv_arrays(pool, block_table, 1)[:, :, 0]
+    s_pad = rows.shape[1]
+    q_pos = jnp.asarray(pos0, jnp.int32)[:, None] + jnp.arange(
+        s, dtype=jnp.int32)[None, :]                       # [B, S]
+    k_pos = jnp.arange(s_pad, dtype=jnp.int32)
+    seen = k_pos[None, None, :] <= q_pos[:, :, None]       # [B, S, S_pad]
+    logits = jnp.einsum("bqhd,bkd->bhqk", q, rows[..., :dk],
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(seen[:, None], logits, _NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkd->bqhd", probs.astype(rows.dtype),
+                     rows[..., :value_dim])
     return out.astype(q.dtype)

@@ -469,6 +469,9 @@ def _interpret() -> bool:
     return True
 
 
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20     # a kernel's VMEM unless it asks
+
+
 def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
                n_heads=1, mask=None, kv_lens=None, segments=None,
                kv_heads=None, window=None):
@@ -541,6 +544,17 @@ def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
         in_specs.append(pl.BlockSpec((1, sk),
                                      lambda b, h, i: (b, 0)))       # k row
         args.extend([segments, segments])
+    # a head's whole K and V stand in VMEM, each buffered twice: past
+    # the compiler's 16 MB of scoped VMEM (16,384 keys of 128 bf16 lanes
+    # are 16.7 MB) the call asks for what it holds; shorter calls compile
+    # as they always did
+    held = 4 * sk * d * q.dtype.itemsize
+    extra = {}
+    if held > 3 * _SCOPED_VMEM_BYTES // 4:
+        from jax.experimental.pallas import tpu as pltpu
+
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=held + _SCOPED_VMEM_BYTES)
     return pl.pallas_call(
         kernel,
         name="flash_fwd",
@@ -555,6 +569,7 @@ def _flash_fwd(q, k, v, is_causal, scale, block_q=None, block_k=None,
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         interpret=_interpret(),
+        **extra,
     )(*args)
 
 

@@ -51,6 +51,14 @@ Two implementations behind one entry point, selected like
   `quantized_gather_kv_arrays`, so the ragged path makes no
   ``lowbit/dequant_calls{site="paged_gather"}`` increments.
 
+A LATENT pool (ISSUE 34, `ragged_latent_attention_arrays`): one pool a
+layer whose row is a token's latent, key and - in its leading lanes -
+value of every query head alike.  The decode kernel (`_latent_kernel`)
+DMAs a tile of rows ONCE and takes both MXU products from the same buffer,
+the query heads as their rows: `q~ [H, lanes] x rows^T` and `p [H, T] x
+rows[:, :value_dim]`.  The fallback is `latent_cache_update_arrays` +
+`latent_paged_attention_arrays`.
+
 Numerics contract of the fallback: same einsum contraction (fp32
 accumulation), same additive -1e30 causal mask over the SAME padded
 [B, max_blocks * block_size] extent, same softmax/probs-cast as
@@ -69,13 +77,16 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .paged_attention import (paged_attention_arrays,
+from .paged_attention import (latent_cache_update_arrays,
+                              latent_paged_attention_arrays,
+                              paged_attention_arrays,
                               paged_cache_update_arrays,
                               quantized_cache_update_arrays)
 from .pallas_ops import (_NEG_INF, _count_path, _decode_seg_helpers,
                          _dot_f32, _interpret, _on_tpu, _two_block_dma_loop)
 
-__all__ = ["ragged_paged_attention_arrays"]
+__all__ = ["ragged_paged_attention_arrays",
+           "ragged_latent_attention_arrays"]
 
 _QMAX = 127
 
@@ -86,13 +97,9 @@ _QMAX = 127
 # are observable)
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
-    """Geometry/flag gate for the fused ragged kernel.  The kernel serves
-    the decode shape (C = 1) — chunked-prefill and speculative-verify
-    rows (C > 1) take the fallback, which is the parity-exact program
-    anyway (a multi-token kernel variant is the natural follow-up once
-    the verify path earns its on-chip A/B).
-    PTPU_RAGGED_KERNEL=0 hard-disables."""
+def _decode_kernel_wanted(c) -> bool:
+    """What both decode kernels' gates ask first: the flag, the platform,
+    the chunk width (the kernels serve C = 1)."""
     if os.environ.get("PTPU_RAGGED_KERNEL", "").lower() in ("0", "false",
                                                             "off"):
         _count_path("ragged_fallback:disabled")
@@ -102,6 +109,18 @@ def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
         return False
     if c != 1:
         _count_path("ragged_fallback:chunk_gt_1")
+        return False
+    return True
+
+
+def _ragged_kernel_ok(q, k_blocks, c, quant, window=None) -> bool:
+    """Geometry/flag gate for the fused ragged kernel.  The kernel serves
+    the decode shape (C = 1) — chunked-prefill and speculative-verify
+    rows (C > 1) take the fallback, which is the parity-exact program
+    anyway (a multi-token kernel variant is the natural follow-up once
+    the verify path earns its on-chip A/B).
+    PTPU_RAGGED_KERNEL=0 hard-disables."""
+    if not _decode_kernel_wanted(c):
         return False
     _, _, h, d = q.shape
     bs = int(k_blocks.shape[1])
@@ -742,3 +761,199 @@ def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
     out = _folded_quant_attention(q, k2, v2, ks2, vs2, block_table, pos0,
                                   scale)
     return out, k2, v2, ks2, vs2
+
+
+# ---------------------------------------------------------------------------
+# latent rows: one pool a layer, every query head reads the same row, as
+# its key and (the leading lanes) as its value
+# ---------------------------------------------------------------------------
+
+# tokens of latents one step of the latent stream consumes: 256 rows of 384
+# lanes are 192 KB, the size at which `_head_stream`'s tile sits
+_LATENT_TILE_TOKENS = 256
+
+
+def _latent_kernel_ok(q, pool, c, value_dim) -> bool:
+    """Geometry/flag gate of the latent decode kernel, counted like
+    `_ragged_kernel_ok`."""
+    if not _decode_kernel_wanted(c):
+        return False
+    bs, lanes = int(pool.shape[1]), int(pool.shape[2])
+    if lanes % 128 or value_dim % 128 or not value_dim <= q.shape[-1] <= lanes:
+        # the value is sliced out of a loaded tile of rows: whole lane
+        # tiles, inside the key
+        _count_path("ragged_fallback:latent_geometry")
+        return False
+    if bs % (16 if pool.dtype == jnp.bfloat16 else 8):
+        _count_path("ragged_fallback:block_size")
+        return False
+    if q.dtype != pool.dtype:
+        _count_path("ragged_fallback:dtype_mix")
+        return False
+    _count_path("ragged_kernel")
+    _count_path("ragged_kernel:latent_products")
+    return True
+
+
+def _latent_kernel(len_ref, slot_ref, tbl_ref, q_ref, rn_ref, p_hbm, o_ref,
+                   po_hbm, buf, sem, ublk, usem, *, bs, dv, nb, maxb, scale):
+    """One program per batch row r, `_ragged_fused_kernel`'s two steps
+    over ONE pool.
+
+    1. The row's target block is DMA'd in, the new latent `rn_ref`
+       `[1, 1, lanes]` spliced in at its offset, and DMA'd back (the pool
+       is aliased in place; an out-of-range slot skips the write).
+    2. The row's latents stream from HBM through its block table, a TILE
+       of `buf.shape[2] // bs` blocks a step gathered by as many DMAs into
+       one `[T, lanes]` buffer that is read TWICE: `q~ [HP, lanes] x
+       rows^T` gives every query head's scores (the lanes past the key
+       hold zeros on both sides), `p [HP, T] x rows[:, :dv]` their sums,
+       with the online-softmax state m, l `[HP, 1]` and acc `[HP, dv]` in
+       float32.  As in `_head_stream`, the row's NEW latent is the state
+       the stream starts from and position `length - 1` is masked in what
+       is streamed; entries of the last tile past the row's last block
+       fetch that last block again, every position of theirs masked.
+
+    q_ref/o_ref hold the query heads as rows, padded with zero rows to
+    whole sublane tiles (HP)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r = pl.program_id(0)
+    length = jnp.maximum(len_ref[r], 0)
+    slot = slot_ref[r]
+    valid = (slot >= 0) & (slot < nb * bs)
+    blk = jnp.clip(slot // bs, 0, nb - 1)
+    off = jnp.where(valid, slot % bs, 0)
+
+    rd = pltpu.make_async_copy(p_hbm.at[pl.ds(blk, 1)], ublk, usem.at[0])
+    rd.start()
+    rd.wait()
+    off_mask = (jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1) == off)
+    ublk[...] = jnp.where(off_mask & valid, rn_ref[...].astype(ublk.dtype),
+                          ublk[...])
+
+    @pl.when(valid)
+    def _writeback():
+        wr = pltpu.make_async_copy(ublk, po_hbm.at[pl.ds(blk, 1)],
+                                   usem.at[0])
+        wr.start()
+        wr.wait()       # before the stream may read the same region
+
+    q = q_ref[0]                                          # [HP, lanes]
+    hp = q.shape[0]
+    t_rows = buf.shape[2]
+    tile = t_rows // bs
+    has_new = valid & (length > 0)
+    n_old = jnp.where(has_new, length - 1, length)
+    num_kb = jnp.minimum((n_old + bs - 1) // bs, maxb)
+
+    def copies(slot_i, t):
+        dmas = []
+        for i in range(tile):
+            kb = jnp.minimum(t * tile + i, num_kb - 1)
+            b_kb = jnp.clip(tbl_ref[r, kb], 0, nb - 1)
+            dmas.append(pltpu.make_async_copy(
+                p_hbm.at[pl.ds(b_kb, 1)],
+                buf.at[slot_i, :, pl.ds(i * bs, bs)], sem.at[slot_i]))
+        return dmas
+
+    def step(sl, t, carry):
+        m, l, acc = carry
+        for c in copies(sl, t):
+            c.wait()
+        rows = buf[sl, 0]                                 # [T, lanes]
+        s = _dot_f32(q, rows, transpose_b=True) * scale   # [HP, T]
+        pos = t * t_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (hp, t_rows), 1)
+        s = jnp.where(pos < n_old, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + _dot_f32(p.astype(rows.dtype), rows[:, :dv]))
+
+    def new_row(lanes):            # [1, n] float32, as the pool keeps it
+        return rn_ref[0, :, lanes].astype(buf.dtype).astype(jnp.float32)
+
+    state0 = (
+        jnp.where(has_new, scale * jnp.sum(
+            q.astype(jnp.float32) * new_row(slice(None)), axis=1,
+            keepdims=True), _NEG_INF),
+        jnp.where(has_new, jnp.ones((hp, 1), jnp.float32), 0.0),
+        jnp.where(has_new, jnp.broadcast_to(new_row(slice(0, dv)),
+                                            (hp, dv)), 0.0))
+    n_tiles = (num_kb + tile - 1) // tile
+    _, l, acc = _two_block_dma_loop(n_tiles, copies, step, state0)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale",
+                                             "interpret"))
+def _latent_kernel_call(q, row_new, pool, block_table, kv_lens, slots,
+                        value_dim, scale, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, h, dk = q.shape
+    nb, bs, lanes = pool.shape
+    hp = -(-h // _GROUP_ROWS) * _GROUP_ROWS
+    tbl = jnp.asarray(block_table, jnp.int32)
+    t_rows = max(1, _LATENT_TILE_TOKENS // bs) * bs
+    q_rows = jnp.pad(q.reshape(b, h, dk),
+                     ((0, 0), (0, hp - h), (0, lanes - dk)))
+    new = jnp.pad(row_new.reshape(b, 1, dk), ((0, 0), (0, 0),
+                                              (0, lanes - dk)))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hp, lanes), lambda r, *pre: (r, 0, 0)),
+                  pl.BlockSpec((1, 1, lanes), lambda r, *pre: (r, 0, 0)),
+                  hbm],
+        out_specs=[pl.BlockSpec((1, hp, value_dim),
+                                lambda r, *pre: (r, 0, 0)), hbm],
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, t_rows, lanes), pool.dtype),   # two tiles
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((1, bs, lanes), pool.dtype),          # target block
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    # aliasing indices INCLUDE the scalar-prefetch args (lens=0, slots=1,
+    # tables=2, q=3, new row=4, pool=5)
+    o, pool2 = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, dv=value_dim, nb=nb,
+                          maxb=int(tbl.shape[1]), scale=scale),
+        name="ragged_latent_attention",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, hp, value_dim), q.dtype),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={5: 1},
+        interpret=interpret,
+    )(jnp.asarray(kv_lens, jnp.int32).reshape(b),
+      jnp.asarray(slots, jnp.int32).reshape(b), tbl, q_rows, new, pool)
+    return o[:, :h].reshape(b, 1, h, value_dim), pool2
+
+
+def ragged_latent_attention_arrays(q, row_new, pool, block_table, pos0,
+                                   kv_lens, slots, value_dim, scale):
+    """`ragged_paged_attention_arrays` over a latent pool: the current
+    tokens' latent rows written, then causal attention of the absorbed
+    queries against the rows, in ONE fixed-shape program.
+
+    q:        [B, C, H, key_dim] absorbed queries (every head scores
+              against the same row)
+    row_new:  [B, C, key_dim] the current tokens' latent rows
+    pool:     [num_blocks, block_size, latent_pool_lanes(key_dim)]
+    block_table, pos0, kv_lens, slots: as `ragged_paged_attention_arrays`
+    value_dim: a row's leading lanes that are its value
+    -> (out [B, C, H, value_dim], pool')."""
+    c = q.shape[1]
+    if _latent_kernel_ok(q, pool, c, value_dim):
+        return _latent_kernel_call(q, row_new, pool, block_table, kv_lens,
+                                   slots, value_dim, scale,
+                                   interpret=_interpret())
+    pool2 = latent_cache_update_arrays(pool, row_new, slots)
+    return latent_paged_attention_arrays(q, pool2, block_table, pos0,
+                                         value_dim, scale), pool2

@@ -221,6 +221,10 @@ def _moe_mlp_dispatch(x, gate_logits, w_in, w_out, top_k, capacity_factor,
 # grouped products run over a quarter of the rows when the held pairs fit
 # there (they lie first after the sort) and over all of them when not.
 _SPLIT_ROWS = 1024
+# The grouped products take at most this many sorted pairs at a time; a
+# longer run goes through them in chunks.  The largest
+# run any program made before the chunks existed: 8,192 tokens x top-4.
+_CHUNK_ROWS = 32768
 
 
 def route_top_k(m, router_w, bias, top_k, route_scale, norm_eps):
@@ -282,19 +286,49 @@ def held_experts_arrays(m, router_w, bias, experts, first, n, top_k,
             jnp.sum((sizes > 0).astype(jnp.int32)),
             jnp.sum(real.astype(jnp.int32))])
 
-    def run(rows):
-        x = jnp.take(m, tok[:rows], axis=0)                   # [rows, H]
-        g = jax.lax.ragged_dot(x, gate_w, sizes)
-        u = jax.lax.ragged_dot(x, up_w, sizes)
+    def products(idx, group_sizes, w_rows, first_row=None):
+        """The held experts' weighted outputs [rows, H] for the sorted
+        pairs `idx`, a run of them that starts at sorted row `first_row`
+        (None: at the first)."""
+        x = jnp.take(m, idx, axis=0)                          # [rows, H]
+        g = jax.lax.ragged_dot(x, gate_w, group_sizes)
+        u = jax.lax.ragged_dot(x, up_w, group_sizes)
         a = (jax.nn.silu(g.astype(jnp.float32))
              * u.astype(jnp.float32)).astype(m.dtype)
-        y = jax.lax.ragged_dot(a, down_w, sizes,
+        y = jax.lax.ragged_dot(a, down_w, group_sizes,
                                preferred_element_type=jnp.float32)
         # rows past the held groups belong to no group: the CPU's
         # ragged_dot leaves them 0, the TPU's leaves them unwritten
-        covered = jnp.arange(rows, dtype=jnp.int32)[:, None] < n_held
-        return jnp.zeros((t, h), jnp.float32).at[tok[:rows]].add(
-            jnp.where(covered, y * w_sorted[:rows, None], 0.0))
+        at = jnp.arange(idx.shape[0], dtype=jnp.int32)
+        if first_row is not None:
+            at = first_row + at
+        return jnp.where(at[:, None] < n_held, y * w_rows[:, None], 0.0)
+
+    def run(rows):
+        if rows <= _CHUNK_ROWS:
+            return jnp.zeros((t, h), jnp.float32).at[tok[:rows]].add(
+                products(tok[:rows], sizes, w_sorted[:rows]))
+        # more pairs than any program multiplied before ISSUE 34 (a
+        # whole-prompt prefill of 16,384 tokens makes 65,536): a chunk of
+        # the sorted pairs at a time, each with the part of every expert's
+        # group that falls in it, so the products' temporaries are a
+        # chunk's (3.5 GB at 65,536 rows of 4096 otherwise)
+        ends = jnp.cumsum(sizes)
+        chunks = -(-rows // _CHUNK_ROWS)
+        pad = (0, chunks * _CHUNK_ROWS - rows)      # rows no group covers
+        tok_p, w_p = jnp.pad(tok[:rows], pad), jnp.pad(w_sorted[:rows], pad)
+
+        def chunk(c, acc):
+            lo = c * _CHUNK_ROWS
+            idx = jax.lax.dynamic_slice_in_dim(tok_p, lo, _CHUNK_ROWS)
+            part = jnp.clip(jnp.minimum(ends, lo + _CHUNK_ROWS)
+                            - jnp.maximum(ends - sizes, lo), 0, _CHUNK_ROWS)
+            return acc.at[idx].add(products(
+                idx, part,
+                jax.lax.dynamic_slice_in_dim(w_p, lo, _CHUNK_ROWS), lo))
+
+        return jax.lax.fori_loop(0, chunks, chunk,
+                                 jnp.zeros((t, h), jnp.float32))
 
     with jax.named_scope(f"{scope}/experts"):
         total = t * top_k

@@ -26,7 +26,15 @@ program takes one slot-index array a state group, a layer's state pool
 travels among the donated pools in layer order, and the layer's step is
 handed the rows' states and hands back the new ones (lfm2_moe's gated
 short convolutions, models/lfm2.py).  A model with no such layer builds
-no state group and its programs are what they were.  Options a family does
+no state group and its programs are what they were.  A layer of latent
+attention names a `LatentSpec`: its cache group keeps ONE pool a layer
+(`BlockKVCache(value_in_key=True)`), and what the layer attends in a
+whole-prompt prefill - keys and values it expands from the chunk's own
+latents, through flash - is not what it stores, so it hands the engine the
+rows to store and both forms, and the engine calls the one its program
+needs: `whole` in `prefill(P)`, `stored` (absorbed queries against the
+pool, `ragged_latent_attention_arrays`) in every program that reads the
+pool (mistral4, models/mistral4.py).  Options a family does
 not carry (`ServingForm.unsupported`) raise at construction by name.
 
 Step programs (all array-level, weights threaded as inputs):
@@ -193,9 +201,11 @@ from ..monitor import slo as mslo
 from ..monitor import memory as mmem
 from ..resilience import faults
 from ..resilience.retry import Deadline
-from ..ops.paged_attention import (paged_cache_update_arrays,
+from ..ops.paged_attention import (latent_cache_update_arrays,
+                                   paged_cache_update_arrays,
                                    quantized_cache_update_arrays)
-from ..ops.ragged_paged_attention import ragged_paged_attention_arrays
+from ..ops.ragged_paged_attention import (ragged_latent_attention_arrays,
+                                          ragged_paged_attention_arrays)
 from ..models.serving_form import StateSpec
 from .kv_cache import (BlockAllocatorError, BlockKVCache, CacheGroups,
                        StateCache, prefix_block_keys)
@@ -363,11 +373,10 @@ class LLMEngine:
         self._groups = dict(sorted(
             self._groups.items(), key=lambda kv: kv[1][0].window is not None))
         for name, layers in self._groups.items():
-            if len({(s.num_kv_heads, s.head_dim, s.window)
-                    for s in layers}) != 1:
+            if len({(s.pool_row(), s.window) for s in layers}) != 1:
                 raise ValueError(
-                    f"cache group {name!r}: its layers must share K/V "
-                    "heads, head size and window")
+                    f"cache group {name!r}: its layers must share a kind, "
+                    "K/V heads, head size and window")
         if c.kv_cache_dtype not in (None, "int8"):
             raise ValueError(
                 f'kv_cache_dtype must be None or "int8", got '
@@ -386,12 +395,13 @@ class LLMEngine:
                     f"to {type(model).__name__} yet (its serving form "
                     "lists it as unsupported); leave it off")
         sizes = self._pool_sizes(wdtype)
-        self.caches = {
-            name: BlockKVCache(
-                len(layers), sizes[name], c.block_size,
-                layers[0].num_kv_heads, layers[0].head_dim, dtype=wdtype,
-                kv_quant=self._kv_quant, window=layers[0].window, name=name)
-            for name, layers in self._groups.items()}
+        self.caches = {}
+        for name, layers in self._groups.items():
+            heads, lanes, pools = layers[0].pool_row()
+            self.caches[name] = BlockKVCache(
+                len(layers), sizes[name], c.block_size, heads, lanes,
+                dtype=wdtype, kv_quant=self._kv_quant,
+                window=layers[0].window, name=name, value_in_key=pools == 1)
         # the first group's cache: the only one of a one-group model
         self.cache = next(iter(self.caches.values()))
         # a slot a running sequence, and the dropped slot of padding rows
@@ -607,8 +617,9 @@ class LLMEngine:
             if spec.window is not None:
                 fp_blocks = min(fp_blocks, c.max_num_seqs
                                 * (-(-spec.window // bs) + 1))
+            heads, lanes, pools = spec.pool_row()
             per_block = len(layers) * BlockKVCache.block_bytes(
-                bs, spec.num_kv_heads, spec.head_dim, wdtype)
+                bs, heads, lanes, wdtype, pools=pools)
             if c.num_blocks is not None:
                 n = (c.num_blocks if spec.window is None
                      else min(c.num_blocks, fp_blocks))
@@ -618,8 +629,7 @@ class LLMEngine:
                 # blocks, fewer preemptions under the same memory ceiling
                 n = fp_blocks * per_block // (
                     len(layers) * BlockKVCache.block_bytes(
-                        bs, spec.num_kv_heads, spec.head_dim, wdtype,
-                        self._kv_quant))
+                        bs, heads, lanes, wdtype, self._kv_quant))
             else:
                 n = fp_blocks
                 asked += n * per_block
@@ -1606,6 +1616,7 @@ class LLMEngine:
         ``ragged_fused.wall_time_s`` vs the trio's sum.
         """
         if len(self.caches) > 1 or self.states \
+                or self.form.layer_specs[0].latent \
                 or self.form.layer_specs[0].num_heads \
                 != self.form.layer_specs[0].num_kv_heads:
             raise ValueError(
@@ -1920,7 +1931,7 @@ class LLMEngine:
 
     @staticmethod
     def _attn_scope(spec):
-        return jax.named_scope("attn/window" if spec.window else "attn/full")
+        return jax.named_scope(spec.scope)
 
     def _get_prefill_exec(self, p_len):
         key = ("prefill", p_len)
@@ -1936,7 +1947,21 @@ class LLMEngine:
                 pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
                 x = self.form.embed(params, ids, pos)
 
-                def builder(spec, g, kc, vc, ksc=None, vsc=None):
+                def builder(spec, g, kc, vc=None, ksc=None, vsc=None):
+                    if spec.latent:
+                        def latent_fn(rows, whole, stored, pool=kc):
+                            # what is stored is the latent; what flash
+                            # attends is what the layer expands from it
+                            pool2 = latent_cache_update_arrays(
+                                pool, rows, slots[g])
+                            with self._attn_scope(spec):
+                                o = whole(lambda q, k, v: (
+                                    flash_attention_arrays(
+                                        q, k, v, is_causal=True,
+                                        scale=spec.scale)))
+                            return o, (pool2,)
+                        return latent_fn
+
                     def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
                         # flash within the chunk reads the fp K/V it just
                         # computed — only the STORED cache is quantized
@@ -1984,7 +2009,23 @@ class LLMEngine:
         pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
         x = self.form.embed(params, ids, pos)
 
-        def builder(spec, g, kc, vc, ksc=None, vsc=None):
+        def builder(spec, g, kc, vc=None, ksc=None, vsc=None):
+            if spec.latent:
+                def latent_fn(rows, whole, stored, pool=kc,
+                              tables=tables[g], slots=slots[g]):
+                    written = []
+
+                    def attend(q):
+                        with self._attn_scope(spec):
+                            o, pool2 = ragged_latent_attention_arrays(
+                                q, rows, pool, tables, pos0, lens, slots,
+                                value_dim=spec.value_dim, scale=spec.scale)
+                        written.append(pool2)
+                        return o
+
+                    return stored(attend), written
+                return latent_fn
+
             def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc,
                         tables=tables[g], slots=slots[g]):
                 with self._attn_scope(spec):

@@ -110,6 +110,17 @@ allocator calls: `allocate` takes one slot and zeroes it, `grow_to` and
 bit-exactly.  The last slot belongs to nobody: padding rows of a
 fixed-shape program read and write it.
 
+**Latent groups** (``value_in_key=True``, ISSUE 34): the pool of a model's
+latent-attention layers.  A token's row is its latent, the key every
+query head scores against and - its leading lanes - the value every head
+sums, so a layer keeps ONE pool (``k_blocks[l]``; ``v_blocks`` is None and
+`pool_names` is ``("k_blocks",)``): ``[num_blocks, block_size, lanes]``
+with `lanes` the row padded to whole lane tiles
+(`ops.paged_attention.latent_pool_lanes`: 320 -> 384).  Every allocator
+path is the full group's - blocks a token, copy-on-fork, swap-out and
+swap-in bit for bit (a snapshot holds ``"k"`` alone) - because each works
+over `pool_names`; int8 and a window are not carried and raise.
+
 **Quantized mode** (``kv_quant="int8"``, the `paddle_tpu.lowbit` KV
 wing): pools store int8 codes plus per-block-per-head float32 scales
 (``k_scales[l], v_scales[l] : [num_blocks, num_heads]``, value =
@@ -138,6 +149,11 @@ from ..monitor import memory as mmemory
 
 __all__ = ["BlockKVCache", "BlockAllocatorError", "CacheGroups",
            "StateCache", "prefix_block_keys"]
+
+
+# a pool's key in a `swap_out` snapshot
+_SAVED_AS = {"k_blocks": "k", "v_blocks": "v", "k_scales": "ks",
+             "v_scales": "vs"}
 
 
 class BlockAllocatorError(RuntimeError):
@@ -180,13 +196,19 @@ class BlockKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, num_heads,
                  head_dim, dtype=jnp.float32, kv_quant=None, window=None,
-                 name="full"):
+                 name="full", value_in_key=False):
         if kv_quant not in (None, "int8"):
             raise ValueError(
                 f'kv_quant must be None or "int8", got {kv_quant!r}')
         if window is not None and kv_quant:
             raise ValueError('kv_quant="int8" is not carried over a '
                              "window group")
+        if value_in_key and (kv_quant or window is not None):
+            raise ValueError("a latent group is kept at full precision "
+                             "and without a window")
+        # a latent group (ISSUE 34): a token's row is its key and, in its
+        # leading lanes, its value, so a layer keeps ONE pool (`k_blocks`)
+        self.value_in_key = bool(value_in_key)
         self.window = None if window is None else int(window)
         self.name = name
         self.released = 0      # blocks given back from behind the window
@@ -202,8 +224,8 @@ class BlockKVCache:
         pool_dtype = jnp.int8 if kv_quant else dtype
         self.k_blocks = [jnp.zeros(shape, pool_dtype)
                          for _ in range(num_layers)]
-        self.v_blocks = [jnp.zeros(shape, pool_dtype)
-                         for _ in range(num_layers)]
+        self.v_blocks = None if value_in_key else [
+            jnp.zeros(shape, pool_dtype) for _ in range(num_layers)]
         if kv_quant:
             # per-block-per-head abs-max scales: value = code * scale
             sshape = (self.num_blocks, self.num_heads)
@@ -252,25 +274,28 @@ class BlockKVCache:
     def pool_names(self) -> tuple:
         """The attributes that hold a layer's device arrays, in the order
         the engine's programs take and return them."""
+        if self.value_in_key:
+            return ("k_blocks",)
         return ("k_blocks", "v_blocks") + (
             ("k_scales", "v_scales") if self.kv_quant else ())
 
     @staticmethod
     def block_bytes(block_size, num_heads, head_dim, dtype=jnp.float32,
-                    kv_quant=None) -> int:
+                    kv_quant=None, pools=2) -> int:
         """Bytes ONE physical block costs per layer (K + V pools, plus the
-        per-block-per-head f32 scales when quantized)."""
+        per-block-per-head f32 scales when quantized; `pools=1`: a latent
+        group's one pool)."""
         per_tok = int(num_heads) * int(head_dim)
         if kv_quant == "int8":
             return 2 * (int(block_size) * per_tok + 4 * int(num_heads))
-        return 2 * int(block_size) * per_tok * np.dtype(dtype).itemsize
+        return pools * int(block_size) * per_tok * np.dtype(dtype).itemsize
 
     @property
     def bytes_per_block(self) -> int:
         """Bytes one block costs across all layers."""
         return self.num_layers * self.block_bytes(
             self.block_size, self.num_heads, self.head_dim, self.dtype,
-            self.kv_quant)
+            self.kv_quant, pools=1 if self.value_in_key else 2)
 
     @property
     def pool_bytes(self) -> int:
@@ -556,16 +581,10 @@ class BlockKVCache:
             self.v_scales[l] = self.v_scales[l].at[idx].set(0.0)
 
     def _copy_block(self, src, dst):
-        for l in range(self.num_layers):
-            self.k_blocks[l] = self.k_blocks[l].at[dst].set(
-                self.k_blocks[l][src])
-            self.v_blocks[l] = self.v_blocks[l].at[dst].set(
-                self.v_blocks[l][src])
-            if self.kv_quant:
-                self.k_scales[l] = self.k_scales[l].at[dst].set(
-                    self.k_scales[l][src])
-                self.v_scales[l] = self.v_scales[l].at[dst].set(
-                    self.v_scales[l][src])
+        for name in self.pool_names:
+            pools = getattr(self, name)
+            for l in range(self.num_layers):
+                pools[l] = pools[l].at[dst].set(pools[l][src])
 
     def _cow_last_block(self, seq_id):
         t = self._tables[seq_id]
@@ -677,22 +696,20 @@ class BlockKVCache:
         t = self._tables[seq_id]
         live = self._live(t)
         idx = np.asarray(live, np.int32)
-        saved = {
-            "len": self._lengths[seq_id],
-            "k": [np.asarray(k[idx]) for k in self.k_blocks],
-            "v": [np.asarray(v[idx]) for v in self.v_blocks],
-        }
+        # by pool, under the snapshot's keys: "k", "v" (a latent group
+        # has no second pool), and under int8 the scales "ks", "vs" -
+        # codes alone are meaningless, the scales ARE the values'
+        # exponents; saving both is what keeps the quantized domain
+        # bit-stable across evict/restore
+        saved = {"len": self._lengths[seq_id]}
+        for name in self.pool_names:
+            saved[_SAVED_AS[name]] = [np.asarray(p[idx])
+                                      for p in getattr(self, name)]
         if self.window is not None:
             # where each saved block sits in the table, and its width
             saved["logical"] = [j for j, i in enumerate(t)
                                 if i < self.num_blocks]
             saved["width"] = len(t)
-        if self.kv_quant:
-            # codes alone are meaningless — the scales ARE the values'
-            # exponents; saving both is what keeps the quantized domain
-            # bit-stable across evict/restore
-            saved["ks"] = [np.asarray(s[idx]) for s in self.k_scales]
-            saved["vs"] = [np.asarray(s[idx]) for s in self.v_scales]
         self.acct.on("swap_out", len(live))
         self.free(seq_id)
         return saved
@@ -721,16 +738,11 @@ class BlockKVCache:
         self._tables[seq_id] = table
         self._lengths[seq_id] = saved["len"]
         idx = jnp.asarray(ids, jnp.int32)
-        for l in range(self.num_layers):
-            self.k_blocks[l] = self.k_blocks[l].at[idx].set(
-                jnp.asarray(saved["k"][l]))
-            self.v_blocks[l] = self.v_blocks[l].at[idx].set(
-                jnp.asarray(saved["v"][l]))
-            if self.kv_quant:
-                self.k_scales[l] = self.k_scales[l].at[idx].set(
-                    jnp.asarray(saved["ks"][l]))
-                self.v_scales[l] = self.v_scales[l].at[idx].set(
-                    jnp.asarray(saved["vs"][l]))
+        for name in self.pool_names:
+            pools = getattr(self, name)
+            for l in range(self.num_layers):
+                pools[l] = pools[l].at[idx].set(
+                    jnp.asarray(saved[_SAVED_AS[name]][l]))
 
 
 

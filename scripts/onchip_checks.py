@@ -33,10 +33,16 @@ heads over `[1024,16,4096]`; afmoe: 32 rows x 48-over-8 heads over
 `[2080,64,1024]` with the window and `[4224,64,1024]` without; lfm2: 64
 rows x 32-over-8 heads of 64 lanes over `[4608,64,512]`), checks
 each against the XLA fallback, and prints ms a call, us a block and GB/s
-of live K/V.  `--cells --split` times it again with its matrix products
-cut out (DMA only), with its DMAs cut out (math only) and with rows of
-one token (what a row costs before its stream), and, where the call takes
-the per-head body, once more with the segment-indicator body forced.
+of live K/V; then the latent decode kernel at the fifth cell's call
+(mistral4: 64 rows x 32 absorbed heads of 320 over the ONE pool
+`[12288,64,384]`): ms a call, us a tile of 256 rows, GB/s of latents at
+the published 640 B a row (`--latent`: that call alone).  `--cells --split`
+times it again with its matrix products cut out (DMA only), with its DMAs
+cut out (math only) and with rows of one token (what a row costs before
+its stream), and, where the call takes the per-head body, once more with
+the segment-indicator body forced; the latent call, in place of the last
+two, over a key of two lane tiles (what a split layout could cost at
+least).
 `--tree DIR` as above: the parent's kernel, same inputs.
 
 `--sampler` runs the engine's sample program (`serving.engine.
@@ -48,6 +54,15 @@ rows ask for: `tests/test_sampler.py::two_sort_row`): tokens and keys
 equal, then us a call of each on the device (the profiler's `XLA Modules`
 line).  No cell sends sampled
 traffic, so the two branches that draw are measured here alone.
+
+`--ties` counts, at the fifth cell's configuration and seeded weights, how
+far the program's choices lie under the float32 reference
+(`benchmark/lib/reference_mistral4.py`) with and without the reference's
+ties: four sequences of 12,288 uniform tokens through the program's
+whole-sequence forward, each position's argmax read against the
+reference's main path alone and against the least over its branches, the
+first sequence under each deliberate fault as well; then one
+`greedy_margins` row at the cell's 17,408 positions, timed.
 
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
@@ -70,8 +85,10 @@ AOT = "--aot" in sys.argv[1:]
 WRITES_ONLY = "--writes" in sys.argv[1:]
 AFMOE_ONLY = "--afmoe" in sys.argv[1:]
 CELLS_ONLY = "--cells" in sys.argv[1:]
+LATENT_ONLY = "--latent" in sys.argv[1:]
 SPLIT = "--split" in sys.argv[1:]
 SAMPLER_ONLY = "--sampler" in sys.argv[1:]
+TIES_ONLY = "--ties" in sys.argv[1:]
 AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
 
@@ -356,6 +373,120 @@ CELLS = {
 }
 
 
+# longctx-c64's rows by the time they stay: prompts 4096 / 8192 / 16384 at
+# 4:4:2 with about half their answers decoded (541k live rows of 786k)
+_LONGCTX = [4300] * 26 + [8450] * 26 + [16900] * 12
+LATENT_CELL = dict(h=32, dk=320, dv=256, bs=64, nb=12288, maxb=272,
+                   lens=_LONGCTX)
+
+
+def check_latent_cell():
+    """The latent decode kernel at `mistral-small-4-ep8-l8.longctx-c64`'s
+    call, alone: against its XLA fallback once (on 8 of the rows: the
+    fallback gathers every row's whole table), then timed as the engine
+    runs it - 8 calls in one program, the pool handed from one to the next
+    in place.  `--split`: again with its products cut out (DMA only), with
+    its DMAs cut out (math only), and over a row of TWO lane tiles (a key
+    of 256 in a pool of 256 lanes: the `c_kv` half of a split layout with
+    nothing done for `k^rope`, the least a split layout could cost)."""
+    import contextlib
+    import time
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ragged_paged_attention as rp
+
+    c = LATENT_CELL
+    h, dv, bs, nb, maxb = (c[k] for k in ("h", "dv", "bs", "nb", "maxb"))
+    calls, scale = 8, 128 ** -0.5 * (0.1 * np.log(128.0) + 1) ** 2
+    rng = np.random.RandomState(19)
+
+    def inputs(lens):
+        lens = np.asarray(lens, np.int32)
+        b = len(lens)
+        live = np.arange(maxb)[None] * bs < lens[:, None]
+        tables = np.full((b, maxb), nb, np.int32)
+        tables[live] = rng.permutation(nb)[:live.sum()]
+        p = lens - 1
+        slots = (tables[np.arange(b), p // bs] * bs + p % bs)[:, None]
+        return tuple(jnp.asarray(x) for x in (tables, p, lens, slots))
+
+    def pool(dk):       # made on the device, zeros past the key's lanes
+        rows = jax.random.normal(jax.random.PRNGKey(5), (nb, bs, dk),
+                                 jnp.bfloat16)
+        return jnp.pad(rows, ((0, 0), (0, 0), (0, -dk % 128)))
+
+    def rows_of(b, dk):
+        return (_randn(rng, (b, 1, h, dk), jnp.bfloat16),
+                _randn(rng, (b, 1, dk), jnp.bfloat16))
+
+    def one(q, new, pl_, idx, i=0):
+        return rp.ragged_latent_attention_arrays(
+            q + jnp.asarray(i, q.dtype), new, pl_, *idx, value_dim=dv,
+            scale=scale)
+
+    name = "cell_mistral4_latent"
+    idx = inputs(c["lens"])
+
+    def program(q, new):
+        def run(pl_):
+            acc = 0.0
+            for i in range(calls):
+                o, pl_ = one(q, new, pl_, idx, i)
+                acc += o.astype(jnp.float32)
+            return acc, pl_
+        return jax.jit(run, donate_argnums=(0,))
+
+    if AOT:
+        _compile_only(program(*rows_of(len(c["lens"]), c["dk"])),
+                      [pool(c["dk"])])
+        print(f"OK {name}", flush=True)
+        return
+    # the new rows and their slots are CLOSED OVER, constants of the
+    # program: what the TPU compiler folded to zeros in the fallback's
+    # writer until `paged_cache_update_arrays` kept the offsets behind a
+    # barrier (my chip runs 2-3, PR 34; PERF.md 6)
+    few = c["lens"][::8]
+    few_idx = inputs(few)
+    few_q, few_new = rows_of(len(few), c["dk"])
+    _ragged_vs_fallback(
+        name, lambda q_, pl_: one(q_, few_new, pl_, few_idx),
+        (few_q, pool(c["dk"])), np.asarray(few) > 0)
+    tokens = int(np.sum(c["lens"]))
+    tiles = int(np.sum(-(-(np.asarray(c["lens"]) - 1) // 256)))
+
+    def timed(what, dk=c["dk"]):
+        lanes = dk + -dk % 128
+        with contextlib.ExitStack() as cut:
+            for obj, attr, value in _cut(rp, what):
+                cut.enter_context(mock.patch.object(obj, attr, value,
+                                                    create=True))
+            jax.clear_caches()      # the kernel call is traced once a shape
+            fn = program(*rows_of(len(c["lens"]), dk))
+            state = jax.block_until_ready(fn(pool(dk)))[1]
+        reps = 5
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            state = fn(state)[1]
+        jax.block_until_ready(state)
+        per = (time.perf_counter() - t0) / (reps * calls)
+        print(f"CELL mistral4_latent {what} key {dk} in {lanes} lanes: "
+              f"{per * 1e3:.4f} ms a call, {per * 1e6 / tiles:.3f} us a "
+              f"tile ({tiles} tiles of 256 rows, {len(c['lens'])} rows, "
+              f"{tokens} live rows), {tokens * 640 / per / 1e9:.1f} GB/s of "
+              f"latents at the published 640 B a row "
+              f"({tokens * lanes * 2 / per / 1e9:.1f} GB/s moved at the "
+              f"pool's {lanes * 2})", flush=True)
+        jax.clear_caches()          # no later trace may meet a cut kernel
+
+    timed("as_is")
+    if SPLIT:
+        timed("dma_only")
+        timed("math_only")
+        timed("as_is", dk=256)
+
+
 class _NoCopy:
     """A DMA descriptor that moves nothing (`--split`, math only)."""
 
@@ -621,6 +752,60 @@ WRITES = {"prompt384": (1, 384, 0), "chunk256+5": (1, 256, 1029),
           "verify16x5": (16, 5, 200), "decode16x1": (16, 1, 333)}
 
 
+def check_ties(sequences=4, length=12288, seed=3300000029):
+    """See the module docstring, `--ties`."""
+    import json
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib import family
+    from benchmark.lib import reference_mistral4 as ref
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mistral-small-4-ep8-l8.json")) as f:
+        config = json.load(f)
+    t0 = time.perf_counter()
+    model, cfg = family.build_model(config, seed)
+    model.eval()
+    params = ref.params_from_model(model)
+    held = model.param_arrays()
+    program = jax.jit(lambda p, ids: jnp.argmax(
+        model.forward_arrays(p, ids)[0].astype(jnp.float32), -1))
+    rng = np.random.default_rng(5)
+    read = {}               # fault -> ([main path alone], [least of branches])
+    for i in range(sequences):
+        ids = rng.integers(0, cfg.vocab_size, length).astype(np.int32)
+        pick = program(held, jnp.asarray(ids)[None])
+        for fault in ref.FAULTS if i == 0 else (None,):
+            for tie, into in zip((0.0, None), read.setdefault(fault,
+                                                              ([], []))):
+                into.append(np.asarray(ref.choice_margins(
+                    params, ids, pick, config, fault=fault, tie=tie)[0]))
+        print(f"TIES sequence {i} of {length} positions done at "
+              f"{time.perf_counter() - t0:.0f} s", flush=True)
+    for fault, pair in read.items():
+        for what, parts in zip(("main path alone", "least of branches"),
+                               pair):
+            m = np.concatenate(parts)
+            print(f"TIES {fault or 'sound'!s:>15} {what:>17}: {m.size} "
+                  f"positions, share within 0.25 {np.mean(m <= 0.25):.4f}, "
+                  f"over 0.5 / 1 / 1.5 / 2: {int((m > 0.5).sum())} / "
+                  f"{int((m > 1).sum())} / {int((m > 1.5).sum())} / "
+                  f"{int((m > 2).sum())}, max {m.max():.3f}", flush=True)
+    print(f"TIES a tie is a gap of at most {ref.TIE} of the score; a layer "
+          f"branches at most 1/{ref.SPAWN} of the positions", flush=True)
+    row = rng.integers(0, cfg.vocab_size, (1, 17408)).astype(np.int32)
+    for what in ("first", "second"):
+        t1 = time.perf_counter()
+        ref.greedy_margins(params, row, config)
+        print(f"TIES greedy_margins, one row of 17,408 positions, {what} "
+              f"call: {time.perf_counter() - t1:.1f} s", flush=True)
+    print("OK check_ties", flush=True)
+
+
 def check_writes(h=16, d=128, layers=12):
     """The KV pool writers at the 1.3B pool (bf16 at block 16, int8 at
     block 32): the pool after `paged_cache_update_arrays` /
@@ -851,12 +1036,19 @@ def main():
     if CELLS_ONLY:
         checks = [(f"check_ragged_cell_{c}", check_ragged_cell, (c,))
                   for c in CELLS]
+        checks.append(("check_latent_cell", check_latent_cell, ()))
+    if LATENT_ONLY:
+        checks = [("check_latent_cell", check_latent_cell, ())]
     if SAMPLER_ONLY:
         checks = [(f"check_sampler_{c}", check_sampler, (c,))
                   for c in SAMPLER_SHAPES]
+    if TIES_ONLY:
+        checks = [("check_ties", check_ties, ())]
     for name, fn, args in checks:
         with _Watchdog(name, 900.0 if fn in (check_writes, check_afmoe_engine,
-                                             check_ragged_cell) else 240.0):
+                                             check_ragged_cell,
+                                             check_latent_cell,
+                                             check_ties) else 240.0):
             fn(*args)
     print("ALL AOT COMPILES OK" if AOT else "ALL ONCHIP CHECKS OK")
 

@@ -27,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import pallas_ops as po
 from paddle_tpu.ops import ragged_paged_attention as rp
-from paddle_tpu.ops.paged_attention import (paged_cache_update_arrays,
+from paddle_tpu.ops.paged_attention import (latent_cache_update_arrays,
+                                            paged_cache_update_arrays,
                                             quantized_cache_update_arrays)
 
 # (rows, heads, pool blocks): chat-c16 on GPT-3 1.3B, docqa-c8 on 6.7B
@@ -136,6 +137,37 @@ def test_cache_write_is_in_place(S, quant, name):
     assert found.get("fusion:scatter") == 1, found    # + its own body
     assert set(found) <= {"parameter", "bitcast", "scatter", "tuple",
                           "fusion:scatter"}, found
+
+
+@pytest.mark.parametrize("where", ["traced", "closed_over"])
+def test_latent_write_keeps_the_rows(S, where):
+    """The latent writer at the longctx cell's decode shape, its rows and
+    slots arguments (the engine's) or constants of the caller's program:
+    the TPU compiler folded the second form's window - constant rows
+    sliced at constant offsets - to a broadcast of ZEROS and wrote those
+    (the chip, PR 34; the CPU's program is right), until
+    `paged_cache_update_arrays` kept closed-over slots behind a barrier.
+    No window-sized broadcast may feed the scatter in either form."""
+    import numpy as np
+
+    pool = S((12288, 64, 384), jnp.bfloat16)
+    rng = np.random.RandomState(0)
+    rows = jnp.asarray(rng.randn(64, 1, 320), jnp.bfloat16)
+    slots = jnp.asarray((np.arange(64) * 5 * 64 + 7)[:, None], jnp.int32)
+    if where == "traced":
+        compiled = jax.jit(latent_cache_update_arrays,
+                           donate_argnums=(0,)).lower(
+            pool, S(rows.shape, rows.dtype),
+            S(slots.shape, slots.dtype)).compile()
+    else:
+        compiled = jax.jit(
+            lambda p: latent_cache_update_arrays(p, rows, slots),
+            donate_argnums=(0,)).lower(pool).compile()
+    text = compiled.as_text()
+    window = re.compile(r"= bf16\[64,(?:1,)?64,384\]\S* broadcast\(")
+    assert not [ln for ln in text.splitlines() if window.search(ln)]
+    found = _pool_sized(text, 12288 * 64 * 384)
+    assert found.get("fusion:scatter") == 1, found
 
 
 # -- PR 28: grouped heads, windows, and the engine's seam ---------------------
@@ -395,6 +427,12 @@ def _ragged(q, kn, vn, kb, vb, tables, pos0, lens, slots, *scales):
         q, kn, vn, kb, vb, tables, pos0, lens, slots, **kw)
 
 
+def _latent(q, new, pool, tables, pos0, lens, slots):
+    return rp.ragged_latent_attention_arrays(
+        q, new, pool, tables, pos0, lens, slots, value_dim=256,
+        scale=0.25)
+
+
 def _qkv(b, sq, h, d, sk=None):
     sk = sq if sk is None else sk
     return [((b, sq, h, d), jnp.bfloat16)] + [((b, sk, h, d),
@@ -437,6 +475,7 @@ def _case(name, fn, shapes, n_calls, counted=None, **kw):
 _MASK = [((2, 1, 256, 256), jnp.float32)]
 _HEADS = {"ragged_kernel": 1, "ragged_kernel:head_products": 1}
 _SEGMENTS = {"ragged_kernel": 1, "ragged_kernel:segment_products": 1}
+_LATENTS = {"ragged_kernel": 1, "ragged_kernel:latent_products": 1}
 _REFUSED = pytest.mark.xfail(strict=True, reason=(
     "the flash kernels' [B, 1] / [B, S] int32 operand: `block shape ... "
     "divisible by 8 and 128` (ROADMAP S9)"))
@@ -508,6 +547,17 @@ KERNEL_CASES = [
     _case("flash_fwd_prefill_gqa64_s4096", _flash(is_causal=True),
           [((1, 4096, 32, 64), jnp.bfloat16)]
           + [((1, 4096, 8, 64), jnp.bfloat16)] * 2, 1),
+    # mistral-small-4-ep8-l8.longctx-c64's calls (PR 34): 64 rows of 32
+    # absorbed query heads of 320 over the ONE latent pool `[12288, 64,
+    # 384]`, tables for 17,408 tokens a row; and its longest prefill, 32
+    # expanded heads of 128 over 16,384 tokens
+    _case("ragged_latent_mistral4", _latent,
+          [((64, 1, 32, 320), jnp.bfloat16), ((64, 1, 320), jnp.bfloat16),
+           ((12288, 64, 384), jnp.bfloat16), ((64, 17408 // 64), jnp.int32),
+           ((64,), jnp.int32), ((64,), jnp.int32), ((64, 1), jnp.int32)],
+          1, _LATENTS),
+    _case("flash_fwd_prefill_h32_s16384", _flash(is_causal=True),
+          _qkv(1, 16384, 32, 128), 1),
 ]
 
 
