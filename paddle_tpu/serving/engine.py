@@ -60,6 +60,9 @@ Step programs (all array-level, weights threaded as inputs):
   positions a row (`EngineConfig.speculative_tokens`), returning every
   position's greedy token (and position 0's logits for the sampler), so
   the accept is decided in one step.
+- ``feed(B)``      — a decode step's upload: the host's input arrays to
+  the device, the token of a row that rode the step in flight taken from
+  that step's sampler output (see "One step in flight").
 - ``sample(B)``    — per-row replication of the dense `_sample_next`
   (greedy argmax / temperature / top-k / top-p + per-request PRNG key
   threading), vmapped so every request reproduces the sampling stream of
@@ -114,50 +117,105 @@ cache).  ISSUE 33: `serving/sampler_steps{path=argmax|categorical|
 truncated}`, one a sampler dispatch, by what the batch's rows made the
 sample program run (`_sample_program`).
 
+One step in flight (ISSUE 35).  `step()` dispatches the step it has just
+scheduled BEFORE it reads back the step dispatched by the call before it:
+the device runs step k while the host schedules, prepares and dispatches
+k+1, and the host's work between two steps hides under the device's.  The
+host has not seen step k's tokens when it dispatches k+1, so:
+
+- a row that rode step k takes its input token and its sampling key from
+  k's sampler ON THE DEVICE (`_feed_sources`: a host-made source index a
+  row into the sampler's outputs, which are padded to `max_num_seqs` rows
+  whether a prefill's one row or a decode batch made them; -1 = from the
+  host).  The ("feed", B) program selects the tokens as it uploads the
+  model program's inputs; the ("sample", B) program selects the keys.
+  One shape of each whatever the mix, so one request alone compiles all;
+- a request with a token OWED (`Request.owed`) is one position longer
+  (`total_len`) for the scheduler and `_decode_inputs`, and is not
+  decoded again when the owed token is its last by count.  A row that
+  ends on its `eos_token_id` is found out a step late: it has ridden
+  k+1, that token is dropped at readback (`_emit`), and its blocks, freed
+  at k's retire, are written by whoever gets them BEHIND k+1 (device
+  programs run in dispatch order);
+- whatever needs a request's tokens, key or blocks as they are settles
+  the step in flight first (`_settle`: readback, emit, retire, nothing
+  new dispatched): `request_output`, `export_request`, `fork_request`,
+  `release_request` (cancel, deadline) of a row that owes, any eviction
+  the scheduler decides (`StepOwed`), an `idle` decision, the public
+  `settle()`, the end of `generate()`.  `adopt_request` and shedding
+  touch only requests that have never ridden a step here and settle
+  nothing;
+- with `speculative_tokens > 0` the drafts of step k+1 come from tokens
+  the host must have seen: every step is settled where it is dispatched
+  (the loop's depth is 0, not 1; the host then holds every token and the
+  decode program takes its inputs straight from it, no feed program).
+  Chunked-prefill continuations and prefix registration depend on no
+  sampled token and stay in flight.
+
+`serving/steps_dispatched{in_flight=1|0}` counts program steps by whether
+an earlier step was still owed at dispatch, `serving/settles{why=spec|
+preempt|release|export|fork|idle|drain}` each time the pipeline ran empty.
+
 Host phases (monitor.trace.phase): every boundary of `step()` is one
-phase, and the API pump adds two of its own around it:
+phase, in this order, and the API pump adds two of its own around it:
 
     api/drain_submits       the pump's _drain_submits, blocking get included;
                             add_request touches the device only for a
                             sampling request's key (read once, there)
     api/push_progress       _push_engine_progress: a queue.put per stream
-    engine/schedule         deadline sweep, shedding, scheduler.schedule(),
-                            preemption counts
-    engine/prepare          the step's inputs as host arrays (tokens,
+    engine/schedule         (step k+1) deadline sweep, shedding,
+                            scheduler.schedule(), preemption counts
+    engine/prepare          (k+1) the step's inputs as host arrays (tokens,
                             positions, lengths; a table and a slot array a
                             cache group, slots by array arithmetic; a spec
-                            step's n-gram drafts), the model program's
-                            dispatch call, which uploads them (_run: one
-                            crossing), _store_kv
-    engine/sample_dispatch  the sampler's five host arrays (the rows' keys
-                            are host words: nothing is read for them), the
-                            path they select (counted; the program branches
-                            on the same arrays: no sort for greedy or
-                            untruncated rows, one where a row truncates) and
-                            its dispatch, the second and last upload — the
-                            device is busy with the model program
-    engine/readback         _to_host of (tokens, keys[, greedy][, stats]) in
-                            one call: the step's only device-to-host wait
-    engine/emit             per row, host only: the new key (a view of the
-                            array read back), record_token, TTFT/TPOT
+                            step's n-gram drafts), their upload (_run: one
+                            crossing; a decode step's goes through the feed
+                            program), the model program's dispatch,
+                            _store_kv
+    engine/sample_dispatch  (k+1) the sampler's five host arrays and the
+                            rows' source indices (the keys are host words
+                            or stay on the device: nothing is read for
+                            them), the path they select (counted; the
+                            program branches on the same arrays: no sort
+                            for greedy or untruncated rows, one where a row
+                            truncates) and its dispatch, the second and
+                            last upload
+    engine/readback         (step k) _to_host of (tokens, keys[, greedy]
+                            [, stats]) in one call: the only device-to-host
+                            wait, for a step dispatched a call ago with
+                            k+1 already queued behind it
+    engine/emit             (k) per row, host only: the new key (a view of
+                            the array read back), record_token, TTFT/TPOT
                             (spec: acceptance and the table roll-back)
-    engine/retire           retire_finished, _finish_request, the SLO tick,
-                            the step's counters and gauges
+    engine/retire           (k) retire_finished, _finish_request, the SLO
+                            tick, the step's counters and gauges
 
-Except in sample_dispatch and readback the device has nothing queued:
-the other six sum to the step's host gap.  A step crosses to the device
-a fixed number of times, whatever the batch holds: a decode step twice
-up (model inputs, sampler inputs) and once down; a prefill step once up,
-and once more up and once down when it samples the first token
-(`serving/device_calls{dir=h2d|d2h}`, counted by `_run` / `_to_host`).
+Each phase is counted once a program step: a call that dispatches onto an
+empty pipeline has no back half, and a call with nothing runnable (nobody
+waits, every running row owes its last token: `Scheduler.has_runnable`)
+reads the step in flight back without a scheduling pass.
+In steady state the device has work queued through every phase: while the
+host is in schedule, prepare and sample_dispatch step k runs, and when
+readback returns k+1 is already running.  The phases' sum is what the
+host is BUSY a step, not a gap; the device idles only where that sum
+exceeds its own time a step, or where a settle empties the pipeline.  A
+step crosses to the device a fixed number of times, whatever the batch
+holds: a decode step twice up (model inputs, sampler inputs) and once
+down; a prefill step once up, and once more up and once down when it
+samples the first token (`serving/device_calls{dir=h2d|d2h}`, counted by
+`_run` / `_to_host`; a call of `step()` makes the uploads of the step it
+dispatches and the readback of the one before).
 Gates: PTPU_MONITOR (default on) puts each duration into
 `serving/host_time{phase}`, nothing synced for it; an open profiler
 session gets a host event `ptpu:<phase>` on the device operations' clock
 (the programs are named for that view: prefill_<len>, ragged_decode,
-ragged_prefill_<c>, spec_verify, sample); PTPU_TRACE=1 adds a
-`serving/step` span per step (`phase`, `rows`, the riders' `trace_ids`,
-`state_slots` where the model has state groups), the phases its children,
-filed under every rider's trace.
+ragged_prefill_<c>, spec_verify, feed, sample); PTPU_TRACE=1 adds a
+`serving/step` span per `step()` call (`phase`, `rows`, the riders'
+`trace_ids` - of the step it DISPATCHES - and `state_slots` where the
+model has state groups), the phases its children, filed under every
+rider's trace; the readback, emit and retire inside it are of the step
+before.  A request's `serving/prefill` / `serving/decode_step` span runs
+from its step's dispatch to its tokens' emit.
 
 Observability v2 (monitor.trace): with PTPU_TRACE=1 every request gets a
 trace — root `serving/request` span with `serving/queue_wait`,
@@ -209,7 +267,8 @@ from ..ops.ragged_paged_attention import (ragged_latent_attention_arrays,
 from ..models.serving_form import StateSpec
 from .kv_cache import (BlockAllocatorError, BlockKVCache, CacheGroups,
                        StateCache, prefix_block_keys)
-from .scheduler import (Request, SamplingParams, Scheduler, priority_rank,
+from .scheduler import (Request, SamplingParams, Scheduler,
+                        SchedulerOutput, StepOwed, priority_rank,
                         should_shed, worst_fast_burn)
 from .spec import propose_ngram
 
@@ -281,6 +340,26 @@ def _sample_program(logits, keys, ds, temp, topk, topp):
         _sampler_path(ds, topk, topp),
         [lambda: (greedy, keys), functools.partial(draw, False),
          functools.partial(draw, True)])
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A program step that was dispatched and that the host has not read
+    back: what `_finish` needs to emit its tokens and to time it."""
+
+    kind: str                  # "prefill" | "decode"
+    rows: list                 # the requests it sampled a token for
+    arrays: Optional[tuple]    # device (tokens, keys, stats, greedy), the
+    #                            first two padded to max_num_seqs rows;
+    #                            None: a prefill chunk that sampled nothing
+    tokens: int                # a prefill's chunk length
+    t_begin: float             # the readback before it returned (or, with
+    #                            nothing in flight then, its step() began)
+    drafts: Optional[list] = None      # a verify step's, a list a row
+    spans: tuple = ()          # the rows' serving/prefill|decode_step spans
+
+    def __post_init__(self):
+        self.row_of = {r.req_id: i for i, r in enumerate(self.rows)}
 
 
 @dataclasses.dataclass
@@ -565,6 +644,29 @@ class LLMEngine:
             "a decode step makes 2 h2d and 1 d2h whatever the batch holds")
         self._m_h2d = calls.labels(dir="h2d")
         self._m_d2h = calls.labels(dir="d2h")
+        # ISSUE 35: the step in flight.  `step()` dispatches the step it
+        # has scheduled before it reads this one back; a verify step's
+        # drafts come from tokens the host must have seen, so with
+        # speculation on every step is read back where it was dispatched
+        self._flight: Optional[_Flight] = None
+        self._retired: list = []     # what the step() under way returns
+        self._settle_each_step = self.spec_tokens > 0
+        dispatched = m.counter(
+            "serving/steps_dispatched",
+            "program steps by whether an earlier step's results were "
+            "still owed at dispatch (in_flight=1|0)")
+        self._m_dispatched = [dispatched.labels(in_flight=str(i))
+                              for i in (0, 1)]
+        self._m_settles = m.counter(
+            "serving/settles",
+            "times the step in flight was read back with nothing "
+            "dispatched behind it "
+            "(why=spec|preempt|release|export|fork|idle|drain)")
+        # what a step feeds from when nothing is in flight: never read
+        # (every row's source index says host), device-resident so that
+        # it is not uploaded
+        self._no_toks = jnp.zeros((c.max_num_seqs,), jnp.int32)
+        self._no_keys = jnp.zeros((c.max_num_seqs, 2), jnp.uint32)
         steps = m.counter(
             "serving/sampler_steps",
             "sampler dispatches by what the batch made the program run "
@@ -687,6 +789,8 @@ class LLMEngine:
         write copies only the shared partial block).  The shared-prompt
         serving shape: N samplings of one prompt pay its prefill once."""
         parent = self._requests[parent_id]
+        if parent.owed:          # the text to continue, all of it
+            self._settle("fork")
         if parent.state not in (Request.RUNNING,) or not parent.prefill_done:
             raise ValueError(
                 "fork requires a running, fully-prefilled parent")
@@ -726,6 +830,8 @@ class LLMEngine:
         (greedy and seeded sampling alike — the shipped key IS the
         row's sampling stream)."""
         req = self._requests[req_id]
+        if req.owed:             # tokens, key and blocks as they are
+            self._settle("export")
         if req.finished or not req.prefill_done or not req.output_ids:
             raise ValueError(
                 "export_request needs an unfinished, fully-prefilled "
@@ -898,8 +1004,11 @@ class LLMEngine:
         return np.array(self._to_host(key), np.uint32)
 
     def request_output(self, req_id) -> np.ndarray:
-        """[prompt + generated] int32 ids (dense generate's row shape)."""
+        """[prompt + generated] int32 ids (dense generate's row shape),
+        the token of a step in flight read back first."""
         req = self._requests[req_id]
+        if req.owed:
+            self._settle("export")
         return np.asarray(req.prompt_ids + req.output_ids, np.int32)
 
     def release_request(self, req_id, reason: "str | None" = None) -> None:
@@ -909,10 +1018,15 @@ class LLMEngine:
         prompt/output token list forever.  `generate()` releases its own
         requests.  ``reason`` overrides the finish attribution (the
         deadline sweep passes "deadline"); unfinished releases default
-        to "released" while still queued, "abort" mid-flight."""
-        req = self._requests.pop(req_id, None)
+        to "released" while still queued, "abort" mid-flight.  A request
+        that rides the step in flight has that step read back first (it
+        may finish there: then this is the release of a finished one)."""
+        req = self._requests.get(req_id)
         if req is None:
             return
+        if req.owed:
+            self._settle("release")
+        del self._requests[req_id]
         if req.finished:
             self._finish_request(req, "stop")
             return
@@ -932,7 +1046,17 @@ class LLMEngine:
         req.state = Request.FINISHED
 
     def has_unfinished(self) -> bool:
-        return self.scheduler.has_work()
+        """Work for `step()`: a request to run, or a step to read back."""
+        return self.scheduler.has_work() or self._flight is not None
+
+    def settle(self) -> list:
+        """Read back the step in flight, if there is one, dispatching
+        nothing behind it; returns the requests that finished there.
+        Afterwards every request's tokens, key and blocks are what a
+        caller that looks at them directly (`engine._requests`, the
+        scheduler's lists) expects; `request_output`, `export_request`,
+        `fork_request` and `release_request` do this themselves."""
+        return list(self._settle("drain"))
 
     # -- the loop -----------------------------------------------------------
 
@@ -951,7 +1075,7 @@ class LLMEngine:
                                  "shared instance)")
         ids = [self.add_request(p, sp) for p, sp in zip(prompts, params)]
         try:
-            while self.scheduler.has_work():
+            while self.has_unfinished():
                 self.step()
             # a deadline-expired request was aborted and released
             # mid-loop: its row comes back as None (partial output is
@@ -965,6 +1089,7 @@ class LLMEngine:
             # poison the next generate() call's work loop
             for i in ids:
                 self.release_request(i)
+            self._settle("drain")     # an error left a step in flight
 
     def _expire_deadlines(self) -> list:
         """Abort every unfinished request whose deadline has passed, via
@@ -1004,35 +1129,59 @@ class LLMEngine:
         return [r.req_id for r in shed]
 
     def step(self) -> list:
-        """One scheduler decision + one jitted exec.  Returns the requests
-        that FINISHED this step."""
+        """One scheduler decision, its programs dispatched, and THEN the
+        step dispatched by the call before this one read back: the device
+        runs that one while the host schedules and prepares this one
+        (module docstring).  Returns the requests that FINISHED in the
+        step READ BACK - their last token arrived in this call.  With
+        nothing to dispatch (`idle`) the step in flight is read back all
+        the same; with nothing in flight the call dispatches and returns.
+        The `serving/step` span of a call covers both halves: the dispatch
+        of step k+1 (whose riders it is filed under) and the readback,
+        emit and retire of step k."""
         t0 = time.perf_counter()
         # deterministic hang injection (PTPU_FAULTS="stall@site=engine.step,
         # secs=..."): the step blocks here, completing no span, so the
         # monitor.watchdog post-mortem path is provable in tests
         faults.maybe_stall(site="engine.step")
+        self._retired = []       # every `_finish` inside this call adds
         with mtrace.shared_span("serving/step") as step_span:
-            out, done = self._step_phases(t0, step_span)
+            out = self._step_phases(t0, step_span)
         if mmem.enabled():
             self._memobs_step(out)
-        return list(done)
+        return self._retired
+
+    def _schedule(self):
+        """The scheduler's decision.  It evicts nothing while a row owes a
+        token (`StepOwed`): the step in flight is read back - which may
+        retire the rows whose blocks were wanted - and it decides again."""
+        try:
+            try:
+                return self.scheduler.schedule()
+            except StepOwed:
+                self._settle("preempt")
+                return self.scheduler.schedule()
+        except RuntimeError as e:
+            # ISSUE 20 pressure forensics: an admission failure ("KV
+            # cache too small") leaves a kv_pressure flight dump naming
+            # who actually holds the pool, then propagates untouched
+            if "KV cache too small" in str(e):
+                self._kv_pressure("admission_failure", error=str(e))
+            raise
 
     def _step_phases(self, t0, step_span):
         """step()'s body, phase by phase (the table in the module
         docstring).  `step_span` is the step's shared span with
         PTPU_TRACE=1 and the null span otherwise."""
+        if self._flight is not None and not self.scheduler.has_runnable():
+            # every row owes its last token and nobody waits: nothing to
+            # decide, only the step in flight to read back
+            self._settle("idle")
+            return SchedulerOutput(kind="idle")
         with mtrace.phase("engine/schedule"):
             self._expire_deadlines()
             self._shed_best_effort()
-            try:
-                out = self.scheduler.schedule()
-            except RuntimeError as e:
-                # ISSUE 20 pressure forensics: an admission failure ("KV
-                # cache too small") leaves a kv_pressure flight dump naming
-                # who actually holds the pool, then propagates untouched
-                if "KV cache too small" in str(e):
-                    self._kv_pressure("admission_failure", error=str(e))
-                raise
+            out = self._schedule()
             if out.preempted:
                 self._m_preempt.inc(len(out.preempted))
                 for r in out.preempted:
@@ -1048,14 +1197,51 @@ class LLMEngine:
                 if self.states:
                     step_span.attrs.update(state_slots=sum(
                         st.slots_in_use for st in self.states.values()))
-        toks = 0
-        if out.kind == "prefill":
-            self._step_prefill(out, step_span)
-            toks = out.chunk_len
-        elif out.kind == "decode":
-            # spec decoding can emit MORE tokens than rows in one step —
-            # the decode body reports the real emitted count
-            toks = self._step_decode(out, step_span)
+        prev = self._flight
+        if out.kind == "idle":
+            if prev is not None:
+                self._settle("idle")
+            else:
+                self._finish(None, idle_since=t0)
+        else:
+            self._m_dispatched[prev is not None].inc()
+            body = (self._step_prefill if out.kind == "prefill"
+                    else self._step_decode)
+            # `_flight` is still the step before: its sampler's outputs
+            # are what the rows that rode it feed from
+            self._flight = body(out, step_span, t0)
+            if self._settle_each_step:     # so `prev` is None
+                self._settle("spec")
+            elif prev is not None:
+                self._finish(prev)
+            elif monitor.enabled():  # no back half, this call: the gauges
+                self._observe_pools()
+        return out
+
+    def _settle(self, why: str) -> tuple:
+        """Read back the step in flight with nothing dispatched behind it
+        (the pipeline runs empty: counted by `why`).  No-op without one."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return ()
+        self._m_settles.labels(why=why).inc()
+        return self._finish(flight)
+
+    def _finish(self, flight, idle_since=None) -> tuple:
+        """The back half of a step: `flight`'s results read back and
+        emitted (nothing for None), finished requests retired, the clocks.
+        Once a program step, so its phases are counted once a step too: a
+        call of `step()` that dispatches onto an empty pipeline has none.
+        `serving/step_time{phase}` is observed here, once a program step,
+        under the kind of the step READ BACK, over the time from the
+        readback before it to its own: while the device is the longer
+        side, what a step of that kind costs it.  An idle step (`flight`
+        None, `idle_since` its start) is observed over the call."""
+        toks, t_ret = 0, None
+        if flight is not None:
+            toks, t_ret = self._read_back(flight)
+            if self._flight is not None:
+                self._flight.t_begin = t_ret
         with mtrace.phase("engine/retire"):
             if mreqlog.enabled():
                 # peak-KV high-water per request: only worth the O(running)
@@ -1065,19 +1251,46 @@ class LLMEngine:
                     if blocks > r.peak_kv_blocks:
                         r.peak_kv_blocks = blocks
             done = self.scheduler.retire_finished()
+            self._retired.extend(done)
             for req in done:
                 self._m_done.inc()
                 self._finish_request(req, "stop")
             mslo.maybe_tick()   # one module-global read with PTPU_SLO unset
-            dt = time.perf_counter() - t0
             mtrace.heartbeat()   # step completed — feed the watchdog even
             #                      with tracing off (no span ends to beat)
             if monitor.enabled():
-                self._observe_step(out.kind, toks, dt)
-        return out, done
+                if flight is not None:
+                    self._observe_step(flight.kind, toks,
+                                       t_ret - flight.t_begin)
+                elif idle_since is not None:
+                    self._observe_step("idle", 0,
+                                       time.perf_counter() - idle_since)
+                self._observe_pools()
+        return done
+
+    def _read_back(self, flight):
+        """`flight`'s sampled tokens to the host and into their requests
+        -> (tokens to count for the step, when the readback returned)."""
+        if flight.arrays is None:      # a prefill chunk that sampled nothing
+            return flight.tokens, time.perf_counter()
+        with mtrace.phase("engine/readback"):   # blocked on the device
+            toks, keys, stats, greedy = self._to_host(flight.arrays)
+            if stats is not None:
+                for counter, n in zip(self._m_stats[flight.kind], stats):
+                    counter.inc(int(n))
+        now = time.perf_counter()
+        with mtrace.phase("engine/emit"):
+            if flight.drafts is not None:
+                emitted = self._emit_spec(flight.rows, flight.drafts, toks,
+                                          keys, greedy, now)
+            else:
+                emitted = self._emit(flight.rows, toks, keys, now)
+            for sp in flight.spans:
+                sp.end()
+        return (flight.tokens if flight.kind == "prefill" else emitted), now
 
     def _observe_step(self, phase, toks, dt) -> None:
-        """The per-step counters and gauges (monitor on)."""
+        """The counters and clocks of one step read back (monitor on)."""
         self._m_step.labels(phase=phase).observe(dt)
         # goodput: generated tokens over TOTAL engine wall time —
         # decode_tps reads a single step, this reads the serving
@@ -1092,6 +1305,9 @@ class LLMEngine:
             self._goodput_toks += toks
         self._m_goodput.set(
             self._goodput_toks / max(self._wall_s_total, 1e-9))
+
+    def _observe_pools(self) -> None:
+        """The queue and pool gauges, once a `step()` (monitor on)."""
         sched = self.scheduler
         # queue_depth: admission backlog (never-started requests);
         # waiting: everything not running, preempted included
@@ -1189,7 +1405,7 @@ class LLMEngine:
 
     # -- step bodies --------------------------------------------------------
 
-    def _step_prefill(self, out, step_span):
+    def _step_prefill(self, out, step_span, t0) -> _Flight:
         req = out.prefill_request
         start, chunk = out.chunk_start, out.chunk_len
         req.prefill_chunks += 1
@@ -1204,18 +1420,23 @@ class LLMEngine:
         if req.queue_span is not None:   # first compute: queue wait over
             req.queue_span.end()
             req.queue_span = None
-        sp = None
+        spans = ()
         if req.trace is not None:
-            sp = mtrace.start_span("serving/prefill", parent=req.trace,
-                                   chunk_start=start, chunk_len=chunk,
-                                   step=step_span.span_id)
+            spans = (mtrace.start_span(
+                "serving/prefill", parent=req.trace, chunk_start=start,
+                chunk_len=chunk, step=step_span.span_id),)
         try:
-            self._prefill_body(req, start, chunk)
-        finally:
-            if sp is not None:
+            arrays = self._prefill_body(req, start, chunk)
+        except BaseException:
+            for sp in spans:
                 sp.end()
+            raise
+        return _Flight("prefill", [req] if arrays else [], arrays, chunk,
+                       t0, spans=spans)
 
     def _prefill_body(self, req, start, chunk):
+        """Dispatch one prefill chunk -> the sampler's device outputs when
+        the chunk ends the prompt (`_sample_rows`), else None."""
         with mtrace.phase("engine/prepare"):
             ids = np.asarray([req.prompt_ids[start:start + chunk]],
                              np.int32)
@@ -1251,49 +1472,52 @@ class LLMEngine:
             req.num_computed = start + chunk
             if req.prefix_keys:
                 # index the blocks this chunk just filled (full prompt
-                # blocks only — their content is final while referenced)
+                # blocks only — their content is final while referenced;
+                # whoever adopts them dispatches behind this program)
                 self.cache.register_prefix(req.req_id, req.prefix_keys,
                                            req.num_computed)
-        if req.prefill_done:
-            if req.params.max_new_tokens <= 0:
-                # dense generate(max_new_tokens=0) emits nothing
-                req.state = Request.FINISHED
-            else:
-                self._sample_rows([req], logits, stats, "prefill")
+        if not req.prefill_done:
+            return None
+        if req.params.max_new_tokens <= 0:
+            # dense generate(max_new_tokens=0) emits nothing
+            req.state = Request.FINISHED
+            return None
+        return self._sample_rows([req], logits, stats)
 
-    def _step_decode(self, out, step_span) -> int:
+    def _step_decode(self, out, step_span, t0) -> _Flight:
         rows = list(out.decode_requests)
-        spans = [mtrace.start_span("serving/decode_step", parent=r.trace,
-                                   pos=r.total_len - 1, batch=len(rows),
-                                   step=step_span.span_id)
-                 for r in rows if r.trace is not None]
+        spans = tuple(
+            mtrace.start_span("serving/decode_step", parent=r.trace,
+                              pos=r.total_len - 1, batch=len(rows),
+                              step=step_span.span_id)
+            for r in rows if r.trace is not None)
         try:
-            return self._decode_body(rows)
-        finally:
+            arrays, drafts = self._decode_body(rows)
+        except BaseException:
             for sp in spans:
                 sp.end()
+            raise
+        return _Flight("decode", rows, arrays, 0, t0, drafts=drafts,
+                       spans=spans)
 
-    def _decode_body(self, rows) -> int:
+    def _decode_body(self, rows):
+        """Dispatch one decode step -> (the sampler's device outputs, the
+        rows' drafts where it was a verify step, else None)."""
         if self.spec_tokens:
             with mtrace.phase("engine/prepare"):   # the host's n-gram scan
                 drafts = [self._propose(r) for r in rows]
             if any(drafts):
-                return self._decode_body_spec(rows, drafts)
+                return self._decode_body_spec(rows, drafts), drafts
             # zero drafts anywhere this step (cold history, sampling
             # rows, n-gram misses): the plain (bb, 1) program is
             # strictly cheaper — C=1 compute, kernel-eligible on TPU —
             # than a verify launch whose k draft positions are all
             # padding.  Both shapes compile once; steady state stays
-            # two launches either way.
-            n = self._decode_body_plain(rows)
-            with mtrace.phase("engine/emit"):
-                for req in rows:
-                    # release the scheduler's (clamped) draft reservation
-                    self.kv.truncate_to(req.req_id, req.total_len)
-            return n
-        return self._decode_body_plain(rows)
+            # two launches either way.  (`_emit` gives the scheduler's
+            # draft reservation back.)
+        return self._decode_body_plain(rows), None
 
-    def _decode_body_plain(self, rows) -> int:
+    def _decode_body_plain(self, rows) -> tuple:
         n = len(rows)
         mon = monitor.enabled()
         # launch accounting (ISSUE 12): every jitted dispatch this step
@@ -1304,14 +1528,26 @@ class LLMEngine:
         # no recompile when the running-request count changes
         bb = self.scheduler.max_num_seqs
         with mtrace.phase("engine/prepare"):
-            toks, pos0, lens, tables, slots, srows = self._decode_inputs(
-                rows, [()] * n, bb, 1)
+            inputs = self._decode_inputs(rows, [()] * n, bb, 1)
+            lens = inputs[2]
             fn = self._get_ragged_exec(bb, 1)
             if mon:
                 self._launches_this_step.add(("ragged", bb, 1))
-            logits, kv_out, stats = self._run(
-                fn, self._param_arrays(), self._kv_flat(),
-                toks, pos0, lens, tables, slots, srows)
+            if self._settle_each_step:
+                # nothing is ever in flight: the host holds every token
+                logits, kv_out, stats = self._run(
+                    fn, self._param_arrays(), self._kv_flat(), *inputs)
+            else:
+                # the step's one upload: the feed program takes every
+                # host array, puts the token of the step in flight where
+                # a row rode it, and hands all of them on as device arrays
+                if mon:
+                    self._launches_this_step.add(("feed", bb))
+                inputs = self._run(
+                    self._get_feed_exec(bb), self._in_flight_outputs()[0],
+                    self._feed_sources(rows, bb), *inputs)
+                logits, kv_out, stats = fn(
+                    self._param_arrays(), self._kv_flat(), *inputs)
             self._store_kv(kv_out)
             if mon:
                 for k, (_, live, held) in zip(self.caches.values(),
@@ -1322,7 +1558,7 @@ class LLMEngine:
                 for st, (_, held) in zip(self.states.values(),
                                          self._m_state):
                     held.inc(st.slots_in_use)
-        self._sample_rows(rows, logits, stats, "decode")
+        arrays = self._sample_rows(rows, logits, stats)
         if mon:
             # padding accounting: bb rows ran, n were real — the
             # serving-goodput blind spot the ragged fixed-shape program
@@ -1334,7 +1570,27 @@ class LLMEngine:
             self._m_pad_toks.set(waste)
             self._m_kernels.set(len(self._launches_this_step))
             self._launches_this_step = None
-        return n
+        return arrays
+
+    def _in_flight_outputs(self) -> tuple:
+        """The sampler's (tokens, keys) of the step in flight, on the
+        device; zeros of their shape where no step is, or it sampled
+        nothing (no row's source index points there)."""
+        flight = self._flight
+        if flight is None or flight.arrays is None:
+            return self._no_toks, self._no_keys
+        return flight.arrays[:2]
+
+    def _feed_sources(self, rows, bb) -> np.ndarray:
+        """Where each of `bb` rows takes its input token and sampling key
+        from: the row of the step in flight that sampled them (its
+        outputs are on the device and nowhere else yet), or -1 for what
+        the host holds - a request that owes nothing, and padding."""
+        src = np.full((bb,), -1, np.int32)
+        for i, req in enumerate(rows):
+            if req.owed:
+                src[i] = self._flight.row_of[req.req_id]
+        return src
 
     def _table_row(self, k, req_id) -> np.ndarray:
         """A request's block table in cache `k`, padded to the programs'
@@ -1422,7 +1678,7 @@ class LLMEngine:
                              ngram_min=c.spec_ngram_min,
                              window=c.spec_lookup_window)
 
-    def _decode_body_spec(self, rows, drafts) -> int:
+    def _decode_body_spec(self, rows, drafts) -> tuple:
         """Speculative decode step: ONE fixed-shape ragged
         (max_num_seqs, k+1) verify program scores the last real token
         plus up to k n-gram drafts per row against the paged pools
@@ -1447,20 +1703,22 @@ class LLMEngine:
                 fn, self._param_arrays(), self._kv_flat(),
                 toks, pos0, lens, tables, slots, srows)
             self._store_kv(kv_out)
-        emitted = self._emit_spec(rows, drafts, logits0, greedy)
+        # the position-0 logits run through the SAME ("sample", bb)
+        # program as plain decode — key threading and sampling rows'
+        # streams are bit-identical to spec-off
+        arrays = self._sample_rows(rows, logits0)
         if mon:
             real_q = n + sum(len(d) for d in drafts)
             self._m_pad_rows.set((bb - n) / max(bb, 1))
             self._m_pad_toks.set((bb * cw - real_q) / max(bb * cw, 1))
             self._m_kernels.set(len(self._launches_this_step))
             self._launches_this_step = None
-        return emitted
+        return arrays[:3] + (greedy,)
 
-    def _emit_spec(self, rows, drafts, logits0, greedy) -> int:
-        """Per-row acceptance + emission.  The position-0 logits run
-        through the SAME (\"sample\", bb) program as plain decode — key
-        threading and sampling rows' streams are bit-identical to
-        spec-off — and greedy rows then extend with their longest
+    def _emit_spec(self, rows, drafts, toks, new_keys, greedy_h,
+                   now) -> int:
+        """Per-row acceptance + emission of a verify step read back.
+        Greedy rows extend the sampler's token with their longest
         verified draft run: draft j is accepted iff it equals the greedy
         token at position j-1, which validates position j's logits,
         whose greedy token is emitted (the correction/bonus token ends
@@ -1468,37 +1726,32 @@ class LLMEngine:
         (rejected-draft blocks return to the pool; finished rows are
         freed by retire_finished right after — truncating first keeps
         the shared-block refcounts exact either way)."""
-        toks, new_keys = self._dispatch_sampler(rows, logits0)
-        with mtrace.phase("engine/readback"):
-            toks, new_keys, greedy_h = self._to_host(
-                (toks, new_keys, greedy))
-        now = time.perf_counter()
         emitted = proposed = accepted = 0
-        with mtrace.phase("engine/emit"):
-            for i, req in enumerate(rows):
-                req.key = new_keys[i]
-                out = [int(toks[i])]
-                m = len(drafts[i])
-                proposed += m
-                if not req.params.do_sample:
-                    g = greedy_h[i]
-                    # out[0] == g[0]: both argmax the same fp32 logits row
-                    for j in range(1, m + 1):
-                        if int(drafts[i][j - 1]) != int(g[j - 1]):
-                            break
-                        out.append(int(g[j]))
-                row_emitted = 0
-                for tok in out:
-                    req.record_token(tok)
-                    row_emitted += 1
-                    self._record_latency(req, now)
-                    if req.finished:
-                        break          # eos inside the accepted run
-                emitted += row_emitted
-                accepted += row_emitted - 1
-                req.spec_proposed += m
-                req.spec_accepted += row_emitted - 1
-                self.kv.truncate_to(req.req_id, req.total_len)
+        for i, req in enumerate(rows):
+            req.owed -= 1
+            req.key = new_keys[i]
+            out = [int(toks[i])]
+            m = len(drafts[i])
+            proposed += m
+            if not req.params.do_sample:
+                g = greedy_h[i]
+                # out[0] == g[0]: both argmax the same fp32 logits row
+                for j in range(1, m + 1):
+                    if int(drafts[i][j - 1]) != int(g[j - 1]):
+                        break
+                    out.append(int(g[j]))
+            row_emitted = 0
+            for tok in out:
+                req.record_token(tok)
+                row_emitted += 1
+                self._record_latency(req, now)
+                if req.finished:
+                    break          # eos inside the accepted run
+            emitted += row_emitted
+            accepted += row_emitted - 1
+            req.spec_proposed += m
+            req.spec_accepted += row_emitted - 1
+            self.kv.truncate_to(req.req_id, req.total_len)
         self._spec_proposed_total += proposed
         self._spec_accepted_total += accepted
         if monitor.enabled():
@@ -1540,11 +1793,14 @@ class LLMEngine:
 
     def _dispatch_sampler(self, rows, logits):
         """Launch the (\"sample\", B) program over [B, V] fp32 logits (B
-        may exceed len(rows) by padding); returns its two device arrays.
-        The model program is still running when this returns.  The rows'
-        own parameters decide what the program runs (`_sampler_path`: a
-        batch of greedy rows sorts nothing); the same arrays count the
-        dispatch into `serving/sampler_steps{path}` here."""
+        may exceed len(rows) by padding); returns its two device arrays,
+        padded to `max_num_seqs` rows.  The model program is still running
+        when this returns.  The rows' own parameters decide what the
+        program runs (`_sampler_path`: a batch of greedy rows sorts
+        nothing); the same arrays count the dispatch into
+        `serving/sampler_steps{path}` here.  A row that rode the step in
+        flight takes its key from that step's sampler, on the device
+        (`_feed_sources`): the host's copy is a step old."""
         with mtrace.phase("engine/sample_dispatch"):
             bb = int(logits.shape[0])
             keys = np.zeros((bb, 2), np.uint32)
@@ -1565,26 +1821,39 @@ class LLMEngine:
                 # accounting only; the prefill path samples too but is not
                 # the steady-state loop the kernel count instruments
                 self._launches_this_step.add(("sample", bb))
-            return self._run(fn, logits, keys, ds, temp, topk, topp)
+            return self._run(
+                fn, logits, self._in_flight_outputs()[1],
+                self._feed_sources(rows, bb), keys, ds, temp, topk, topp)
 
-    def _sample_rows(self, rows, logits, stats=None, phase="decode"):
-        """Sample one token per live row and emit it.  `stats`: what the
-        form's layers counted in the program that made `logits`, read
-        back behind the tokens (that program has ended by then)."""
+    def _sample_rows(self, rows, logits, stats=None):
+        """Dispatch the sampler over one token per live row; from here on
+        each row owes that token.  -> what `_read_back` reads: the
+        sampler's two device arrays, `stats` - what the form's layers
+        counted in the program that made `logits` - and no greedy run."""
         toks, new_keys = self._dispatch_sampler(rows, logits)
-        if not monitor.enabled():
-            stats = None
-        with mtrace.phase("engine/readback"):   # blocked on the device
-            toks, new_keys, stats = self._to_host((toks, new_keys, stats))
-            if stats is not None:
-                for counter, n in zip(self._m_stats[phase], stats):
-                    counter.inc(int(n))
-        now = time.perf_counter()
-        with mtrace.phase("engine/emit"):
-            for i, req in enumerate(rows):
-                req.key = new_keys[i]
-                req.record_token(int(toks[i]))
-                self._record_latency(req, now)
+        for req in rows:
+            req.owed += 1
+        return toks, new_keys, stats if monitor.enabled() else None, None
+
+    def _emit(self, rows, toks, new_keys, now) -> int:
+        """The tokens of a step read back into their requests -> how many
+        were emitted.  A row that ended on its `eos_token_id` in the step
+        before rode this one unseen: its token is dropped (its blocks
+        went back at that step's retire)."""
+        emitted = 0
+        for i, req in enumerate(rows):
+            req.owed -= 1
+            if req.finished:
+                continue
+            req.key = new_keys[i]
+            req.record_token(int(toks[i]))
+            self._record_latency(req, now)
+            emitted += 1
+            if self.spec_tokens:
+                # no row drafted this step: give the scheduler's (clamped)
+                # draft reservation back
+                self.kv.truncate_to(req.req_id, req.total_len)
+        return emitted
 
     # -- perf attribution ---------------------------------------------------
 
@@ -1761,8 +2030,9 @@ class LLMEngine:
         logits = jnp.zeros((bb, self.form.vocab_size), jnp.float32)
         out["sampler"] = mperf.measure(
             self._get_sample_exec(bb),
-            logits, jnp.zeros((bb, 2), jnp.uint32),
-            jnp.zeros((bb,), bool), jnp.ones((bb,), jnp.float32),
+            logits, self._no_keys, jnp.full((bb,), -1, jnp.int32),
+            jnp.zeros((bb, 2), jnp.uint32), jnp.zeros((bb,), bool),
+            jnp.ones((bb,), jnp.float32),
             jnp.zeros((bb,), jnp.int32), jnp.ones((bb,), jnp.float32),
             label="decode:sampler_exec", reps=reps)
         # NOT "decode:sampler": the in-situ segment record of that name
@@ -1784,7 +2054,9 @@ class LLMEngine:
         the chip 0.4-0.7 ms a call under one `jax.device_put` of the
         tuple, PERF.md PR 31).  Every host-to-device crossing of a step
         goes through here and counts one
-        `serving/device_calls{dir="h2d"}`."""
+        `serving/device_calls{dir="h2d"}`; the decode program, whose
+        inputs the feed program has left on the device, is called
+        without."""
         self._m_h2d.inc()
         return fn(*args)
 
@@ -1820,6 +2092,7 @@ class LLMEngine:
     _KEY_FIELDS = {"prefill": ("prompt_len",),
                    "ragged": ("batch", "chunk_len"),
                    "verify": ("batch", "chunk_len"),
+                   "feed": ("batch",),
                    "sample": ("batch",)}
 
     def _count_compile(self, kind: str, key=None) -> None:
@@ -2084,11 +2357,44 @@ class LLMEngine:
                 self._named(verify, "spec_verify"), donate_argnums=(1,))
         return self._jit_cache[key]
 
+    def _get_feed_exec(self, b):
+        """The program that puts a decode step's inputs on the device:
+        `(prev_toks, src, toks, *rest)`, all but the first from the host,
+        -> `(toks, *rest)` as device arrays, where row i's token is
+        `prev_toks[src[i]]` - what the step in flight sampled for it, which
+        the host has not seen - wherever `src[i] >= 0`.  A program of its
+        own so that the model programs stay what they are; ONE shape
+        whatever the mix of rows fed from the device and from the host."""
+        key = ("feed", b)
+        if key not in self._jit_cache:
+            self._count_compile("feed", key)
+
+            def feed(prev_toks, src, toks, *rest):
+                fed = jnp.where(src >= 0, prev_toks[jnp.maximum(src, 0)],
+                                toks[:, 0])
+                return (fed[:, None],) + rest
+
+            self._jit_cache[key] = jax.jit(self._named(feed, "feed"))
+        return self._jit_cache[key]
+
     def _get_sample_exec(self, b):
+        """`_sample_program` over `b` rows, between what this engine adds
+        around it: a row's key comes from `prev_keys[src]`, the keys the
+        step in flight left on the device, wherever `src >= 0` (else from
+        `keys`, the host's), and both results are padded to `max_num_seqs`
+        rows, so that the next step's programs take a prefill's one-row
+        outputs and a decode step's in one shape."""
         key = ("sample", b)
         if key not in self._jit_cache:
             self._count_compile("sample", key)
-            # a wrapper of its own: _named renames what it is given
-            self._jit_cache[key] = jax.jit(self._named(
-                lambda *inputs: _sample_program(*inputs), "sample"))
+            pad = self.scheduler.max_num_seqs - b
+
+            def sample(logits, prev_keys, src, keys, *params):
+                keys = jnp.where((src >= 0)[:, None],
+                                 prev_keys[jnp.maximum(src, 0)], keys)
+                toks, new_keys = _sample_program(logits, keys, *params)
+                return (jnp.pad(toks, (0, pad)),
+                        jnp.pad(new_keys, ((0, pad), (0, 0))))
+
+            self._jit_cache[key] = jax.jit(self._named(sample, "sample"))
         return self._jit_cache[key]

@@ -130,6 +130,11 @@ class ReplicaWorker:
         self._admit()
         if self.engine.has_unfinished():
             self.engine.step()
+            if self.role == "prefill":
+                # what this worker hands off is a prompt's first token:
+                # read it back now, not behind a decode step that the
+                # engine would dispatch for a row about to leave
+                self.engine.settle()
         else:
             mtrace.heartbeat()   # idle pump still feeds the watchdog
         self._harvest()

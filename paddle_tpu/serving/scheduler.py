@@ -26,6 +26,13 @@ Preemption evicts lowest-priority-youngest first.  With default params
 (no tenant, one priority) every ordering degenerates to the original
 FIFO/youngest policy bit-for-bit.
 
+The engine keeps one step in flight (ISSUE 35): a request may OWE a token
+that a dispatched step has sampled and the host has not read (`Request.
+owed`).  The scheduler counts it: an owing request is one position longer
+(`total_len`), is not decoded again when the owed token is its last by
+count, and nothing is evicted while any row owes - `_evict` raises
+`StepOwed`, the engine reads the step back and asks again.
+
 The scheduler owns request state machines and the block accounting calls;
 it never touches device math — that is `engine.LLMEngine`'s half.
 """
@@ -37,8 +44,8 @@ from collections import deque
 from typing import Optional
 
 __all__ = ["SamplingParams", "Request", "Scheduler", "SchedulerOutput",
-           "PRIORITIES", "priority_rank", "tenant_weights", "should_shed",
-           "worst_fast_burn"]
+           "StepOwed", "PRIORITIES", "priority_rank", "tenant_weights",
+           "should_shed", "worst_fast_burn"]
 
 # Priority classes, best first.  Admission prefers lower rank; eviction
 # victimizes higher rank.  Unknown strings rank with "best-effort" so a
@@ -151,6 +158,8 @@ class Request:
         self.params = params
         self.state = Request.WAITING
         self.output_ids: list = []         # generated tokens (incl. eos)
+        self.owed = 0                      # tokens sampled by a dispatched
+        #                                    step and not read back (engine)
         self.num_computed = 0              # prompt tokens prefilled so far
         self.key = None                    # per-request PRNG key: a host
         #                                    numpy uint32[2] (engine)
@@ -186,7 +195,9 @@ class Request:
 
     @property
     def total_len(self) -> int:
-        return self.prompt_len + len(self.output_ids)
+        """Prompt + generated tokens, the owed one counted: the next
+        decode step writes position `total_len - 1`."""
+        return self.prompt_len + len(self.output_ids) + self.owed
 
     @property
     def prefill_done(self) -> bool:
@@ -195,6 +206,13 @@ class Request:
     @property
     def finished(self) -> bool:
         return self.state == Request.FINISHED
+
+    @property
+    def owes_last(self) -> bool:
+        """The token it owes is its last by count: no step is left for it
+        to ride (an `eos_token_id` is found out only at readback)."""
+        return bool(self.owed) and (len(self.output_ids) + self.owed
+                                    >= self.params.max_new_tokens)
 
     def record_token(self, tok: int) -> None:
         self.output_ids.append(int(tok))
@@ -207,6 +225,14 @@ class Request:
         names = {0: "WAITING", 1: "RUNNING", 2: "PREEMPTED", 3: "FINISHED"}
         return (f"Request({self.req_id}, state={names[self.state]}, "
                 f"prompt={self.prompt_len}, out={len(self.output_ids)})")
+
+
+class StepOwed(Exception):
+    """Raised by `schedule()` before it evicts anything while a running
+    request owes a token: a swap snapshot carries a request's tokens and
+    key as the host has them.  The engine reads the step in flight back
+    and calls `schedule()` again (what the first call did until then -
+    `grow_to`, a swap-resume - stands)."""
 
 
 @dataclasses.dataclass
@@ -280,6 +306,12 @@ class Scheduler:
 
     def has_work(self) -> bool:
         return bool(self.waiting or self.running)
+
+    def has_runnable(self) -> bool:
+        """Could `schedule()` find a step to run: someone waits, or a
+        running request has a step left to ride."""
+        return bool(self.waiting) or any(
+            not (r.finished or r.owes_last) for r in self.running)
 
     # -- multi-tenant fair share (ISSUE 19) --------------------------------
 
@@ -375,6 +407,8 @@ class Scheduler:
             for req in list(self.running):   # oldest first
                 if req.state != Request.RUNNING or not req.prefill_done:
                     continue                 # evicted mid-loop / mid-prefill
+                if req.owes_last:
+                    continue
                 # this step writes position total_len - 1 (the last
                 # sampled token's K/V) — coverage of total_len tokens is
                 # exactly enough (one more would take a block a step
@@ -522,6 +556,8 @@ class Scheduler:
             priority_rank(getattr(r.params, "priority", None)), r.arrival))
 
     def _evict(self, req, preempted) -> None:
+        if any(r.owed for r in self.running):
+            raise StepOwed
         req.swap = self.cache.swap_out(req.req_id)
         req.state = Request.PREEMPTED
         self.running.remove(req)

@@ -172,6 +172,26 @@ def test_preemption_swaps_both_caches_and_the_tokens_do_not_move(tiny):
         assert _margins(model, cfg, g, len(p)).max() <= NEAR_TIE
 
 
+@pytest.mark.parametrize("scenario", [
+    "tokens_and_keys", "eos_mid_flight", "cancel_and_deadline",
+    "forced_preemption"])
+def test_a_step_in_flight_equals_the_settled_engine(tiny, scenario):
+    """ISSUE 35 over lfm2's state slots: the scenarios of
+    tests/_step_in_flight.py (tokens, keys at export, pools after a
+    cancel, a deadline, an eviction) against the same engine with every
+    step settled."""
+    import _step_in_flight as sif
+
+    model, cfg = tiny
+    small = dict(num_blocks=12) if scenario == "forced_preemption" else {}
+    monitor.enable(True)
+    try:
+        getattr(sif, "check_" + scenario)(
+            lambda: _engine(model, max_num_seqs=3, **small), cfg.vocab_size)
+    finally:
+        monitor.refresh()
+
+
 def test_export_and_adopt_carry_kv_and_state_bit_exactly(tiny):
     """A request exported mid-decode ships its K/V blocks AND its state
     rows as they stood in the exporter's pools, and decodes on in another

@@ -110,11 +110,11 @@ def _served_logits(eng, prompts, sp):
     seen = {}
     sample = eng._sample_rows
 
-    def spy(rows, logits, stats=None, phase="decode"):
+    def spy(rows, logits, stats=None):
         lg = np.asarray(logits, np.float32)
         for i, r in enumerate(rows):
             seen.setdefault(r.req_id, []).append(lg[i])
-        return sample(rows, logits, stats, phase)
+        return sample(rows, logits, stats)
 
     eng._sample_rows = spy
     outs = eng.generate(prompts, sp)
@@ -733,3 +733,23 @@ def test_grouped_products_in_chunks_equal_one_run(monkeypatch):
             np.testing.assert_array_equal(np.asarray(stats),
                                           np.asarray(want_stats))
         monkeypatch.undo()
+
+
+@pytest.mark.parametrize("scenario", [
+    "tokens_and_keys", "eos_mid_flight", "cancel_and_deadline",
+    "forced_preemption"])
+def test_a_step_in_flight_equals_the_settled_engine(tiny, scenario):
+    """ISSUE 35 over mistral4's latent group: the scenarios of
+    tests/_step_in_flight.py (tokens, keys at export, pools after a
+    cancel, a deadline, an eviction) against the same engine with every
+    step settled."""
+    import _step_in_flight as sif
+
+    model, cfg = tiny
+    small = dict(num_blocks=12) if scenario == "forced_preemption" else {}
+    monitor.enable(True)
+    try:
+        getattr(sif, "check_" + scenario)(
+            lambda: _engine(model, max_num_seqs=3, **small), cfg.vocab_size)
+    finally:
+        monitor.refresh()

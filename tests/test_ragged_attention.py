@@ -526,10 +526,11 @@ def _dense_solo(model, prompt, **kw):
 
 
 class TestEngineRaggedParity:
-    def test_four_kinds_of_program(self, model, prompts):
+    def test_five_kinds_of_program(self, model, prompts):
         """Whole-prompt prefill, its chunked continuation, decode with
         and without drafts, sampling: `prefill`, `ragged`, `verify` and
-        `sample`, and nothing else."""
+        `sample`; an engine that keeps a step in flight adds `feed`, its
+        decode step's upload; and nothing else."""
         eng = LLMEngine(model, EngineConfig(
             block_size=16, max_num_seqs=2, max_num_batched_tokens=4,
             speculative_tokens=2))
@@ -537,6 +538,11 @@ class TestEngineRaggedParity:
         eng.generate([repeat, prompts[0]], SamplingParams(max_new_tokens=4))
         assert {key[0] for key in eng._jit_cache} == {
             "prefill", "ragged", "verify", "sample"}
+        eng = LLMEngine(model, EngineConfig(
+            block_size=16, max_num_seqs=2, max_num_batched_tokens=4))
+        eng.generate([repeat, prompts[0]], SamplingParams(max_new_tokens=4))
+        assert {key[0] for key in eng._jit_cache} == {
+            "prefill", "ragged", "feed", "sample"}
 
     @pytest.mark.slow
     def test_ragged_matches_dense(self, model, prompts):

@@ -502,6 +502,10 @@ class FakeEngine:
         self.steps += 1
         return []
 
+    def settle(self):
+        self.settles = getattr(self, "settles", 0) + 1
+        return []
+
     def request_output(self, erid):
         r = self._requests[erid]
         return np.asarray(r.prompt_ids + r.output_ids, np.int32)
@@ -624,6 +628,7 @@ def test_worker_prefill_role_exports_handoff():
     req.state = Request.RUNNING
     eng.scheduler.running.append(req)
     w.pump()
+    assert eng.settles == eng.steps    # a prefill worker keeps no step in flight
     doc = w.poll_local()
     assert doc["results"] == []
     (hof,) = doc["handoffs"]

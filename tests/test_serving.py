@@ -23,6 +23,8 @@ from paddle_tpu.models import GPTForCausalLM, gpt_test_config
 from paddle_tpu.serving import (BlockAllocatorError, BlockKVCache,
                                 EngineConfig, LLMEngine, SamplingParams)
 
+import _step_in_flight as sif
+
 NEW = 5
 LENS = [3, 5, 7, 3, 5, 7, 4, 4]        # 8 prompts, 4 distinct lengths
 
@@ -845,7 +847,10 @@ class TestDeviceCrossings:
             self, family, monitored):
         """A decode step uploads twice (model inputs, sampler inputs) and
         reads back once at 1 live row and at `max_num_seqs` live rows; a
-        whole-prompt prefill step the same whatever the prompt's length."""
+        whole-prompt prefill step the same whatever the prompt's length.
+        A call of `step()` makes the uploads of the step it dispatches and
+        the readback of the step before: the first call reads nothing
+        back, the last one (nothing left to dispatch) uploads nothing."""
         m, kw = family
         eng = LLMEngine(m, EngineConfig(max_num_seqs=4, **kw))
         assert list(eng.caches) in (["full"], ["full", "window"])
@@ -853,17 +858,22 @@ class TestDeviceCrossings:
         rng = np.random.RandomState(3)
         sp = SamplingParams(max_new_tokens=8)
         ids = [eng.add_request(rng.randint(0, m.cfg.vocab_size, (5,)), sp)]
-        assert _step_calls(eng) == (2, 1) and kinds[-1] == ("prefill", 0)
+        assert _step_calls(eng) == (2, 0) and kinds[-1] == ("prefill", 0)
         assert _step_calls(eng) == (2, 1) and kinds[-1] == ("decode", 1)
         for n in (3, 11, 17):          # 17 passes afmoe tiny's window of 8
             ids.append(eng.add_request(
                 rng.randint(0, m.cfg.vocab_size, (n,)), sp))
         seen = set()
         while eng.has_unfinished():
+            decided = len(kinds)
             calls = _step_calls(eng)
+            if len(kinds) == decided:      # nothing left to schedule
+                assert calls == (0, 1) and not eng.has_unfinished()
+                break
             assert calls == (2, 1), (kinds[-1], calls)
             seen.add(kinds[-1])
         assert ("decode", 4) in seen and ("prefill", 0) in seen
+        assert "idle" not in {k for k, _ in kinds}
         for i in ids:
             eng.release_request(i)
 
@@ -877,10 +887,12 @@ class TestDeviceCrossings:
                               SamplingParams(max_new_tokens=2))
         assert _step_calls(eng) == (1, 0)      # positions 0-7
         assert _step_calls(eng) == (1, 0)      # 8-15
-        assert _step_calls(eng) == (2, 1)      # 16-18 and the first token
+        assert _step_calls(eng) == (2, 0)      # 16-18 and the first token,
         assert [k for k, _ in kinds] == ["prefill"] * 3
+        # which is read back behind the decode step's two uploads
         assert _step_calls(eng) == (2, 1) and kinds[-1] == ("decode", 1)
         eng.release_request(rid)
+        assert not eng.has_unfinished()
 
     def test_add_request_reads_the_device_for_a_sampling_key_only(
             self, engine, monitored):
@@ -892,6 +904,118 @@ class TestDeviceCrossings:
         assert tuple(_device_calls() - before) == (0, 1)
         for i in (a, b):
             engine.release_request(i)
+
+
+class TestStepInFlight:
+    """ISSUE 35: `step()` dispatches the step it has scheduled before it
+    reads back the one before.  The scenarios are tests/_step_in_flight.py's
+    (lfm2's state slots and mistral4's latent group run them in their own
+    files)."""
+
+    @staticmethod
+    def _make(family, **over):
+        m, kw = family
+        return lambda: LLMEngine(m, EngineConfig(
+            **dict(kw, max_num_seqs=3, **over)))
+
+    def test_tokens_and_keys_equal_the_settled_engines(self, family,
+                                                       monitored):
+        sif.check_tokens_and_keys(self._make(family), family[0].cfg.vocab_size)
+
+    def test_eos_mid_flight(self, family, monitored):
+        sif.check_eos_mid_flight(self._make(family), family[0].cfg.vocab_size)
+
+    def test_cancel_and_deadline_with_a_step_owed(self, family, monitored):
+        sif.check_cancel_and_deadline(self._make(family),
+                                      family[0].cfg.vocab_size)
+
+    def test_forced_preemption_with_a_step_owed(self, family, monitored):
+        sif.check_forced_preemption(
+            self._make(family, block_size=4, num_blocks=12),
+            family[0].cfg.vocab_size)
+
+
+class TestStepInFlightCounters:
+    def test_a_steady_batch_keeps_a_step_in_flight(self, family, monitored):
+        """`serving/steps_dispatched{in_flight}`: after the first step of
+        a steady batch every step is dispatched behind one still owed, and
+        nothing settles until the batch ends; `serving/step_time{phase}`
+        counts each program step once, under the kind of the step READ
+        BACK by the call (the one dispatched a call earlier)."""
+        m, kw = family
+        eng = LLMEngine(m, EngineConfig(max_num_seqs=3, **kw))
+        kinds = _watch_schedule(eng)
+        steps0 = sif.counter("serving/steps_dispatched")
+        settles0 = sif.counter("serving/settles")
+        ps = sif.prompts(m.cfg.vocab_size)
+        rids = [eng.add_request(p, SamplingParams(max_new_tokens=6))
+                for p in ps[:3]]
+        read_back = []
+        while eng.has_unfinished():
+            before = {k: v["count"] for k, v in monitor.snapshot().get(
+                "serving/step_time", {}).items()}
+            eng.step()
+            after = {k: v["count"] for k, v in monitor.snapshot()[
+                "serving/step_time"].items()}
+            read_back.append([k[len("phase="):] for k in after
+                              if after[k] != before.get(k, 0)])
+        # 3 prefills and 5 decode steps, then a call with nothing to
+        # schedule; call n observes the step that call n - 1 dispatched
+        dispatched = [k for k, _ in kinds]
+        assert dispatched == ["prefill"] * 3 + ["decode"] * 5
+        assert read_back == [[]] + [[k] for k in dispatched]
+        assert sif.moved("serving/steps_dispatched", steps0) == {
+            "in_flight=0": 1, "in_flight=1": 7}
+        assert sif.moved("serving/settles", settles0) == {"why=idle": 1}
+        for r in rids:
+            assert len(eng.request_output(r)) == len(ps[rids.index(r)]) + 6
+            eng.release_request(r)
+
+    def test_speculation_settles_every_step(self, model, monitored):
+        """The drafts of step k+1 are made from step k's tokens: with
+        `speculative_tokens` on, every step is read back where it is
+        dispatched, and the engine decides that from its own
+        configuration."""
+        eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=3,
+                                            speculative_tokens=2))
+        steps0 = sif.counter("serving/steps_dispatched")
+        settles0 = sif.counter("serving/settles")
+        ps = sif.prompts(model.cfg.vocab_size)
+        rids = [eng.add_request(np.tile(p, 3),
+                                SamplingParams(max_new_tokens=8))
+                for p in ps[:2]]
+        calls = 0
+        while eng.has_unfinished():
+            eng.step()
+            calls += 1
+            assert all(r.owed == 0 for r in eng._requests.values())
+        assert sif.moved("serving/steps_dispatched", steps0) == {
+            "in_flight=0": calls}
+        assert sif.moved("serving/settles", settles0) == {"why=spec": calls}
+        for r in rids:
+            eng.release_request(r)
+
+    def test_public_reads_settle_and_say_why(self, engine, monitored):
+        settles0 = sif.counter("serving/settles")
+        ps = sif.prompts(engine.cfg.vocab_size)
+        sp = SamplingParams(max_new_tokens=8)
+        a, b = (engine.add_request(p, sp) for p in ps[:2])
+        for _ in range(3):
+            engine.step()
+        assert engine._requests[a].owed == 1
+        assert len(engine.request_output(a)) == len(ps[0]) + 2
+        assert engine._requests[a].owed == 0 and engine._flight is None
+        engine.step()
+        child = engine.fork_request(a, sp)
+        engine.step()
+        engine.settle()
+        engine.settle()                 # nothing in flight: counts nothing
+        assert sif.moved("serving/settles", settles0) == {
+            "why=export": 1, "why=fork": 1, "why=drain": 1}
+        for r in (a, b, child):
+            engine.release_request(r)
+        while engine.has_unfinished():
+            engine.step()
 
 
 def _parent_padded_table(k, seq_id, width):
@@ -1054,5 +1178,6 @@ class TestKeysRestOnTheHost:
         assert _is_host_key(adopted.key)
         np.testing.assert_array_equal(adopted.key, handoff["key"])
         other.step()
+        other.settle()              # the host's copy arrives a step late
         assert _is_host_key(adopted.key)
         assert (adopted.key != handoff["key"]).any()   # handoff not aliased
