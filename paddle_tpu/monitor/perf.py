@@ -702,17 +702,23 @@ def _host_phase_lines() -> list:
     ``serving/host_time{phase}`` histogram that ``monitor.trace.phase``
     feeds (unsynced host clock — the device runs underneath
     ``engine/sample_dispatch`` and is waited for in ``engine/readback``)."""
-    series = _registry().snapshot().get("serving/host_time") or {}
-    rows = sorted(((k.partition("=")[2], v) for k, v in series.items()
+    snap = _registry().snapshot()
+    series = snap.get("serving/host_time") or {}
+    cpu = snap.get("serving/host_cpu") or {}
+    rows = sorted(((k, v) for k, v in series.items()
                    if k and v.get("count")), key=lambda kv: -kv[1]["sum"])
     if not rows:
         return []
-    lines = ["serving host phases (serving/host_time, host clock, "
-             "not synced):",
-             f"  {'phase':28s} {'calls':>6s} {'mean_ms':>9s} {'total_s':>9s}"]
-    lines += [f"  {name[:28]:28s} {v['count']:6d} "
-              f"{1e3 * v['sum'] / v['count']:9.3f} {v['sum']:9.3f}"
-              for name, v in rows]
+    # cpu_s: the thread's own CPU time inside the phase; what total_s
+    # holds beyond it the thread waited (the GIL, its core, the runtime)
+    lines = ["serving host phases (serving/host_time and serving/host_cpu, "
+             "host clock, not synced):",
+             f"  {'phase':28s} {'calls':>6s} {'mean_ms':>9s} {'total_s':>9s} "
+             f"{'cpu_s':>9s}"]
+    lines += [f"  {key.partition('=')[2][:28]:28s} {v['count']:6d} "
+              f"{1e3 * v['sum'] / v['count']:9.3f} {v['sum']:9.3f} "
+              f"{cpu.get(key, 0.0):9.3f}"
+              for key, v in rows]
     return lines
 
 

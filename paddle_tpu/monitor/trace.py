@@ -23,7 +23,11 @@ parent so a trace renders as a tree.  Producers:
   (``distributed/rpc.py`` carries the header on every call);
 - ``with trace.phase("engine/schedule"):`` — one boundary of the serving
   loop, timed three ways at once: ALWAYS (under the PTPU_MONITOR gate)
-  into the ``serving/host_time{phase}`` histogram; whenever a profiler
+  into the ``serving/host_time{phase}`` histogram, and beside it the
+  thread's own CPU seconds (``time.thread_time``) into the
+  ``serving/host_cpu{phase}`` counter - what the first holds beyond the
+  second the thread spent NOT running: waiting for the GIL, descheduled,
+  or blocked in the runtime; whenever a profiler
   session is open, as a ``ptpu:<name>`` event in the session's xplane,
   on the device operations' clock (:func:`annotation` is the ONE place
   the package talks to jax's trace annotations — ``profiler.RecordEvent``
@@ -76,11 +80,14 @@ import threading
 import time
 from collections import OrderedDict
 
-from . import enabled as _monitor_enabled, histogram as _histogram
+from . import counter as _counter, histogram as _histogram
+
+_monitor = sys.modules[__package__]    # its `_enabled`: the PTPU_MONITOR gate
 
 __all__ = [
     "Span", "SpanContext", "span", "start_span", "current_span", "attach",
-    "shared_span", "phase", "annotation", "PHASE_METRIC", "PHASE_PREFIX",
+    "shared_span", "phase", "annotation", "PHASE_METRIC",
+    "PHASE_CPU_METRIC", "PHASE_PREFIX",
     "inject", "extract", "get_trace",
     "trace_ids", "chrome_events", "export_chrome_trace", "enabled",
     "enable", "refresh", "reset", "heartbeat", "last_activity_age",
@@ -561,6 +568,7 @@ def shared_span(name: str, **attrs):
 
 # -- phases: one boundary, three clocks -------------------------------------
 PHASE_METRIC = "serving/host_time"
+PHASE_CPU_METRIC = "serving/host_cpu"
 PHASE_PREFIX = "ptpu:"
 _annotation_cls = None
 _phase_series: dict = {}
@@ -581,7 +589,7 @@ def _profiler_annotation():
 
 def _session_open() -> bool:
     """Is a profiler session collecting annotations in this process?"""
-    cls = _profiler_annotation()
+    cls = _annotation_cls or _profiler_annotation()
     return cls is not None and cls.is_enabled()
 
 
@@ -595,7 +603,7 @@ def annotation(name: str):
 
 
 class _Phase:
-    __slots__ = ("_name", "_t0", "_ann", "_active")
+    __slots__ = ("_name", "_t0", "_c0", "_ann", "_active")
 
     def __init__(self, name):
         self._name = name
@@ -609,21 +617,31 @@ class _Phase:
             self._active = _Active(start_span(self._name, parent=_ctx.span))
             self._active.__enter__()
         self._t0 = time.perf_counter()
-        return self
+        self._c0 = time.thread_time()    # inside the wall interval: the
+        return self                      # CPU share of a phase is <= 1
 
     def __exit__(self, etype, evalue, tb):
+        cpu = time.thread_time() - self._c0
         dt = time.perf_counter() - self._t0
         if self._active is not None:
             self._active.__exit__(etype, evalue, tb)
         if self._ann is not None:
             self._ann.__exit__(etype, evalue, tb)
         series = _phase_series.get(self._name)
-        if series is None:       # PHASE_METRIC, spelt out for the lint
-            series = _phase_series[self._name] = _histogram(
-                "serving/host_time",
-                "host seconds in one phase of the serving loop").labels(
-                phase=self._name)
-        series.observe(dt)
+        if series is None:       # PHASE_METRIC and PHASE_CPU_METRIC, spelt
+            #                      out for the lint
+            series = _phase_series[self._name] = (
+                _histogram(
+                    "serving/host_time",
+                    "host seconds in one phase of the serving loop").labels(
+                    phase=self._name),
+                _counter(
+                    "serving/host_cpu",
+                    "seconds of the thread's own CPU time inside one phase "
+                    "of the serving loop (host_time's sum less this: the "
+                    "thread waited)").labels(phase=self._name))
+        series[0].observe(dt)
+        series[1].inc(cpu)
         return False
 
 
@@ -632,7 +650,7 @@ def phase(name: str):
     the three places the time goes, and `serving/engine.py` for the
     phase names).  With the monitor and tracing off and no profiler
     session open it is three flag reads and the no-op singleton."""
-    if not (_monitor_enabled() or _enabled or _session_open()):
+    if not (_enabled or _monitor._enabled or _session_open()):
         return _NULL
     return _Phase(name)
 

@@ -88,7 +88,9 @@ Monitor wiring (PR-1 StatRegistry): `serving/queue_depth`,
 `serving/block_utilization`, `serving/prefill_tokens`,
 `serving/decode_tokens`, `serving/prefill_tps`, `serving/decode_tps`,
 `serving/preemptions`, `serving/requests_finished`, plus
-`serving/step_time` histograms labeled by phase.  ISSUE-12 goodput and
+`serving/step_time` histograms labeled by phase (and beside them the
+step record, below: `serving/step_wait{phase}`, `serving/host_stalls`,
+`serving/host_stall_seconds`).  ISSUE-12 goodput and
 launch accounting: `serving/kernels_per_step` (distinct compiled
 programs one decode step dispatches — the mega-kernel before/after
 number, flat across batch compositions),
@@ -101,7 +103,6 @@ wall time, prefill/idle included).  ISSUE 15:
 caching, counted by the cache) and
 `serving/spec_proposed`/`spec_accepted`/`spec_accept_rate`
 (speculative decoding).  ISSUE 28: `serving/kv_blocks_in_use{group}`,
-`serving/kv_window_released` (blocks given back from behind a window),
 `serving/kv_tokens_live{group}` (keys the decode kernels have to read,
 summed over decode steps: a row's length, or `min(length, window)` in a
 window group), and what a form's layers count a step
@@ -190,10 +191,53 @@ phase, in this order, and the API pump adds two of its own around it:
     engine/retire           (k) retire_finished, _finish_request, the SLO
                             tick, the step's counters and gauges
 
+What a phase may wait on, beside the GIL (the pump's clients and the
+HTTP handlers are threads of this process), and whether it counts into
+the benchmark's `host_cpu_share.serve` - the six that should never wait
+do; their `serving/host_cpu{phase}` over `serving/host_time{phase}`:sum
+is the share of the phase the thread RAN:
+
+    phase                   counts  may wait on
+    api/drain_submits       no      its queue: a blocking get of up to
+                                    poll_s, by design; the device, once,
+                                    for a sampling request's key
+    api/push_progress       yes     nothing
+    engine/schedule         yes     nothing of its own (a settle it
+                                    decides, `preempt` or `release`, runs
+                                    its readback, emit and retire INSIDE
+                                    it, each under its own name too)
+    engine/prepare          yes     the runtime: its jitted calls return
+                                    before the device runs them, but a
+                                    call over the donated pools can block
+                                    for a buffer or the dispatch queue
+    engine/sample_dispatch  yes     the same, one call
+    engine/readback         no      the device, for step k: by design,
+                                    and `serving/step_wait` is this wait
+    engine/emit             yes     nothing
+    engine/retire           yes     nothing (the request log's write and
+                                    the SLO tick, where those are on)
+
 Each phase is counted once a program step: a call that dispatches onto an
 empty pipeline has no back half, and a call with nothing runnable (nobody
 waits, every running row owes its last token: `Scheduler.has_runnable`)
 reads the step in flight back without a scheduling pass.
+
+The step record (ISSUE 36).  With a step in flight a step costs the
+LONGER of two sides, the device's and the host's, and
+`serving/step_time{phase}` (readback to readback) is that maximum.  Where
+it is observed (`_observe_step`, once a program step, under the kind of
+the step read back) the engine observes beside it
+`serving/step_wait{phase=prefill|decode}`: the seconds the thread was
+blocked in `_to_host` for that step, 0 for a prefill chunk that sampled
+nothing - the device's lead over the host.  `step_time - step_wait` is the
+host's side of the step: emit and retire of the step before, the pump,
+schedule, prepare and sample_dispatch of the step after, and every
+microsecond between them that no phase names.  Wait near 0: the host sets
+the pace; wait a large share of the step: the device does.  A host side
+over `_HOST_STALL_S` (0.25 s) counts one `serving/host_stalls`, adds
+itself to `serving/host_stall_seconds` and leaves a `host_stall` note
+(phase, rows, host_s, wait_s) in the flight ring; a first call's compile
+is such a stall, and says so.  An idle call observes none of these.
 In steady state the device has work queued through every phase: while the
 host is in schedule, prepare and sample_dispatch step k runs, and when
 readback returns k+1 is already running.  The phases' sum is what the
@@ -206,13 +250,16 @@ samples the first token (`serving/device_calls{dir=h2d|d2h}`, counted by
 `_run` / `_to_host`; a call of `step()` makes the uploads of the step it
 dispatches and the readback of the one before).
 Gates: PTPU_MONITOR (default on) puts each duration into
-`serving/host_time{phase}`, nothing synced for it; an open profiler
+`serving/host_time{phase}` and the thread's own CPU seconds inside it
+(`time.thread_time`) into `serving/host_cpu{phase}`, nothing synced for
+either; an open profiler
 session gets a host event `ptpu:<phase>` on the device operations' clock
 (the programs are named for that view: prefill_<len>, ragged_decode,
 ragged_prefill_<c>, spec_verify, feed, sample); PTPU_TRACE=1 adds a
 `serving/step` span per `step()` call (`phase`, `rows`, the riders'
-`trace_ids` - of the step it DISPATCHES - and `state_slots` where the
-model has state groups), the phases its children, filed under every
+`trace_ids` - of the step it DISPATCHES - `state_slots` where the
+model has state groups, and `wait_ms` / `host_ms` of the step it READS
+BACK: what the step record counts), the phases its children, filed under every
 rider's trace; the readback, emit and retire inside it are of the step
 before.  A request's `serving/prefill` / `serving/decode_step` span runs
 from its step's dispatch to its tokens' emit.
@@ -275,6 +322,10 @@ from .spec import propose_ngram
 __all__ = ["EngineConfig", "LLMEngine"]
 
 _NEG_INF = -1e30
+# a program step whose host side (step_time - step_wait) is longer than
+# this is a stall (`serving/host_stalls`): fifteen times the longest host
+# side any benchmark cell has shown (17 ms); a constant, on purpose
+_HOST_STALL_S = 0.25
 
 
 def _sampler_path(ds, topk, topp):
@@ -537,6 +588,18 @@ class LLMEngine:
         self._m_expired = m.counter("serving/deadline_expired",
                                     "requests aborted past deadline_s")
         self._m_step = m.histogram("serving/step_time")
+        # ISSUE 36, the step record (module docstring)
+        self._m_step_wait = m.histogram(
+            "serving/step_wait",
+            "seconds the engine's thread was blocked reading a program "
+            "step back, by the step's kind (phase=prefill|decode)")
+        self._m_stalls = m.counter(
+            "serving/host_stalls",
+            "program steps whose host side (step_time - step_wait) was "
+            "over 0.25 s")
+        self._m_stall_s = m.counter(
+            "serving/host_stall_seconds",
+            "the host side of those steps, summed")
         self._m_ttft = m.histogram("serving/ttft",
                                    "arrival to first token, seconds")
         self._m_tpot = m.histogram("serving/tpot",
@@ -620,9 +683,6 @@ class LLMEngine:
                       "group"))
         self._m_group = [tuple(x.labels(group=g) for x in per_group)
                          for g in self.caches]
-        self._m_kv_released = m.counter(
-            "serving/kv_window_released",
-            "blocks given back from behind a sliding window")
         # ISSUE 32: per state group (slots held now, and summed over
         # decode steps; the pools' bytes, which never change)
         per_state = (
@@ -637,7 +697,6 @@ class LLMEngine:
             m.gauge("serving/state_bytes",
                     "bytes of a state group's pools").labels(
                 group=g).set(st.pool_bytes)
-        self._released_seen = 0
         calls = m.counter(
             "serving/device_calls",
             "host/device crossings of the engine (_run, _to_host): "
@@ -650,6 +709,7 @@ class LLMEngine:
         # speculation on every step is read back where it was dispatched
         self._flight: Optional[_Flight] = None
         self._retired: list = []     # what the step() under way returns
+        self._step_span = None       # its serving/step span (PTPU_TRACE=1)
         self._settle_each_step = self.spec_tokens > 0
         dispatched = m.counter(
             "serving/steps_dispatched",
@@ -1146,7 +1206,11 @@ class LLMEngine:
         faults.maybe_stall(site="engine.step")
         self._retired = []       # every `_finish` inside this call adds
         with mtrace.shared_span("serving/step") as step_span:
-            out = self._step_phases(t0, step_span)
+            self._step_span = step_span    # `_finish` notes its step there
+            try:
+                out = self._step_phases(t0, step_span)
+            finally:
+                self._step_span = None
         if mmem.enabled():
             self._memobs_step(out)
         return self._retired
@@ -1232,16 +1296,24 @@ class LLMEngine:
         emitted (nothing for None), finished requests retired, the clocks.
         Once a program step, so its phases are counted once a step too: a
         call of `step()` that dispatches onto an empty pipeline has none.
-        `serving/step_time{phase}` is observed here, once a program step,
-        under the kind of the step READ BACK, over the time from the
-        readback before it to its own: while the device is the longer
-        side, what a step of that kind costs it.  An idle step (`flight`
-        None, `idle_since` its start) is observed over the call."""
-        toks, t_ret = 0, None
+        `serving/step_time{phase}` and the rest of the step record
+        (`_observe_step`) are observed here and nowhere else, once a program
+        step - a settled step, a `prefill`-role replica's and a speculative
+        one each come through here once - under the kind of the step READ
+        BACK, over the time from the readback before it to its own: what a
+        step of that kind costs the longer of the two sides.  An idle step
+        (`flight` None, `idle_since` its start) is observed over the call
+        and leaves no record."""
+        toks = 0
         if flight is not None:
-            toks, t_ret = self._read_back(flight)
+            toks, t_ret, wait = self._read_back(flight)
+            dt = t_ret - flight.t_begin
             if self._flight is not None:
                 self._flight.t_begin = t_ret
+            if self._step_span:
+                self._step_span.attrs.update(
+                    wait_ms=round(wait * 1e3, 3),
+                    host_ms=round((dt - wait) * 1e3, 3))
         with mtrace.phase("engine/retire"):
             if mreqlog.enabled():
                 # peak-KV high-water per request: only worth the O(running)
@@ -1260,8 +1332,8 @@ class LLMEngine:
             #                      with tracing off (no span ends to beat)
             if monitor.enabled():
                 if flight is not None:
-                    self._observe_step(flight.kind, toks,
-                                       t_ret - flight.t_begin)
+                    self._observe_step(flight.kind, toks, dt, wait,
+                                       len(flight.rows))
                 elif idle_since is not None:
                     self._observe_step("idle", 0,
                                        time.perf_counter() - idle_since)
@@ -1270,9 +1342,11 @@ class LLMEngine:
 
     def _read_back(self, flight):
         """`flight`'s sampled tokens to the host and into their requests
-        -> (tokens to count for the step, when the readback returned)."""
+        -> (tokens to count for the step, when the readback returned, the
+        seconds the thread was blocked in it)."""
+        t_in = time.perf_counter()
         if flight.arrays is None:      # a prefill chunk that sampled nothing
-            return flight.tokens, time.perf_counter()
+            return flight.tokens, t_in, 0.0
         with mtrace.phase("engine/readback"):   # blocked on the device
             toks, keys, stats, greedy = self._to_host(flight.arrays)
             if stats is not None:
@@ -1287,11 +1361,26 @@ class LLMEngine:
                 emitted = self._emit(flight.rows, toks, keys, now)
             for sp in flight.spans:
                 sp.end()
-        return (flight.tokens if flight.kind == "prefill" else emitted), now
+        return ((flight.tokens if flight.kind == "prefill" else emitted),
+                now, now - t_in)
 
-    def _observe_step(self, phase, toks, dt) -> None:
-        """The counters and clocks of one step read back (monitor on)."""
+    def _observe_step(self, phase, toks, dt, wait=None, rows=0) -> None:
+        """The counters and clocks of one step read back (monitor on).
+        `wait` (a program step's; None for an idle one) is what the thread
+        was blocked in `_to_host` for it: the device's lead over the host.
+        `dt - wait` is the host's side of the step - emit and retire of
+        the step before, the pump, schedule, prepare and sample_dispatch
+        of the step after, and whatever lies between phases."""
         self._m_step.labels(phase=phase).observe(dt)
+        if wait is not None:
+            self._m_step_wait.labels(phase=phase).observe(wait)
+            host = dt - wait
+            if host > _HOST_STALL_S:
+                self._m_stalls.inc()
+                self._m_stall_s.inc(host)
+                monitor.flight.note("host_stall", phase=phase, rows=rows,
+                                    host_s=round(host, 6),
+                                    wait_s=round(wait, 6))
         # goodput: generated tokens over TOTAL engine wall time —
         # decode_tps reads a single step, this reads the serving
         # story (prefill, scheduling, idle steps all dilute it)
@@ -1322,9 +1411,6 @@ class LLMEngine:
         c = per[0]
         if len(per) > 1:
             c = {key: sum(p[key] for p in per) for key in c}
-            released = sum(k.released for k in self.caches.values())
-            self._m_kv_released.inc(released - self._released_seen)
-            self._released_seen = released
         for p, (in_use, _, _) in zip(per, self._m_group):
             in_use.set(p["in_use"])
         for st, (in_use, _) in zip(self.states.values(), self._m_state):

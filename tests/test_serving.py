@@ -955,8 +955,8 @@ class TestStepInFlightCounters:
             before = {k: v["count"] for k, v in monitor.snapshot().get(
                 "serving/step_time", {}).items()}
             eng.step()
-            after = {k: v["count"] for k, v in monitor.snapshot()[
-                "serving/step_time"].items()}
+            after = {k: v["count"] for k, v in monitor.snapshot().get(
+                "serving/step_time", {}).items()}
             read_back.append([k[len("phase="):] for k in after
                               if after[k] != before.get(k, 0)])
         # 3 prefills and 5 decode steps, then a call with nothing to
@@ -992,6 +992,48 @@ class TestStepInFlightCounters:
         assert sif.moved("serving/steps_dispatched", steps0) == {
             "in_flight=0": calls}
         assert sif.moved("serving/settles", settles0) == {"why=spec": calls}
+        for r in rids:
+            eng.release_request(r)
+
+    @pytest.mark.parametrize("mode", ["in_flight", "settled_each_call",
+                                      "speculation"])
+    def test_step_record_once_a_program_step(self, model, monitored, mode):
+        """ISSUE 36: `serving/step_wait{phase}` is observed where
+        `serving/step_time{phase}` is, so a step read back behind the
+        next dispatch, one settled by the caller after its call (what a
+        `prefill`-role replica does) and a speculative step (settled
+        where it is dispatched) each count once, a call that only reads
+        back dispatches nothing, and no wait is longer than its step."""
+        def hist(name):
+            return {k: (v["count"], v["sum"]) for k, v in
+                    monitor.snapshot().get(name, {}).items()}
+
+        spec = 2 if mode == "speculation" else 0
+        eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=3,
+                                            speculative_tokens=spec))
+        ps = sif.prompts(model.cfg.vocab_size)
+        rids = [eng.add_request(np.tile(p, 3),
+                                SamplingParams(max_new_tokens=6))
+                for p in ps[:2]]
+        steps0 = sum(sif.counter("serving/steps_dispatched").values())
+        time0, wait0 = hist("serving/step_time"), hist("serving/step_wait")
+        while eng.has_unfinished():
+            eng.step()
+            if mode == "settled_each_call":
+                eng.settle()
+        dispatched = sum(
+            sif.counter("serving/steps_dispatched").values()) - steps0
+        time1, wait1 = hist("serving/step_time"), hist("serving/step_wait")
+        assert "phase=idle" not in wait1
+        counted = 0
+        for kind in ("phase=prefill", "phase=decode"):
+            n, waited = (a - b for a, b in zip(
+                wait1[kind], wait0.get(kind, (0, 0.0))))
+            m, lasted = (a - b for a, b in zip(
+                time1[kind], time0.get(kind, (0, 0.0))))
+            assert n == m > 0 and 0 < waited <= lasted
+            counted += n
+        assert counted == dispatched
         for r in rids:
             eng.release_request(r)
 

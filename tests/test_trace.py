@@ -452,6 +452,123 @@ def test_phases_cover_the_step_and_nothing_twice(phase_run):
     assert named >= 0.7 * stepped, (by_phase, stepped)
 
 
+@pytest.mark.parametrize("name", ENGINE_PHASES)
+def test_engine_phase_cpu_beside_its_wall_time(phase_run, name):
+    """`serving/host_cpu{phase}` is the thread's own CPU time inside the
+    phase: there for every phase, and never more than the phase lasted
+    (the CPU clock is read inside the wall clock's interval)."""
+    _, snap = phase_run
+    cpu = snap[trace.PHASE_CPU_METRIC][f"phase={name}"]
+    wall = snap[trace.PHASE_METRIC][f"phase={name}"]
+    assert 0 <= cpu <= wall["sum"] + 1e-5 * wall["count"]
+
+
+def test_phase_cpu_counts_work_and_not_waiting():
+    """A phase that sleeps has wall time and no CPU time; one that spins
+    has both."""
+    with trace.phase("t/sleeps"):
+        time.sleep(0.05)
+    with trace.phase("t/spins"):
+        end = time.perf_counter() + 0.05
+        while time.perf_counter() < end:
+            pass
+    snap = monitor.snapshot()
+    wall, cpu = snap[trace.PHASE_METRIC], snap[trace.PHASE_CPU_METRIC]
+    assert wall["phase=t/sleeps"]["sum"] >= 0.05
+    assert cpu["phase=t/sleeps"] < 0.01
+    # a spin that other threads or the machine interrupt is still work
+    assert 0.01 < cpu["phase=t/spins"] <= wall["phase=t/spins"]["sum"]
+
+
+def test_step_record_once_a_program_step(phase_run):
+    """ISSUE 36: beside `serving/step_time{phase}` the engine observes
+    `serving/step_wait{phase}`, the part of the step its thread was
+    blocked in the readback - the same count, never more seconds, and no
+    stall in a sound run."""
+    _, snap = phase_run
+    steps, waits = snap["serving/step_time"], snap["serving/step_wait"]
+    assert {k: v["count"] for k, v in waits.items()} == {
+        k: v["count"] for k, v in steps.items()} == {
+        "phase=prefill": 2, "phase=decode": 3}
+    for kind, w in waits.items():
+        assert 0 < w["sum"] <= steps[kind]["sum"]
+    # the wait is the readback phase's own duration (its clock reads
+    # lie just outside the phase's)
+    readback = snap[trace.PHASE_METRIC]["phase=engine/readback"]
+    waited = sum(w["sum"] for w in waits.values())
+    assert readback["sum"] <= waited <= readback["sum"] + 1e-3
+    # (a counter nobody has touched is not in the snapshot at all)
+    assert snap.get("serving/host_stalls", 0) == 0
+    assert snap.get("serving/host_stall_seconds", 0) == 0
+
+
+def test_step_span_carries_its_wait_and_host_side(model, eng):
+    """With tracing on, the `serving/step` span of a call that reads a
+    step back says how long it waited for it and what the host's side of
+    that step was; the call that read nothing back says neither."""
+    (rid, _), _ = _run_two(model, eng)
+    spans = [s for s in eng.request_trace(rid)
+             if s["name"] == "serving/step"]
+    first, rest = spans[0], spans[1:]
+    assert "wait_ms" not in first["attrs"] and "host_ms" not in first["attrs"]
+    assert len(rest) == 3
+    for s in rest:
+        assert s["attrs"]["wait_ms"] >= 0 and s["attrs"]["host_ms"] >= 0
+        readback, = [k for k in eng.request_trace(rid)
+                     if k["parent_id"] == s["span_id"]
+                     and k["name"] == "engine/readback"]
+        assert s["attrs"]["wait_ms"] >= readback["dur_us"] / 1e3 - 1e-3
+
+
+def test_injected_stall_is_the_hosts_and_no_phase_sees_it(model, eng):
+    """A stall between two phases of ONE call of step() (the fault plan's
+    `stall@site=engine.step` sleeps before the first phase opens): the
+    step record counts one host stall of its length, `step_time -
+    step_wait` holds it, no phase's `host_time` does, and the flight ring
+    names the step."""
+    from paddle_tpu.serving import SamplingParams
+
+    trace.enable(False)
+    rids = [eng.add_request(_prompt(model, 40 + i),
+                            SamplingParams(max_new_tokens=6))
+            for i in range(2)]
+    for _ in range(3):
+        eng.step()                     # both prefilled, a decode in flight
+    flight.get_recorder().clear()
+    before = monitor.snapshot()
+    faults.set_plan(faults.FaultPlan("stall@site=engine.step,secs=0.4"))
+    try:
+        eng.step()                     # the plan fires once
+        while eng.has_unfinished():
+            eng.step()
+    finally:
+        faults.set_plan(None)
+        for rid in rids:
+            eng.release_request(rid)
+    after = monitor.snapshot()
+
+    def gained(name, label=None, part=None):
+        def value(snap):
+            v = snap.get(name, 0)
+            v = v.get(label, 0) if label else v
+            return v[part] if part and v else v
+        return value(after) - value(before)
+
+    assert gained("serving/host_stalls") == 1
+    assert gained("serving/host_stall_seconds") >= 0.4
+    host_side = sum(
+        gained("serving/step_time", f"phase={k}", "sum")
+        - gained("serving/step_wait", f"phase={k}", "sum")
+        for k in ("prefill", "decode"))
+    assert host_side >= 0.4
+    for name in ENGINE_PHASES:
+        assert gained(trace.PHASE_METRIC, f"phase={name}", "sum") < 0.2, name
+    note, = [r for r in flight.get_recorder().records()
+             if r.get("event") == "host_stall"]
+    assert note["phase"] == "decode" and note["rows"] == 2
+    assert note["host_s"] >= 0.4 and 0 <= note["wait_s"] < 0.2
+
+
 def test_request_trace_shows_the_steps_it_rode(model, eng):
     """With tracing on, every engine step is ONE `serving/step` span filed
     under each rider's trace as a child of its root; the step's phases
