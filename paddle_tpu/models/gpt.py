@@ -363,6 +363,18 @@ def _stacked_mlp(p, h, eps):
     return h + m @ p["fc_out_w"] + p["fc_out_b"]
 
 
+def _split_heads(qkv, nh, hd):
+    """[B, S, 3*nh*hd] QKV product -> q, k, v, each [B, S, nh, hd]: column
+    `i*H + n*hd + d` is q/k/v `i`, head `n`, lane `d`.  The RESULT is
+    split, never reshaped to [.., 3, nh, hd]: XLA pushes that reshape
+    into the product's weight, and a stacked `qkv_w[l]` is then sliced
+    out and transposed as a copy in every layer of every step (a
+    quarter of a serving step's device time; PERF.md, PR 37)."""
+    mb, s, _ = qkv.shape
+    return tuple(t.reshape(mb, s, nh, hd)
+                 for t in jnp.split(qkv, 3, axis=-1))
+
+
 def _stacked_block_body(p, h, attn_fn, nh, hd, eps):
     """One pre-LN transformer block over a stacked-weight slice `p`.
     attn_fn: (q, k, v) [B,S,nh,hd] -> (o, extra); `extra` threads cache
@@ -371,8 +383,8 @@ def _stacked_block_body(p, h, attn_fn, nh, hd, eps):
     .forward_cached."""
     mb, s, H = h.shape
     hn = _stacked_ln(h, p["ln1_w"], p["ln1_b"], eps)
-    qkv = (hn @ p["qkv_w"] + p["qkv_b"]).reshape(mb, s, 3, nh, hd)
-    o, extra = attn_fn(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
+    q, k, v = _split_heads(hn @ p["qkv_w"] + p["qkv_b"], nh, hd)
+    o, extra = attn_fn(q, k, v)
     h = h + o.reshape(mb, s, H) @ p["out_w"] + p["out_b"]
     return _stacked_mlp(p, h, eps), extra
 

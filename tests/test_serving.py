@@ -68,6 +68,31 @@ def _dense_all(model, prompts, kw_fn):
     return outs
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("nh", [2, 4])
+def test_block_splits_the_qkv_product_in_the_column_order(nh, dtype):
+    """The block splits the `[B, S, 3H]` product's RESULT (so that no
+    program re-lays the stacked weight: tests/test_tpu_aot_layout.py); the
+    heads it hands to attention are, bit for bit, those of the
+    `reshape(B, S, 3, nh, hd)[:, :, i]` the weights' columns were laid
+    out for."""
+    from paddle_tpu.models.gpt import _split_heads
+
+    mb, s, hd = 2, 5, 8
+    H = nh * hd
+    rng = np.random.RandomState(nh)
+    hn, w, b = (jnp.asarray(rng.standard_normal(shape), dtype)
+                for shape in [(mb, s, H), (H, 3 * H), (3 * H,)])
+    qkv = hn @ w + b
+    want = qkv.reshape(mb, s, 3, nh, hd)
+    got = _split_heads(qkv, nh, hd)
+    assert len(got) == 3
+    for i, t in enumerate(got):
+        assert t.shape == (mb, s, nh, hd) and t.dtype == qkv.dtype
+        np.testing.assert_array_equal(np.asarray(t, np.float32),
+                                      np.asarray(want[:, :, i], np.float32))
+
+
 class TestDenseParity:
     def test_greedy_mixed_length_batch(self, model, prompts, engine):
         dense = _dense_all(model, prompts, lambda i: {})

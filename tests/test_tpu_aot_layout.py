@@ -4,7 +4,10 @@ benchmark configurations' pool shapes, and the optimised HLO may hold no
 operation that rewrites a whole pool.  A pool kept as ``[nb, bs, H, D]``
 was relaid (`reshape` in, `copy` out: two tilings of the same bytes) around
 every ragged kernel call, two fifths of a decode step (PERF.md, PR 27); no
-CPU test could see it.
+CPU test could see it.  The same holds for the GPT block's stacked weights:
+a layer's `qkv_w` was written out and transposed in every layer of every
+serving step until the block split the QKV product's result instead of
+reshaping it (PR 37).
 
 `test_kernel_compiles_for_the_chip` holds the tree to a rule: a kernel
 that does not compile for the chip does not live in the tree.  Interpret
@@ -81,6 +84,33 @@ def _pool_sized(hlo_text, elems):
                 found[op] = found.get(op, 0) + 1
                 break
     return found
+
+
+def _unfused(hlo_text):
+    """`hlo_text` less the bodies of the computations a fusion calls: what
+    is left are the instructions that write their result to memory.  A
+    layer's slice of a stacked weight INSIDE a product's fusion is a view
+    (it is how `fc_in_w[l]` has always been read); the same slice as an
+    instruction of its own is a copy of the layer's weight."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo_text))
+    kept, inside = [], False
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            inside = head.group(1) in fused
+        if not inside:
+            kept.append(line)
+    return "\n".join(kept)
+
+
+# what may yield an array of a weight's size: the argument itself, and the
+# compiler's own asynchronous prefetch of it into the chip's fast memory
+# (`S(1)`; `slice-start` of a layer or `copy-start` of a small stack, the
+# pieces joined by the custom call `ConcatBitcast`), which moves a weight
+# once, as it lies, beside the compute
+_IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "tuple",
+             "slice-start", "slice-done", "copy-start", "copy-done",
+             "custom-call"}
 
 
 @pytest.mark.parametrize("name", sorted(SHAPES))
@@ -222,29 +252,38 @@ def test_flash_forward_compiles_at_the_afmoe_shapes(S, monkeypatch, seq,
     assert f"bf16[1,{seq},{a['hq']},{a['d']}]" in text
 
 
-def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
-                                                         monkeypatch):
-    """The seam (ISSUE 28) costs the GPT cells nothing: the engine's decode
-    and prefill programs of a 2-layer GPT at the 1.3B widths compile, for
-    the described v5e, to the operation list recorded from the parent
-    commit (tests/fixtures/gpt_engine_ops_pr27.json)."""
-    import collections
-    import json
-
+def _gpt_engine_programs(one_chip, monkeypatch, name, prefill_len):
+    """The engine's decode program and one prefill program of a 2-layer
+    GPT at `name`'s widths and rows, compiled for the described v5e
+    -> {program: compiled}.  The weights are shapes from the start (at
+    the 6.7B widths two layers and the embedding are 2.4 GB of float32
+    zeros under `LazyGuard`)."""
     from paddle_tpu.framework.compat import LazyGuard
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.nn import layer as nn_layer
     from paddle_tpu.ops import pallas_ops as po
     from paddle_tpu.serving import EngineConfig, LLMEngine
 
+    rows, nh, _ = SHAPES[name]
     monkeypatch.setattr(rp, "_on_tpu", lambda: True)
     monkeypatch.setattr(po, "_on_tpu", lambda: True)
-    with LazyGuard():
+
+    class ShapesOnly:
+        def __getattr__(self, attr):
+            return getattr(jnp, attr)
+
+        def zeros(self, shape, dtype=None):
+            return jax.ShapeDtypeStruct(tuple(shape), jnp.bfloat16)
+
+    with monkeypatch.context() as m, LazyGuard():
+        m.setattr(nn_layer, "jnp", ShapesOnly())
         model = GPTForCausalLM(GPTConfig(
-            vocab_size=50304, hidden_size=2048, num_hidden_layers=2,
-            num_attention_heads=16, intermediate_size=8192,
+            vocab_size=50304, hidden_size=nh * D, num_hidden_layers=2,
+            num_attention_heads=nh, intermediate_size=4 * nh * D,
             max_position_embeddings=2048, stacked_blocks=True))
-    model.to(dtype="bfloat16")
-    eng = LLMEngine(model, EngineConfig(block_size=16, max_num_seqs=16))
+    for layer in model.sublayers(include_self=True):
+        layer._dtype = jnp.dtype(jnp.bfloat16)
+    eng = LLMEngine(model, EngineConfig(block_size=BS, max_num_seqs=rows))
 
     def on_chip(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -252,33 +291,90 @@ def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    def ops(compiled):
-        found = collections.Counter()
-        for line in compiled.as_text().splitlines():
-            m = re.match(
-                r"^\s*(?:ROOT )?%[\w.\-]+ = .*?[\]\})] ([\w\-]+)\(", line)
-            if m:
-                found[m.group(1)] += 1
-        return dict(found)
-
     params = jax.tree_util.tree_map(on_chip, eng._param_arrays())
     kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
-    decode = eng._get_ragged_exec(16, 1).lower(
-        params, kv, i32(16, 1), i32(16), i32(16),
-        (i32(16, eng.blocks_per_seq),), (i32(16, 1),)).compile()
+    return {
+        "jit_ragged_decode": eng._get_ragged_exec(rows, 1).lower(
+            params, kv, i32(rows, 1), i32(rows), i32(rows),
+            (i32(rows, eng.blocks_per_seq),), (i32(rows, 1),)).compile(),
+        f"jit_prefill_{prefill_len}": eng._get_prefill_exec(
+            prefill_len).lower(params, kv, i32(1, prefill_len),
+                               (i32(1, prefill_len),)).compile()}
+
+
+def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
+                                                         monkeypatch):
+    """The engine's decode and prefill programs of a 2-layer GPT at the
+    1.3B widths compile, for the described v5e, to a recorded operation
+    list (tests/fixtures/gpt_engine_ops_pr27.json): a change that is not
+    meant to touch the GPT cells' programs leaves it as it is (the seam
+    of ISSUE 28 did).  Recorded from PR 27's tree and again from PR 37's,
+    whose QKV product reads `qkv_w[l]` in place
+    (`test_gpt_engine_programs_read_the_stacked_weights_in_place`).
+    What that moved, PR 27 -> PR 37, decode / prefill_512: `copy` 10 -> 4
+    / 12 -> 14 (the two transposing copies of a layer's weight gone; the
+    prefill copies its `[1, 512, 6144]` result into q, k and v),
+    `fusion` 60 -> 61 / 90 -> 89, `bitcast` 29 -> 22 / 66 -> 61,
+    `reshape` 10 -> 4 (decode), `copy-start` and `copy-done` 21 -> 7 /
+    6 -> 5, `slice-start` and `slice-done` 4 -> 2 (decode), `custom-call`
+    6 -> 5 (decode: a `ConcatBitcast`), `get-tuple-element` 40 -> 38 / 44
+    -> 38, `tuple` 17 -> 16 / 18 -> 17, `parameter` 221 -> 222 / 288 ->
+    287; every arithmetic opcode and `slice` are as they were."""
+    import json
+
+    _, nh, nb = SHAPES["gpt3-1.3b"]
+    programs = _gpt_engine_programs(one_chip, monkeypatch, "gpt3-1.3b", 512)
     # the program around the kernel moves no pool (PR 27), with the tile
     # as without it: each layer's K and V pool enters one kernel call
-    pools = _pool_sized(decode.as_text(), kv[0].size)
+    pools = _pool_sized(programs["jit_ragged_decode"].as_text(),
+                        nb * BS * nh * D)
     assert pools.pop("custom-call") == 2, pools
     assert set(pools) <= {"parameter", "get-tuple-element", "bitcast",
                           "tuple"}, pools
-    got = {
-        "jit_ragged_decode": ops(decode),
-        "jit_prefill_512": ops(eng._get_prefill_exec(512).lower(
-            params, kv, i32(1, 512), (i32(1, 512),)).compile())}
+    got = {k: _program_ops(c) for k, c in programs.items()}
     with open(os.path.join(os.path.dirname(__file__), "fixtures",
                            "gpt_engine_ops_pr27.json")) as f:
         assert got == json.load(f)
+
+
+# a prefill length of the cell whose activations have no weight's element
+# count (the MLP's `[512, 8192]` at 1.3B and `[1024, 16384]` at 6.7B have
+# `out_w[l]`'s)
+_PREFILL = {"gpt3-1.3b": 256, "gpt3-6.7b": 768}
+
+
+@pytest.mark.parametrize("name", sorted(SHAPES))
+def test_gpt_engine_programs_read_the_stacked_weights_in_place(
+        one_chip, monkeypatch, name):
+    """No serving program re-lays a stacked weight.  In the engine's decode
+    program and a prefill program, at both GPT cells' widths and rows,
+    nothing outside a fusion's body yields an array of the element count
+    of a layer's `qkv_w`, `out_w`, `fc_in_w` or `fc_out_w`, or of a whole
+    stack of them, but the arguments and the compiler's prefetch of them
+    (`_IN_PLACE`): each product's fusion reads its `w[l]` as a view.
+
+    `qkv_w` was the exception until PR 37.  The block reshaped the QKV
+    product's result to `[.., 3, nh, hd]`; XLA pushed that into the weight,
+    and the program wrote every layer's `[H, 3H]` slice out as `[3H, H]`
+    (`slice_bitcast_fusion`, a `fusion` here) and transposed it once more
+    (`copy`) before the product read it: 24-27% of both GPT cells' device
+    time (ledger, PR 36).  With the parent's expression in
+    `models/gpt.py::_stacked_block_body` this test fails on those two."""
+    _, nh, nb = SHAPES[name]
+    H = nh * D
+    programs = _gpt_engine_programs(one_chip, monkeypatch, name,
+                                    _PREFILL[name])
+    for program, compiled in programs.items():
+        # at the 6.7B widths a K/V pool has `fc_in_w[l]`'s element count
+        text = _unfused(compiled.as_text()).replace(
+            f"bf16[{nb},{BS},{H}]", "bf16[pool]")
+        for weight, per_layer in [("qkv_w", 3 * H * H), ("out_w", H * H),
+                                  ("fc_in_w, fc_out_w", 4 * H * H)]:
+            for what, elems in [("layer", per_layer),
+                                ("stack", 2 * per_layer)]:
+                found = _pool_sized(text, elems)
+                assert set(found) <= _IN_PLACE, (program, weight, what,
+                                                 found)
 
 
 def _program_ops(compiled):
