@@ -18,6 +18,7 @@ from .afmoe import AfmoeConfig, AfmoeForCausalLM, afmoe_test_config
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_test_config
 from .mistral4 import (Mistral4Config, Mistral4ForCausalLM,
                        mistral4_test_config)
+from .brumby import BrumbyConfig, BrumbyForCausalLM, brumby_test_config
 from .serving_form import LatentSpec, LayerSpec, ServingForm, StateSpec
 from .bert import BertConfig, BertModel, BertForSequenceClassification, bert_base_config
 
@@ -28,6 +29,7 @@ __all__ = [
     "AfmoeConfig", "AfmoeForCausalLM", "afmoe_test_config",
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_test_config",
     "Mistral4Config", "Mistral4ForCausalLM", "mistral4_test_config",
+    "BrumbyConfig", "BrumbyForCausalLM", "brumby_test_config",
     "LatentSpec", "LayerSpec", "ServingForm", "StateSpec",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "bert_base_config",

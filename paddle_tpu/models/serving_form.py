@@ -17,7 +17,10 @@ group (`serving.kv_cache.StateCache`) and hands the layer step its rows.
 `AfmoeForCausalLM.serving_form()` (models/afmoe.py) the second,
 `Lfm2MoeForCausalLM.serving_form()` (models/lfm2.py), the first with
 state layers, the third, `Mistral4ForCausalLM.serving_form()`
-(models/mistral4.py), the first with latent layers, the fourth.
+(models/mistral4.py), the first with latent layers, the fourth,
+`BrumbyForCausalLM.serving_form()` (models/brumby.py), the first with
+NO attention layer at all - every layer a `StateSpec`, its state updated
+in place in its slot - the fifth.
 
 A `LatentSpec` names an attention layer whose cache keeps ONE row a token,
 a latent from which every head's key and value are made (multi-head latent
@@ -92,6 +95,13 @@ class StateSpec:
     group: str                   # state group: layers of one group share
     #                              a shape, a dtype and a slot a sequence
     dtype: Optional[object] = None   # None: the form's `dtype`
+    # False: the layer's step takes the rows' states, gathered, and
+    # returns the new ones, which the engine scatters back (a state of
+    # kilobytes).  True: it takes the layer's POOL and the rows' slot
+    # indices and returns the pool, updated in those slots alone: a state
+    # of megabytes is then moved once in and once out, by the layer's own
+    # kernel, and no program holds a gathered copy of it
+    in_place: bool = False
 
 
 class ServingForm:
@@ -127,7 +137,14 @@ class ServingForm:
         (y, new state)` is the layer's own, called once with the rows'
         states as they stood before this chunk (zeros at a sequence's
         start), and what it returns as the new state is kept for the next
-        one.  For a `LatentSpec` layer it passes `latent_fn(rows [B,S,
+        one.  For a `StateSpec` with `in_place` the step is `step(pool
+        [slots + 1, *shape], rows [B] int32, fresh) -> (y, pool)`: the
+        layer's pool as it is, the rows' slot indices (padding rows of a
+        decode batch name the last slot, which no sequence holds) and
+        whether the rows start from no history (a whole-prompt prefill:
+        the slots' contents are then not to be read); it hands back the
+        pool, updated in the rows' slots.  For a `LatentSpec` layer it
+        passes `latent_fn(rows [B,S,
         key_dim], whole, stored) -> (o, extra)`: `rows` are the chunk's
         latents, which the engine stores; `whole(attend) -> o` is called
         in a whole-prompt prefill with `attend(q, k, v [B,S,H,D]) -> [B,S,

@@ -25,8 +25,16 @@ fixed size a sequence names a `StateSpec` instead, and the engine keeps a
 program takes one slot-index array a state group, a layer's state pool
 travels among the donated pools in layer order, and the layer's step is
 handed the rows' states and hands back the new ones (lfm2_moe's gated
-short convolutions, models/lfm2.py).  A model with no such layer builds
-no state group and its programs are what they were.  A layer of latent
+short convolutions, models/lfm2.py); a spec that asks for it
+(`StateSpec.in_place`: a state of megabytes) is handed the pool itself and
+the rows' slot indices instead, and updates the rows' slots in it, so no
+program gathers or scatters the rows' states (brumby's power retention,
+models/brumby.py).  A model with no such layer builds
+no state group and its programs are what they were.  A model whose EVERY
+layer names a `StateSpec` has no K/V group at all: `self.cache` is None,
+the programs take no table and no slot array, and admission, preemption
+and release go by state slots alone (`CacheGroups` over the state groups;
+a sequence never grows, so none is ever evicted for room).  A layer of latent
 attention names a `LatentSpec`: its cache group keeps ONE pool a layer
 (`BlockKVCache(value_in_key=True)`), and what the layer attends in a
 whole-prompt prefill - keys and values it expands from the chunk's own
@@ -491,10 +499,9 @@ class LLMEngine:
             layers = kind.setdefault(spec.group, [])
             self._layer_slot.append(len(layers))
             layers.append(spec)
-        if not self._groups:
+        if not self._groups and not state_groups:
             raise ValueError(
-                f"{type(model).__name__}'s serving form names no attention "
-                "layer: the engine schedules by K/V blocks")
+                f"{type(model).__name__}'s serving form names no layer")
         shared = sorted(set(self._groups) & set(state_groups))
         if shared:
             raise ValueError(
@@ -518,7 +525,9 @@ class LLMEngine:
         on = {"kv_cache_dtype": self._kv_quant,
               "speculative_tokens": self.spec_tokens,
               "enable_prefix_caching": self.prefix_caching}
-        for option in form.unsupported:
+        # with no K/V group there is no block to share, quantise or roll
+        # back, whatever the form says
+        for option in (form.unsupported if self._groups else on):
             if on.get(option):
                 raise ValueError(
                     f"EngineConfig.{option}={on[option]!r} is not carried "
@@ -532,8 +541,9 @@ class LLMEngine:
                 len(layers), sizes[name], c.block_size, heads, lanes,
                 dtype=wdtype, kv_quant=self._kv_quant,
                 window=layers[0].window, name=name, value_in_key=pools == 1)
-        # the first group's cache: the only one of a one-group model
-        self.cache = next(iter(self.caches.values()))
+        # the first group's cache: the only one of a one-group model, None
+        # for a model with no attention layer
+        self.cache = next(iter(self.caches.values()), None)
         # a slot a running sequence, and the dropped slot of padding rows
         self.states = {}
         for name, layers in state_groups.items():
@@ -546,8 +556,8 @@ class LLMEngine:
         # what the scheduler allocates from: all groups or none
         self.kv = (self.cache if len(self.caches) == 1 and not self.states
                    else CacheGroups({**self.caches, **self.states}))
-        num_blocks = self.cache.num_blocks
-        if monitor.enabled():
+        if monitor.enabled() and self.cache is not None:
+            num_blocks = self.cache.num_blocks
             monitor.gauge("lowbit/kv_blocks",
                           "paged KV pool size in blocks").labels(
                 dtype=self._kv_quant or str(wdtype)).set(num_blocks)
@@ -1315,7 +1325,7 @@ class LLMEngine:
                     wait_ms=round(wait * 1e3, 3),
                     host_ms=round((dt - wait) * 1e3, 3))
         with mtrace.phase("engine/retire"):
-            if mreqlog.enabled():
+            if mreqlog.enabled() and self.cache is not None:
                 # peak-KV high-water per request: only worth the O(running)
                 # walk when someone is collecting the wide events
                 for r in self.scheduler.running:
@@ -1407,14 +1417,16 @@ class LLMEngine:
         # ISSUE 20: every capacity gauge reads the cache's ONE
         # counts() source — utilization and the admission view
         # (free+parked) can no longer be computed in two places
+        for st, (in_use, _) in zip(self.states.values(), self._m_state):
+            in_use.set(st.slots_in_use)
         per = [k.counts() for k in self.caches.values()]
+        if not per:              # no K/V group: the block gauges stay 0
+            return
         c = per[0]
         if len(per) > 1:
             c = {key: sum(p[key] for p in per) for key in c}
         for p, (in_use, _, _) in zip(per, self._m_group):
             in_use.set(p["in_use"])
-        for st, (in_use, _) in zip(self.states.values(), self._m_state):
-            in_use.set(st.slots_in_use)
         self._m_blocks.set(c["in_use"])
         self._m_util.set(c["in_use"] / max(c["total"], 1))
         self._m_kv_free.set(c["free"])
@@ -1428,8 +1440,11 @@ class LLMEngine:
         storm/swap-thrash detector, and the interval-limited /kv pool-
         map publication.  Everything here is host-side dict walking —
         the sequence is charged in bench.py --config trace_overhead
-        and must stay inside the <5%-enabled budget."""
+        and must stay inside the <5%-enabled budget.  It reads K/V blocks:
+        a model without a K/V group has nothing for it."""
         cache = self.cache
+        if cache is None:
+            return
         c = cache.counts()
         # (b) timeline: compiled-program HBM peak (perf capture; None
         # with perf off), live KV-pool bytes, host RSS (TTL-cached)
@@ -1480,7 +1495,7 @@ class LLMEngine:
         """Write one rate-limited, replica-tagged ``kv_pressure``
         flight dump naming the ranked pool holders, and refresh the
         published /kv map so the endpoint agrees with the forensics."""
-        if not mmem.enabled():
+        if not mmem.enabled() or self.cache is None:
             return None
         requests = list(self._requests.values())
         mmem.publish_kv(mmem.build_kv_snapshot(self.cache, requests))
@@ -2263,8 +2278,8 @@ class LLMEngine:
             at += width
             if isinstance(spec, StateSpec):
                 attn_fn = self._state_fn(
-                    layer_kv[0], srows[state_groups.index(spec.group)],
-                    fresh)
+                    spec, layer_kv[0],
+                    srows[state_groups.index(spec.group)], fresh)
             else:
                 attn_fn = attn_builder(spec, groups.index(spec.group),
                                        *layer_kv)
@@ -2276,11 +2291,19 @@ class LLMEngine:
         return h, tuple(outs), stats
 
     @staticmethod
-    def _state_fn(pool, rows, fresh):
+    def _state_fn(spec, pool, rows, fresh):
         """What a state layer's step calls (`ServingForm.layer`): the
         rows' states out of the layer's `pool` (zeros when `fresh`), the
         layer's own `step` over them, and the states it returns written
-        back to the same slots - the dropped slot for padding rows."""
+        back to the same slots - the dropped slot for padding rows.  A
+        spec that keeps its state `in_place` gets the pool and the slot
+        indices themselves and returns the pool: nothing is gathered."""
+        if spec.in_place:
+            def pool_fn(step):
+                y, new = step(pool, rows, fresh)
+                return y, (new,)
+            return pool_fn
+
         def state_fn(step):
             prev = (jnp.zeros((rows.shape[0],) + pool.shape[1:], pool.dtype)
                     if fresh else pool[rows])

@@ -760,6 +760,7 @@ class StateCache:
     rows and is never handed out."""
 
     pool_names = ("state",)
+    block_size = None              # a slot is not made of blocks
 
     def __init__(self, num_layers, num_slots, shape, dtype, name="state"):
         self.name = name
@@ -792,6 +793,15 @@ class StateCache:
     def num_free_blocks(self) -> int:
         """Free SLOTS: what this group can still admit."""
         return len(self._free)
+
+    # what the scheduler's messages read of a model that has state groups
+    # alone: its unit of memory is a slot
+    @property
+    def num_blocks(self) -> int:
+        return self.num_slots
+
+    def blocks_needed(self, num_tokens) -> int:
+        return 1
 
     def slot_of(self, seq_id) -> int:
         return self._tables[seq_id]
@@ -871,12 +881,18 @@ class CacheGroups:
     scheduler and the engine make.  Every group has its own id space, pool
     shape and table; a sequence holds a table in each K/V group and a slot
     in each state group, and each call reaches all groups or none (the
-    checks run over every group before any group is touched)."""
+    checks run over every group before any group is touched).  A model
+    with no attention layer has state groups alone: it is admitted, kept
+    and freed by slots, `block_size` is None and what counts blocks counts
+    slots (`StateCache.num_blocks`)."""
 
     def __init__(self, groups: dict):
         self.groups = dict(groups)   # name -> BlockKVCache or StateCache
         self._all = list(self.groups.values())
-        self._kv = [c for c in self._all if isinstance(c, BlockKVCache)]
+        # what is counted in blocks: the K/V groups, or with none the
+        # state groups, in slots
+        self._kv = [c for c in self._all
+                    if isinstance(c, BlockKVCache)] or self._all
         self.first = self._kv[0]
         self.block_size = self.first.block_size
         if any(c.block_size != self.block_size for c in self._kv):

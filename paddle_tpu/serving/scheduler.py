@@ -467,7 +467,8 @@ class Scheduler:
         # a prefix-hit request's chunk starts at the first uncached
         # token, so a hot request admits its real remaining work instead
         # of being under-batched by its (already-paid) cached prefix.
-        hit_tokens = hit_blocks * self.cache.block_size
+        # (a model with state groups alone has no block: nothing to hit)
+        hit_tokens = hit_blocks * self.cache.block_size if hit_blocks else 0
         chunk = min(req.prompt_len - start - hit_tokens,
                     self.max_num_batched_tokens)
         target = start + hit_tokens + chunk

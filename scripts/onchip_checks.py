@@ -4,6 +4,7 @@
     python scripts/onchip_checks.py --aot     # no chip: compile only
     chiprun -- python scripts/onchip_checks.py --writes [--tree DIR]
     chiprun -- python scripts/onchip_checks.py --sampler
+    chiprun -- python scripts/onchip_checks.py --retention
 
 Every Pallas kernel on the main path goes through actual Mosaic
 compilation and is compared with its XLA reference at the widths of both
@@ -64,15 +65,26 @@ reference's main path alone and against the least over its branches, the
 first sequence under each deliberate fault as well; then one
 `greedy_margins` row at the cell's 17,408 positions, timed.
 
+`--retention` runs both power-retention kernels (`ops/power_retention.py`)
+at the Brumby cell's calls - decode: 24 rows x 40-over-8 heads of 128 over
+a `[25, 8, 65, 136, 128]` float32 pool; prefill: one row of 1,024 positions
+from a zero state and 1,024 more from the state that left - against the
+same mathematics in XLA at the highest matmul precision (outputs and the
+states written), then ms a call of each with the pool donated, as the
+engine calls them, and the GB/s of published state a decode call moves.  With `--aot`
+it compiles the same calls and stops.
+
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
 stops there.  That catches Mosaic refusals from a CPU-only sandbox; it
 says nothing about numerics or DMA semaphore balance, which only the
 chip run does.
 """
+import functools
 import os
 import sys
 import threading
+import time
 
 sys.path.insert(0, os.path.abspath(sys.argv[sys.argv.index("--tree") + 1])
                 if "--tree" in sys.argv else
@@ -89,6 +101,7 @@ LATENT_ONLY = "--latent" in sys.argv[1:]
 SPLIT = "--split" in sys.argv[1:]
 SAMPLER_ONLY = "--sampler" in sys.argv[1:]
 TIES_ONLY = "--ties" in sys.argv[1:]
+RETENTION_ONLY = "--retention" in sys.argv[1:]
 AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
 
@@ -970,6 +983,105 @@ def check_sampler(cell):
     print(f"OK sampler_{cell}", flush=True)
 
 
+def check_retention(rows=24, hq=40, hkv=8, d=128, prompt=1024):
+    """Both retention kernels at the Brumby cell's calls against the XLA
+    form, then what a call of each costs."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import power_retention as pr
+
+    rng = np.random.RandomState(0)
+    bf = jnp.bfloat16
+    shape = pr.state_shape(hkv, d)
+
+    def unit(x):        # a head as the per-head RMSNorm leaves it
+        return (x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True))).astype(bf)
+
+    def inputs(b, t):
+        q, k = (unit(_randn(rng, (b, t, h, d), jnp.float32))
+                for h in (hq, hkv))
+        v = _randn(rng, (b, t, hkv, d), bf)
+        log_g = jnp.log(jnp.asarray(rng.uniform(0.2, 0.999, (b, t, hkv)),
+                                    jnp.float32))
+        return q, k, v, log_g
+
+    slots = jnp.asarray(rng.permutation(rows), jnp.int32)
+    one = slots[:1]
+
+    def prefill_kernel(fresh):
+        return lambda *a: pr.retention_prefill(*a, one, fresh)
+
+    def prefill_xla(fresh):
+        def ref(q, k, v, log_g, pool):
+            o, new = pr.retention_chunked(
+                q, k, v, log_g, None if fresh else pool[one], chunk=64)
+            return o.astype(bf), pool.at[one].set(new)
+        return ref
+
+    def decode_kernel(q, k, v, log_g, pool, fast=True):
+        return pr.retention_decode(q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+                                   pool, slots, fast=fast)
+
+    def decode_xla(q, k, v, log_g, pool):
+        o, new = pr._decode_math(q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+                                 pool[slots])
+        return o, pool.at[slots].set(new)
+
+    if AOT:
+        pool = np.zeros((rows + 1,) + shape, np.float32)
+        for fresh in (True, False):
+            _compile_only(prefill_kernel(fresh),
+                          inputs(1, prompt) + (pool,))
+        _compile_only(decode_kernel, inputs(rows, 1) + (pool,))
+        print("OK retention kernels compile", flush=True)
+        return
+    pool = jnp.zeros((rows + 1,) + shape, jnp.float32)
+    first, second = inputs(1, prompt), inputs(1, prompt)
+    with jax.default_matmul_precision("highest"):
+        # the kernel's MXU operands are bfloat16: one output in millions,
+        # where a denominator is small, strays past 5e-2
+        _check("retention_prefill_fresh", prefill_kernel(True),
+               prefill_xla(True), first + (pool,), tol=1e-1)
+        _, pool = jax.jit(prefill_xla(True))(*first, pool)
+        _check("retention_prefill_carried", prefill_kernel(False),
+               prefill_xla(False), second + (pool,), tol=1e-1)
+        # every slot a state of its own, as far as a prefill leaves one
+        _, seeded = jax.jit(prefill_xla(False))(*second, pool)
+        pool = jnp.broadcast_to(seeded[one], (rows + 1,) + shape) \
+            * jnp.linspace(0.5, 1.5, rows + 1)[:, None, None, None, None]
+        step = inputs(rows, 1)
+        _check("retention_decode", decode_kernel, decode_xla,
+               step + (pool,), tol=2e-2)
+    def ms_a_call(fn, args, pool, reps=10):
+        """Wall time of `fn(*args, pool) -> (_, pool)`, the pool donated
+        and handed on as in the engine (a call that keeps its pool pays
+        XLA's copy of all of it first: 0.9 GB here)."""
+        fn = jax.jit(fn, donate_argnums=(len(args),))
+        _, pool = fn(*args, pool)
+        jax.block_until_ready(pool)
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out, pool = fn(*args, pool)
+        jax.block_until_ready((out, pool))
+        return (time.perf_counter() - t0) / reps * 1e3, pool
+
+    err = float(jnp.abs(jax.jit(decode_kernel)(*step, pool)[0]
+                        - jax.jit(decode_xla)(*step, pool)[0]).max())
+    print(f"retention_decode: largest error of an output {err:.5f}")
+    ms, pool = ms_a_call(prefill_kernel(True), first, pool)
+    flop = 2 * pr.published_state_numbers(hkv, d) * (hq + hkv) / hkv * prompt
+    print(f"retention_prefill {prompt} positions: {ms:.3f} ms a call "
+          f"({flop / ms / 1e9:.1f} TFLOP/s through the published state)")
+    moved = 2 * rows * pr.published_state_numbers(hkv, d) * 4
+    for fast, what in ((True, "3 bfloat16 products"),
+                       (False, "float32 at the highest precision")):
+        fn = functools.partial(decode_kernel, fast=fast)
+        ms, pool = ms_a_call(fn, step, pool)
+        print(f"retention_decode {rows} rows, readout in {what}: {ms:.3f} "
+              f"ms a call ({moved / ms / 1e6:.1f} GB/s of published state)",
+              flush=True)
+
+
 def check_generate():
     """`generate()` with the flash-decode kernel forced on: the one
     integration check of the kernel-inside-generate routing."""
@@ -1030,6 +1142,8 @@ def main():
     if not AOT and not AFMOE_ONLY:
         checks.append(("check_generate", check_generate, ()))
     if not AFMOE_ONLY:
+        checks.append(("check_retention", check_retention, ()))
+    if not AFMOE_ONLY:
         checks.append(("check_writes", check_writes, ()))
     if WRITES_ONLY:
         checks = [c for c in checks if c[1] is check_writes]
@@ -1044,11 +1158,13 @@ def main():
                   for c in SAMPLER_SHAPES]
     if TIES_ONLY:
         checks = [("check_ties", check_ties, ())]
+    if RETENTION_ONLY:
+        checks = [("check_retention", check_retention, ())]
     for name, fn, args in checks:
         with _Watchdog(name, 900.0 if fn in (check_writes, check_afmoe_engine,
                                              check_ragged_cell,
-                                             check_latent_cell,
-                                             check_ties) else 240.0):
+                                             check_latent_cell, check_ties,
+                                             check_retention) else 240.0):
             fn(*args)
     print("ALL AOT COMPILES OK" if AOT else "ALL ONCHIP CHECKS OK")
 

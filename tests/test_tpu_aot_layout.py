@@ -29,6 +29,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import pallas_ops as po
+from paddle_tpu.ops import power_retention as pr
 from paddle_tpu.ops import ragged_paged_attention as rp
 from paddle_tpu.ops.paged_attention import (latent_cache_update_arrays,
                                             paged_cache_update_arrays,
@@ -502,6 +503,71 @@ def test_state_group_decode_program_donates_pools_and_state(one_chip,
                            "copy-done"}, states
 
 
+def test_all_state_decode_program_updates_each_state_in_its_slot(
+        one_chip, monkeypatch):
+    """The decode program of an engine with NO K/V group (brumby at the
+    published widths, 2 layers, 24 rows over 25 slots of `[8, 65, 136,
+    128]` float32: 36.2 MB a slot a layer) compiles for the described
+    v5e: it takes no table and no slot array of a K/V pool; both state
+    pools go in donated and come out aliased; each enters ONE kernel call
+    and nothing else of its size is computed - no copy, no relayout - and
+    no array of the ROWS' states (`[24, 8, 65, 136, 128]`) exists at all:
+    nothing is gathered out of a pool or scattered into one."""
+    from paddle_tpu.framework.compat import LazyGuard
+    from paddle_tpu.models import BrumbyConfig, BrumbyForCausalLM
+    from paddle_tpu.nn import layer as nn_layer
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+    from paddle_tpu.serving import kv_cache
+
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)
+    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
+    po.reset_attention_path_counts()
+
+    class ShapesOnly:       # 3.1 GB of weights and 1.8 GB of pools as shapes
+        def __init__(self, dtype=None):
+            self.dtype = dtype
+
+        def __getattr__(self, attr):
+            return getattr(jnp, attr)
+
+        def zeros(self, shape, dtype=None):
+            return jax.ShapeDtypeStruct(tuple(shape),
+                                        jnp.dtype(self.dtype or dtype))
+
+    rows = 24
+    with monkeypatch.context() as m, LazyGuard():
+        m.setattr(nn_layer, "jnp", ShapesOnly(jnp.bfloat16))
+        m.setattr(kv_cache, "jnp", ShapesOnly())
+        model = BrumbyForCausalLM(BrumbyConfig(num_hidden_layers=2))
+        for layer in model.sublayers(include_self=True):
+            layer._dtype = jnp.dtype(jnp.bfloat16)
+        eng = LLMEngine(model, EngineConfig(
+            block_size=64, max_num_seqs=rows, max_model_len=10240))
+    assert eng.caches == {} and list(eng.states) == ["retention"]
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
+    assert [(p.shape, p.dtype) for p in kv] == [
+        ((rows + 1, 8, 65, 136, 128), jnp.float32)] * 2
+    compiled = eng._get_ragged_exec(rows, 1).lower(
+        jax.tree_util.tree_map(on_chip, eng._param_arrays()), kv,
+        i32(rows, 1), i32(rows), i32(rows), (), (), (i32(rows),)).compile()
+    assert po.attention_path_counts() == {"retention_decode_kernel": 2}
+    text = compiled.as_text()
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == len(kv), header[:400]
+    pools = _pool_sized(text, kv[0].size)
+    assert pools.pop("custom-call") == 2, pools    # a call a layer
+    assert set(pools) <= {"parameter", "get-tuple-element", "bitcast",
+                          "tuple"}, pools
+    assert _pool_sized(text, kv[0].size // (rows + 1) * rows) == {}
+
+
 # -- PR 30: every kernel that stays compiles for the chip ---------------------
 
 def _flash(**kw):
@@ -527,6 +593,21 @@ def _latent(q, new, pool, tables, pos0, lens, slots):
     return rp.ragged_latent_attention_arrays(
         q, new, pool, tables, pos0, lens, slots, value_dim=256,
         scale=0.25)
+
+
+def _retention_decode(q, k, v, log_g, pool, slots):
+    return pr.retention_decode(q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], pool,
+                               slots)
+
+
+def _retention_prefill(fresh):
+    return lambda *a: pr.retention_prefill(*a, fresh)
+
+
+def _retention_args(b, t, rows=24, hq=40, hkv=8, d=128):
+    return [((b, t, hq, d), jnp.bfloat16), ((b, t, hkv, d), jnp.bfloat16),
+            ((b, t, hkv, d), jnp.bfloat16), ((b, t, hkv), jnp.float32),
+            ((rows + 1,) + pr.state_shape(hkv, d), jnp.float32)]
 
 
 def _qkv(b, sq, h, d, sk=None):
@@ -654,6 +735,16 @@ KERNEL_CASES = [
           1, _LATENTS),
     _case("flash_fwd_prefill_h32_s16384", _flash(is_causal=True),
           _qkv(1, 16384, 32, 128), 1),
+    # brumby-14b-l6.longgen-c24's calls (PR 38): 24 rows of 40 query heads
+    # over 8 K/V heads of 128 against the float32 state pool `[25, 8, 65,
+    # 136, 128]`, one position a row; and a prompt's 1,024 positions from
+    # a zero state and from the state in the slot
+    _case("retention_decode_brumby", _retention_decode,
+          _retention_args(24, 1) + [((24,), jnp.int32)], 1),
+    _case("retention_prefill_fresh_brumby", _retention_prefill(True),
+          _retention_args(1, 1024) + [((1,), jnp.int32)], 1),
+    _case("retention_prefill_carried_brumby", _retention_prefill(False),
+          _retention_args(1, 1024) + [((1,), jnp.int32)], 1),
 ]
 
 
