@@ -768,9 +768,37 @@ def ragged_paged_attention_arrays(q, k_new, v_new, k_blocks, v_blocks,
 # its key and (the leading lanes) as its value
 # ---------------------------------------------------------------------------
 
-# tokens of latents one step of the latent stream consumes: 256 rows of 384
-# lanes are 192 KB, the size at which `_head_stream`'s tile sits
-_LATENT_TILE_TOKENS = 256
+# tokens of latents one step of the latent stream consumes: 1,024 rows of
+# 384 lanes are 768 KB, 16 copies of a 64-row block (PR 39, my chip runs
+# A-B: at 256 rows a tile's products cost 0.42 us a 256 rows, at 1,024 0.19)
+_LATENT_TILE_TOKENS = 1024
+# tiles of the latent stream a program holds at once: tile t + RING - 1's
+# copies are in flight while tile t's products run
+_LATENT_RING = 3
+
+
+def _ring_dma_loop(num_t, copies, step, carry, depth):
+    """carry = step(slot, t, carry) for t in [0, num_t), each tile's DMAs
+    started `depth - 1` tiles AHEAD of its products, across loop
+    iterations: the copies of tile t + depth - 1 (into the buffer tile
+    t - 1 left) start at the top of iteration t, before `step` waits on
+    tile t.  Every tile is started once and waited on once, so no copy is
+    in flight when the program ends.  `slot` is traced here (t % depth),
+    unlike `_two_block_dma_loop`'s."""
+    from jax.experimental import pallas as pl
+
+    def start(t):
+        for c in copies(t % depth, t):
+            c.start()
+
+    for t in range(depth - 1):
+        pl.when(t < num_t)(functools.partial(start, t))
+
+    def body(t, carry):
+        pl.when(t + depth - 1 < num_t)(lambda: start(t + depth - 1))
+        return step(t % depth, t, carry)
+
+    return jax.lax.fori_loop(0, num_t, body, carry)
 
 
 def _latent_kernel_ok(q, pool, c, value_dim) -> bool:
@@ -813,6 +841,9 @@ def _latent_kernel(len_ref, slot_ref, tbl_ref, q_ref, rn_ref, p_hbm, o_ref,
        the stream starts from and position `length - 1` is masked in what
        is streamed; entries of the last tile past the row's last block
        fetch that last block again, every position of theirs masked.
+       The tiles go through a ring of `buf.shape[0]` buffers
+       (`_ring_dma_loop`): a tile's copies are in flight while the
+       products of the tiles before it run.
 
     q_ref/o_ref hold the query heads as rows, padded with zero rows to
     whole sublane tiles (HP)."""
@@ -884,7 +915,7 @@ def _latent_kernel(len_ref, slot_ref, tbl_ref, q_ref, rn_ref, p_hbm, o_ref,
         jnp.where(has_new, jnp.broadcast_to(new_row(slice(0, dv)),
                                             (hp, dv)), 0.0))
     n_tiles = (num_kb + tile - 1) // tile
-    _, l, acc = _two_block_dma_loop(n_tiles, copies, step, state0)
+    _, l, acc = _ring_dma_loop(n_tiles, copies, step, state0, buf.shape[0])
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -914,8 +945,8 @@ def _latent_kernel_call(q, row_new, pool, block_table, kv_lens, slots,
         out_specs=[pl.BlockSpec((1, hp, value_dim),
                                 lambda r, *pre: (r, 0, 0)), hbm],
         scratch_shapes=[
-            pltpu.VMEM((2, 1, t_rows, lanes), pool.dtype),   # two tiles
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((_LATENT_RING, 1, t_rows, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((_LATENT_RING,)),
             pltpu.VMEM((1, bs, lanes), pool.dtype),          # target block
             pltpu.SemaphoreType.DMA((1,)),
         ],

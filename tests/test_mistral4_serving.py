@@ -630,23 +630,45 @@ def _latent_case(rng, dt, b, h, dk, dv, bs, nb, lens, maxb):
     return q, new, pool, tbl, np.maximum(lens - 1, 0), lens, slots
 
 
-@pytest.mark.parametrize("dt,h,dk,dv,bs,tol", [
-    (jnp.float32, 32, 320, 256, 64, 2e-5),
-    (jnp.bfloat16, 32, 320, 256, 64, 2e-2),
-    (jnp.float32, 4, 192, 128, 16, 2e-5),
-    (jnp.bfloat16, 12, 144, 128, 32, 2e-2)],
-    ids=["mistral4-f32", "mistral4-bf16", "h4-bs16", "h12-bs32"])
+_M4 = (jnp.float32, 32, 320, 256, 64, 2e-5)        # the cell's geometry
+_T = rp._LATENT_TILE_TOKENS     # a tile of the stream, in rows
+
+
+@pytest.mark.parametrize("dt,h,dk,dv,bs,tol,lens,maxb,drop", [
+    (jnp.float32, 32, 320, 256, 64, 2e-5, None, 12, ()),
+    (jnp.bfloat16, 32, 320, 256, 64, 2e-2, None, 12, ()),
+    (jnp.float32, 4, 192, 128, 16, 2e-5, None, 12, ()),
+    (jnp.bfloat16, 12, 144, 128, 32, 2e-2, None, 12, ()),
+    # the stream's edges at the cell's geometry; the rows streamed are a
+    # row's length less its new one
+    _M4 + ([0, 1, 2], 4, ()),
+    _M4 + ([_T + 1, _T + 2, 2 * _T + 1, 2 * _T + 2], 2 * _T // 64 + 1, ()),
+    _M4 + ([4 * _T + 1, 5 * _T + 1, 3 * _T - 40], 5 * _T // 64 + 1, ()),
+    _M4 + ([2560, 2559, 2496], 40, ()),           # the table's full maxb
+    _M4 + ([5, 300, 0, 70], 8, (0, 1)),           # slots out of range
+    _M4 + ([1, 3 * _T + 200, 3, _T - 100, 0, 64], 3 * _T // 64 + 4, ())],
+    ids=["mistral4-f32", "mistral4-bf16", "h4-bs16", "h12-bs32",
+         "len-0-1-2", "tile-edge", "ring-remainder", "full-table",
+         "padding-slot", "ragged-mix"])
 def test_latent_kernel_against_the_fallback(_interpret_mode, dt, h, dk, dv,
-                                            bs, tol):
+                                            bs, tol, lens, maxb, drop):
     """The published geometry (32 heads over a 256 + 64 row in 384 lanes,
-    blocks of 64, tiles of 4 blocks) and two others: rows of 1 token, a
-    part block, several tiles and an odd tile count, an empty (padding)
-    row.  The pool the kernel hands back is the fallback's bit for bit;
-    the outputs agree to the online softmax's reordering."""
+    blocks of 64) and two others: rows of 1 token, a part block, an empty
+    (padding) row; then the stream's edges at the published geometry: rows
+    of 0-2 tokens, streams that end on a tile's edge and one row past it,
+    4 and 5 tiles in a ring of 3, a row that fills its table, rows whose
+    slot lies out of range (their block untouched) and rows of very
+    different lengths in one call.  The pool the kernel hands back is the
+    fallback's bit for bit; the outputs agree to the online softmax's
+    reordering."""
     rng = np.random.default_rng(5)
-    lens = [1, bs + 1, 9 * bs + 3, 0, 4 * bs, 5 * bs + 7]
-    args = _latent_case(rng, dt, 6, h, dk, dv, bs, 40, lens, 12)
+    lens = lens or [1, bs + 1, 9 * bs + 3, 0, 4 * bs, 5 * bs + 7]
+    b = len(lens)
+    nb = max(40, b * maxb)
+    args = _latent_case(rng, dt, b, h, dk, dv, bs, nb, lens, maxb)
     q, new, pool, tbl, pos0, lens, slots = args
+    for i, bad in zip(drop, (-1, nb * bs + 7)):
+        slots[i, 0] = bad
     out, pool2 = rp.ragged_latent_attention_arrays(
         q, new, pool, tbl, pos0, lens, slots, dv, 0.09)
     assert po.attention_path_counts() == {
@@ -655,7 +677,13 @@ def test_latent_kernel_against_the_fallback(_interpret_mode, dt, h, dk, dv,
     want = latent_paged_attention_arrays(q, want_pool, tbl, pos0, dv, 0.09)
     np.testing.assert_array_equal(np.asarray(pool2, np.float32),
                                   np.asarray(want_pool, np.float32))
-    assert out.shape == (6, 1, h, dv)
+    # every row but the slots written is the pool handed in
+    kept = np.ones(nb * bs, bool)
+    kept[slots[(slots >= 0) & (slots < nb * bs)]] = False
+    np.testing.assert_array_equal(
+        np.asarray(pool2, np.float32).reshape(nb * bs, -1)[kept],
+        np.asarray(pool, np.float32).reshape(nb * bs, -1)[kept])
+    assert out.shape == (b, 1, h, dv)
     real = lens > 0
     np.testing.assert_allclose(np.asarray(out, np.float32)[real],
                                np.asarray(want, np.float32)[real],
