@@ -39,7 +39,10 @@ under PTPU_ATTN_DEBUG=1 as `dsa_prefill_kernel` / `dsa_decode_kernel` and
   tiles, each tile's scores read back and turned into the mask.  The
   attention does the dense FLOPs under a mask (a token-level selection
   leaves nearly every tile with a selected key); the mask is never
-  recomputed, so it is the selection exactly.
+  recomputed, so it is the selection exactly.  Its products go by K/V
+  head: a head's G query heads are the rows of ONE operand `[G*tq, d]`,
+  so each K and V tile feeds the MXU once a K/V head, and the mask is an
+  additive bias made once a tile and shared by the G members.
 - `sparse_decode_attention` (rows against a paged pool, one query a row):
   `dsa_decode_scores` streams ONLY a row's live blocks of the index pool
   through its block table (a tile of `_DECODE_TILE` tokens a step, a ring
@@ -70,7 +73,7 @@ _NEG = -1e30                  # a masked score in the flash state
 _SUPER_ROWS = 2048            # query rows a prefill's scores are held for
 _PREFILL_TQ = 256             # query rows of a score tile
 _FLASH_TQ = 128               # query rows of a flash tile
-_TK = 512                     # keys of a prefill tile (scores and flash)
+_TK = 1024                    # keys of a prefill tile (scores and flash)
 _DECODE_TILE = 1024           # index keys a decode step's tile holds
 _DECODE_RING = 3              # tiles of the decode stream held at once
 _SELECT_ROWS = 8              # rows a selection program holds
@@ -369,17 +372,30 @@ def _prefill_scores_call(row0, qi_t, w, ki, interpret):
 
 
 def _prefill_flash_kernel(row0_ref, q_ref, k_ref, v_ref, s_ref, sel_ref,
-                          o_ref, m_ref, l_ref, acc_ref, *, heads, kv_heads,
-                          d, scale):
+                          o_ref, qg_ref, m_ref, l_ref, acc_ref, *, d, scale):
+    """One program a (query tile i, key tile j).  A K/V head's G query
+    heads are stacked as the rows of one operand, `qg_ref[g]` [G*tq, d]
+    (head g*G + u at rows u*tq..), scaled once when the query tile
+    arrives, so each K and V tile feeds the MXU once a K/V head.  The
+    selection is applied once a tile, as an additive bias (0 or _NEG)
+    broadcast over the G members: a row whose tiles so far hold no
+    selected key sums junk under m = _NEG, which alpha = exp(_NEG - m)
+    = 0 clears when its first selected key arrives."""
     from jax.experimental import pallas as pl
 
     i, j = pl.program_id(0), pl.program_id(1)
     tq, tk = s_ref.shape
+    kv_heads, rows, _ = qg_ref.shape
+    group = rows // tq
     diag = _diag(row0_ref[0], i, tq, tk)
-    group = heads // kv_heads
 
     @pl.when(j == 0)
     def _init():
+        for h in range(kv_heads * group):
+            g, u = divmod(h, group)
+            qg_ref[g, u * tq:(u + 1) * tq, :] = (
+                q_ref[:, h * d:(h + 1) * d].astype(jnp.float32) * scale
+            ).astype(qg_ref.dtype)
         m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -391,26 +407,27 @@ def _prefill_flash_kernel(row0_ref, q_ref, k_ref, v_ref, s_ref, sel_ref,
         kpos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
         chosen = selected(sort_keys(s_ref[...]), kpos, kpos <= qpos,
                           sel_ref[:, 0:1], sel_ref[:, 1:2])
-        for h in range(heads):
-            g = h // group
-            kg = k_ref[:, g * d:(g + 1) * d]
-            sc = _dot_f32(q_ref[:, h * d:(h + 1) * d], kg,
-                          transpose_b=True) * scale
-            sc = jnp.where(chosen, sc, _NEG)
-            m_prev = m_ref[h]                                  # [tq, 128]
+        bias = jnp.where(chosen, 0.0, _NEG)                    # [tq, tk]
+        for g in range(kv_heads):
+            sc = _dot_f32(qg_ref[g], k_ref[:, g * d:(g + 1) * d],
+                          transpose_b=True)                    # [G*tq, tk]
+            sc = (sc.reshape(group, tq, tk) + bias).reshape(rows, tk)
+            m_prev = m_ref[g]                                  # [G*tq, 128]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-            p = jnp.where(chosen, jnp.exp(sc - m_new[:, 0:1]), 0.0)
+            p = jnp.exp(sc - m_new[:, 0:1])
             alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha[:, 0:1] + _dot_f32(
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha[:, 0:1] + _dot_f32(
                 p.astype(v_ref.dtype), v_ref[:, g * d:(g + 1) * d])
-            m_ref[h] = m_new
+            m_ref[g] = m_new
 
     @pl.when(j == diag)
     def _out():
-        for h in range(heads):
+        for h in range(kv_heads * group):
+            g, u = divmod(h, group)
+            at = slice(u * tq, (u + 1) * tq)
             o_ref[:, h * d:(h + 1) * d] = (
-                acc_ref[h] / jnp.maximum(l_ref[h][:, 0:1], 1e-30)
+                acc_ref[g, at] / jnp.maximum(l_ref[g, at][:, 0:1], 1e-30)
             ).astype(o_ref.dtype)
 
 
@@ -424,6 +441,7 @@ def _prefill_flash_call(row0, q, k, v, scores, sel, heads, kv_heads, d,
     r = q.shape[0]
     t = k.shape[0]
     tq, tk = _FLASH_TQ, _TK
+    rows = heads // kv_heads * tq
 
     def kmap(i, j, row0):
         return jnp.minimum(j, _diag(row0[0], i, tq, tk)), 0
@@ -435,8 +453,7 @@ def _prefill_flash_call(row0, q, k, v, scores, sel, heads, kv_heads, d,
         return i, 0
 
     return pl.pallas_call(
-        functools.partial(_prefill_flash_kernel, heads=heads,
-                          kv_heads=kv_heads, d=d, scale=scale),
+        functools.partial(_prefill_flash_kernel, d=d, scale=scale),
         name="dsa_prefill_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -447,9 +464,10 @@ def _prefill_flash_call(row0, q, k, v, scores, sel, heads, kv_heads, d,
                       pl.BlockSpec((tq, tk), smap),
                       pl.BlockSpec((tq, 128), rowmap)],
             out_specs=pl.BlockSpec((tq, heads * d), rowmap),
-            scratch_shapes=[pltpu.VMEM((heads, tq, 128), jnp.float32),
-                            pltpu.VMEM((heads, tq, 128), jnp.float32),
-                            pltpu.VMEM((heads, tq, d), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((kv_heads, rows, d), q.dtype),
+                            pltpu.VMEM((kv_heads, rows, 128), jnp.float32),
+                            pltpu.VMEM((kv_heads, rows, 128), jnp.float32),
+                            pltpu.VMEM((kv_heads, rows, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((r, heads * d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

@@ -81,7 +81,11 @@ K/V pools and a `[8192, 64, 128]` index pool, contexts of 16k-66k;
 prefill: one prompt of 4,096 against the XLA form, then 16,384 / 32,768 /
 65,536 timed - with ms a call of each piece, GB/s or TFLOP/s of the
 published work, and `lax.top_k` at k = 2,048 timed beside the selection
-kernel.  With `--aot` it compiles the decode and the 16,384 prefill.
+kernel; the flash alone at the last super-block of 65,536 rows prints its
+ms, the TFLOP/s of the dense causal products it computes and their share
+of the v5e's 197.  `--sparse --prefill` runs the prefill half alone;
+`--sparse --split` times that flash again at other tile sizes.  With
+`--aot` it compiles the decode and the 16,384 prefill.
 
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
@@ -112,6 +116,7 @@ SAMPLER_ONLY = "--sampler" in sys.argv[1:]
 TIES_ONLY = "--ties" in sys.argv[1:]
 RETENTION_ONLY = "--retention" in sys.argv[1:]
 SPARSE_ONLY = "--sparse" in sys.argv[1:]
+PREFILL_ONLY = "--prefill" in sys.argv[1:]
 AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
 
@@ -1094,6 +1099,7 @@ def check_retention(rows=24, hq=40, hkv=8, d=128, prompt=1024):
 
 SPARSE = dict(rows=12, heads=32, kv_heads=4, d=128, index_heads=16,
               index_dim=64, topk=2048, bs=64, blocks=8192, max_len=67584)
+V5E_TFLOPS = 197.0         # bf16 peak of one TPU v5e (Google Cloud docs)
 
 
 def _ms(fn, args, reps=5):
@@ -1162,7 +1168,7 @@ def check_sparse():
     if AOT:
         _compile_only(with_kernels(True), dec)
         print("OK sparse decode compiles", flush=True)
-    else:
+    elif not PREFILL_ONLY:
         _check("dsa_decode", with_kernels(True), with_kernels(False), dec,
                tol=5e-2)
         live = int(np.asarray(lens).sum())
@@ -1262,13 +1268,27 @@ def check_sparse():
             ms = _ms(sel_fn, (scores, lens_), reps=3)
             print(f"  dsa_select of those rows: {ms:.2f} ms", flush=True)
             sel = sel_fn(scores, lens_)
-            fl = jax.jit(lambda *a: sa._prefill_flash_call(
-                *a, h, g, d, scale, False))
-            ms = _ms(fl, (row0, args[0][0, -rows:].reshape(rows, h * d),
-                          args[1][0].reshape(t, g * d),
-                          args[2][0].reshape(t, g * d), scores, sel), reps=3)
-            print(f"  dsa_prefill_attention of those rows: {ms:.2f} ms",
-                  flush=True)
+            fl_args = (row0, args[0][0, -rows:].reshape(rows, h * d),
+                       args[1][0].reshape(t, g * d),
+                       args[2][0].reshape(t, g * d), scores, sel)
+            # the dense causal products the flash computes: every row
+            # against every key up to its own, two products of 2 x d
+            dense = float((np.arange(t - rows, t) + 1).sum()) * h * d * 4
+            tiles = [(sa._FLASH_TQ, sa._TK)]
+            if SPLIT:
+                # 256 x 1024 asks more VMEM than the 48 MB the call has
+                tiles += [x for x in ((128, 512), (256, 512), (128, 1024),
+                                      (128, 2048)) if x != tiles[0]]
+            for tq, tk in tiles:
+                sa._FLASH_TQ, sa._TK = tq, tk
+                ms = _ms(jax.jit(lambda *a: sa._prefill_flash_call(
+                    *a, h, g, d, scale, False)), fl_args, reps=3)
+                tflops = dense / ms / 1e9
+                print(f"  dsa_prefill_attention of those rows, tiles {tq} x "
+                      f"{tk}: {ms:.2f} ms ({tflops:.1f} TFLOP/s dense, "
+                      f"{tflops / V5E_TFLOPS:.1%} of {V5E_TFLOPS:.0f})",
+                      flush=True)
+            sa._FLASH_TQ, sa._TK = tiles[0]
         del args
     s = jnp.asarray(rng.randn(512, 65536), jnp.float32)
     ms = _ms(jax.jit(lambda s_: jax.lax.top_k(s_, k)), (s,), reps=2)

@@ -374,22 +374,73 @@ def _normal(rng, shape, dt=jnp.bfloat16):
     return jnp.asarray(rng.standard_normal(shape), jnp.float32).astype(dt)
 
 
-@pytest.mark.parametrize("t", [700, 2048 + 515], ids=["one-block", "two"])
-def test_prefill_kernels_equal_the_xla_form(_interpret_mode, t):
+def _prefill_xla_by_kv_head(args, k, scale):
+    """`_prefill_xla` over one K/V head and its query heads at a time (the
+    heads are independent): a [G, T, T] score array at a time."""
+    q, kk, vv, qi, ki, w = (x[0] for x in args)
+    group = q.shape[1] // kk.shape[1]
+    return jnp.concatenate([sa._prefill_xla(
+        q[:, i * group:(i + 1) * group], kk[:, i:i + 1], vv[:, i:i + 1], qi,
+        ki, w, k, scale) for i in range(kk.shape[1])], axis=1)[None]
+
+
+@pytest.mark.parametrize("t,h,g", [(700, 4, 2), (2048 + 515, 4, 2),
+                                   (2700, 32, 4)],
+                         ids=["one-block", "two", "cell-heads"])
+def test_prefill_kernels_equal_the_xla_form(_interpret_mode, t, h, g):
     """Scores by tiles to the diagonal, the selection, flash over streamed
     K/V masked by it: the XLA form's output to bfloat16's rounding, over
-    one and two super-blocks of rows (the prompt padded to whole tiles)."""
+    one and several super-blocks of rows (the prompt padded to whole
+    tiles), at two query heads a K/V head and at the cell's 32 over 4."""
     rng = np.random.default_rng(1)
-    h, g, d, hi, di, k = 4, 2, 128, 4, 64, 48
+    d, hi, di, k = 128, 4, 64, 48
     args = (_normal(rng, (1, t, h, d)), _normal(rng, (1, t, g, d)),
             _normal(rng, (1, t, g, d)), _normal(rng, (1, t, hi, di)),
             _normal(rng, (1, t, di)),
             jnp.asarray(rng.standard_normal((1, t, hi)), jnp.float32))
     got = sa.sparse_prefill_attention(*args, k, d ** -0.5)
     assert po.attention_path_counts() == {"dsa_prefill_kernel": 1}
-    want = sa._prefill_xla(*(x[0] for x in args), k, d ** -0.5)[None]
+    want = _prefill_xla_by_kv_head(args, k, d ** -0.5)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=3e-2)
+
+
+def test_prefill_rows_whose_earlier_tiles_hold_no_selected_key(
+        _interpret_mode):
+    """Index scores that grow with the key's position (index key s reads
+    256 * (s // 256) + s % 256 against the one live index head), so each
+    row selects its last topk positions and a row past the topk-th of
+    its key tile selects keys of that tile alone: the flash sums every
+    earlier tile under m = _NEG, and the first selected key must clear
+    it.  The XLA form's output, and each such row is its last topk
+    keys' attention."""
+    rng = np.random.default_rng(3)
+    t, h, g, d, hi, di, k = 1100, 16, 2, 128, 4, 64, 48
+    pos = np.arange(t)
+    ki = np.zeros((1, t, di), np.float32)
+    ki[0, :, 0], ki[0, :, 1] = pos // 256, pos % 256
+    qi = np.zeros((1, t, hi, di), np.float32)
+    qi[0, :, 0, 0], qi[0, :, 0, 1] = 256.0, 1.0
+    w = np.zeros((1, t, hi), np.float32)
+    w[0, :, 0] = 1.0
+    q, kk, vv = (_normal(rng, (1, t, n, d)) for n in (h, g, g))
+    args = (q, kk, vv, jnp.asarray(qi, jnp.bfloat16),
+            jnp.asarray(ki, jnp.bfloat16), jnp.asarray(w))
+    got = np.asarray(sa.sparse_prefill_attention(*args, k, d ** -0.5),
+                     np.float32)
+    want = sa._prefill_xla(*(x[0] for x in args), k, d ** -0.5)[None]
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), atol=3e-2)
+    late = [r for r in range(t) if r % sa._TK >= k - 1]
+    assert len(late) > t // 2
+    kf, vf = (np.asarray(x[0], np.float32) for x in (kk, vv))
+    for r in late[::97]:
+        last = slice(r - k + 1, r + 1)
+        for head in range(h):
+            kv = head // (h // g)
+            s = np.asarray(q[0, r, head], np.float32) @ kf[last, kv].T
+            p = np.exp((s - s.max()) * d ** -0.5)
+            o = (p / p.sum()) @ vf[last, kv]
+            np.testing.assert_allclose(got[0, r, head], o, atol=3e-2)
 
 
 def _decode_case(rng, lens, bs=64, nb=64, maxb=18, h=4, g=2, d=128, hi=4,

@@ -694,6 +694,11 @@ def _sparse_prefill(q, k, v, qi, ki, w):
     return sa.sparse_prefill_attention(q, k, v, qi, ki, w, 2048, 128 ** -0.5)
 
 
+def _sparse_flash(row0, q, k, v, scores, sel):
+    return sa._prefill_flash_call(row0, q, k, v, scores, sel, 32, 4, 128,
+                                  128 ** -0.5, False)
+
+
 def _sparse_prefill_args(t, h=32, g=4, d=128, hi=16, di=64):
     return [((1, t, h, d), jnp.bfloat16), ((1, t, g, d), jnp.bfloat16),
             ((1, t, g, d), jnp.bfloat16), ((1, t, hi, di), jnp.bfloat16),
@@ -851,6 +856,13 @@ KERNEL_CASES = [
           _sparse_prefill_args(16384), 3),
     _case("dsa_prefill_keye_s65536", _sparse_prefill,
           _sparse_prefill_args(65536), 3),
+    # the flash alone at the 65,536-token prompt's last super-block, at
+    # its tiles of 128 query rows (1,024 rows a K/V head) and 1,024 keys:
+    # the VMEM the stacked products ask
+    _case("dsa_prefill_attention_keye_last_super_block", _sparse_flash,
+          [((), jnp.int32), ((2048, 4096), jnp.bfloat16),
+           ((65536, 512), jnp.bfloat16), ((65536, 512), jnp.bfloat16),
+           ((2048, 65536), jnp.float32), ((2048, 128), jnp.int32)], 1),
 ]
 
 
