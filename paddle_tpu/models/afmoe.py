@@ -239,7 +239,9 @@ class AfmoeServingForm(ServingForm):
                 (e["exp_gate_w"], e["exp_up_w"], e["exp_down_w"]),
                 cfg.first_expert, cfg.num_experts, cfg.num_experts_per_tok,
                 cfg.route_scale,
-                valid=None if valid is None else jnp.repeat(valid, s),
+                valid=None if valid is None else (
+                    valid.reshape(-1) if valid.ndim == 2
+                    else jnp.repeat(valid, s)),
                 scope="afmoe", norm_eps=1e-20)
             with jax.named_scope("afmoe/shared_expert"):
                 shared = swiglu(flat, e["shared_gate_w"], e["shared_up_w"],

@@ -203,7 +203,9 @@ class ServingForm:
         w [B,S,index_heads] float32) -> (o, extra)`: the engine stores k,
         v and the index keys ki, and attends each query's `topk` keys of
         largest `sum_j w_j relu(qi_j . ki)`.  `valid` [B] bool marks real
-        rows of a padded decode batch (None: all).  -> (h, extra, stats or None), `stats`
+        rows of a padded decode batch, or [B, S] real positions where a
+        prompt carries decode rows (a form whose every layer is a
+        `LayerSpec`; None: all).  -> (h, extra, stats or None), `stats`
         an int32 vector along `stat_counters`."""
         raise NotImplementedError
 

@@ -54,7 +54,19 @@ Step programs (all array-level, weights threaded as inputs):
   matches dense generate" from a tolerance into token-for-token equality
   (tests/test_serving.py).  One compile per distinct prompt length — the
   prefill-compile price of exactness; decode, the steady-state loop, is
-  ONE fixed-shape program (ragged).
+  ONE fixed-shape program (ragged).  Where every layer of the form is a
+  `LayerSpec` (decided once, at construction) the program also CARRIES
+  THE DECODE ROWS: the prompt's P tokens and `max_num_seqs - 1` rider
+  rows, one token each, run every layer's products as ONE [1, P + B]
+  sequence; the attention splits at P, flash over the prompt and the C = 1
+  ragged call over the riders (against the pools the prompt has just
+  written), and the logits of position P - 1 and of the riders go to ONE
+  sampler call of `max_num_seqs` rows, the decode step's.  A rider takes
+  its token from the step in flight as a decode step's row does (the
+  feed's selection, inside the program).  Rows the scheduler did not
+  hand it are padding (`lens` 0), so one request alone compiles the
+  program a loaded loop runs.  A form with state, latent or sparse
+  layers keeps the prompt alone (`Scheduler.mix_decode`).
 - ``ragged(B, 1)`` — the decode program: per layer ONE fused
   `ops.ragged_paged_attention` call writes the new tokens' K/V to their
   slots and attends the ragged batch against the paged pools (int8
@@ -94,7 +106,9 @@ assumption).
 Monitor wiring (PR-1 StatRegistry): `serving/queue_depth`,
 `serving/running`, `serving/waiting`, `serving/blocks_in_use`,
 `serving/block_utilization`, `serving/prefill_tokens`,
-`serving/decode_tokens`, `serving/prefill_tps`, `serving/decode_tps`,
+`serving/decode_tokens` (decode steps' tokens alone),
+`serving/prefill_riders` (the running rows a prefill step carried, one
+token each), `serving/prefill_tps`, `serving/decode_tps`,
 `serving/preemptions`, `serving/requests_finished`, plus
 `serving/step_time` histograms labeled by phase (and beside them the
 step record, below: `serving/step_wait{phase}`, `serving/host_stalls`,
@@ -319,7 +333,7 @@ from ..ops.paged_attention import (latent_cache_update_arrays,
                                    quantized_cache_update_arrays)
 from ..ops.ragged_paged_attention import (ragged_latent_attention_arrays,
                                           ragged_paged_attention_arrays)
-from ..models.serving_form import StateSpec
+from ..models.serving_form import LayerSpec, StateSpec
 from .kv_cache import (BlockAllocatorError, BlockKVCache, CacheGroups,
                        StateCache, prefix_block_keys)
 from .scheduler import (Request, SamplingParams, Scheduler,
@@ -572,12 +586,16 @@ class LLMEngine:
                 monitor.counter("lowbit/bytes_saved").labels(
                     wing="kv_cache").add(max(0, fp_cost
                                              - self.cache.pool_bytes))
+        # a whole prompt's prefill carries the decode rows where every
+        # layer is plain K/V attention (module docstring, `prefill(P)`)
+        self._mix = (all(isinstance(s, LayerSpec) for s in specs)
+                     and not self.spec_tokens and c.max_num_seqs > 1)
         self.scheduler = Scheduler(
             self.kv, max_num_seqs=c.max_num_seqs,
             max_num_batched_tokens=(c.max_num_batched_tokens
                                     or self.max_model_len),
             spec_tokens=self.spec_tokens,
-            max_model_len=self.max_model_len)
+            max_model_len=self.max_model_len, mix_decode=self._mix)
         self._requests: dict = {}
         self._next_id = 0
         self._jit_cache: dict = {}
@@ -593,6 +611,9 @@ class LLMEngine:
                                "blocks_in_use / num_blocks")
         self._m_pre_toks = m.counter("serving/prefill_tokens")
         self._m_dec_toks = m.counter("serving/decode_tokens")
+        self._m_riders = m.counter(
+            "serving/prefill_riders",
+            "running rows a prefill step carried, one token each")
         self._m_pre_tps = m.gauge("serving/prefill_tps")
         self._m_dec_tps = m.gauge("serving/decode_tps")
         self._m_preempt = m.counter("serving/preemptions")
@@ -1267,7 +1288,7 @@ class LLMEngine:
                 # before any phase span ends: the subtree is filed under
                 # the traces linked by then
                 riders = ([out.prefill_request] if out.prefill_request
-                          else out.decode_requests)
+                          else []) + list(out.decode_requests)
                 for r in riders:
                     step_span.link(r.trace)
                 step_span.attrs.update(phase=out.kind, rows=len(riders))
@@ -1524,23 +1545,33 @@ class LLMEngine:
         if req.queue_span is not None:   # first compute: queue wait over
             req.queue_span.end()
             req.queue_span = None
+        rows = list(out.decode_requests)      # the running rows it carries
         spans = ()
         if req.trace is not None:
             spans = (mtrace.start_span(
                 "serving/prefill", parent=req.trace, chunk_start=start,
                 chunk_len=chunk, step=step_span.span_id),)
+        spans += tuple(
+            mtrace.start_span("serving/decode_step", parent=r.trace,
+                              pos=r.total_len - 1, batch=len(rows),
+                              step=step_span.span_id)
+            for r in rows if r.trace is not None)
         try:
-            arrays = self._prefill_body(req, start, chunk)
+            arrays = self._prefill_body(req, start, chunk, rows)
         except BaseException:
             for sp in spans:
                 sp.end()
             raise
-        return _Flight("prefill", [req] if arrays else [], arrays, chunk,
-                       t0, spans=spans)
+        if rows:
+            self._m_riders.inc(len(rows))
+        return _Flight("prefill", [req] + rows if arrays else [], arrays,
+                       chunk, t0, spans=spans)
 
-    def _prefill_body(self, req, start, chunk):
-        """Dispatch one prefill chunk -> the sampler's device outputs when
-        the chunk ends the prompt (`_sample_rows`), else None."""
+    def _prefill_body(self, req, start, chunk, rows=()):
+        """Dispatch one prefill chunk, carrying the running `rows` where it
+        is a whole prompt -> the sampler's device outputs when the chunk
+        ends the prompt (`_sample_rows`: the prompt's row first), else
+        None."""
         with mtrace.phase("engine/prepare"):
             ids = np.asarray([req.prompt_ids[start:start + chunk]],
                              np.int32)
@@ -1561,7 +1592,8 @@ class LLMEngine:
                 # dense prefill's exact arithmetic
                 fn = self._get_prefill_exec(chunk)
                 logits, kv_out, stats = self._run(
-                    fn, self._param_arrays(), kv, ids, slots, srows)
+                    fn, self._param_arrays(), kv, ids, slots,
+                    *(self._rider_inputs(rows) if self._mix else (srows,)))
             else:
                 tables = tuple(
                     self._table_row(k, req.req_id)[None]
@@ -1586,7 +1618,19 @@ class LLMEngine:
             # dense generate(max_new_tokens=0) emits nothing
             req.state = Request.FINISHED
             return None
-        return self._sample_rows([req], logits, stats)
+        return self._sample_rows([req] + list(rows), logits, stats)
+
+    def _rider_inputs(self, rows) -> tuple:
+        """What `prefill(P)` takes of the running rows it carries, at its
+        fixed `max_num_seqs - 1` of them: the step in flight's sampled
+        tokens (on the device), where each row takes its token from there
+        (`_feed_sources`), and `_decode_inputs`' arrays but the state
+        slots (a form that mixes has no state group)."""
+        b = self.scheduler.max_num_seqs - 1
+        toks, pos0, lens, tables, slots, _ = self._decode_inputs(
+            rows, [()] * len(rows), b, 1)
+        return (self._in_flight_outputs()[0], self._feed_sources(rows, b),
+                toks, pos0, lens, tables, slots)
 
     def _step_decode(self, out, step_span, t0) -> _Flight:
         rows = list(out.decode_requests)
@@ -2359,64 +2403,112 @@ class LLMEngine:
             tails = [k.tail_start(p_len) for k in self.caches.values()]
 
             def prefill(params, kv_flat, ids, slots, srows=()):
-                from ..ops.pallas_ops import flash_attention_arrays
-
                 pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
                 x = self.form.embed(params, ids, pos)
-
-                def builder(spec, g, kc, vc=None, ksc=None, vsc=None):
-                    if spec.sparse:
-                        # a sparse layer's third pool holds index keys
-                        return self._sparse_fn(spec, kc, vc, ksc, slots[g])
-                    if spec.latent:
-                        def latent_fn(rows, whole, stored, pool=kc):
-                            # what is stored is the latent; what flash
-                            # attends is what the layer expands from it
-                            pool2 = latent_cache_update_arrays(
-                                pool, rows, slots[g])
-                            with self._attn_scope(spec):
-                                o = whole(lambda q, k, v: (
-                                    flash_attention_arrays(
-                                        q, k, v, is_causal=True,
-                                        scale=spec.scale)))
-                            return o, (pool2,)
-                        return latent_fn
-
-                    def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
-                        # flash within the chunk reads the fp K/V it just
-                        # computed — only the STORED cache is quantized
-                        t0, sl = tails[g], slots[g]
-                        kw, vw = (k, v) if not t0 else (k[:, t0:], v[:, t0:])
-                        if ksc is None:
-                            kc2 = paged_cache_update_arrays(kc, kw, sl)
-                            vc2 = paged_cache_update_arrays(vc, vw, sl)
-                            extra = (kc2, vc2)
-                        else:
-                            kc2, ks2 = quantized_cache_update_arrays(
-                                kc, ksc, kw, sl)
-                            vc2, vs2 = quantized_cache_update_arrays(
-                                vc, vsc, vw, sl)
-                            extra = (kc2, vc2, ks2, vs2)
-                        with self._attn_scope(spec):
-                            if spec.window is None:
-                                o = flash_attention_arrays(
-                                    q, k, v, is_causal=True)
-                            else:
-                                o = flash_attention_arrays(
-                                    q, k, v, is_causal=True,
-                                    window=spec.window)
-                        return o, extra
-                    return attn_fn
-
                 h, kv_out, stats = self._run_blocks(
-                    params, kv_flat, x, pos, builder, srows=srows,
+                    params, kv_flat, x, pos,
+                    self._prefill_builder(tails, slots), srows=srows,
                     fresh=True)
                 return self._model_tail(params, h), kv_out, stats
 
+            def mixed(params, kv_flat, ids, slots, prev_toks, src, toks,
+                      pos0, lens, tables, rslots):
+                # the riders' tokens as the feed program selects them
+                fed = jnp.where(src >= 0, prev_toks[jnp.maximum(src, 0)],
+                                toks[:, 0])
+                pos = jnp.concatenate(
+                    [jnp.arange(p_len, dtype=jnp.int32), pos0])
+                x = self.form.embed(
+                    params, jnp.concatenate([ids[0], fed])[None], pos)
+
+                def attend(spec, g, q, k, v, slots, tables, rslots, pos0,
+                           lens, *pools):
+                    o, written = self._prefill_builder(tails, slots)(
+                        spec, g, *pools)(
+                        q[:, :p_len], k[:, :p_len], v[:, :p_len])
+                    o_r, written = self._ragged_builder(
+                        tables, rslots, pos0, lens)(spec, g, *written)(
+                        q[0, p_len:, None], k[0, p_len:, None],
+                        v[0, p_len:, None])
+                    return jnp.concatenate(
+                        [o, o_r.reshape((1, -1) + o_r.shape[2:])],
+                        axis=1), written
+
+                # one function for every layer of a group, so that a
+                # program traces and lowers its attention once a group and
+                # not once a layer (set-up time)
+                attend = jax.jit(self._named(attend, "attend"),
+                                 static_argnums=(0, 1))
+
+                def builder(spec, g, *pools):
+                    return lambda q, k, v: attend(
+                        spec, g, q, k, v, slots, tables, rslots, pos0, lens,
+                        *pools)
+
+                # a padding rider routes nowhere: validity a position
+                valid = jnp.concatenate(
+                    [jnp.ones((p_len,), bool), lens > 0])[None]
+                h, kv_out, stats = self._run_blocks(
+                    params, kv_flat, x, pos, builder, valid=valid)
+                logits = self.form.logits(params, h[:, p_len - 1:])[0]
+                return logits.astype(jnp.float32), kv_out, stats
+
             self._jit_cache[key] = jax.jit(
-                self._named(prefill, f"prefill_{p_len}"),
+                self._named(mixed if self._mix else prefill,
+                            f"prefill_{p_len}"),
                 donate_argnums=(1,))
         return self._jit_cache[key]
+
+    def _prefill_builder(self, tails, slots):
+        """The attention builder of a whole-prompt prefill (`_run_blocks`'
+        `attn_builder`): flash within the chunk, its K/V written at
+        `slots` (one array a cache group) from the group's `tails` on."""
+        from ..ops.pallas_ops import flash_attention_arrays
+
+        def builder(spec, g, kc, vc=None, ksc=None, vsc=None):
+            if spec.sparse:
+                # a sparse layer's third pool holds index keys
+                return self._sparse_fn(spec, kc, vc, ksc, slots[g])
+            if spec.latent:
+                def latent_fn(rows, whole, stored, pool=kc):
+                    # what is stored is the latent; what flash
+                    # attends is what the layer expands from it
+                    pool2 = latent_cache_update_arrays(
+                        pool, rows, slots[g])
+                    with self._attn_scope(spec):
+                        o = whole(lambda q, k, v: (
+                            flash_attention_arrays(
+                                q, k, v, is_causal=True,
+                                scale=spec.scale)))
+                    return o, (pool2,)
+                return latent_fn
+
+            def attn_fn(q, k, v, kc=kc, vc=vc, ksc=ksc, vsc=vsc):
+                # flash within the chunk reads the fp K/V it just
+                # computed — only the STORED cache is quantized
+                t0, sl = tails[g], slots[g]
+                kw, vw = (k, v) if not t0 else (k[:, t0:], v[:, t0:])
+                if ksc is None:
+                    kc2 = paged_cache_update_arrays(kc, kw, sl)
+                    vc2 = paged_cache_update_arrays(vc, vw, sl)
+                    extra = (kc2, vc2)
+                else:
+                    kc2, ks2 = quantized_cache_update_arrays(
+                        kc, ksc, kw, sl)
+                    vc2, vs2 = quantized_cache_update_arrays(
+                        vc, vsc, vw, sl)
+                    extra = (kc2, vc2, ks2, vs2)
+                with self._attn_scope(spec):
+                    if spec.window is None:
+                        o = flash_attention_arrays(
+                            q, k, v, is_causal=True)
+                    else:
+                        o = flash_attention_arrays(
+                            q, k, v, is_causal=True,
+                            window=spec.window)
+                return o, extra
+            return attn_fn
+        return builder
 
     def _ragged_blocks(self, c, params, kv_flat, ids, pos0, lens, tables,
                        slots, srows=()):
@@ -2428,6 +2520,17 @@ class LLMEngine:
         -> (h, kv_out, stats)."""
         pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
         x = self.form.embed(params, ids, pos)
+        # a padding row of the fixed-shape batch has no keys
+        return self._run_blocks(
+            params, kv_flat, x, pos,
+            self._ragged_builder(tables, slots, pos0, lens),
+            valid=lens > 0, srows=srows)
+
+    def _ragged_builder(self, tables, slots, pos0, lens):
+        """The attention builder of a program that reads the pools
+        (`_run_blocks`' `attn_builder`): each row's queries at `pos0` on,
+        written at `slots` and attending through `tables` (one array a
+        cache group each) up to `lens`."""
 
         def builder(spec, g, kc, vc=None, ksc=None, vsc=None):
             if spec.sparse:
@@ -2462,10 +2565,7 @@ class LLMEngine:
                         k_scales=ksc, v_scales=vsc)
                     return o, (kc2, vc2, ks2, vs2)
             return attn_fn
-
-        # a padding row of the fixed-shape batch has no keys
-        return self._run_blocks(params, kv_flat, x, pos, builder,
-                                valid=lens > 0, srows=srows)
+        return builder
 
     def _get_ragged_exec(self, b, c):
         """The ISSUE-8 decode program (`_ragged_blocks` + the last

@@ -16,6 +16,14 @@ Per engine step the scheduler picks ONE of:
   preserved) until the rest fit.  Evicting the youngest minimizes wasted
   work — the oldest requests are closest to finishing.
 
+With `mix_decode` (the engine's choice, from its model's layers) a prefill
+of a WHOLE prompt also carries the running rows: each whose next slot can
+be reserved without an eviction decodes one token in the same step
+(`SchedulerOutput.decode_requests` of a "prefill"), so the rows do not
+stall while the prompt runs.  A chunk, a prefix hit's tail, a forked
+child's continuation and a swap-in carry none, and nothing rides where
+`spec_tokens > 0`.
+
 Multi-tenant policy (ISSUE 19): requests carry a `tenant` and a
 `priority` class.  Admission candidates are ordered by (priority class,
 weighted tenant service, arrival) — a deficit-style fair share where every
@@ -243,13 +251,15 @@ class SchedulerOutput:
     prefill_request: Optional[Request] = None
     chunk_start: int = 0           # prefill: first prompt position of chunk
     chunk_len: int = 0
-    decode_requests: tuple = ()    # decode: rows of the batch
+    decode_requests: tuple = ()    # decode: rows of the batch; prefill:
+    #                                the running rows it carries
     preempted: tuple = ()          # requests evicted while scheduling
 
 
 class Scheduler:
     def __init__(self, cache, max_num_seqs=8, max_num_batched_tokens=2048,
-                 spec_tokens=0, max_model_len=None, weights=None):
+                 spec_tokens=0, max_model_len=None, weights=None,
+                 mix_decode=False):
         self.cache = cache
         self.max_num_seqs = int(max_num_seqs)
         self.max_num_batched_tokens = int(max_num_batched_tokens)
@@ -267,6 +277,9 @@ class Scheduler:
         # step).  Clamped per row so no write position ever reaches
         # max_model_len.
         self.spec_tokens = max(0, int(spec_tokens))
+        # a whole prompt's prefill carries the running rows (module
+        # docstring); a verify step's drafts have no such program
+        self.mix_decode = bool(mix_decode) and not self.spec_tokens
         self.max_model_len = (None if max_model_len is None
                               else int(max_model_len))
         self.waiting: deque = deque()
@@ -378,7 +391,7 @@ class Scheduler:
             order = sorted(self.waiting, key=self._admission_key)
             got = self._admit_or_resume(order[0], preempted)
             if isinstance(got, SchedulerOutput):
-                return got
+                return self._with_riders(got)
             if got is None and not self.running:
                 for req in order[1:]:
                     got = self._admit_or_resume(req, preempted)
@@ -502,6 +515,30 @@ class Scheduler:
         return SchedulerOutput(kind="prefill", prefill_request=req,
                                chunk_start=start, chunk_len=chunk,
                                preempted=tuple(preempted))
+
+    def _with_riders(self, out) -> SchedulerOutput:
+        """`out`, a prefill just admitted, with the running rows it carries
+        where it is a whole prompt and `mix_decode` is on: every row that
+        `schedule()`'s decode branch would take and whose next slot needs
+        no eviction (a row that does waits for the next decode step, where
+        it may evict)."""
+        req = out.prefill_request
+        if (not self.mix_decode or out.chunk_start
+                or out.chunk_len != req.prompt_len
+                or req.params.max_new_tokens <= 0):
+            return out
+        rows = []
+        for r in self.running:           # oldest first
+            if r is req or not r.prefill_done or r.owes_last:
+                continue
+            reserve = self._decode_reserve_len(r)
+            if not self.cache.can_grow_to(r.req_id, reserve):
+                continue
+            self.cache.grow_to(r.req_id, reserve)
+            self._charge(r, 1)
+            rows.append(r)
+        out.decode_requests = tuple(rows)
+        return out
 
     def _emit_prefill(self, req, preempted) -> SchedulerOutput:
         start = req.num_computed

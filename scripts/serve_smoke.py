@@ -721,17 +721,19 @@ def check_memobs(engine, model, cfg):
 
     # (b) pressure forensics on a tiny-pool twin (same block_size /
     # max_num_seqs as the shared engine, so every program is already
-    # compiled).  Four same-length requests fill the 4-block pool one
-    # block each; ~12 quiet decode steps build the storm detector's
-    # zero baseline; then every row crosses into its second block on
-    # the SAME step — the pool can only re-home two, so two rows are
-    # preempted at once: an eviction storm.  The dump must name the
+    # compiled).  Four requests fill the 4-block pool one block each;
+    # ~12 quiet decode steps build the storm detector's zero baseline;
+    # then every row crosses into its second block on the SAME step —
+    # the pool can only re-home two, so two rows are preempted at once:
+    # an eviction storm.  Each prefill carries the rows admitted before
+    # it one token further, so request i's prompt is i tokens longer
+    # and all four reach position 16 together.  The dump must name the
     # oldest surviving holder (tenant acme).
     eng = LLMEngine(model, EngineConfig(
         block_size=16, num_blocks=4, max_num_seqs=8))
     rng = np.random.RandomState(21)
-    prompts = [rng.randint(0, cfg.vocab_size, (4,)).astype(np.int32)
-               for _ in range(4)]
+    prompts = [rng.randint(0, cfg.vocab_size, (4 + i,)).astype(np.int32)
+               for i in range(4)]
     rids = [eng.add_request(p, SamplingParams(
         max_new_tokens=16, tenant="acme" if i == 0 else "hog"))
         for i, p in enumerate(prompts)]

@@ -95,7 +95,9 @@ def check_tokens_and_keys(make, vocab):
     eng = make()
     ps = prompts(vocab)
     rids = {i: eng.add_request(ps[i], params(i, 12)) for i in (0, 1, 3)}
-    for _ in range(5):
+    # few enough steps that neither exports more tokens than DECK gives
+    # it, where a prefill carries the rows before it as where it does not
+    for _ in range(4):
         eng.step()
     for i in (1, 3):
         eng.step()                  # an export empties the pipeline

@@ -872,7 +872,8 @@ class TestDeviceCrossings:
             self, family, monitored):
         """A decode step uploads twice (model inputs, sampler inputs) and
         reads back once at 1 live row and at `max_num_seqs` live rows; a
-        whole-prompt prefill step the same whatever the prompt's length.
+        whole-prompt prefill step the same whatever the prompt's length
+        and however many running rows it carries.
         A call of `step()` makes the uploads of the step it dispatches and
         the readback of the step before: the first call reads nothing
         back, the last one (nothing left to dispatch) uploads nothing."""
@@ -897,7 +898,8 @@ class TestDeviceCrossings:
                 break
             assert calls == (2, 1), (kinds[-1], calls)
             seen.add(kinds[-1])
-        assert ("decode", 4) in seen and ("prefill", 0) in seen
+        # each prompt added while rows decode carries them all
+        assert ("decode", 4) in seen and ("prefill", 3) in seen
         assert "idle" not in {k for k, _ in kinds}
         for i in ids:
             eng.release_request(i)
@@ -1067,10 +1069,10 @@ class TestStepInFlightCounters:
         ps = sif.prompts(engine.cfg.vocab_size)
         sp = SamplingParams(max_new_tokens=8)
         a, b = (engine.add_request(p, sp) for p in ps[:2])
-        for _ in range(3):
+        for _ in range(3):      # a's prefill, b's (a rides it), a decode
             engine.step()
         assert engine._requests[a].owed == 1
-        assert len(engine.request_output(a)) == len(ps[0]) + 2
+        assert len(engine.request_output(a)) == len(ps[0]) + 3
         assert engine._requests[a].owed == 0 and engine._flight is None
         engine.step()
         child = engine.fork_request(a, sp)

@@ -254,12 +254,22 @@ def test_flash_forward_compiles_at_the_afmoe_shapes(S, monkeypatch, seq,
     assert f"bf16[1,{seq},{a['hq']},{a['d']}]" in text
 
 
+def _riders(eng, i32):
+    """The arguments of the rows a whole-prompt prefill carries beside its
+    prompt (`LLMEngine._rider_inputs`), as shapes: `max_num_seqs - 1`
+    rows, one table and one slot array a cache group."""
+    b, groups = eng.scheduler.max_num_seqs - 1, len(eng.caches)
+    return (i32(b + 1), i32(b), i32(b, 1), i32(b), i32(b),
+            (i32(b, eng.blocks_per_seq),) * groups, (i32(b, 1),) * groups)
+
+
 def _gpt_engine_programs(one_chip, monkeypatch, name, prefill_len):
     """The engine's decode program and one prefill program of a 2-layer
     GPT at `name`'s widths and rows, compiled for the described v5e
-    -> {program: compiled}.  The weights are shapes from the start (at
-    the 6.7B widths two layers and the embedding are 2.4 GB of float32
-    zeros under `LazyGuard`)."""
+    -> {program: compiled}.  The prefill carries `rows - 1` decode rows
+    beside its prompt.  The weights are shapes from the start (at the
+    6.7B widths two layers and the embedding are 2.4 GB of float32 zeros
+    under `LazyGuard`)."""
     from paddle_tpu.framework.compat import LazyGuard
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     from paddle_tpu.nn import layer as nn_layer
@@ -301,7 +311,8 @@ def _gpt_engine_programs(one_chip, monkeypatch, name, prefill_len):
             (i32(rows, eng.blocks_per_seq),), (i32(rows, 1),)).compile(),
         f"jit_prefill_{prefill_len}": eng._get_prefill_exec(
             prefill_len).lower(params, kv, i32(1, prefill_len),
-                               (i32(1, prefill_len),)).compile()}
+                               (i32(1, prefill_len),),
+                               *_riders(eng, i32)).compile()}
 
 
 def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
@@ -321,7 +332,14 @@ def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
     6 -> 5, `slice-start` and `slice-done` 4 -> 2 (decode), `custom-call`
     6 -> 5 (decode: a `ConcatBitcast`), `get-tuple-element` 40 -> 38 / 44
     -> 38, `tuple` 17 -> 16 / 18 -> 17, `parameter` 221 -> 222 / 288 ->
-    287; every arithmetic opcode and `slice` are as they were."""
+    287; every arithmetic opcode and `slice` are as they were.  The
+    prefill was recorded again once it carried 15 decode rows beside its
+    512 prompt tokens (the decode program did not move): `custom-call` 12
+    -> 15 (the two layers' ragged kernel calls among them), `copy-start`
+    and `copy-done` 5 -> 19, `copy` 14 -> 20 (none of a pool), `pad` 12
+    -> 27, `slice` 41 -> 57, `reshape` 24 -> 41, `fusion` 89 -> 107,
+    `parameter` 287 -> 337: the riders' inputs, their tokens' selection
+    and the split of q, k and v at the prompt's end."""
     import json
 
     _, nh, nb = SHAPES["gpt3-1.3b"]
@@ -341,15 +359,18 @@ def test_gpt_engine_programs_keep_the_parents_operations(one_chip,
 
 # a prefill length of the cell whose activations have no weight's element
 # count (the MLP's `[512, 8192]` at 1.3B and `[1024, 16384]` at 6.7B have
-# `out_w[l]`'s)
-_PREFILL = {"gpt3-1.3b": 256, "gpt3-6.7b": 768}
+# `out_w[l]`'s); at 6.7B docqa's longest prompt, whose program runs 1,543
+# rows with the 7 it carries
+_PREFILL = {"gpt3-1.3b": 256, "gpt3-6.7b": 1536}
 
 
 @pytest.mark.parametrize("name", sorted(SHAPES))
 def test_gpt_engine_programs_read_the_stacked_weights_in_place(
         one_chip, monkeypatch, name):
     """No serving program re-lays a stacked weight.  In the engine's decode
-    program and a prefill program, at both GPT cells' widths and rows,
+    program and a prefill program that carries the decode rows (at 6.7B
+    docqa's 1,536-token prompt beside its 7), at both GPT cells' widths
+    and rows,
     nothing outside a fusion's body yields an array of the element count
     of a layer's `qkv_w`, `out_w`, `fc_in_w` or `fc_out_w`, or of a whole
     stack of them, but the arguments and the compiler's prefetch of them
@@ -428,15 +449,17 @@ def _afmoe_engine_ops(one_chip, monkeypatch):
             params, kv, i32(32, 1), i32(32), i32(32), (table, table),
             (i32(32, 1), i32(32, 1))).compile()),
         "jit_prefill_1024": _program_ops(eng._get_prefill_exec(1024).lower(
-            params, kv, i32(1, 1024),
-            (i32(1, 1024), i32(1, 1024))).compile())}
+            params, kv, i32(1, 1024), (i32(1, 1024), i32(1, 1024)),
+            *_riders(eng, i32)).compile())}
 
 
 def test_afmoe_engine_programs_keep_the_parents_operations(one_chip,
                                                            monkeypatch):
     """The state group (ISSUE 32) costs the afmoe cell nothing: a model
     with no state layer builds no state group, and its programs compile to
-    the operation lists recorded from the parent commit."""
+    the operation lists recorded from the parent commit.  The prefill's
+    list was recorded again once it carried 31 decode rows beside its
+    prompt (the decode program's did not move)."""
     import json
 
     got = _afmoe_engine_ops(one_chip, monkeypatch)

@@ -427,16 +427,17 @@ def phase_run(model, eng):
 @pytest.mark.parametrize("name", ENGINE_PHASES)
 def test_engine_phase_counted_once_a_step(phase_run, name):
     calls, snap = phase_run
-    # 2 prefill steps + 3 decode steps: each ran a program and sampled;
-    # one call more reads the last of them back (a step is in flight)
-    steps = 5
+    # 2 prefill steps (the second carries the first request) + 2 decode
+    # steps: each ran a program and sampled; one call more reads the last
+    # of them back (a step is in flight)
+    steps = 4
     assert calls == steps + 1
     # the first call has no step to read back, the last none to schedule
     h = snap[trace.PHASE_METRIC][f"phase={name}"]
     assert h["count"] == steps and h["sum"] > 0
     by_kind = snap["serving/step_time"]
     assert {k: v["count"] for k, v in by_kind.items()} == {
-        "phase=prefill": 2, "phase=decode": 3}
+        "phase=prefill": 2, "phase=decode": 2}
 
 
 def test_phases_cover_the_step_and_nothing_twice(phase_run):
@@ -489,7 +490,7 @@ def test_step_record_once_a_program_step(phase_run):
     steps, waits = snap["serving/step_time"], snap["serving/step_wait"]
     assert {k: v["count"] for k, v in waits.items()} == {
         k: v["count"] for k, v in steps.items()} == {
-        "phase=prefill": 2, "phase=decode": 3}
+        "phase=prefill": 2, "phase=decode": 2}
     for kind, w in waits.items():
         assert 0 < w["sum"] <= steps[kind]["sum"]
     # the wait is the readback phase's own duration (its clock reads
@@ -577,10 +578,11 @@ def test_request_trace_shows_the_steps_it_rode(model, eng):
     a, b = eng.request_trace(rid_a), eng.request_trace(rid_b)
     root = [s for s in a if s["name"] == "serving/request"][0]
     step_spans = [s for s in a if s["name"] == "serving/step"]
-    # a rode its prefill step and 3 decode steps, not b's prefill
+    # a rode every program step: its prefill, b's prefill (which carries
+    # it) and 2 decode steps
     assert len(step_spans) == 4 < steps
     assert [s["attrs"]["phase"] for s in step_spans] == \
-        ["prefill", "decode", "decode", "decode"]
+        ["prefill", "prefill", "decode", "decode"]
     assert all(s["parent_id"] == root["span_id"]
                and s["trace_id"] == root["trace_id"] for s in step_spans)
     own = {s["attrs"]["step"] for s in a
@@ -599,9 +601,10 @@ def test_request_trace_shows_the_steps_it_rode(model, eng):
             assert k["ts_us"] >= end - 1e-3
             end = k["ts_us"] + k["dur_us"]
         assert end <= st["ts_us"] + st["dur_us"] + 1e-3
-    # the two decode steps both rode are the SAME spans in both traces
+    # the three steps both rode (b's prefill, two decode steps) are the
+    # SAME spans in both traces
     shared = [s for s in step_spans if s["attrs"]["rows"] == 2]
-    assert len(shared) == 2
+    assert len(shared) == 3
     assert {s["span_id"] for s in shared} <= {s["span_id"] for s in b}
     # and the all-traces export holds each of them once
     ids = [e["args"]["span_id"] for e in trace.chrome_events()]
