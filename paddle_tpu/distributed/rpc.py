@@ -295,7 +295,8 @@ def _call(to, fn, args, kwargs, timeout):
     with mtrace.span("rpc/call", to=to):
         # the header parents the REMOTE rpc/serve span under this call
         # span; with tracing off span() is the no-op singleton and
-        # inject() is one global read → None (trace_overhead-gated).
+        # inject() is one global read → None (tests/test_fleet.py;
+        # tests/test_disabled_overhead.py times it).
         # No header → the LEGACY 3-tuple frame, so the DEFAULT
         # (PTPU_TRACE off) path is wire-identical to older servers
         # mid-deploy; a TRACED call sends the 4-tuple and therefore

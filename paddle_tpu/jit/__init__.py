@@ -661,7 +661,7 @@ __all__ += ["enable_to_static", "set_code_level", "set_verbosity"]
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache somewhere that survives
     the process, and return the directory in effect.  Entry points
-    (chip_smoke.py, bench.py) call this before their first trace;
+    (chip_smoke.py, benchmark/run.py) call this before their first trace;
     `import paddle_tpu` does not.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: nothing is set here — JAX reads that

@@ -38,9 +38,9 @@ memory-side instrument plane the ZeRO-sharding and KV-tiering arcs
   state or its lock.
 
 Gating: everything is default-off behind ``PTPU_MEMOBS`` (enable at
-runtime via :func:`enable`); the per-step hooks live inside the
-standing trace_overhead budget (<1% disabled / <5% enabled —
-``bench.py --config trace_overhead`` charges the sequence).  Knobs:
+runtime via :func:`enable`); off, every hook returns at one flag read
+and counts nothing (``tests/test_memobs.py::
+test_memobs_off_gate_counts_nothing``).  Knobs:
 ``PTPU_MEMOBS_RING`` (timeline ring length, default 512) and
 ``PTPU_MEMOBS_COOLDOWN_S`` (seconds between kv_pressure dumps,
 default 30).  stdlib-only, no jax, like every monitor sibling.

@@ -16,10 +16,7 @@ Three data sources, one registry:
   achieved-vs-optimal ratio — the number a perf PR must move.
 - **step-segment breakdown** — named, properly-synced sub-step timers:
   ``hapi.Model`` splits the eager train step into
-  forward/backward/optimizer, and ``LLMEngine.decode_breakdown()``
-  attributes the inside of the fused decode program (block gather,
-  attention, cache update, sampler) against each segment's own
-  cost-analysis prediction.  The serving step itself is NOT synced for
+  forward/backward/optimizer.  The serving step itself is NOT synced for
   timing: its host phases are the always-on ``serving/host_time{phase}``
   histogram (``monitor.trace.phase``), which ``report()`` appends.
 - **HBM attribution** — per-program peak-bytes estimate and headroom vs
@@ -29,8 +26,9 @@ Gate: ``PTPU_PERF=1`` (default OFF — perf mode syncs after every timed
 call and routes fresh compiles through the AOT path to capture their
 analyses, both of which perturb steady-state pipelining; it is a
 diagnostic mode, not an always-on tax).  With the gate off every hook
-is one module-global read (guarded by the trace_overhead bench gate and
-tests/test_perf.py's <1µs check).
+is one module-global read: a disabled segment is the shared no-op
+object (tests/test_perf.py), held to < 1 µs a call by
+tests/test_disabled_overhead.py.
 
 Import constraints (shared with trace/flight/serve): importing this
 module never imports jax — analyses arrive as plain dicts/objects from
@@ -119,8 +117,9 @@ class ChipSpec:
                 f"{self.hbm_bytes/2**30:.0f} GiB)")
 
 
-# (peak bf16 FLOP/s, HBM bytes/s, HBM bytes) — v5e numbers match bench.py's
-# PEAK_BF16/hbm_bw constants so MFU here and vs_baseline there agree.
+# (peak bf16 FLOP/s, HBM bytes/s, HBM bytes) — v5e's FLOP/s and HBM
+# bandwidth match benchmark/lib/peaks.json, so MFU here and the cells'
+# rooflines agree.
 _KNOWN_CHIPS = (
     ("v5 lite", ("tpu-v5e", 197e12, 819e9, 16 * 2**30)),
     ("v5e", ("tpu-v5e", 197e12, 819e9, 16 * 2**30)),
@@ -143,11 +142,11 @@ _chip_lock = threading.Lock()
 
 def chip_spec(refresh_probe: bool = False) -> ChipSpec:
     """The current backend's ChipSpec (probed once, cached).  CPU hosts
-    get the same stand-in peaks bench.py's cpu-smoke baselines use, and
-    HBM capacity falls back to host RAM — the numbers still rank
-    segments correctly relative to each other, which is what the
-    attribution table is for.  A TPU whose ``device_kind`` is not in
-    ``_KNOWN_CHIPS`` is an error: no peaks are assumed for it."""
+    get stand-in peaks, and HBM capacity falls back to host RAM — the
+    numbers still rank segments correctly relative to each other, which
+    is what the attribution table is for.  A TPU whose ``device_kind``
+    is not in ``_KNOWN_CHIPS`` is an error: no peaks are assumed for
+    it."""
     global _chip
     if _chip is not None and not refresh_probe:
         return _chip
@@ -647,9 +646,8 @@ def measure(fn, *arrays, label=None, reps: int = 2, donate_argnums=(),
     cost/memory analyses, execute it ``reps``+1 times (first run is
     warmup/page-in) with a full sync, and return the record's
     ``as_dict()`` plus ``wall_time_s`` (best synced run).  The shared
-    backend of ``CostModel.profile_measure`` and
-    ``LLMEngine.decode_breakdown`` — ONE lower/compile/analyze
-    convention instead of three hand-rolled ones.
+    backend of ``CostModel.profile_measure`` — ONE lower/compile/analyze
+    convention instead of hand-rolled ones.
 
     ``fn`` may already be a ``jax.jit`` object (it is lowered as-is,
     preserving its own donation).  With donation, buffers are re-armed

@@ -35,8 +35,8 @@ additionally rolls the worst burn into the router feed), and served
 structured at ``GET /slo``.
 
 Default off; the per-step cost with ``PTPU_SLO`` unset is the one
-module-global read in :func:`maybe_tick` (gated by bench.py --config
-trace_overhead).  Enabled, a tick is rate-limited to once per
+module-global read in :func:`maybe_tick` (``tests/test_slo.py::
+test_module_report_and_maybe_tick``).  Enabled, a tick is rate-limited to once per
 ``min_interval`` (1 s) — a bisect over bucket bounds per objective,
 off the request hot path.  stdlib-only, no jax, like every sibling.
 """

@@ -429,7 +429,7 @@ def inject(span_=None) -> "str | None":
     the client half of cross-process propagation.  Returns None when
     tracing is disabled or there is no open span, so a disabled caller
     attaches nothing (allocation-free, same budget as a disabled
-    ``span()``; gated by bench.py --config trace_overhead)."""
+    ``span()``; ``tests/test_disabled_overhead.py`` times the pair)."""
     if not _enabled:
         return None
     s = _ctx.span if span_ is None else span_

@@ -27,8 +27,9 @@ diagnostic — the per-layer fused reduction, one extra device sync per
 sampled step.  The always-cheap paths (loss-spike EWMA, goodput
 accounting, and the ``collective/time`` walls at the already-blocking
 barrier/wait boundaries) ride the ordinary ``PTPU_MONITOR`` gate like
-the rest of the hot-path metrics and stay inside the trace_overhead
-bench budget (<1% disabled / <5% enabled of a train step).
+the rest of the hot-path metrics: off, each is a disabled counter
+or histogram call (tests/test_monitor.py, timed by
+tests/test_disabled_overhead.py).
 
 Import constraints (shared with trace/flight/serve/perf/fleet/hlo):
 pure stdlib — device reductions happen at the call sites (optimizer,

@@ -112,10 +112,7 @@ token each), `serving/prefill_tps`, `serving/decode_tps`,
 `serving/preemptions`, `serving/requests_finished`, plus
 `serving/step_time` histograms labeled by phase (and beside them the
 step record, below: `serving/step_wait{phase}`, `serving/host_stalls`,
-`serving/host_stall_seconds`).  ISSUE-12 goodput and
-launch accounting: `serving/kernels_per_step` (distinct compiled
-programs one decode step dispatches — the mega-kernel before/after
-number, flat across batch compositions),
+`serving/host_stall_seconds`).  Goodput:
 `serving/padding_waste{kind=rows|tokens}` (padded fraction of the
 fixed-shape decode program — rows and tokens diverge under speculative
 decoding, where a row carries 1+drafts query positions),
@@ -661,13 +658,7 @@ class LLMEngine:
             "by tenant")
         self._m_compiles = m.counter("serving/compiles",
                                      "step-program cache misses")
-        # ISSUE 12 goodput/launch accounting: how many separate compiled
-        # programs one decode step dispatches (the mega-kernel PR's
-        # before/after number — FLAT across batch compositions), and how
-        # much of the fixed-shape decode program is padding
-        self._m_kernels = m.gauge(
-            "serving/kernels_per_step",
-            "distinct compiled programs dispatched per decode step")
+        # goodput: how much of the fixed-shape decode program is padding
         pad = m.gauge(
             "serving/padding_waste",
             "padded fraction of the fixed-shape decode program")
@@ -782,7 +773,6 @@ class LLMEngine:
         self._spec_accepted_total = 0
         self._wall_s_total = 0.0
         self._goodput_toks = 0
-        self._launches_this_step = None
         # rid -> trace_id survives release_request (the spans live in the
         # bounded monitor.trace store, not on the request); bounded like
         # that store — entries past it map to evicted traces anyway, and
@@ -1462,9 +1452,10 @@ class LLMEngine:
         """Per-step memory-microscope sampling: one HBM/host timeline
         reading, tenant-labeled capacity attribution, the eviction-
         storm/swap-thrash detector, and the interval-limited /kv pool-
-        map publication.  Everything here is host-side dict walking —
-        the sequence is charged in bench.py --config trace_overhead
-        and must stay inside the <5%-enabled budget.  It reads K/V blocks:
+        map publication.  Everything here is host-side dict walking,
+        paid only under PTPU_MEMOBS, which no benchmark cell turns on
+        (off, the gate is ``tests/test_memobs.py::
+        test_memobs_off_gate_counts_nothing``).  It reads K/V blocks:
         a model without a K/V group has nothing for it."""
         cache = self.cache
         if cache is None:
@@ -1668,10 +1659,6 @@ class LLMEngine:
     def _decode_body_plain(self, rows) -> tuple:
         n = len(rows)
         mon = monitor.enabled()
-        # launch accounting (ISSUE 12): every jitted dispatch this step
-        # records its cache key; the gauge is the LIVE twin of the
-        # round-2 hand count — len() only, never iterated
-        self._launches_this_step = set() if mon else None
         # ONE fixed shape (max_num_seqs) serves every batch composition:
         # no recompile when the running-request count changes
         bb = self.scheduler.max_num_seqs
@@ -1679,8 +1666,6 @@ class LLMEngine:
             inputs = self._decode_inputs(rows, [()] * n, bb, 1)
             lens = inputs[2]
             fn = self._get_ragged_exec(bb, 1)
-            if mon:
-                self._launches_this_step.add(("ragged", bb, 1))
             if self._settle_each_step:
                 # nothing is ever in flight: the host holds every token
                 logits, kv_out, stats = self._run(
@@ -1689,8 +1674,6 @@ class LLMEngine:
                 # the step's one upload: the feed program takes every
                 # host array, puts the token of the step in flight where
                 # a row rode it, and hands all of them on as device arrays
-                if mon:
-                    self._launches_this_step.add(("feed", bb))
                 inputs = self._run(
                     self._get_feed_exec(bb), self._in_flight_outputs()[0],
                     self._feed_sources(rows, bb), *inputs)
@@ -1716,8 +1699,6 @@ class LLMEngine:
             waste = (bb - n) / max(bb, 1)
             self._m_pad_rows.set(waste)
             self._m_pad_toks.set(waste)
-            self._m_kernels.set(len(self._launches_this_step))
-            self._launches_this_step = None
         return arrays
 
     def _in_flight_outputs(self) -> tuple:
@@ -1838,15 +1819,12 @@ class LLMEngine:
         same TWO program launches as plain decode."""
         n = len(rows)
         mon = monitor.enabled()
-        self._launches_this_step = set() if mon else None
         cw = self.spec_tokens + 1      # verify chunk width, fixed
         bb = self.scheduler.max_num_seqs
         with mtrace.phase("engine/prepare"):
             toks, pos0, lens, tables, slots, srows = self._decode_inputs(
                 rows, drafts, bb, cw)
             fn = self._get_verify_exec(bb, cw)
-            if mon:
-                self._launches_this_step.add(("verify", bb, cw))
             logits0, greedy, kv_out = self._run(
                 fn, self._param_arrays(), self._kv_flat(),
                 toks, pos0, lens, tables, slots, srows)
@@ -1859,8 +1837,6 @@ class LLMEngine:
             real_q = n + sum(len(d) for d in drafts)
             self._m_pad_rows.set((bb - n) / max(bb, 1))
             self._m_pad_toks.set((bb * cw - real_q) / max(bb * cw, 1))
-            self._m_kernels.set(len(self._launches_this_step))
-            self._launches_this_step = None
         return arrays[:3] + (greedy,)
 
     def _emit_spec(self, rows, drafts, toks, new_keys, greedy_h,
@@ -1965,10 +1941,6 @@ class LLMEngine:
                 topp[i] = p.top_p
             self._m_sampler[_sampler_path(ds, topk, topp)].inc()
             fn = self._get_sample_exec(bb)
-            if self._launches_this_step is not None:   # decode-step launch
-                # accounting only; the prefill path samples too but is not
-                # the steady-state loop the kernel count instruments
-                self._launches_this_step.add(("sample", bb))
             return self._run(
                 fn, logits, self._in_flight_outputs()[1],
                 self._feed_sources(rows, bb), keys, ds, temp, topk, topp)
@@ -2002,197 +1974,6 @@ class LLMEngine:
                 # draft reservation back
                 self.kv.truncate_to(req.req_id, req.total_len)
         return emitted
-
-    # -- perf attribution ---------------------------------------------------
-
-    def decode_breakdown(self, reps: int = 2) -> dict:
-        """Roofline attribution of the decode step at this engine's LIVE
-        shapes (ISSUE 6 / ROADMAP item 1's targeting data).
-
-        The production decode program fuses block gather, attention and
-        cache update into one XLA executable, so their split cannot be
-        observed in situ; this runs each named segment as its own
-        compiled program over the live KV pools — properly synced,
-        best-of-``reps`` — and attributes each against its own XLA
-        cost-analysis prediction via ``monitor.perf.measure``.  Also
-        measures the real fused step program (``decode:step``) so the
-        segment sum can be compared against what fusion actually buys.
-
-        Returns ``{segment: perf-record dict}`` plus ``"worst"``: the
-        segment with the lowest achieved-vs-optimal ratio — the next
-        kernel to rewrite.  Segment arithmetic mirrors
-        ``ops.paged_attention`` exactly; numbers are attribution
-        estimates (the fused program may never materialize the gather),
-        which is precisely their job.
-
-        The dict also carries ``"ragged_fused"`` — the fused
-        update+attention program of `ops.ragged_paged_attention` per
-        layer — so the before-side trio
-        (block_gather/attention/cache_update) and the after-side fusion
-        sit in ONE report and the fusion win is readable as
-        ``ragged_fused.wall_time_s`` vs the trio's sum.
-        """
-        if len(self.caches) > 1 or self.states \
-                or self.form.layer_specs[0].latent \
-                or self.form.layer_specs[0].sparse \
-                or self.form.layer_specs[0].num_heads \
-                != self.form.layer_specs[0].num_kv_heads:
-            raise ValueError(
-                "decode_breakdown covers one cache group of dense layers "
-                "with as many K/V heads as query heads")
-        L = len(self.form.layer_specs)
-        nh = self.form.layer_specs[0].num_heads
-        hd = self.form.layer_specs[0].head_dim
-        bb = self.scheduler.max_num_seqs    # the LIVE decode batch width
-        s_pad = self.blocks_per_seq * self.cache.block_size
-        num_slots = self.cache.num_slots
-        wdtype = self.form.dtype
-        kv_flat = self._kv_flat()
-        tables = (jnp.arange(bb * self.blocks_per_seq, dtype=jnp.int32)
-                  % max(self.cache.num_blocks, 1)).reshape(
-            bb, self.blocks_per_seq)
-        pos0 = jnp.full((bb,), s_pad - 1, jnp.int32)
-        slots = (jnp.arange(bb, dtype=jnp.int32) * self.cache.block_size
-                 % num_slots).reshape(bb, 1)
-        q = jnp.zeros((bb, 1, nh, hd), wdtype)
-        rows = jnp.zeros((bb, 1, nh, hd), wdtype)
-        quant = bool(self._kv_quant)
-        stride = 4 if quant else 2
-
-        from ..ops.paged_attention import (paged_gather_kv_arrays,
-                                           quantized_gather_kv_arrays)
-
-        def gather_fn(kv, tbl):
-            acc = jnp.float32(0.0)
-            for l in range(L):
-                part = kv[stride * l:stride * (l + 1)]
-                if quant:
-                    kg = quantized_gather_kv_arrays(part[0], part[2], tbl)
-                    vg = quantized_gather_kv_arrays(part[1], part[3], tbl)
-                else:
-                    kg = paged_gather_kv_arrays(part[0], tbl, nh)
-                    vg = paged_gather_kv_arrays(part[1], tbl, nh)
-                acc += jnp.sum(kg.astype(jnp.float32)) \
-                    + jnp.sum(vg.astype(jnp.float32))
-            return acc
-
-        # one layer's gathered view feeds the attention segment for all L
-        # iterations (per-iteration q offsets defeat CSE, so every layer
-        # pays its reads/FLOPs in the cost model and on the device)
-        if quant:
-            kg0 = quantized_gather_kv_arrays(kv_flat[0], kv_flat[2], tables)
-            vg0 = quantized_gather_kv_arrays(kv_flat[1], kv_flat[3], tables)
-        else:
-            kg0 = paged_gather_kv_arrays(kv_flat[0], tables, nh)
-            vg0 = paged_gather_kv_arrays(kv_flat[1], tables, nh)
-
-        def attention_fn(q_, kg, vg, pos0_):
-            import math as _math
-
-            scale = 1.0 / _math.sqrt(hd)
-            acc = jnp.float32(0.0)
-            k_pos = jnp.arange(s_pad, dtype=jnp.int32)
-            for l in range(L):
-                ql = q_ + jnp.asarray(l, q_.dtype)
-                logits = jnp.einsum(
-                    "bqhd,bkhd->bhqk", ql, kg,
-                    preferred_element_type=jnp.float32) * scale
-                causal = k_pos[None, None, :] <= pos0_[:, None, None]
-                logits = jnp.where(causal[:, None], logits, _NEG_INF)
-                probs = jax.nn.softmax(logits, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(vg.dtype),
-                               vg)
-                acc += jnp.sum(o.astype(jnp.float32))
-            return acc
-
-        def update_fn(kv, rows_, slots_):
-            out = list(kv)
-            for l in range(L):
-                if quant:
-                    k2, ks2 = quantized_cache_update_arrays(
-                        kv[4 * l], kv[4 * l + 2], rows_, slots_)
-                    v2, vs2 = quantized_cache_update_arrays(
-                        kv[4 * l + 1], kv[4 * l + 3], rows_, slots_)
-                    out[4 * l:4 * l + 4] = [k2, v2, ks2, vs2]
-                else:
-                    out[2 * l] = paged_cache_update_arrays(
-                        kv[2 * l], rows_, slots_)
-                    out[2 * l + 1] = paged_cache_update_arrays(
-                        kv[2 * l + 1], rows_, slots_)
-            return tuple(out)
-
-        kv_copy = tuple(jnp.array(a, copy=True) for a in kv_flat)
-        out = {
-            "block_gather": mperf.measure(
-                gather_fn, kv_flat, tables,
-                label="decode:block_gather", reps=reps),
-            "attention": mperf.measure(
-                attention_fn, q, kg0, vg0, pos0,
-                label="decode:attention", reps=reps),
-            "cache_update": mperf.measure(
-                update_fn, kv_copy, rows, slots,
-                label="decode:cache_update", reps=reps,
-                donate_argnums=(0,)),
-        }
-        lens = jnp.full((bb,), s_pad, jnp.int32)
-
-        # the ISSUE-8 after-side: ONE fused program per layer doing
-        # update + attention (+ int8 dequant at the loads) — measured
-        # against the same roofline as the before-side trio above
-        def ragged_fn(kv, q_, rows_, slots_):
-            kvo = list(kv)
-            acc = jnp.float32(0.0)
-            for l in range(L):
-                ql = q_ + jnp.asarray(l, q_.dtype)   # defeat CSE
-                part = kv[stride * l:stride * (l + 1)]
-                if quant:
-                    o, k2, v2, ks2, vs2 = ragged_paged_attention_arrays(
-                        ql, rows_, rows_, part[0], part[1], tables,
-                        pos0, lens, slots_,
-                        k_scales=part[2], v_scales=part[3])
-                    kvo[stride * l:stride * (l + 1)] = [k2, v2, ks2, vs2]
-                else:
-                    o, k2, v2 = ragged_paged_attention_arrays(
-                        ql, rows_, rows_, part[0], part[1], tables,
-                        pos0, lens, slots_)
-                    kvo[stride * l:stride * (l + 1)] = [k2, v2]
-                acc += jnp.sum(o.astype(jnp.float32))
-            return tuple(kvo), acc
-
-        kv_copy_r = tuple(jnp.array(a, copy=True) for a in kv_flat)
-        out["ragged_fused"] = mperf.measure(
-            ragged_fn, kv_copy_r, q, rows, slots,
-            label="decode:ragged_fused", reps=reps,
-            donate_argnums=(0,),
-            rearm=lambda args, o: (o[0],) + args[1:])
-        # the real step programs, measured as compiled (donated pools
-        # ping-ponged through the output so the engine's live cache is
-        # never consumed)
-        toks = jnp.zeros((bb, 1), jnp.int32)
-        kv_copy2 = tuple(jnp.array(a, copy=True) for a in kv_flat)
-        out["step"] = mperf.measure(
-            self._get_ragged_exec(bb, 1),
-            self._param_arrays(), kv_copy2, toks, pos0, lens, (tables,),
-            (slots,), label="decode:step", reps=reps,
-            rearm=lambda args, o: args[:1] + (o[1],) + args[2:])
-        # every row greedy, as the rows above: the program's argmax path
-        logits = jnp.zeros((bb, self.form.vocab_size), jnp.float32)
-        out["sampler"] = mperf.measure(
-            self._get_sample_exec(bb),
-            logits, self._no_keys, jnp.full((bb,), -1, jnp.int32),
-            jnp.zeros((bb, 2), jnp.uint32), jnp.zeros((bb,), bool),
-            jnp.ones((bb,), jnp.float32),
-            jnp.zeros((bb,), jnp.int32), jnp.ones((bb,), jnp.float32),
-            label="decode:sampler_exec", reps=reps)
-        # NOT "decode:sampler": the in-situ segment record of that name
-        # has no cost analysis, so _match_record would merge this
-        # compiled program's flops into its host-loop-inflated walls
-        ranked = [(name, d["achieved_vs_optimal"])
-                  for name, d in out.items()
-                  if name != "step" and d.get("achieved_vs_optimal")]
-        out["worst"] = (min(ranked, key=lambda kv_: kv_[1])[0]
-                        if ranked else None)
-        return out
 
     # -- array plumbing -----------------------------------------------------
 

@@ -25,26 +25,17 @@ request's queue/prefill/decode spans are parent-linked under a single
 trace_id, and live endpoint responses; it prints the TTFT/TPOT
 percentiles plus a sample request trace.
 
-Perf mode (the monitor v3 perf-attribution layer end-to-end):
+Perf mode (the serving loop's host phases and goodput end-to-end):
 
     python scripts/serve_smoke.py --perf
 
 --perf enables PTPU_PERF accounting and asserts the ISSUE-6 acceptance
 surface: the serving step's host phases (serving/host_time, always on)
-are populated, `LLMEngine.decode_breakdown()` attributes the
-fused step's segments (block gather/attention/cache update/sampler)
-against their rooflines and names the worst one, and — combined with
---trace's live endpoint — /metrics exposes perf_mfu, perf_hbm_headroom
-and per-fn flops/bytes; it prints the ranked attribution table.
-
---perf additionally asserts the ISSUE-12 "program microscope" surface:
-`serving/kernels_per_step` is populated and stays FLAT across a 3→5
-batch crossing with zero fresh compiles and zero new
+are populated and `perf.report()` prints them, a 3→5 batch crossing
+makes zero fresh compiles and zero new
 `jit/recompile_cause{fn=serving:*}` entries (the ragged acceptance
-invariant), `serving/padding_waste` + `serving/goodput_tokens_per_s`
-are live, and `perf.hlo_report("decode:step")` names the compiled
-decode program's top fusions with flops/bytes (degrading to
-'unavailable' on backends without `as_text`, never garbage).
+invariant), and `serving/padding_waste` +
+`serving/goodput_tokens_per_s` are live.
 
 API mode (the ISSUE-19 OpenAI-compatible front door end-to-end):
 
@@ -68,8 +59,8 @@ ring answer on the live endpoint, a tiny-pool twin engine driven into
 an eviction storm produces EXACTLY ONE rate-limited kv_pressure flight
 dump whose ranked holders name the actual top block-holding
 request/tenant, an admission failure inside the cooldown is suppressed
-(never a second dump), and compiles + kernels_per_step stay FLAT under
-both pressure events.
+(never a second dump), and compiles stay FLAT under both pressure
+events.
 
 tests/test_serving.py runs the plain mode, tests/test_lowbit.py the
 quantized one, tests/test_trace.py + test_perf.py lean on the combined
@@ -111,7 +102,8 @@ def main():
                          "assert/print the v2 observability surface")
     ap.add_argument("--perf", action="store_true",
                     help="enable perf attribution and assert/print the "
-                         "decode segment breakdown + roofline table")
+                         "host-phase table, goodput gauges and compiles "
+                         "FLAT across a batch crossing")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="assert the ISSUE-15 automatic-prefix-caching "
                          "surface (hits, hit_tokens=(N-1)*prefix_len, "
@@ -197,7 +189,7 @@ def main():
               f"greedy agreement {agree:.2f} vs fp")
         del dense, qdense
     # max_num_seqs=8: headroom for the --perf leg's 3→5 batch crossing
-    # (the ISSUE-12 kernels_per_step FLAT assertion needs 5 live rows)
+    # (the compiles FLAT assertion needs 5 live rows)
     engine = LLMEngine(model, EngineConfig(
         block_size=16, max_num_seqs=8, kv_cache_dtype=args.kv_cache_dtype,
         metrics_port=0 if (args.trace or args.slo or args.api
@@ -257,12 +249,10 @@ def main():
 
 
 def check_perf(engine, snap, cfg):
-    """ISSUE 6 acceptance: the decode-segment breakdown is populated, the
-    fused-step attribution names a worst segment, and the perf/* surface
-    (segments histogram + per-fn accounting + MFU) is live.  Extended by
-    ISSUE 12 with the program-microscope surface (kernels_per_step FLAT
-    across a batch crossing, padding/goodput gauges, hlo_report)."""
-    from paddle_tpu.monitor import hlo, perf
+    """The serving loop's host phases are populated and reported, the
+    padding/goodput gauges are live, and compiles stay FLAT across a
+    batch crossing."""
+    from paddle_tpu.monitor import perf
 
     # in-situ host phases: every step observed each phase of the serving
     # loop into serving/host_time (always on; nothing is synced for it)
@@ -274,67 +264,22 @@ def check_perf(engine, snap, cfg):
         assert h and h["count"] > 0, (f"host phase {name} not populated",
                                       sorted(phases))
 
-    # off-line attribution of the fused step at live shapes
-    bd = engine.decode_breakdown(reps=1)
-    # ISSUE 8: the fused update+attention program must sit in the same
-    # report as the before-side trio it replaces
-    segs = ("block_gather", "attention", "cache_update", "step", "sampler",
-            "ragged_fused")
-    rec = perf.get("decode:ragged_fused")
-    assert rec is not None and rec.calls > 0, (
-        "decode:ragged_fused segment not populated")
-    print(f"fused update+attention "
-          f"{bd['ragged_fused']['wall_time_s']*1e3:.2f} ms vs "
-          f"gather+attn+update "
-          f"{(bd['block_gather']['wall_time_s'] + bd['attention']['wall_time_s'] + bd['cache_update']['wall_time_s'])*1e3:.2f} ms")
-    for name in segs:
-        assert name in bd and bd[name]["wall_time_s"] > 0, (name, bd.get(name))
-    if all(bd[name]["available"] for name in segs):
-        assert bd["worst"] in segs, bd["worst"]
-        print(f"decode breakdown: worst achieved-vs-optimal segment is "
-              f"'{bd['worst']}' "
-              f"({bd[bd['worst']]['achieved_vs_optimal']:.3f} of roofline)")
-    else:   # stat-less backend: degraded but never garbage
-        assert all(bd[name]["mfu"] is None for name in segs
-                   if not bd[name]["available"])
-        print("decode breakdown: cost analysis unavailable on this "
-              "backend (ranking degraded to wall times)")
-
     table = perf.report()
-    assert "perf attribution" in table and "decode:step" in table, table
     assert "serving host phases" in table and "engine/prepare" in table, \
         table
     print(table)
 
-    # ISSUE 12 (a): the program microscope on the live decode program —
-    # decode_breakdown's measure() captured "decode:step" through the
-    # perf AOT path, so its optimized HLO is already parsed
-    an = hlo.get("decode:step")
-    assert an is not None, "decode:step HLO was not captured"
-    if an["available"]:
-        assert an["ops"] > 0 and an["flops"] > 0, an
-        rep = perf.hlo_report("decode:step", top=5)
-        assert "hlo[decode:step]" in rep, rep
-        if an["fusions"]:
-            assert "fusion" in rep, rep
-        print(rep)
-    else:   # backend without as_text: degraded, never garbage
-        assert "unavailable" in perf.hlo_report("decode:step")
-        print("hlo: decode:step analysis unavailable on this backend")
-
-    # ISSUE 12 (b): launch accounting populated by the main run...
+    # goodput accounting populated by the main run...
     snap = monitor.snapshot()
-    kern = snap.get("serving/kernels_per_step")
-    assert kern and kern > 0, kern
     pad = snap.get("serving/padding_waste")
     assert pad and "kind=rows" in pad and "kind=tokens" in pad, pad
     good = snap.get("serving/goodput_tokens_per_s")
     assert good and good > 0, good
 
     # ...and FLAT across a 3→5 batch crossing: zero fresh compiles, zero
-    # new serving recompile causes, same kernels-per-step (the ragged
-    # fixed-shape invariant; prompt lengths reuse already-compiled
-    # prefill programs so the cause count isolates the decode path)
+    # new serving recompile causes (the ragged fixed-shape invariant;
+    # prompt lengths reuse already-compiled prefill programs so the
+    # cause count isolates the decode path)
     def serving_causes(s):
         v = s.get("jit/recompile_cause") or {}
         return sum(n for k, n in sorted(v.items()) if "serving:" in k)
@@ -346,30 +291,14 @@ def check_perf(engine, snap, cfg):
                 for n in (4, 6, 4, 6, 4)]
     engine.generate(prompts5, SamplingParams(max_new_tokens=4))
     snap = monitor.snapshot()
-    assert snap.get("serving/kernels_per_step") == kern, (
-        kern, snap.get("serving/kernels_per_step"))
     d_compiles = sum(snap["serving/compiles"].values()) - compiles_before
     d_causes = serving_causes(snap) - causes_before
     assert d_compiles == 0, f"{d_compiles} fresh compiles at the crossing"
     assert d_causes == 0, f"{d_causes} new serving recompile causes"
-    print(f"kernels_per_step={kern:.0f} FLAT across 3→5 crossing "
+    print(f"compiles FLAT across 3→5 crossing "
           f"(0 compiles, 0 causes); padding rows="
           f"{snap['serving/padding_waste']['kind=rows']:.3f}, goodput="
           f"{snap['serving/goodput_tokens_per_s']:.1f} tok/s")
-
-    # live perf gauges ride the same endpoint as the rest of the monitor
-    if getattr(engine, "metrics_server", None) is not None:
-        import urllib.request
-
-        txt = urllib.request.urlopen(engine.metrics_server.url + "/metrics",
-                                     timeout=10).read().decode()
-        assert "perf_mfu" in txt, "perf_mfu missing from /metrics"
-        for want in ("perf_flops", "perf_bytes", "perf_hbm_headroom"):
-            if want not in txt:
-                # stat-less backends may omit per-fn analysis gauges, but
-                # then the unavailability marker must be exported instead
-                assert "perf_analysis_unavailable" in txt, want
-        print("endpoint: perf/* gauges exported")
 
 
 def check_prefix_spec(model, cfg, prefix, spec):
@@ -692,8 +621,8 @@ def check_memobs(engine, model, cfg):
     kv_pressure flight dump whose ranked holders name the actual top
     block-holding request/tenant; an admission failure inside the
     cooldown is a suppressed trigger, never a second dump — with zero
-    fresh compiles and kernels_per_step FLAT throughout (neither
-    pressure path reaches prefill on a new shape)."""
+    fresh compiles throughout (neither pressure path reaches prefill on
+    a new shape)."""
     import glob
     import json
     import urllib.request
@@ -744,7 +673,6 @@ def check_memobs(engine, model, cfg):
             # observations
         snap_ = monitor.snapshot()
         compiles0 = sum(snap_["serving/compiles"].values())
-        kern0 = snap_.get("serving/kernels_per_step")
         steps = 10
         while eng.has_unfinished() and steps < 300:
             eng.step()
@@ -795,14 +723,12 @@ def check_memobs(engine, model, cfg):
             "admission failure inside the cooldown was not rate-limited")
         d_compiles = sum(snap_["serving/compiles"].values()) - compiles0
         assert d_compiles == 0, f"{d_compiles} compiles under pressure"
-        assert snap_.get("serving/kernels_per_step") == kern0, (
-            kern0, snap_.get("serving/kernels_per_step"))
     finally:
         eng2.release_request(bid)
     print(f"memobs: eviction storm -> one kv_pressure dump, top holder "
           f"rid={rids[0]} tenant=acme ({top['blocks']} blocks); "
-          f"admission failure inside cooldown suppressed; compiles + "
-          f"kernels_per_step FLAT under pressure")
+          f"admission failure inside cooldown suppressed; "
+          f"compiles FLAT under pressure")
 
 
 def check_trace(engine, snap, n_requests):

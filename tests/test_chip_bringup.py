@@ -162,31 +162,6 @@ def test_set_device_tpu_needs_an_accelerator():
 
 # -- entry points -----------------------------------------------------------
 
-def test_bench_ladder_exits_nonzero_on_a_failed_config(monkeypatch,
-                                                        tmp_path, capsys):
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(ROOT)
-
-    def fake_run(cmd, **kw):
-        ok = cmd[-1] == "good"
-        return types.SimpleNamespace(
-            returncode=0 if ok else 1, stderr="boom",
-            stdout='{"metric": "good", "value": 1.0}\n' if ok else "")
-
-    monkeypatch.setattr(bench, "LADDER", {"good": None, "bad": None})
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--ladder"])
-    monkeypatch.chdir(tmp_path)
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code not in (0, None)
-    assert "bad" in str(exc.value.code)
-    assert '"error": "rc=1"' in capsys.readouterr().out
-
-
 def _smoke(cwd, script):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     t0 = time.monotonic()

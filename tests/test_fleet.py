@@ -3,8 +3,8 @@
 Subprocess-free fast tier: the exposition parser and the
 merge-round-trip exactness pin (scrape → parse → merge → re-export ==
 sum/union of the sources, histograms included), the trace
-inject/extract propagation (incl. the rpc frame carrying it and the
-<1 µs disabled budget), the store-registration key format, the rollup
+inject/extract propagation (incl. the rpc frame carrying it, and the
+disabled pair returning None and recording nothing), the store-registration key format, the rollup
 state machine driven by a fake scraper (healthy → stalled → down, with
 flight-dump harvesting on transition), and the endpoint surface
 (/healthz identity fields, /flight/latest).
@@ -99,25 +99,22 @@ def test_inject_outside_any_span_is_none():
 
 
 def test_inject_extract_disabled_and_under_budget():
-    """Disabled propagation hooks share the disabled-span budget: the
-    rpc hot path runs inject+extract per call, so the pair must stay
-    < 1 µs with PTPU_TRACE=0 (the bench trace_overhead gate's unit
-    twin)."""
+    """Disabled propagation hooks return at the gate: the rpc hot path
+    runs inject+extract per call, so with PTPU_TRACE=0 the pair hands
+    back None, even inside a span opened before tracing went off, and
+    records nothing.  The per-call time bound lives in
+    tests/test_disabled_overhead.py (slow tier)."""
+    open_span = trace.start_span("t/open")   # a live context to leak
     trace.enable(False)
     try:
         assert trace.inject() is None
+        assert trace.inject(open_span) is None
         assert trace.extract("ptpu1;a;b") is None   # receiver-side gate
-        n, per_call = 50_000, float("inf")
-        for _ in range(4):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                hdr = trace.inject()
-                trace.extract(hdr)
-            per_call = min(per_call, (time.perf_counter() - t0) / n)
+        assert trace.extract(trace.inject()) is None
     finally:
         trace.enable(True)
-    assert per_call < 1e-6, (
-        f"disabled inject+extract costs {per_call*1e9:.0f} ns")
+        open_span.end()
+    assert trace.trace_ids() == [open_span.trace_id]   # only the span
 
 
 def _rpc_probe():

@@ -249,7 +249,7 @@ class TestLiveCapture:
         # the acceptance shape: a jitted program on THIS host — XLA-CPU
         # as_text parses, fusions are named with flops/bytes, and the
         # gauges ride the registry (perf.measure is the same AOT capture
-        # path the jit hook and decode_breakdown use)
+        # path the jit hook and CostModel.profile_measure use)
         import jax.numpy as jnp
 
         perf.enable(True)

@@ -2,7 +2,8 @@
 monitor.h StatRegistry + STAT_INT gauges; ISSUE 1 tentpole).
 
 Covers the registry/metric API, the three exporters, the PTPU_MONITOR
-gate (including the <1 µs disabled-overhead guard), the no-jax import
+gate (a disabled metric records nothing; its per-call time is bounded
+in tests/test_disabled_overhead.py), the no-jax import
 constraint, and the end-to-end acceptance smoke: a 2-stage pipeline +
 MoE + autotune run on the CPU mesh must populate the pipeline/moe/
 autotune/device series and export valid Prometheus text.
@@ -297,20 +298,20 @@ def test_env_gate_refresh(monkeypatch):
 
 
 def test_disabled_overhead_guard():
-    """A disabled counter increment must stay < 1 µs amortized so
-    PTPU_MONITOR=0 can never regress the hot path."""
+    """A disabled counter increment returns at the gate: it takes no
+    lock and leaves the counter's state as it was, so PTPU_MONITOR=0
+    can never regress the hot path.  The per-call time bound lives in
+    tests/test_disabled_overhead.py (slow tier)."""
     monitor.enable(False)
     try:
         c = monitor.counter("t/overhead")
-        n = 200_000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            c.inc()
-        per_call = (time.perf_counter() - t0) / n
+        before = (c.value, c._touched, dict(c._children))
+        assert c.inc() is c
+        assert (c.value, c._touched, dict(c._children)) == before
     finally:
         monitor.enable(True)
     assert c.value == 0
-    assert per_call < 1e-6, f"disabled inc costs {per_call*1e9:.0f} ns"
+    assert "t/overhead" not in monitor.snapshot()
 
 
 def test_monitor_imports_without_jax():

@@ -1,29 +1,21 @@
 """monitor.perf — MFU/roofline perf attribution (ISSUE 6 tentpole).
 
-Covers the PTPU_PERF gate (<1 µs disabled-overhead guard mirroring the
-PR-1/PR-5 guards), cost-analysis normalization (non-scalar entries
+Covers the PTPU_PERF gate (a disabled segment is the shared no-op
+object and records nothing; its per-call time is bounded by
+tests/test_disabled_overhead.py), cost-analysis normalization (non-scalar entries
 counted, never silently dropped — the CostModel bug the module dedupes
 away), graceful degradation on stat-less backends (every derived figure
 reads None/'unavailable', never garbage MFU), the jit CompiledFunction
 perf hook + memory_analysis signature cache, the segment timers, the
-`measure()` backend shared by CostModel.profile_measure, the report
-table, and the BENCH_HISTORY.jsonl ledger + `check_bench_regression.py
---history` trailing-median gate.
+`measure()` backend shared by CostModel.profile_measure, and the report
+table.
 """
-import json
-import pathlib
-import subprocess
-import sys
-import time
-
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.monitor import perf
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(autouse=True)
@@ -42,22 +34,16 @@ def _fresh_perf():
 # -- gate / overhead --------------------------------------------------------
 
 def test_disabled_overhead_guard():
+    """A disabled segment is the shared no-op object: no allocation, no
+    clock read, nothing recorded.  The per-call time bound lives in
+    tests/test_disabled_overhead.py (slow tier)."""
     perf.enable(False)
-    n = 20_000
-
-    def run():
-        t0 = time.perf_counter()
-        for _ in range(n):
-            with perf.segment("t", "x"):
-                pass
-        return (time.perf_counter() - t0) / n
-
-    # min-of-5: a disabled segment is an object + ctx manager (heavier
-    # than the PR-1 counter inc), so give scheduler noise on a shared
-    # host more windows to miss at least one run
-    per_call = min(run() for _ in range(5))
-    assert per_call < 1e-6, f"disabled perf.segment costs {per_call*1e9:.0f}ns"
+    seg = perf.segment("t", "x")
+    assert seg is perf._NOOP_SEGMENT
+    with seg as s:
+        assert s.sync(np.zeros(2)) is perf._NOOP_SEGMENT
     assert perf.get("t:x") is None      # and records nothing
+    assert perf.records() == []
 
 
 def test_enable_refresh_roundtrip(monkeypatch):
@@ -348,114 +334,3 @@ def test_report_ranks_and_names_worst():
 def test_report_empty_when_nothing_recorded():
     assert perf.report() == ""
 
-
-# -- bench ledger + history gate --------------------------------------------
-
-def test_bench_emit_appends_tagged_ledger(tmp_path, monkeypatch):
-    monkeypatch.setenv("PTPU_BENCH_HISTORY", str(tmp_path / "h.jsonl"))
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    line = bench._emit("unit_test_metric_cpu_smoke", 123.0, "tokens/sec", 100.0)
-    assert line["vs_baseline"] == pytest.approx(1.23)
-    recs = [json.loads(ln) for ln in
-            (tmp_path / "h.jsonl").read_text().splitlines()]
-    assert len(recs) == 1
-    rec = recs[0]
-    assert rec["metric"] == "unit_test_metric_cpu_smoke"
-    assert rec["cpu_smoke"] is True
-    assert rec["host"] and rec["backend"]
-    assert "ts" in rec
-
-
-def _run_history_gate(path, *extra):
-    return subprocess.run(
-        [sys.executable, str(REPO / "tools" / "check_bench_regression.py"),
-         "--history", str(path), *extra],
-        capture_output=True, text=True)
-
-
-def _write_ledger(path, values, metric="m_tokens_per_sec", host="h1",
-                  backend="cpu", **kw):
-    with open(path, "a") as f:
-        for v in values:
-            f.write(json.dumps({"metric": metric, "value": v,
-                                "unit": "tokens/sec", "host": host,
-                                "backend": backend, **kw}) + "\n")
-
-
-def test_history_gate_pass_fail_and_direction(tmp_path):
-    led = tmp_path / "hist.jsonl"
-    _write_ledger(led, [100, 102, 98, 101, 100])    # current 100 vs med ~100.5
-    r = _run_history_gate(led)
-    assert r.returncode == 0, r.stdout
-    _write_ledger(led, [60])                        # -40%: regression
-    r = _run_history_gate(led)
-    assert r.returncode == 1 and "FAIL" in r.stdout
-    # lower-is-better: overhead RISING fails, dropping passes
-    led2 = tmp_path / "ov.jsonl"
-    _write_ledger(led2, [1.0, 1.1, 0.9, 1.0, 4.0],
-                  metric="step_overhead_pct")
-    r = _run_history_gate(led2)
-    assert r.returncode == 1, r.stdout
-    _write_ledger(led2, [0.5], metric="step_overhead_pct")
-    r = _run_history_gate(led2)
-    assert r.returncode == 0, r.stdout
-
-
-def test_history_gate_lanes_and_smoke(tmp_path):
-    led = tmp_path / "hist.jsonl"
-    _write_ledger(led, [100, 100, 100, 100])
-    # same metric, terrible value, DIFFERENT host: new lane, never gates
-    _write_ledger(led, [5], host="h2")
-    r = _run_history_gate(led)
-    assert r.returncode == 0 and "lane too young" in r.stdout
-    # smoke lines report but don't gate without --gate-smoke
-    led3 = tmp_path / "smoke.jsonl"
-    _write_ledger(led3, [100, 100, 100, 5], metric="m_cpu_smoke",
-                  cpu_smoke=True)
-    r = _run_history_gate(led3)
-    assert r.returncode == 0 and "skip" in r.stdout
-    r = _run_history_gate(led3, "--gate-smoke")
-    assert r.returncode == 1
-    # backend_unavailable priors are excluded from the lane
-    led4 = tmp_path / "out.jsonl"
-    _write_ledger(led4, [1, 1], backend_unavailable=True)
-    _write_ledger(led4, [100])
-    r = _run_history_gate(led4)
-    assert r.returncode == 0 and "lane too young" in r.stdout
-
-
-def test_history_gate_stale_and_naive_timestamps(tmp_path):
-    import datetime
-
-    led = tmp_path / "hist.jsonl"
-    # a regressed run whose newest entry is days old: it was NOT produced
-    # by this invocation — reported stale, skipped, exit 0
-    old = (datetime.datetime.now(datetime.timezone.utc)
-           - datetime.timedelta(hours=72)).isoformat(timespec="seconds")
-    _write_ledger(led, [100, 101, 99, 100])
-    _write_ledger(led, [10], ts=old)
-    r = _run_history_gate(led)
-    assert r.returncode == 0 and "stale" in r.stdout, r.stdout
-    # naive ISO timestamps (no offset — other tooling) must not crash
-    # the gate: treated as UTC, so a fresh naive ts still gates
-    naive_now = datetime.datetime.utcnow().isoformat(timespec="seconds")
-    led2 = tmp_path / "naive.jsonl"
-    _write_ledger(led2, [100, 101, 99, 100])
-    _write_ledger(led2, [10], ts=naive_now)
-    r = _run_history_gate(led2)
-    assert r.returncode == 1 and "FAIL" in r.stdout, \
-        r.stdout + r.stderr
-
-
-def test_history_gate_corrupt_lines_skipped(tmp_path):
-    led = tmp_path / "hist.jsonl"
-    _write_ledger(led, [100, 101, 99, 100])
-    with open(led, "a") as f:
-        f.write('{"metric": "m_tokens_per_sec", "val')   # killed mid-write
-    _write_ledger(led, [100])
-    r = _run_history_gate(led)
-    assert r.returncode == 0, r.stdout

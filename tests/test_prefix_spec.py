@@ -385,7 +385,6 @@ class TestSpecEngineParity:
                              for n in ns]
             sp = SamplingParams(max_new_tokens=4)
             eng.generate(mk((4, 6, 4)), sp)        # warm: 3 rows
-            kern = monitor.gauge("serving/kernels_per_step").value
             snap = monitor.snapshot()
             compiles = sum(snap["serving/compiles"].values())
             causes = sum(v for k, v in sorted(
@@ -397,7 +396,6 @@ class TestSpecEngineParity:
             assert sum(v for k, v in sorted(
                 (snap.get("jit/recompile_cause") or {}).items())
                 if "serving:" in k) == causes
-            assert monitor.gauge("serving/kernels_per_step").value == kern
         finally:
             monitor.refresh()
 

@@ -612,9 +612,9 @@ class TestMonitorAndSmoke:
     def test_serve_smoke_script(self):
         # --trace: the ISSUE-5 observability acceptance (ttft/tpot
         # percentiles, parent-linked request trace, chrome export, live
-        # endpoint), --perf: the ISSUE-6 one (decode-segment
-        # breakdown populated, attribution table, perf/* gauges on the
-        # endpoint), and --prefix-cache --spec: the ISSUE-15 one
+        # endpoint), --perf: the host-phase table, padding/goodput
+        # gauges and compiles FLAT across a 3→5 batch crossing, and
+        # --prefix-cache --spec: the prefix and speculation one
         # (hit_tokens == (N-1)*prefix_len, accept_rate > 0 with >1
         # token per decode step, compiles FLAT across hit/miss and
         # spec rounds), and --slo: the ISSUE-16 one (deadline request
@@ -625,8 +625,7 @@ class TestMonitorAndSmoke:
         # burn), and --memobs: the ISSUE-20 one (/kv + /memory/timeline
         # live, an eviction storm yielding EXACTLY ONE rate-limited
         # kv_pressure dump naming the actual top holder, a suppressed
-        # admission-failure trigger, compiles + kernels_per_step FLAT
-        # under pressure) all assert in-script ON TOP of the plain smoke
+        # admission-failure trigger, compiles FLAT under pressure) all assert in-script ON TOP of the plain smoke
         # checks, so ONE subprocess covers every leg (tests/test_trace.py
         # and tests/test_perf.py lean on this invocation; tier-1 budget
         # leaves no room for a second engine-compiling subprocess)
@@ -647,9 +646,8 @@ class TestMonitorAndSmoke:
         assert "tokens/s" in proc.stdout
         assert "ttft:" in proc.stdout and "request 0 trace:" in proc.stdout
         assert "chrome trace:" in proc.stdout
-        assert "decode breakdown:" in proc.stdout
-        assert "perf attribution" in proc.stdout
-        assert "perf/* gauges exported" in proc.stdout
+        assert "serving host phases" in proc.stdout
+        assert "compiles FLAT across 3→5 crossing" in proc.stdout
         assert "prefix cache: hits=3 hit_tokens=96" in proc.stdout
         assert "compiles FLAT across hit/miss round" in proc.stdout
         assert "accept_rate=" in proc.stdout
@@ -671,7 +669,7 @@ class TestMonitorAndSmoke:
         assert "tenant=acme" in proc.stdout
         assert "admission failure inside cooldown suppressed" \
             in proc.stdout
-        assert "kernels_per_step FLAT under pressure" in proc.stdout
+        assert "compiles FLAT under pressure" in proc.stdout
 
 
 class TestPagedAttentionOp:

@@ -1,7 +1,8 @@
 """paddle_tpu.monitor v2 — span tracing, flight recorder, watchdog, live
 endpoint (ISSUE 5 tentpole).
 
-The bar: a disabled span costs < 1 µs (mirroring the PR-1 metric guard);
+The bar: a disabled span is the shared no-op span and records nothing
+(its per-call time is bounded in tests/test_disabled_overhead.py);
 context propagates across threads; a traced serving request decomposes
 into queue-wait → prefill → per-step decode spans whose durations sum to
 (approximately) the request's wall time, with `serving/ttft` and
@@ -127,21 +128,21 @@ def test_context_propagation_across_threads():
 
 
 def test_disabled_overhead_guard():
-    """A disabled span must stay < 1 µs amortized so PTPU_TRACE=0 can
-    never regress a hot path (the PR-1 guard, tracing edition)."""
+    """A disabled span is the shared no-op span: no allocation, no
+    clock read, nothing recorded, so PTPU_TRACE=0 can never regress a
+    hot path.  The per-call time bound lives in
+    tests/test_disabled_overhead.py (slow tier)."""
     trace.enable(False)
     try:
-        n, per_call = 50_000, float("inf")
-        for _ in range(4):           # min-of-rounds: a loaded shared
-            t0 = time.perf_counter()  # host must not flake the bound
-            for i in range(n):
-                with trace.span("t/overhead", step=i):
-                    pass
-            per_call = min(per_call, (time.perf_counter() - t0) / n)
+        sp = trace.span("t/overhead", step=1)
+        assert sp is trace._NULL
+        with sp as s:
+            assert s is trace._NULL
+            assert trace.current_span() is None   # nothing opened
     finally:
         trace.enable(True)
-    assert per_call < 1e-6, f"disabled span costs {per_call*1e9:.0f} ns"
-    assert trace.get_trace("t/overhead") == []   # nothing recorded
+    assert trace.trace_ids() == []                 # nothing recorded
+    assert flight.get_recorder().records() == []
 
 
 def test_disabled_records_nothing():
@@ -668,23 +669,21 @@ def test_api_pump_phases_and_submit_wait(model, eng):
 
 def test_phase_disabled_overhead_guard():
     """Monitor off, tracing off, no profiler session: a phase is the
-    no-op singleton, under the bound of the module's other guards."""
+    no-op singleton and records nothing (its per-call time is bounded
+    in tests/test_disabled_overhead.py)."""
     trace.enable(False)
     monitor.enable(False)
     try:
-        n, per_call = 50_000, float("inf")
-        for _ in range(4):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                with trace.phase("t/overhead"):
-                    pass
-            per_call = min(per_call, (time.perf_counter() - t0) / n)
+        ph = trace.phase("t/overhead")
+        assert ph is trace._NULL
+        with ph as p:
+            assert p is trace._NULL
     finally:
         monitor.enable(True)
         trace.enable(True)
-    assert per_call < 1e-6, f"disabled phase costs {per_call*1e9:.0f} ns"
     assert "phase=t/overhead" not in monitor.snapshot().get(
         trace.PHASE_METRIC, {})
+    assert trace.trace_ids() == []
 
 
 def test_phase_outside_a_span_opens_no_trace():

@@ -15,7 +15,7 @@ os.getenv / environ[...] / environ.setdefault / environ.pop`` call
 sites whose key is a full ``PTPU_*`` string literal.  The documented
 set is every ``PTPU_*`` token in the repo-root ``README.md``.  For the
 README→code direction, root-level driver scripts outside the analyzer's
-default scope (``bench.py`` etc.) and ``examples/`` are included via a
+default scope (``chip_smoke.py`` etc.) and ``examples/`` are included via a
 light text scan, so a flag read only there does not get flagged as
 phantom.
 
@@ -42,7 +42,7 @@ from ..core import Finding, Rule
 FLAG_RE = re.compile(r"PTPU_[A-Z0-9]+(?:_[A-Z0-9]+)*")
 ENV_CALL_LASTS = {"get", "getenv", "setdefault", "pop"}
 # root-level .py files + examples/ are outside the analyzer's default
-# scope but still read flags (bench.py's PTPU_BENCH_HISTORY), and shell
+# scope but still read flags (the examples' PTPU_FORCE_PLATFORM), and shell
 # CI lanes read flags too (run_ci.sh's PTPU_CHECK_BASE); scan them
 # textually for the README→code direction only
 EXTRA_SCAN_DIRS = ("", "examples", "tools", "scripts")

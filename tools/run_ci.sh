@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
-# CI entry point (reference capability: paddle_build.sh test stages +
-# tools/gen_ut_cmakelists.py tier metadata — here: pytest tiers + the
-# driver-shaped gates).
+# CI entry point (reference capability: Paddle's paddle_build.sh test
+# stages + gen_ut_cmakelists.py tier metadata — here: pytest tiers + the
+# entry() and multichip gates).  Performance is measured on the chip by
+# benchmark/run.py, one cell of BENCHMARK.json at a time.
 #
 #   tools/run_ci.sh fast    — "not slow" tier on the virtual 8-device CPU mesh
 #                             (includes the resilience suite + ptpu_check)
@@ -21,10 +22,7 @@
 #                             garble/stall/SIGKILL, asserting no-hang,
 #                             token-identity and zero KV leaks
 #   tools/run_ci.sh gates   — driver gates: compile-check entry() + the
-#                             8-device multichip dryrun + CPU bench smoke
-#   tools/run_ci.sh bench-check OLD.json NEW.json — perf regression gate
-#   tools/run_ci.sh bench-history [args] — gate the newest BENCH_HISTORY
-#                             ledger entries against their trailing median
+#                             8-device multichip dryrun
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -48,19 +46,6 @@ case "${1:-fast}" in
     # which rides the EXISTING test_serving.py smoke subprocess — no
     # second engine-compiling process in the fast lane
     python -m pytest tests/ -m "not slow" -q --ignore=tests/test_examples.py
-    # perf-history gate, CPU-smoke lane: the headline bench appends this
-    # host's run to BENCH_HISTORY.jsonl, then gates against the trailing
-    # median of SAME-host same-backend runs (a host change starts a fresh
-    # lane — reported, never failed).  Loose 50% tolerance: the CPU smoke
-    # config is tiny and shared-host noisy; it catches cliffs, the real
-    # lane in `gates` catches percent-level drift on chip hosts.
-    python bench.py
-    # router fan-out (ISSUE 17): host-side dispatch throughput over fake
-    # in-process replicas — backend-free, so the CPU lane IS the lane;
-    # self-asserts sticky routing actually engaged before emitting
-    python bench.py --config router_fanout
-    python tools/check_bench_regression.py --history BENCH_HISTORY.jsonl \
-      --gate-smoke --tolerance 0.50
     ;;
   full)
     python -m tools.ptpu_check --json-out /tmp/ptpu_check_report.json
@@ -104,36 +89,9 @@ print("entry() abstract eval:", jax.eval_shape(fn, *args))
 g.dryrun_multichip(8)
 print("gates OK")
 EOF
-    python bench.py
-    # ISSUE 12 launch-accounting lane: programs-per-decode-step +
-    # padding-waste, self-asserting the 3→5 crossing stays FLAT (lives
-    # here, NOT in fast — tier-1 room is scarce at ~790s of 870s)
-    python bench.py --config kernel_count
-    # ISSUE 15 serving-throughput lanes (same tier-placement logic):
-    # cold-vs-hot TTFT for a shared-prefix batch, and steady-state
-    # decode-step tokens/s spec-on vs spec-off (min/best-over-steps —
-    # whole-generate walls drift >50% on shared hosts)
-    python bench.py --config prefix_prefill
-    python bench.py --config spec_decode
-    # ISSUE 19 API front-door lane: seeded open-loop arrivals at rising
-    # QPS through a live ApiServer socket — goodput gates higher-is-
-    # better, the *_overhead_* TTFT/TPOT percentiles gate lower-is-better
-    python bench.py --config serving_load
-    # real-lane history gate: default 7% tolerance, smoke lines skipped
-    # (on a chip host the headline is the non-smoke metric and gates;
-    # after an outage fallback the smoke line is reported only)
-    python tools/check_bench_regression.py --history BENCH_HISTORY.jsonl
-    ;;
-  bench-check)
-    shift
-    python tools/check_bench_regression.py "$@"
-    ;;
-  bench-history)
-    shift
-    python tools/check_bench_regression.py --history BENCH_HISTORY.jsonl "$@"
     ;;
   *)
-    echo "usage: $0 {fast|full|lint|chaos|gates|bench-check OLD NEW|bench-history}" >&2
+    echo "usage: $0 {fast|full|lint|chaos|gates}" >&2
     exit 2
     ;;
 esac
