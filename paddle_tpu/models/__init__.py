@@ -20,6 +20,8 @@ from .mistral4 import (Mistral4Config, Mistral4ForCausalLM,
                        mistral4_test_config)
 from .brumby import BrumbyConfig, BrumbyForCausalLM, brumby_test_config
 from .keye import KeyeConfig, KeyeForCausalLM, keye_test_config
+from .falcon_h1 import (FalconH1Config, FalconH1ForCausalLM,
+                        falcon_h1_test_config)
 from .serving_form import (LatentSpec, LayerSpec, ServingForm, SparseSpec,
                            StateSpec)
 from .bert import BertConfig, BertModel, BertForSequenceClassification, bert_base_config
@@ -33,6 +35,7 @@ __all__ = [
     "Mistral4Config", "Mistral4ForCausalLM", "mistral4_test_config",
     "BrumbyConfig", "BrumbyForCausalLM", "brumby_test_config",
     "KeyeConfig", "KeyeForCausalLM", "keye_test_config",
+    "FalconH1Config", "FalconH1ForCausalLM", "falcon_h1_test_config",
     "LatentSpec", "LayerSpec", "ServingForm", "SparseSpec", "StateSpec",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "bert_base_config",

@@ -21,7 +21,18 @@ state layers, the third, `Mistral4ForCausalLM.serving_form()`
 `BrumbyForCausalLM.serving_form()` (models/brumby.py), the first with
 NO attention layer at all - every layer a `StateSpec`, its state updated
 in place in its slot - the fifth, `KeyeForCausalLM.serving_form()`
-(models/keye.py), the first with sparse layers, the sixth.
+(models/keye.py), the first with sparse layers, the sixth, and
+`FalconH1ForCausalLM.serving_form()` (models/falcon_h1.py), the first
+whose every layer keeps THREE memories side by side - its attention's K/V,
+a convolution's last inputs and a Mamba-2 state updated in place - the
+seventh.
+
+A layer names one spec, or a TUPLE of them when it keeps several memories
+side by side (an attention and a state-space mixer that read the same
+normed input in one block): the engine keeps each in its own group as if
+it were a layer of its own, and hands the layer step one function a spec,
+in the tuple's order.  Such a layer cannot be split into two: the second
+branch reads the residual as it stood before the first wrote to it.
 
 A `LatentSpec` names an attention layer whose cache keeps ONE row a token,
 a latent from which every head's key and value are made (multi-head latent
@@ -152,7 +163,7 @@ class StateSpec:
 
 class ServingForm:
     """Base of a model's serving form.  Subclasses set `layer_specs`
-    (one `LayerSpec` or `StateSpec` a layer), `vocab_size`,
+    (a spec a layer, or a tuple of specs), `vocab_size`,
     `max_position_embeddings` and `dtype`, and implement the four
     array-level functions."""
 
@@ -202,7 +213,11 @@ class ServingForm:
         attn_fn's], qi [B,S,index_heads,index_dim], ki [B,S,index_dim],
         w [B,S,index_heads] float32) -> (o, extra)`: the engine stores k,
         v and the index keys ki, and attends each query's `topk` keys of
-        largest `sum_j w_j relu(qi_j . ki)`.  `valid` [B] bool marks real
+        largest `sum_j w_j relu(qi_j . ki)`.  A layer whose spec is a
+        tuple takes one such function a spec in its place, in the tuple's
+        order (`layer(l, params, h, pos, fn_1, .., fn_n, valid=None)`),
+        and returns as `extra` what each hands back, concatenated in that
+        order.  `valid` [B] bool marks real
         rows of a padded decode batch, or [B, S] real positions where a
         prompt carries decode rows (a form whose every layer is a
         `LayerSpec`; None: all).  -> (h, extra, stats or None), `stats`

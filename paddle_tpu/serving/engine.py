@@ -29,7 +29,11 @@ short convolutions, models/lfm2.py); a spec that asks for it
 (`StateSpec.in_place`: a state of megabytes) is handed the pool itself and
 the rows' slot indices instead, and updates the rows' slots in it, so no
 program gathers or scatters the rows' states (brumby's power retention,
-models/brumby.py).  A model with no such layer builds
+models/brumby.py).  A layer that keeps several memories side by side
+names a tuple of specs, and its step is handed one function a spec, in
+that order, its pools travelling in the same order (Falcon-H1's attention
+beside a Mamba-2 mixer, a K/V group and two state groups a layer,
+models/falcon_h1.py).  A model with no such layer builds
 no state group and its programs are what they were.  A model whose EVERY
 layer names a `StateSpec` has no K/V group at all: `self.cache` is None,
 the programs take no table and no slot array, and admission, preemption
@@ -498,9 +502,14 @@ class LLMEngine:
         # reduces over the SAME padded extent as the dense oracle
         ring = -(-self.max_model_len // 128) * 128
         self.blocks_per_seq = -(-ring // c.block_size)
-        # cache groups, in the order the layers first name them; a layer's
-        # pools are entry `_layer_slot[l]` of its group's cache
-        specs = list(form.layer_specs)
+        # a layer names one spec, or a tuple of them when it keeps several
+        # memories side by side (`ServingForm.layer_specs`); `specs` is
+        # every spec in layer order, a tuple's in its own order
+        self._layer_specs = [tuple(s) if isinstance(s, tuple) else (s,)
+                             for s in form.layer_specs]
+        specs = [s for layer in self._layer_specs for s in layer]
+        # cache groups, in the order the specs first name them; a spec's
+        # pools are entry `_layer_slot[i]` of its group's cache
         self._groups: dict = {}          # K/V groups: name -> [LayerSpec]
         state_groups: dict = {}          # state groups: name -> [StateSpec]
         self._layer_slot = []
@@ -584,8 +593,8 @@ class LLMEngine:
                     wing="kv_cache").add(max(0, fp_cost
                                              - self.cache.pool_bytes))
         # a whole prompt's prefill carries the decode rows where every
-        # layer is plain K/V attention (module docstring, `prefill(P)`)
-        self._mix = (all(isinstance(s, LayerSpec) for s in specs)
+        # layer is plain K/V attention alone (module docstring, `prefill(P)`)
+        self._mix = (all(isinstance(s, LayerSpec) for s in form.layer_specs)
                      and not self.spec_tokens and c.max_num_seqs > 1)
         self.scheduler = Scheduler(
             self.kv, max_num_seqs=c.max_num_seqs,
@@ -761,7 +770,7 @@ class LLMEngine:
             phase: [m.counter(name).labels(phase=phase, **labels)
                     for name, labels in form.stat_counters]
             for phase in ("prefill", "decode")}
-        # every layer's (cache, index in it), in layer order
+        # every spec's (cache, index in it), in layer order
         self._layer_pools = [
             ((self.states if isinstance(spec, StateSpec)
               else self.caches)[spec.group], i)
@@ -2002,9 +2011,9 @@ class LLMEngine:
         return self.form.params()
 
     def _kv_flat(self):
-        """Every layer's pools in layer order: (k, v) an attention layer,
-        or (k, v, k_scales, v_scales) under int8; (state,) a state
-        layer."""
+        """Every layer's pools in layer order, a layer's specs in their
+        order: (k, v) an attention spec, or (k, v, k_scales, v_scales)
+        under int8; (state,) a state spec."""
         return tuple(getattr(c, n)[i] for c, i in self._layer_pools
                      for n in c.pool_names)
 
@@ -2091,28 +2100,32 @@ class LLMEngine:
     def _run_blocks(self, params, kv_flat, x, pos, attn_builder,
                     valid=None, srows=(), fresh=False):
         """Every layer of the form over `x`; `attn_builder(spec, group
-        index, *the layer's pools)` makes the attention an attention
-        layer's step calls, `_state_fn` what a state layer's step calls
+        index, *the spec's pools)` makes the attention an attention
+        spec's step calls, `_state_fn` what a state spec's step calls
         over its pool and its group's entry of `srows` (`fresh`: the rows
-        start from zeros, a whole-prompt prefill).  -> (h, the updated
+        start from zeros, a whole-prompt prefill).  A layer is handed one
+        such function a spec, in its specs' order.  -> (h, the updated
         pools in layer order, the layers' summed counts or None)."""
         groups, state_groups = list(self._groups), list(self.states)
         h = x
         outs = []
         stats = None
         at = 0
-        for l, spec in enumerate(self.form.layer_specs):
-            width = len(self._layer_pools[l][0].pool_names)
-            layer_kv = kv_flat[at:at + width]
-            at += width
-            if isinstance(spec, StateSpec):
-                attn_fn = self._state_fn(
-                    spec, layer_kv[0],
-                    srows[state_groups.index(spec.group)], fresh)
-            else:
-                attn_fn = attn_builder(spec, groups.index(spec.group),
-                                       *layer_kv)
-            h, extra, st = self.form.layer(l, params, h, pos, attn_fn,
+        pools = iter(self._layer_pools)
+        for l, specs in enumerate(self._layer_specs):
+            fns = []
+            for spec in specs:
+                width = len(next(pools)[0].pool_names)
+                layer_kv = kv_flat[at:at + width]
+                at += width
+                if isinstance(spec, StateSpec):
+                    fns.append(self._state_fn(
+                        spec, layer_kv[0],
+                        srows[state_groups.index(spec.group)], fresh))
+                else:
+                    fns.append(attn_builder(spec, groups.index(spec.group),
+                                            *layer_kv))
+            h, extra, st = self.form.layer(l, params, h, pos, *fns,
                                            valid=valid)
             outs += list(extra)
             if st is not None:

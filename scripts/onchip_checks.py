@@ -5,6 +5,7 @@
     chiprun -- python scripts/onchip_checks.py --writes [--tree DIR]
     chiprun -- python scripts/onchip_checks.py --sampler
     chiprun -- python scripts/onchip_checks.py --retention
+    python scripts/onchip_checks.py --ssd      # on a TPU host
 
 Every Pallas kernel on the main path goes through actual Mosaic
 compilation and is compared with its XLA reference at the widths of both
@@ -87,6 +88,15 @@ of the v5e's 197.  `--sparse --prefill` runs the prefill half alone;
 `--sparse --split` times that flash again at other tile sizes.  With
 `--aot` it compiles the decode and the 16,384 prefill.
 
+`--ssd` runs both Mamba-2 SSD kernels (`ops/mamba2_ssd.py`) at the
+Falcon-H1 cell's calls - decode: 64 rows x 32 heads of 128 over 2 groups of
+a 256-wide state, a `[65, 32, 256, 128]` float32 pool; prefill: one row of
+2,048 positions from a zero state and 2,048 more from the state that left -
+against the same mathematics in XLA at the highest matmul precision, then
+ms a call of each with the pool donated, the GB/s of state a decode call
+moves in and out and the TFLOP/s of a prefill call's SSD work.  With `--aot`
+it compiles the same calls and stops.
+
 `--aot` needs no chip: it compiles each kernel for a v5e topology
 description with the local libtpu (`jax.experimental.topologies`) and
 stops there.  That catches Mosaic refusals from a CPU-only sandbox; it
@@ -116,6 +126,7 @@ SAMPLER_ONLY = "--sampler" in sys.argv[1:]
 TIES_ONLY = "--ties" in sys.argv[1:]
 RETENTION_ONLY = "--retention" in sys.argv[1:]
 SPARSE_ONLY = "--sparse" in sys.argv[1:]
+SSD_ONLY = "--ssd" in sys.argv[1:]
 PREFILL_ONLY = "--prefill" in sys.argv[1:]
 AFMOE = dict(hq=48, hkv=8, d=128, window=4096, bs=64)
 _AOT_SHARDING = None
@@ -1097,6 +1108,98 @@ def check_retention(rows=24, hq=40, hkv=8, d=128, prompt=1024):
               flush=True)
 
 
+def check_ssd(rows=64, h=32, g=2, p=128, n=256, prompt=2048, chunk=128):
+    """Both Mamba-2 SSD kernels at the Falcon-H1 cell's calls against the
+    XLA form, then what a call of each costs: ms, the GB/s of state a
+    decode call moves, the TFLOP/s of a prefill call's SSD work."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mamba2_ssd as ssd
+
+    rng = np.random.RandomState(0)
+    bf = jnp.bfloat16
+    shape = ssd.state_shape(h, p, n)
+    a = -jnp.asarray(np.linspace(1.0, 16.0, h), jnp.float32)
+
+    def inputs(b, t):
+        x = _randn(rng, (b, t, h, p), bf)
+        dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                            (b, t, h))), jnp.float32)
+        bb, cc = (_randn(rng, (b, t, g, n), bf) for _ in range(2))
+        return x, dt, a, bb, cc
+
+    slots = jnp.asarray(rng.permutation(rows), jnp.int32)
+    one = slots[:1]
+
+    def prefill_kernel(fresh):
+        return lambda *args: ssd.ssd_prefill(*args, one, fresh, chunk)
+
+    def prefill_xla(fresh):
+        def ref(x, dt, a_, bb, cc, pool):
+            y, new = ssd.ssd_chunked(x, dt, a_, bb, cc,
+                                     None if fresh else pool[one], chunk=64)
+            return y, pool.at[one].set(new)
+        return ref
+
+    def step(args):
+        return tuple(v[:, 0] if v.ndim > 1 else v for v in args)
+
+    def decode_kernel(x, dt, a_, bb, cc, pool):
+        return ssd.ssd_decode(x, dt, a_, bb, cc, pool, slots)
+
+    def decode_xla(x, dt, a_, bb, cc, pool):
+        y, new = ssd._decode_math(x, dt, a_, bb, cc, pool[slots])
+        return y, pool.at[slots].set(new)
+
+    first, second = inputs(1, prompt), inputs(1, prompt)
+    one_step = step(inputs(rows, 1))
+    if AOT:
+        pool = np.zeros((rows + 1,) + shape, np.float32)
+        for fresh in (True, False):
+            _compile_only(prefill_kernel(fresh), first + (pool,))
+        _compile_only(decode_kernel, one_step + (pool,))
+        print("OK ssd kernels compile", flush=True)
+        return
+    pool = jnp.zeros((rows + 1,) + shape, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        _check("ssd_prefill_fresh", prefill_kernel(True), prefill_xla(True),
+               first + (pool,), tol=2e-2)
+        _, pool = jax.jit(prefill_xla(True))(*first, pool)
+        _check("ssd_prefill_carried", prefill_kernel(False),
+               prefill_xla(False), second + (pool,), tol=2e-2)
+        _, seeded = jax.jit(prefill_xla(False))(*second, pool)
+        # every slot a state of its own, as far as a prefill leaves one
+        pool = jnp.broadcast_to(seeded[one], (rows + 1,) + shape) \
+            * jnp.linspace(0.5, 1.5, rows + 1)[:, None, None, None]
+        _check("ssd_decode", decode_kernel, decode_xla,
+               one_step + (pool,), tol=2e-2)
+
+    def ms_a_call(fn, args, pool, reps=10):
+        """Wall time of `fn(*args, pool) -> (_, pool)`, the pool donated
+        and handed on as in the engine."""
+        fn = jax.jit(fn, donate_argnums=(len(args),))
+        _, pool = fn(*args, pool)
+        jax.block_until_ready(pool)
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out, pool = fn(*args, pool)
+        jax.block_until_ready((out, pool))
+        return (time.perf_counter() - t0) / reps * 1e3, pool
+
+    # the published SSD work a position (benchmark/lib/ssd_ops.py)
+    flop = 2 * (g * chunk * n + h * chunk * p + 2 * h * n * p)
+    for fresh in (True, False):
+        ms, pool = ms_a_call(prefill_kernel(fresh), first, pool)
+        print(f"ssd_prefill {prompt} positions, "
+              f"{'fresh' if fresh else 'carried'}: {ms:.3f} ms a call "
+              f"({flop * prompt / ms / 1e9:.2f} TFLOP/s of SSD work)",
+              flush=True)
+    moved = 2 * rows * int(np.prod(shape)) * 4
+    ms, pool = ms_a_call(decode_kernel, one_step, pool)
+    print(f"ssd_decode {rows} rows: {ms:.3f} ms a call "
+          f"({moved / ms / 1e6:.1f} GB/s of state in and out)", flush=True)
+
+
 SPARSE = dict(rows=12, heads=32, kv_heads=4, d=128, index_heads=16,
               index_dim=64, topk=2048, bs=64, blocks=8192, max_len=67584)
 V5E_TFLOPS = 197.0         # bf16 peak of one TPU v5e (Google Cloud docs)
@@ -1358,6 +1461,7 @@ def main():
         checks.append(("check_generate", check_generate, ()))
     if not AFMOE_ONLY:
         checks.append(("check_retention", check_retention, ()))
+        checks.append(("check_ssd", check_ssd, ()))
     if not AFMOE_ONLY:
         checks.append(("check_writes", check_writes, ()))
     if WRITES_ONLY:
@@ -1377,11 +1481,13 @@ def main():
         checks = [("check_retention", check_retention, ())]
     if SPARSE_ONLY:
         checks = [("check_sparse", check_sparse, ())]
+    if SSD_ONLY:
+        checks = [("check_ssd", check_ssd, ())]
     for name, fn, args in checks:
         with _Watchdog(name, 900.0 if fn in (check_writes, check_afmoe_engine,
                                              check_ragged_cell,
                                              check_latent_cell, check_ties,
-                                             check_retention,
+                                             check_retention, check_ssd,
                                              check_sparse) else 240.0):
             fn(*args)
     print("ALL AOT COMPILES OK" if AOT else "ALL ONCHIP CHECKS OK")

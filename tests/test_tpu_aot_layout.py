@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.ops import mamba2_ssd as ssd
 from paddle_tpu.ops import pallas_ops as po
 from paddle_tpu.ops import power_retention as pr
 from paddle_tpu.ops import ragged_paged_attention as rp
@@ -666,6 +667,88 @@ def test_sparse_engine_programs_fit_the_chip(one_chip, monkeypatch):
         assert header.count("-alias)") == len(kv), (name, header[:400])
 
 
+def test_hybrid_engine_programs_fit_the_chip(one_chip, monkeypatch):
+    """The Falcon-H1 cell's engine (6 layers at the published widths, the
+    whole 261,120-row vocabulary; `benchmark/traffic/shortchat-c64.json`'s
+    `EngineConfig`: 2,560 blocks of 64 and 65 state slots) compiles for the
+    described v5e: the decode program and the 2,048-token prefill, the
+    largest program the cell runs, fit the chip beside the weights (the
+    compiler refuses a program that does not), every pool - a layer's K, V,
+    convolution state and SSM state - goes in donated and comes out
+    aliased, and both take the attention and SSD kernels in every layer."""
+    import json
+
+    from benchmark.lib import family
+    from paddle_tpu.framework.compat import LazyGuard
+    from paddle_tpu.nn import layer as nn_layer
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+    from paddle_tpu.serving import kv_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(*parts):
+        with open(os.path.join(root, "benchmark", *parts)) as f:
+            return json.load(f)
+
+    config = load("configs", "falcon-h1-34b-l6.json")
+    engine = load("traffic", "shortchat-c64.json")["engine"]
+    monkeypatch.setattr(po, "_on_tpu", lambda: True)
+    monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+    monkeypatch.setenv("PTPU_ATTN_DEBUG", "1")
+    po.reset_attention_path_counts()
+
+    class ShapesOnly:       # 10.5 GB of weights and 3.7 GB of pools
+        def __init__(self, dtype=None):
+            self.dtype = dtype
+
+        def __getattr__(self, attr):
+            return getattr(jnp, attr)
+
+        def zeros(self, shape, dtype=None):
+            return jax.ShapeDtypeStruct(tuple(shape),
+                                        jnp.dtype(self.dtype or dtype))
+
+    with monkeypatch.context() as m, LazyGuard():
+        m.setattr(nn_layer, "jnp", ShapesOnly(jnp.bfloat16))
+        m.setattr(kv_cache, "jnp", ShapesOnly())
+        model = family.resolve(config["harness"]["model"])(
+            family.model_config(config))
+        for layer in model.sublayers(include_self=True):
+            layer._dtype = jnp.dtype(jnp.bfloat16)
+        eng = LLMEngine(model, EngineConfig(**engine))
+    assert list(eng.caches) == ["full"] and list(eng.states) == [
+        "conv", "ssm"]
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._param_arrays())
+    kv = jax.tree_util.tree_map(on_chip, eng._kv_flat())
+    assert [(p.shape, p.dtype) for p in kv[:4]] == [
+        ((2560, 64, 512), jnp.bfloat16), ((2560, 64, 512), jnp.bfloat16),
+        ((65, 3, 5120), jnp.bfloat16), ((65, 32, 256, 128), jnp.float32)]
+    assert len(kv) == 4 * 6
+    rows = engine["max_num_seqs"]
+    programs = {
+        "decode": eng._get_ragged_exec(rows, 1).lower(
+            params, kv, i32(rows, 1), i32(rows), i32(rows),
+            (i32(rows, eng.blocks_per_seq),), (i32(rows, 1),),
+            (i32(rows), i32(rows))).compile(),
+        "prefill": eng._get_prefill_exec(2048).lower(
+            params, kv, i32(1, 2048), (i32(1, 2048),),
+            (i32(1), i32(1))).compile()}
+    assert po.attention_path_counts() == {
+        "ragged_kernel": 6, "ragged_kernel:head_products": 6,
+        "ssd_decode_kernel": 6, "attn_kernel:grouped": 6,
+        "ssd_prefill_kernel": 6}
+    for name, compiled in programs.items():
+        header = compiled.as_text().split("\n", 1)[0]
+        assert header.count("-alias)") == len(kv), (name, header[:400])
+
+
 # -- PR 30: every kernel that stays compiles for the chip ---------------------
 
 def _flash(**kw):
@@ -706,6 +789,25 @@ def _retention_args(b, t, rows=24, hq=40, hkv=8, d=128):
     return [((b, t, hq, d), jnp.bfloat16), ((b, t, hkv, d), jnp.bfloat16),
             ((b, t, hkv, d), jnp.bfloat16), ((b, t, hkv), jnp.float32),
             ((rows + 1,) + pr.state_shape(hkv, d), jnp.float32)]
+
+
+def _ssd_decode(x, dt, a, b, c, pool, slots):
+    return ssd.ssd_decode(x, dt, a, b, c, pool, slots)
+
+
+def _ssd_prefill(fresh):
+    return lambda *args: ssd.ssd_prefill(*args, fresh)
+
+
+def _ssd_args(b, t, rows=64, h=32, g=2, p=128, n=256):
+    """One layer's call at the Falcon-H1 cell's widths: x, dt, A, B, C and
+    the `[rows + 1, 32, 256, 128]` float32 pool (one position a row without
+    the position axis)."""
+    at = (b, t) if t else (b,)
+    return [(at + (h, p), jnp.bfloat16), (at + (h,), jnp.float32),
+            ((h,), jnp.float32), (at + (g, n), jnp.bfloat16),
+            (at + (g, n), jnp.bfloat16),
+            ((rows + 1,) + ssd.state_shape(h, p, n), jnp.float32)]
 
 
 def _sparse_decode(q, qi, w, kp, vp, ip, tables, lens):
@@ -863,6 +965,16 @@ KERNEL_CASES = [
           _retention_args(1, 1024) + [((1,), jnp.int32)], 1),
     _case("retention_prefill_carried_brumby", _retention_prefill(False),
           _retention_args(1, 1024) + [((1,), jnp.int32)], 1),
+    # falcon-h1-34b-l6.shortchat-c64's calls: 64 rows of 32 mixer
+    # heads of 128 over 2 B/C groups of a 256-wide state against the
+    # float32 pool `[65, 32, 256, 128]`, one position a row; and a prompt's
+    # 2,048 positions from a zero state and from the state in the slot
+    _case("ssd_decode_falcon_h1", _ssd_decode,
+          _ssd_args(64, None) + [((64,), jnp.int32)], 1),
+    _case("ssd_prefill_fresh_falcon_h1", _ssd_prefill(True),
+          _ssd_args(1, 2048) + [((1,), jnp.int32)], 1),
+    _case("ssd_prefill_carried_falcon_h1", _ssd_prefill(False),
+          _ssd_args(1, 2048) + [((1,), jnp.int32)], 1),
     # keye-vl-2-30b-a3b-ep8-l8.longdoc-c12's calls: a decode step of 12
     # rows of 32-over-4 heads of 128 and 16 index heads of 64 against the
     # `[8192, 64, 512]` K/V pools and the `[8192, 64, 128]` index pool,
